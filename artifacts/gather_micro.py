@@ -137,7 +137,7 @@ if jax.devices()[0].platform == "tpu":
     # VMEM-resident pallas kernels at the REAL config #3 shapes: the
     # bf16 fused [k|v] table (10.2 MB, fits VMEM) and its cotangent.
     # Each measurement is individually guarded: a kernel failure must
-    # not discard the XLA numbers of an unattended vigil run.
+    # not discard the XLA numbers of an unattended run.
     from dragonfly2_tpu.ops.table_gather import (
         table_gather, table_scatter_add)
 

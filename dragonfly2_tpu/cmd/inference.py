@@ -33,8 +33,8 @@ def main(argv=None) -> int:
                              "dispatch (debugging; loses coalescing)")
     parser.add_argument("--batch-max-wait-s", type=float, default=0.0,
                         help="hold every batch open this long for "
-                             "stragglers (remote-device throughput mode; "
-                             "0 = never wait)")
+                             "stragglers (fuller dispatches at the cost "
+                             "of latency; 0 = never wait)")
     parser.add_argument("--batch-adaptive-wait-s", type=float,
                         default=0.0005,
                         help="open the batch window this long only when "
@@ -71,6 +71,11 @@ def main(argv=None) -> int:
     args = parse_with_config(parser, argv)
     init_logging(args.verbose, args.log_dir, service="inference")
     init_tracing(args, "inference")
+    # Before the first compile: every restart otherwise recompiles every
+    # scorer bucket.
+    from dragonfly2_tpu.utils.compilecache import enable_compilation_cache
+
+    print(f"compile cache: {enable_compilation_cache()}", flush=True)
 
     from dragonfly2_tpu.inference.sidecar import (
         INFERENCE_SPEC,

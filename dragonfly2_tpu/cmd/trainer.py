@@ -66,6 +66,11 @@ def main(argv=None) -> int:
     args = parse_with_config(parser, argv)
     init_logging(args.verbose, args.log_dir, service="trainer")
     init_tracing(args, "trainer")
+    # Before the first compile: every restart otherwise recompiles every
+    # train step (configuration only — no backend is touched here).
+    from dragonfly2_tpu.utils.compilecache import enable_compilation_cache
+
+    print(f"compile cache: {enable_compilation_cache()}", flush=True)
     # Joining a fleet must precede any other JAX use in the process.
     fleet_mesh = maybe_init_multihost(args)
 

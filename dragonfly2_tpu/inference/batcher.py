@@ -11,9 +11,9 @@ latency is one in-flight dispatch, and throughput scales to
 
 **Multi-lane sharding.** A single pipelined worker bounds throughput at
 one in-flight dispatch: past ~8 concurrent callers the tail is pure
-queueing growth behind that one worker (BENCH_r05: p99 1.5 ms @ 8
-threads → 14 ms @ 128). The batcher therefore shards into ``lanes``
-independent lanes — each lane owns its own request queue, worker
+queueing growth behind that one worker (seen on the CPU device: p99
+1.5 ms @ 8 threads → 14 ms @ 128). The batcher therefore shards into
+``lanes`` independent lanes — each lane owns its own request queue, worker
 thread, in-flight slot, and (via the scorer's staging pool, grown to
 ``2 × lanes`` buffers per bucket) its own staging capacity — so lane
 workers stage, dispatch, and retire concurrently instead of
@@ -59,10 +59,10 @@ Batch close is deadline-aware: by default (``max_wait_s=0``) a lane
 never waits — it blocks for the first request, then drains whatever
 queued while the previous dispatch ran (natural batching under load,
 zero added latency when idle). A positive ``max_wait_s`` lets the
-worker hold the batch open up to that long for stragglers — a
-throughput knob for remote/tunneled devices where dispatches are
-expensive — but the deadline is firm, so the knob bounds queueing delay
-instead of trading it away. ``adaptive_wait_s`` is the load-aware
+worker hold the batch open up to that long for stragglers — it trades
+per-request latency for fewer, fuller dispatches — but the deadline is
+firm, so the knob bounds queueing delay instead of trading it away
+(whether an attached chip ever wants it is ROADMAP C5's to measure). ``adaptive_wait_s`` is the load-aware
 version: the window only opens when the lane's queue-depth ladder
 detects strict growth, so the idle path keeps the zero-wait guarantee.
 """

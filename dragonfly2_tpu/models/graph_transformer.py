@@ -48,14 +48,13 @@ The model/scale targets come from BASELINE.md config #3.
 from __future__ import annotations
 
 import math
+from functools import partial
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
-
-from dragonfly2_tpu.parallel.mesh import ambient_mesh, shard_map_compat
 
 NEG_INF = -1e9
 # Neighbor-list pad sentinel: never inside [0, N) for any padded N, so a
@@ -64,11 +63,6 @@ PAD_ID = np.int32(2**30)
 
 
 def _mesh_empty() -> bool:
-    # jax ≤0.4.x has no abstract-mesh / explicit-sharding API at all, so
-    # no ambient mesh can exist — every sharding-aware branch below must
-    # take its plain (single-program, GSPMD-inferred) path there.
-    if not hasattr(jax.sharding, "get_abstract_mesh"):
-        return True
     return jax.sharding.get_abstract_mesh().empty
 
 
@@ -251,9 +245,7 @@ def ring_graph_attention(q, k, v, nbr, val, chunk, axis="data"):
     q/k/v: [N, heads, head_dim] row-sharded over ``axis``; nbr/val:
     [N, K] row-sharded. Requires an ambient mesh (jax.set_mesh).
     """
-    from functools import partial
-
-    mesh = ambient_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     if mesh.empty or axis not in mesh.shape:
         # No ambient mesh (e.g. model.init outside jax.set_mesh, or a
         # single-process run): the ring degenerates to the local chunked
@@ -268,7 +260,7 @@ def ring_graph_attention(q, k, v, nbr, val, chunk, axis="data"):
     scale = 1.0 / np.sqrt(q.shape[-1])
     spec3, spec2 = P(axis, None, None), P(axis, None)
 
-    @partial(shard_map_compat(), mesh=mesh,
+    @partial(jax.shard_map, mesh=mesh,
              in_specs=(spec3, spec3, spec3, spec2, spec2),
              out_specs=spec3)
     def run(ql, kl, vl, nbrl, vall):
@@ -305,8 +297,8 @@ def ring_graph_attention(q, k, v, nbr, val, chunk, axis="data"):
                 vj = jax.lax.dynamic_slice_in_dim(vb, j * block, block, 0)
                 bias, mask = _block_bias(
                     nbrl, vall, base_pos + j * block, block, local=True)
-                s = jnp.einsum("nhd,bhd->nhb", ql, kj).astype(
-                    jnp.float32) * scale
+                s = jnp.einsum("nhd,bhd->nhb", ql, kj,
+                               preferred_element_type=jnp.float32) * scale
                 s = s + bias[:, None, :]
                 s = jnp.where(mask[:, None, :], s, NEG_INF)
                 m_new = jnp.maximum(m, s.max(-1))
@@ -365,9 +357,7 @@ def build_inverse_index(nbr: np.ndarray) -> np.ndarray:
 
 def _neighbor_gather_impl(table, idx):
     """[N, h, d] table gathered to [N, K, h, d] by row indices."""
-    from dragonfly2_tpu.parallel import supports_out_sharding
-
-    if _mesh_empty() or not supports_out_sharding():
+    if _mesh_empty():
         return table[idx]
     # Rows shard over data; head/feature axes keep whatever sharding
     # the table carries (the 'model' axis under tensor parallelism).
@@ -443,15 +433,30 @@ def _single_device_tpu() -> bool:
     """Is this trace a single-device TPU program? (Pallas kernels are
     per-device; a >1-device mesh keeps the XLA paths that explicit
     sharding partitions.)"""
-    mesh = ambient_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     return ((mesh.empty or mesh.size == 1)
             and jax.devices()[0].platform == "tpu")
 
 
+def _per_device(fn, in_specs, out_specs):
+    """``fn`` as a per-device program. Under an ambient (explicit) mesh
+    a value's sharding is part of its type, and a pallas kernel refuses
+    to index refs typed as sharded — even over a one-device mesh — so
+    the kernel dispatchers run ``fn`` under ``shard_map``, where every
+    operand is a plain local array. Outside a mesh, ``fn`` itself.
+    ``check_vma`` is off because a kernel mixes row-varying operands
+    with replicated ones and ``pallas_call`` declares no variance."""
+    if _mesh_empty():
+        return fn
+    return jax.shard_map(fn, mesh=jax.sharding.get_abstract_mesh(),
+                         in_specs=in_specs, out_specs=out_specs,
+                         check_vma=False)
+
+
 def _pallas_gather_enabled(table) -> bool:
     """Gate for the VMEM-resident pallas gather: explicit opt-in, a
-    single-device TPU program, lane-aligned row width, and BOTH
-    directions' residents (bf16 table forward, column-chunked f32
+    single-device TPU program, lane-aligned row width, and the f32
+    column chunk BOTH directions keep resident (table forward,
     accumulator backward) within the VMEM budget."""
     import os
 
@@ -501,17 +506,12 @@ def gather_graph_attention(q, k, v, nbr, val, inv=None):
         # until the on-chip A/B (gather_micro_r5b) proves this faster.
         from dragonfly2_tpu.ops.table_gather import neighbor_gather_pallas
 
-        wide = 2 * heads * head_dim
-        if _mesh_empty():
-            kv2 = kv.reshape(n, wide)
-        else:
-            kv2 = jnp.reshape(kv, (n, wide), out_sharding=P(None, None))
-        kvg = neighbor_gather_pallas(kv2, idx)
-        if _mesh_empty():
-            kvg = kvg.reshape(n, -1, heads, 2 * head_dim)
-        else:
-            kvg = jnp.reshape(kvg, (n, idx.shape[1], heads, 2 * head_dim),
-                              out_sharding=P(None, None, None, None))
+        def gather_rows(kv_, idx_):
+            wide = neighbor_gather_pallas(
+                kv_.reshape(kv_.shape[0], -1), idx_)   # [n, K, heads·2d]
+            return wide.reshape(*idx_.shape, heads, 2 * head_dim)
+
+        kvg = _per_device(gather_rows, (P(), P("data")), P("data"))(kv, idx)
     elif inv is not None:
         # Scatter-free training path: custom backward via the host-built
         # inverse index (config #3 step 424 ms autodiff → 271 ms,
@@ -537,12 +537,21 @@ def blocks_graph_attention(q, k, v, nbr, val, chunk):
 
     if (_single_device_tpu()
             and not os.environ.get("DF2_DISABLE_GRAPH_FLASH")):
-        from dragonfly2_tpu.ops.flash_attention import graph_flash_attention
-
-        block = _flash_block(q.shape[0], chunk)
-        return graph_flash_attention(q, k, v, nbr, val,
-                                     block_q=block, block_k=block)
+        return _graph_flash(q, k, v, nbr, val, chunk, interpret=False)
     return sparse_graph_attention(q, k, v, nbr, val, chunk)
+
+
+def _graph_flash(q, k, v, nbr, val, chunk, interpret):
+    """The pallas graph-flash kernel over row-local queries and
+    neighbor lists against full-width K/V."""
+    from dragonfly2_tpu.ops.flash_attention import graph_flash_attention
+
+    block = _flash_block(q.shape[0], chunk)
+    rows = P("data")
+    return _per_device(
+        partial(graph_flash_attention, block_q=block, block_k=block,
+                interpret=interpret),
+        (rows, P(), P(), rows, rows), rows)(q, k, v, nbr, val)
 
 
 def _flash_block(n: int, chunk: int) -> int:
@@ -575,7 +584,12 @@ def sparse_graph_attention(q, k, v, nbr, val, chunk):
         kj = jax.lax.dynamic_slice_in_dim(k, start, block, axis=0)
         vj = jax.lax.dynamic_slice_in_dim(v, start, block, axis=0)
         bias, mask = _block_bias(nbr, val, start, block)     # [N, block]
-        s = jnp.einsum("nhd,bhd->nhb", q, kj).astype(jnp.float32) * scale
+        # f32 straight from the MXU, not a bf16 product converted up: on
+        # the v5e (libtpu 0.0.34) the VJP of the row max below over a
+        # converted bf16 dot came back NaN in every element of dq and dk
+        # (found by PR 21's chip run; tests_tpu pins it).
+        s = jnp.einsum("nhd,bhd->nhb", q, kj,
+                       preferred_element_type=jnp.float32) * scale
         s = s + bias[:, None, :]
         s = jnp.where(mask[:, None, :], s, NEG_INF)
         m_new = jnp.maximum(m, s.max(-1))
@@ -712,13 +726,8 @@ class GraphAttentionBlock(nn.Module):
             elif self.attention == "flash":
                 # Force the pallas kernel (interpret-mode off TPU) —
                 # hermetic kernel tests and A/B benchmarks use this.
-                from dragonfly2_tpu.ops.flash_attention import (
-                    graph_flash_attention,
-                )
-
-                block = _flash_block(q.shape[0], self.chunk)
-                out = graph_flash_attention(
-                    q, k, v, nbr, val, block_q=block, block_k=block,
+                out = _graph_flash(
+                    q, k, v, nbr, val, self.chunk,
                     interpret=jax.devices()[0].platform != "tpu")
             else:
                 out = blocks_graph_attention(q, k, v, nbr, val, self.chunk)

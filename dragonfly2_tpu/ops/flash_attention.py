@@ -90,7 +90,10 @@ def chunked_attention(q, k, v, causal: bool = False, block: int = 512):
         start = j * block
         kj = jax.lax.dynamic_slice_in_dim(k, start, block, 0)
         vj = jax.lax.dynamic_slice_in_dim(v, start, block, 0)
-        s = jnp.einsum("nhd,mhd->hnm", q, kj).astype(jnp.float32) * scale
+        # f32 from the dot itself: see sparse_graph_attention (the VJP
+        # of a max over a converted bf16 dot is NaN on the v5e).
+        s = jnp.einsum("nhd,mhd->hnm", q, kj,
+                       preferred_element_type=jnp.float32) * scale
         k_pos = start + jnp.arange(block)
         mask = (k_pos < t)[None, None, :]
         if causal:
@@ -177,7 +180,11 @@ def _pallas_forward(q, k, v, causal: bool, t_real: int,
             pl.BlockSpec((1, block_k, d), lambda h, i, j: (h, j, 0)),
         ],
         out_specs=pl.BlockSpec((1, block_q, d), lambda h, i, j: (h, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((heads, t, d), q.dtype),
+        # Inside a shard_map (parallel/ulysses.py) the output varies
+        # over the same mesh axes as the operands; the replication check
+        # refuses an out_shape that does not say so.
+        out_shape=jax.ShapeDtypeStruct((heads, t, d), q.dtype,
+                                       vma=jax.typeof(q).vma),
         scratch_shapes=[
             pltpu.VMEM((block_q,), jnp.float32),       # running max
             pltpu.VMEM((block_q,), jnp.float32),       # running sum
@@ -246,16 +253,24 @@ flash_attention.defvjp(_fwd, _bwd)
 # ----------------------------------------------------------------------
 
 
+# Query rows per step of the in-kernel bias scatter: one f32 sublane
+# tile, so a step's [rows, block_k] bias stays in vector registers
+# across the K statically unrolled slot selects.
+_SCATTER_ROWS = 8
+
+
 def _graph_kernel(q_ref, k_ref, v_ref, nbr_ref, val_ref, o_ref,
-                  m_ref, l_ref, acc_ref, *, block_k: int):
+                  m_ref, l_ref, acc_ref, bias_ref, *, block_k: int):
     """One (head, q-block, k-block) tile: scatter this tile's bias/mask
     from the q-rows' neighbor lists, then the online-softmax update.
 
-    The scatter runs as a fori_loop over the K neighbor slots — each
-    iteration one [block_q, block_k] one-hot compare — so no
-    [block_q, K, block_k] intermediate ever materializes in VMEM.
-    Slots are deduped host-side (build_neighbor_lists), so add is exact;
-    PAD_ID slots are out of range of every block and contribute nothing.
+    The scatter fills ``bias_ref`` [block_q, block_k] eight query rows
+    at a time: per row group, one [8, block_k] select per neighbor slot,
+    the K slots unrolled statically (Mosaic lowers no dynamic slice of a
+    loaded value, and a whole-tile unroll spills K live tiles). Slots are
+    deduped host-side (build_neighbor_lists), so select is exact; PAD_ID
+    slots match no column of any block. Untouched columns keep NEG_INF,
+    which doubles as the mask (a listed bias is a finite -log1p(rtt)).
     """
     j = pl.program_id(2)
     n_k = pl.num_programs(2)
@@ -266,32 +281,30 @@ def _graph_kernel(q_ref, k_ref, v_ref, nbr_ref, val_ref, o_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
+    k_start = j * block_k
+    cols_iota = jax.lax.broadcasted_iota(
+        jnp.int32, (_SCATTER_ROWS, block_k), 1)
+
+    def scatter_rows(r, carry):
+        rows = pl.ds(pl.multiple_of(r * _SCATTER_ROWS, _SCATTER_ROWS),
+                     _SCATTER_ROWS)
+        col = nbr_ref[rows, :] - k_start               # [8, K] int32
+        valb = val_ref[rows, :]                        # [8, K] f32
+        bias = jnp.full(cols_iota.shape, NEG_INF, jnp.float32)
+        for kk in range(col.shape[1]):
+            bias = jnp.where(cols_iota == col[:, kk:kk + 1],
+                             valb[:, kk:kk + 1], bias)
+        bias_ref[rows, :] = bias
+        return carry
+
+    jax.lax.fori_loop(0, bias_ref.shape[0] // _SCATTER_ROWS,
+                      scatter_rows, 0)
+
     q = q_ref[0]                                       # [bq, d]
     kb = k_ref[0]                                      # [bk, d]
     vb = v_ref[0]
-    nbrb = nbr_ref[...]                                # [bq, K] int32
-    valb = val_ref[...]                                # [bq, K] f32
-    k_start = j * block_k
-
-    col = nbrb - k_start                               # [bq, K]
-    in_rng = (col >= 0) & (col < block_k)
-    cols_iota = jax.lax.broadcasted_iota(
-        jnp.int32, (q.shape[0], block_k), 1)           # [bq, bk]
-
-    def slot(kk, carry):
-        bias, hit = carry
-        c = jax.lax.dynamic_index_in_dim(col, kk, axis=1, keepdims=True)
-        ok = jax.lax.dynamic_index_in_dim(in_rng, kk, axis=1,
-                                          keepdims=True)
-        vv = jax.lax.dynamic_index_in_dim(valb, kk, axis=1, keepdims=True)
-        onehot = (cols_iota == c) & ok                 # [bq, bk]
-        return bias + jnp.where(onehot, vv, 0.0), hit | onehot
-
-    bias, hit = jax.lax.fori_loop(
-        0, nbrb.shape[1], slot,
-        (jnp.zeros_like(cols_iota, jnp.float32),
-         jnp.zeros_like(cols_iota, jnp.bool_)))
-
+    bias = bias_ref[...]                               # [bq, bk]
+    hit = bias > NEG_INF
     scale = 1.0 / np.sqrt(q.shape[-1])
     s = jax.lax.dot_general(
         q, kb, (((1,), (1,)), ((), ())),
@@ -330,9 +343,22 @@ def graph_flash_attention(q, k, v, nbr, val, block_q=128, block_k=128,
     return out
 
 
+def _graph_vmem_limit(block_q: int, block_k: int, kw: int) -> int:
+    """Scoped-VMEM request for one graph tile. The default scoped limit
+    (16 MiB on v5e, of 128 MiB physical) is what the compiler's own
+    [block_q, block_k] f32 temporaries (scores, probabilities, mask)
+    overrun at block 1024 once K reaches 128: bound them as six tiles
+    beside the bias scratch's one, plus the double-buffered neighbor
+    blocks (lane-padded to 128) and slack for the q/k/v/out blocks."""
+    tile = block_q * block_k * 4
+    lists = 2 * 2 * block_q * max(kw, 128) * 4
+    return max(16 << 20, 7 * tile + lists + (4 << 20))
+
+
 def _graph_fwd(q, k, v, nbr, val, block_q, block_k, interpret):
     n_q, heads, d = q.shape
     n_k = k.shape[0]
+    assert block_q % _SCATTER_ROWS == 0, block_q
     on_tpu = jax.devices()[0].platform == "tpu"
     if not (on_tpu or interpret):
         from dragonfly2_tpu.models.graph_transformer import (
@@ -374,7 +400,10 @@ def _graph_fwd(q, k, v, nbr, val, block_q, block_k, interpret):
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, block_k), jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_graph_vmem_limit(block_q, block_k, kw)),
         interpret=interpret,
     )(qp, kp, vp, nbrp, valp)
     return jnp.moveaxis(out, 0, 1)[:n_q], (q, k, v, nbr, val)

@@ -7,14 +7,7 @@ annotations. No explicit allreduce calls anywhere in the framework — we
 annotate, XLA lays out the collectives.
 """
 
-from dragonfly2_tpu.parallel.mesh import (
-    MeshContext,
-    ambient_mesh,
-    data_parallel_mesh,
-    mesh_context,
-    shard_map_compat,
-    supports_out_sharding,
-)
+from dragonfly2_tpu.parallel.mesh import MeshContext, data_parallel_mesh
 from dragonfly2_tpu.parallel.moe import moe_apply
 from dragonfly2_tpu.parallel.multihost import (
     MultihostMeshContext,
@@ -31,8 +24,6 @@ from dragonfly2_tpu.parallel.ring_attention import ring_attention
 from dragonfly2_tpu.parallel.ulysses import ulysses_attention
 
 __all__ = ["MeshContext", "MultihostMeshContext", "agree",
-           "ambient_mesh", "data_parallel_mesh", "init_multihost",
-           "mesh_context", "moe_apply",
+           "data_parallel_mesh", "init_multihost", "moe_apply",
            "multihost_mesh", "pipeline_apply", "ring_attention",
-           "shard_map_compat", "supports_out_sharding",
            "stack_stage_params", "sync", "ulysses_attention"]

@@ -42,6 +42,18 @@ class MeshContext:
     def replicated(self) -> NamedSharding:
         return NamedSharding(self.mesh, P())
 
+    @property
+    def serialize_launches(self) -> bool:
+        """True on a multi-device CPU mesh. XLA:CPU's in-process
+        collectives deadlock when several launches of a multi-device
+        program are in flight at once (the rendezvous starves the shared
+        thread pool: "expected 8 threads ... only 7 arrived", then the
+        process aborts) — a step loop with little host work per step
+        stacks launches through async dispatch, so on this platform only
+        it blocks on each step. Real TPU collectives pipeline fine."""
+        return (self.mesh.devices.flat[0].platform == "cpu"
+                and self.mesh.size > 1)
+
     def shard_spec(self, *axes: str | None) -> NamedSharding:
         return NamedSharding(self.mesh, P(*axes))
 
@@ -54,97 +66,6 @@ class MeshContext:
 
     def put_replicated(self, tree):
         return jax.tree.map(lambda a: jax.device_put(a, self.replicated), tree)
-
-
-_OUT_SHARDING_SUPPORTED: bool | None = None
-
-
-def supports_out_sharding() -> bool:
-    """True when this jax exposes the explicit-sharding gather keyword
-    (``x.at[idx].get(out_sharding=...)``). Probed ONCE with a trivial
-    eager gather — older jax (≤0.4.x) raises TypeError on the unknown
-    keyword, in which case callers fall back to plain ``table[idx]``
-    under the mesh context and let GSPMD infer the output sharding.
-    The fallback is semantically identical; the explicit form only
-    pins the no-collective local-gather partitioning."""
-    global _OUT_SHARDING_SUPPORTED
-    if _OUT_SHARDING_SUPPORTED is None:
-        import jax.numpy as jnp
-
-        try:
-            jnp.zeros(2).at[jnp.zeros((1,), jnp.int32)].get(out_sharding=None)
-            _OUT_SHARDING_SUPPORTED = True
-        except TypeError:
-            _OUT_SHARDING_SUPPORTED = False
-    return _OUT_SHARDING_SUPPORTED
-
-
-_SHARD_MAP_FN = None
-
-
-def shard_map_compat():
-    """The ``shard_map`` entry point of this jax, probed once —
-    top-level ``jax.shard_map`` where it exists, else the
-    ``jax.experimental.shard_map`` original (same ``mesh``/``in_specs``/
-    ``out_specs`` keyword surface on both, so call sites are written
-    once against the newer name)."""
-    global _SHARD_MAP_FN
-    if _SHARD_MAP_FN is None:
-        fn = getattr(jax, "shard_map", None)
-        if fn is None:
-            from jax.experimental.shard_map import shard_map as fn
-        _SHARD_MAP_FN = fn
-    return _SHARD_MAP_FN
-
-
-def ambient_mesh():
-    """The ambient mesh of the current trace: the explicit-sharding
-    abstract mesh on newer jax, the ``with mesh:`` thread-resources
-    physical mesh on ≤0.4.x. Both expose ``empty``/``shape``/``size``,
-    so sharded kernels can gate their collective paths identically on
-    either tree."""
-    get_abstract = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_abstract is not None:
-        return get_abstract()
-    from jax._src import mesh as mesh_lib
-
-    return mesh_lib.thread_resources.env.physical_mesh
-
-
-def pvary_compat(x, axis):
-    """Mark ``x`` varying over ``axis`` inside a shard_map body —
-    ``jax.lax.pcast`` / ``jax.lax.pvary`` where this jax has them.
-    On ≤0.4.x neither exists and the value is returned unchanged;
-    callers disable the replication check instead (see
-    :func:`shard_map_unchecked_kwargs`), which is the only thing the
-    varying mark feeds."""
-    pcast = getattr(jax.lax, "pcast", None)
-    if pcast is not None:
-        return pcast(x, axis, to="varying")
-    pvary = getattr(jax.lax, "pvary", None)
-    if pvary is not None:
-        return pvary(x, axis)
-    return x
-
-
-def shard_map_unchecked_kwargs() -> dict:
-    """Extra shard_map kwargs for bodies whose carries need the varying
-    mark: empty where :func:`pvary_compat` can mark them, else
-    ``check_rep=False`` for the ≤0.4.x experimental shard_map (whose
-    replication check would reject the unmarked per-device carries)."""
-    if hasattr(jax.lax, "pcast") or hasattr(jax.lax, "pvary"):
-        return {}
-    return {"check_rep": False}
-
-
-def mesh_context(mesh):
-    """``jax.set_mesh(mesh)`` where this jax has it (explicit-sharding
-    ambient mesh), else the classic ``Mesh`` context manager — which is
-    exactly what :func:`ambient_mesh` reads back on those trees."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return mesh
 
 
 def data_parallel_mesh(

@@ -30,8 +30,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from dragonfly2_tpu.parallel.mesh import shard_map_compat
-
 
 def moe_apply(
     expert_fn: Callable,
@@ -76,7 +74,7 @@ def moe_apply(
     t_loc = t_total // n_exp
     capacity = max(int(np.ceil(t_loc / n_exp * capacity_factor)), 1)
 
-    @partial(shard_map_compat(), mesh=mesh,
+    @partial(jax.shard_map, mesh=mesh,
              in_specs=(P(axis), P(axis, None), P(axis, None)),
              out_specs=P(axis, None))
     def run(params_local, xl, gl):

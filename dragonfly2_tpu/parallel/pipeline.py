@@ -38,12 +38,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from dragonfly2_tpu.parallel.mesh import (
-    pvary_compat,
-    shard_map_compat,
-    shard_map_unchecked_kwargs,
-)
-
 
 def check_stacked(params, n: int, axis: str, name: str, unit: str) -> None:
     """Every leaf's leading dim must equal the mesh axis size — with a
@@ -96,9 +90,8 @@ def pipeline_apply(
     n_steps = m + n_stages - 1
     perm = [(i, (i + 1) % n_stages) for i in range(n_stages)]
 
-    @partial(shard_map_compat(), mesh=mesh,
-             in_specs=(P(axis), P(None)), out_specs=P(None),
-             **shard_map_unchecked_kwargs())
+    @partial(jax.shard_map, mesh=mesh,
+             in_specs=(P(axis), P(None)), out_specs=P(None))
     def run(params_local, x_all):
         # params_local leaves: [1, ...] — this device's stage.
         params_s = jax.tree.map(lambda p: p[0], params_local)
@@ -106,8 +99,9 @@ def pipeline_apply(
         # The carries differ per stage from step one, so their init
         # must already be marked varying over the axis or the scan
         # rejects the carry type.
-        carry_act = pvary_compat(jnp.zeros_like(x_all[0]), axis)
-        out_buf = pvary_compat(jnp.zeros_like(x_all), axis)
+        carry_act = jax.lax.pcast(jnp.zeros_like(x_all[0]), axis,
+                                  to="varying")
+        out_buf = jax.lax.pcast(jnp.zeros_like(x_all), axis, to="varying")
 
         def step(carry, t):
             act, out = carry

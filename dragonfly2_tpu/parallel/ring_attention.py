@@ -35,7 +35,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from dragonfly2_tpu.parallel.mesh import shard_map_compat
 
 NEG_INF = -1e9
 
@@ -78,7 +77,7 @@ def ring_attention(
     qk = "bnhd,bmhd->bhnm" if batched else "nhd,mhd->hnm"
     pv = "bhnm,bmhd->bnhd" if batched else "hnm,mhd->nhd"
 
-    @partial(shard_map_compat(), mesh=mesh,
+    @partial(jax.shard_map, mesh=mesh,
              in_specs=(seq_spec, seq_spec, seq_spec, valid_spec),
              out_specs=seq_spec)
     def run(ql, kl, vl, validl):
@@ -97,7 +96,8 @@ def ring_attention(
         for step in range(n_dev):
             src_idx = (my_idx - step) % n_dev                # block owner
             k_pos = src_idx * t_loc + jnp.arange(t_loc)      # global cols
-            s = jnp.einsum(qk, ql, kb).astype(jnp.float32) * inv_scale
+            s = jnp.einsum(qk, ql, kb,
+                           preferred_element_type=jnp.float32) * inv_scale
             # mask shape [(B,)1?,n,m] matching s [(B,)h,n,m]
             block_mask = validb[..., None, None, :] if s.ndim == 4 \
                 else validb[None, None, :]

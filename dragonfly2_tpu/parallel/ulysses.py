@@ -35,8 +35,6 @@ from typing import Optional
 import jax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from dragonfly2_tpu.parallel.mesh import shard_map_compat
-
 
 def _local_attention(q, k, v, causal: bool, chunk: int, use_flash: bool):
     """Full-sequence attention on ONE device: [T, h, d] → [T, h, d] —
@@ -87,7 +85,7 @@ def ulysses_attention(
         use_flash = mesh.devices.flat[0].platform == "tpu"
     seq_spec = P(axis, None, None)
 
-    @partial(shard_map_compat(), mesh=mesh, in_specs=(seq_spec,) * 3,
+    @partial(jax.shard_map, mesh=mesh, in_specs=(seq_spec,) * 3,
              out_specs=seq_spec)
     def run(ql, kl, vl):
         # [T/d, H, D] → [T, H/d, D]: sequence gathers, heads scatter.

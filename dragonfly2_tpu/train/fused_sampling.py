@@ -34,7 +34,7 @@ import optax
 
 from dragonfly2_tpu.data.graph_sampler import CSRGraph
 from dragonfly2_tpu.models.graphsage import GraphSAGE
-from dragonfly2_tpu.parallel import MeshContext, supports_out_sharding
+from dragonfly2_tpu.parallel import MeshContext
 
 
 class GraphTables(NamedTuple):
@@ -77,10 +77,7 @@ def put_edge_tables(src: np.ndarray, dst: np.ndarray, labels: np.ndarray,
 
 
 def _gather(table: jax.Array, idx: jax.Array, out_sharding) -> jax.Array:
-    # Older jax (≤0.4.x) lacks the explicit out_sharding keyword; the
-    # plain gather under the same in_shardings lets GSPMD infer the
-    # identical local-gather partitioning (see supports_out_sharding).
-    if out_sharding is None or not supports_out_sharding():
+    if out_sharding is None:
         return table[idx]
     return table.at[idx].get(out_sharding=out_sharding)
 
@@ -202,10 +199,10 @@ def make_fused_multi_step(model: GraphSAGE, mesh: MeshContext,
     """jit: (state, graph, edges, edge_ids[K, B], key) → (state, losses[K]).
 
     K fused steps under one ``lax.scan`` — one dispatch amortizes the
-    host→device round trip across K optimizer updates. On a remote/
-    tunneled accelerator (or any host-bound pipeline) per-step dispatch
-    is the throughput ceiling; scan moves the loop onto the device the
-    XLA-idiomatic way (no Python control flow in the compiled program).
+    host→device round trip across K optimizer updates. Where per-step
+    dispatch is the throughput ceiling (a host-bound pipeline), scan
+    moves the loop onto the device the XLA-idiomatic way (no Python
+    control flow in the compiled program).
     """
     b = mesh.batch_sharding
     ids_sharding = mesh.shard_spec(None, "data")  # [K, B]: B over data

@@ -38,11 +38,7 @@ from dragonfly2_tpu.models.graph_transformer import (
     pad_graph_sparse,
     pad_multiple,
 )
-from dragonfly2_tpu.parallel import (
-    MeshContext,
-    data_parallel_mesh,
-    mesh_context,
-)
+from dragonfly2_tpu.parallel import MeshContext, data_parallel_mesh
 from dragonfly2_tpu.train.gnn_trainer import edge_split
 from dragonfly2_tpu.train.metrics import metrics_from_confusion, padded_chunks
 
@@ -71,10 +67,10 @@ class GATTrainConfig:
     attention: str = "gather"
     # >1 runs this many optimizer steps per dispatch under lax.scan —
     # the same dispatch amortization the GNN path uses
-    # (gnn_trainer.steps_per_call): on a remote/tunneled accelerator the
-    # per-dispatch round trip bounds throughput, and the GAT step's
-    # edge minibatches are tiny next to the resident graph tensors, so
-    # stacking K of them per call is nearly free.
+    # (gnn_trainer.steps_per_call): one host→device round trip per K
+    # updates. The GAT step's edge minibatches are tiny next to the
+    # resident graph tensors, so stacking K of them per call is nearly
+    # free.
     steps_per_call: int = 1
     # Shared step-loop accounting (see GNNTrainConfig): wall cap for the
     # step loop plus incremental publishing hooks.
@@ -281,7 +277,7 @@ def train_gat(
     stop = False
     # Explicit-sharding mode: the in-model reshards (K/V + embedding
     # all-gathers, block-bias scatter) need the ambient mesh during trace.
-    with mesh_context(mesh.mesh):
+    with jax.set_mesh(mesh.mesh):
         # Full-k groups plus one tail dispatch for the remainder — no
         # silently dropped steps when k ∤ steps_per_epoch (the tail is a
         # second, smaller scan program; compiled once).
@@ -312,6 +308,8 @@ def train_gat(
                     rep_put(graph.edge_dst[ids_k].astype(np.int32)),
                     rep_put(labels_all[ids_k]),
                 )
+                if mesh.serialize_launches:
+                    jax.block_until_ready(loss_k)
                 losses.append(loss_k)
                 if budget.tick(gk * batch, jnp.mean(loss_k),
                                new_program=new_prog):

@@ -73,9 +73,9 @@ class GNNTrainConfig:
     # (equivalence tests, and graphs too large for replicated HBM tables).
     device_sample: bool = True
     # >1 runs this many optimizer steps per dispatch under lax.scan
-    # (device_sample only): amortizes host→device round trips when
-    # dispatch latency bounds throughput (remote/tunneled accelerators).
-    # Budget checks and progress publishing then happen per dispatch.
+    # (device_sample only): one host→device round trip per K updates,
+    # for when dispatch latency bounds throughput. Budget checks and
+    # progress publishing then happen per dispatch.
     steps_per_call: int = 1
     prefetch_depth: int = 2
     prefetch_workers: int = 2
@@ -135,9 +135,7 @@ def apply_indexed(model: GraphSAGE, params, node_features, center_idx,
     own index shard locally — no collective); single-device jit leaves
     ``out_sharding`` None.
     """
-    from dragonfly2_tpu.parallel import supports_out_sharding
-
-    if out_sharding is None or not supports_out_sharding():
+    if out_sharding is None:
         def gather(idx):
             return node_features[idx]
     else:
@@ -275,13 +273,6 @@ def train_gnn(
             fused_step = make_fused_train_step(model, mesh, config.fanouts)
         base_key = mesh.put_replicated(jax.random.key(config.seed + 1))
         train_step = None
-        # The fused step has near-zero host work, so async dispatch stacks
-        # many in-flight launches. XLA:CPU's in-process collectives
-        # deadlock under that (rendezvous starves the shared thread pool —
-        # observed on the 8-device virtual mesh); real TPU collectives
-        # pipeline fine. Serialize launches on CPU only.
-        serialize_steps = (
-            mesh.mesh.devices.flat[0].platform == "cpu" and mesh.n_data > 1)
     else:
         train_step = make_train_step(model, mesh)
 
@@ -351,10 +342,10 @@ def train_gnn(
             if config.device_sample:
                 state, loss = fused_step(
                     state, graph_tables, train_edges, arrays, base_key)
-                if serialize_steps:
-                    jax.block_until_ready(loss)
             else:
                 state, loss = train_step(state, nf_dev, *arrays)
+            if mesh.serialize_launches:
+                jax.block_until_ready(loss)
             epoch_losses.append(jnp.mean(loss) if group > 1 else loss)
             if budget.tick(batch_size * group, loss):
                 stream.close()

@@ -163,6 +163,8 @@ def train_mlp(
                                            epoch=epoch):
                 state, loss = train_step(state, mesh.put_batch(bx),
                                          mesh.put_batch(by))
+                if mesh.serialize_launches:
+                    jax.block_until_ready(loss)
                 losses.append(loss)
                 if budget.tick(len(bx), loss):
                     stop = True

@@ -109,6 +109,8 @@ class TrainOutcome:
     mlp_evaluation: dict = field(default_factory=dict)
     gat_evaluation: dict = field(default_factory=dict)
     cost_evaluation: dict = field(default_factory=dict)
+    # model type -> per-epoch mean training loss of this cycle's job.
+    loss_history: dict = field(default_factory=dict)
     errors: list = field(default_factory=list)
 
 
@@ -233,6 +235,7 @@ class Training:
                     "fanouts": list(result.config.fanouts)},
         )
         outcome.gnn_model_id = model_id
+        outcome.loss_history["gnn"] = list(result.history)
         outcome.gnn_evaluation = evaluation
 
     def _train_gat(self, ip, hostname, host_id, scheduler_id,
@@ -277,6 +280,7 @@ class Training:
                     "chunk": result.config.chunk},
         )
         outcome.gat_model_id = model_id
+        outcome.loss_history["gat"] = list(result.history)
         outcome.gat_evaluation = evaluation
 
     def _train_mlp(self, ip, hostname, host_id, scheduler_id, files,
@@ -308,6 +312,7 @@ class Training:
             config={"hidden": list(result.config.hidden)},
         )
         outcome.mlp_model_id = model_id
+        outcome.loss_history["mlp"] = list(result.history)
         outcome.mlp_evaluation = evaluation
 
     def _train_cost(self, ip, hostname, host_id, scheduler_id, files,
@@ -342,6 +347,7 @@ class Training:
             config={"hidden": list(result.config.hidden)},
         )
         outcome.cost_model_id = model_id
+        outcome.loss_history["cost"] = list(result.history)
         outcome.cost_evaluation = evaluation
 
     def _register(self, model_id, model_type, host_id, ip, hostname,

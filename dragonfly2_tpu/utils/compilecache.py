@@ -1,57 +1,41 @@
-"""Persistent XLA compilation cache (round-2 verdict weak item 3).
+"""Persistent XLA compilation cache.
 
-Every fresh process on the chip repays ~25 s of train-step compile; JAX's
-persistent compilation cache amortizes that across bench runs, services,
-and the smoke tier. The reference has no equivalent (its training path is
-a stub); this is TPU-operational plumbing, same spirit as the reference's
-pprof/jaeger bootstrap (cmd/dependency/dependency.go:95-130).
+Every fresh process on the chip repays the train-step compiles (tens of
+seconds each); JAX's persistent compilation cache amortizes them across
+service restarts, bench runs and the smoke tier. The reference has no
+equivalent (its training path is a stub); this is TPU-operational
+plumbing, same spirit as the reference's pprof/jaeger bootstrap
+(cmd/dependency/dependency.go:95-130).
 
-Call :func:`enable_compilation_cache` before the first compile. Safe to
-call multiple times and safe on machines where the cache dir is not
-writable (falls back to no cache rather than failing the caller).
+Call :func:`enable_compilation_cache` before the first compile. The
+directory is part of the cache key, so it is never derived from a pid,
+a clock or a temporary path: ``$JAX_COMPILATION_CACHE_DIR`` where that
+is set (and then no other), else ``<checkout>/.jax_cache``.
 """
 
 from __future__ import annotations
 
-import logging
 import os
+import tempfile
 
 _DEFAULT_DIR = os.path.join(os.path.dirname(os.path.dirname(
     os.path.dirname(os.path.abspath(__file__)))), ".jax_cache")
 
-_enabled = False
 
-
-def enable_compilation_cache(cache_dir: str = "") -> str:
-    """Point JAX at a persistent on-disk compilation cache.
-
-    Priority: explicit arg > $JAX_COMPILATION_CACHE_DIR > <repo>/.jax_cache.
-    Returns the directory used ("" when disabled by failure).
-    """
-    global _enabled
-    if _enabled and not cache_dir:
-        # Already configured and no explicit override requested.
-        import jax
-
-        return jax.config.jax_compilation_cache_dir or ""
-    cache_dir = (cache_dir or os.environ.get("JAX_COMPILATION_CACHE_DIR", "")
-                 or _DEFAULT_DIR)
-    try:
-        os.makedirs(cache_dir, exist_ok=True)
-        probe = os.path.join(cache_dir, ".writable")
-        with open(probe, "w"):
-            pass
-        os.remove(probe)
-    except OSError:
-        logging.getLogger(__name__).warning(
-            "compilation cache dir %s not writable; cache disabled", cache_dir)
-        return ""
+def enable_compilation_cache() -> str:
+    """Point JAX at the persistent on-disk compilation cache and return
+    its directory. Raises ``OSError`` when the directory cannot be
+    created or written: a process that silently recompiles everything
+    is a slow start nobody can see."""
+    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR") or _DEFAULT_DIR
+    os.makedirs(cache_dir, exist_ok=True)
+    with tempfile.TemporaryFile(dir=cache_dir):
+        pass
     import jax
 
     jax.config.update("jax_compilation_cache_dir", cache_dir)
-    # Cache everything: small entries and fast compiles still pay dispatch
-    # repeatedly across the bench's subprocess probes and service restarts.
+    # Cache everything: small entries and fast compiles are still paid
+    # again by every service restart.
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    _enabled = True
     return cache_dir

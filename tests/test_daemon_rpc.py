@@ -213,7 +213,10 @@ class TestRemoteSeedPeer:
             from dragonfly2_tpu.utils import idgen
 
             task_id = idgen.task_id_v1(origin.url("seeded.bin"))
-            assert seed.storage.find_completed_task(task_id) is not None
+            # The record above can be the PEER's: the seed marks its own
+            # copy complete a beat later under load.
+            assert wait_for(lambda: seed.storage.find_completed_task(
+                task_id) is not None, timeout=15.0)
         finally:
             peer.stop()
             seed_rpc.stop()

@@ -49,6 +49,32 @@ class TestSynthetic:
         assert records_to_table(Download, downloads).num_rows == 10
         assert records_to_table(NetworkTopology, topo).num_rows == 10
 
+    @pytest.mark.parametrize("n_dest", [None, 5])
+    def test_bulk_topology_csv_is_the_record_path(self, tmp_path, n_dest):
+        """``write_topology_csv`` and ``topology`` are one generator: the
+        same seed gives, byte for byte, the file ``CsvRecordWriter``
+        writes from the record objects — so the trainer parses a bulk
+        dataset exactly as it parses a scheduler's."""
+        from dragonfly2_tpu.schema.io import CsvRecordWriter, read_csv_records
+
+        records = SyntheticCluster(n_hosts=50, seed=3).topology(200, n_dest)
+        bulk, reference = (str(tmp_path / name) for name in ("b.csv", "r.csv"))
+        n_edges = SyntheticCluster(n_hosts=50, seed=3).write_topology_csv(
+            200, bulk, n_dest)
+        with CsvRecordWriter(NetworkTopology, reference) as writer:
+            for record in records:
+                writer.write(record)
+        with open(bulk, "rb") as a, open(reference, "rb") as b:
+            assert a.read() == b.read()
+        assert list(read_csv_records(NetworkTopology, bulk)) == records
+        assert n_edges == sum(len(r.dest_hosts) for r in records)
+        assert n_edges == 200 * 5 if n_dest else 200 < n_edges < 200 * 5
+        for record in records:
+            assert all(d.id != record.host.id and d.probes.average_rtt > 0
+                       for d in record.dest_hosts)
+        graph = graph_from_table(records_to_table(NetworkTopology, records))
+        assert graph.n_edges == n_edges and graph.n_nodes == 50
+
     def test_deterministic(self):
         a = SyntheticCluster(n_hosts=32, seed=3).pair_example_columns(100)
         b = SyntheticCluster(n_hosts=32, seed=3).pair_example_columns(100)

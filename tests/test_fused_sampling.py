@@ -207,16 +207,42 @@ class TestFusedTraining:
 
 
 class TestCompileCache:
-    def test_enable_points_jax_at_dir(self, tmp_path):
+    @pytest.fixture(autouse=True)
+    def _restore_jax_config(self):
         import jax
 
+        names = ("jax_compilation_cache_dir",
+                 "jax_persistent_cache_min_compile_time_secs",
+                 "jax_persistent_cache_min_entry_size_bytes")
+        saved = {n: getattr(jax.config, n) for n in names}
+        yield
+        for name, value in saved.items():
+            jax.config.update(name, value)
+
+    @pytest.mark.parametrize("from_env", [True, False])
+    def test_env_var_else_checkout_dir(self, tmp_path, monkeypatch,
+                                       from_env):
+        import os
+
+        import jax
+
+        import dragonfly2_tpu
         from dragonfly2_tpu.utils.compilecache import enable_compilation_cache
 
-        d = str(tmp_path / "cache")
-        assert enable_compilation_cache(d) == d
-        assert jax.config.jax_compilation_cache_dir == d
+        if from_env:
+            want = str(tmp_path / "cache")
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", want)
+        else:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+            want = os.path.join(os.path.dirname(os.path.dirname(
+                os.path.abspath(dragonfly2_tpu.__file__))), ".jax_cache")
+        assert enable_compilation_cache() == want
+        assert jax.config.jax_compilation_cache_dir == want
+        assert os.path.isdir(want)
 
-    def test_unwritable_dir_disables_not_raises(self):
+    def test_unwritable_dir_raises(self, monkeypatch):
         from dragonfly2_tpu.utils.compilecache import enable_compilation_cache
 
-        assert enable_compilation_cache("/proc/nope/cache") == ""
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/proc/nope/cache")
+        with pytest.raises(OSError):
+            enable_compilation_cache()

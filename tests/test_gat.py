@@ -13,7 +13,6 @@ import jax
 import numpy as np
 import pytest
 
-from dragonfly2_tpu.parallel.mesh import mesh_context
 from dragonfly2_tpu.data import SyntheticCluster
 from dragonfly2_tpu.models.graph_transformer import (
     PAD_ID,
@@ -222,7 +221,7 @@ class TestTraining:
                     p, f_, nb_, vl_,
                     method=GraphTransformer.node_embeddings)
 
-            with mesh_context(mesh.mesh):
+            with jax.set_mesh(mesh.mesh):
                 return np.asarray(run(
                     result.params,
                     jax.device_put(f, row), jax.device_put(nb, row),
@@ -294,7 +293,7 @@ class TestTraining:
             return model.apply(p, f_, nb_, vl_,
                                method=GraphTransformer.node_embeddings)
 
-        with mesh_context(mesh.mesh):
+        with jax.set_mesh(mesh.mesh):
             # Plain (unsharded) host arrays, mesh ambient.
             params = model.init(jax.random.key(0), f, nb, vl,
                                 jnp.zeros(2, jnp.int32),
@@ -416,7 +415,7 @@ class TestInverseIndex:
                 jax.device_put(feats, row), jax.device_put(nbr, row),
                 jax.device_put(val, row),
                 None if inv is None else jax.device_put(inv, row))
-        with mesh_context(mesh.mesh):
+        with jax.set_mesh(mesh.mesh):
             return grad_fn(*args)
 
     def _assert_close(self, g0, g1):
@@ -478,7 +477,7 @@ class TestScale:
         params = model.init(
             jax.random.key(0), t_feat, t_nbr, t_val,
             jnp.zeros(2, jnp.int32), jnp.zeros(2, jnp.int32))
-        with mesh_context(mesh.mesh):
+        with jax.set_mesh(mesh.mesh):
             # Commit params replicated: the backward's kernel-grad dot
             # contracts over the data-sharded row axis, and explicit
             # mode resolves its psum only when the weights carry an
@@ -553,7 +552,7 @@ class TestScale:
             params = model.init(
                 jax.random.key(0), tf, tn, tv,
                 jnp.zeros(2, jnp.int32), jnp.zeros(2, jnp.int32))
-            with mesh_context(mesh.mesh):
+            with jax.set_mesh(mesh.mesh):
                 # Replicate-commit params: the backward's kernel-grad
                 # dot contracts over the sharded row axis and needs
                 # explicitly-replicated weights to place its psum.

@@ -13,7 +13,6 @@ import jax
 import numpy as np
 import pytest
 
-from dragonfly2_tpu.parallel.mesh import mesh_context
 from dragonfly2_tpu.data import SyntheticCluster
 from dragonfly2_tpu.models.graph_transformer import (
     GraphTransformer,
@@ -44,12 +43,6 @@ def dp_result(graph):
 
 
 class TestTensorParallel:
-    @pytest.mark.skipif(
-        not hasattr(jax, "set_mesh"),
-        reason="TP/DP trajectory identity needs the explicit-sharding "
-               "ambient mesh (jax.set_mesh); on ≤0.4.x the in-model "
-               "reshards degrade to GSPMD-inferred placements, which "
-               "train correctly but walk a different loss path")
     def test_tp_training_matches_data_parallel(self, graph, dp_result):
         """Same seed, same batches: a (4 data × 2 model) mesh must walk
         the same loss trajectory as pure data parallelism — weight
@@ -82,7 +75,7 @@ class TestTensorParallel:
             return model.apply(p, f_, nb_, vl_,
                                method=GraphTransformer.node_embeddings)
 
-        with mesh_context(mesh_tp.mesh):
+        with jax.set_mesh(mesh_tp.mesh):
             row = mesh_tp.shard_spec("data")
             params_tp = jax.device_put(
                 result.params, tp_state_shardings(result.params, mesh_tp))

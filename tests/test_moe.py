@@ -12,7 +12,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from dragonfly2_tpu.parallel.mesh import mesh_context
 from dragonfly2_tpu.parallel.moe import moe_apply
 from dragonfly2_tpu.parallel.pipeline import stack_stage_params
 
@@ -95,7 +94,7 @@ class TestMoE:
             return (moe_apply(expert_fn, p, x, g, mesh=mesh,
                               capacity_factor=8.0) ** 2).sum()
 
-        with mesh_context(mesh):
+        with jax.set_mesh(mesh):
             gp, gg = jax.jit(jax.grad(loss, argnums=(0, 1)))(params, gates)
         assert all(np.isfinite(np.asarray(l)).all()
                    for l in jax.tree.leaves(gp))

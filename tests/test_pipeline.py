@@ -11,7 +11,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from dragonfly2_tpu.parallel.mesh import mesh_context
 from dragonfly2_tpu.parallel.pipeline import (
     pipeline_apply,
     stack_stage_params,
@@ -84,7 +83,7 @@ class TestPipeline:
         def seq_loss(p):
             return ((sequential(p, x) - y) ** 2).mean()
 
-        with mesh_context(mesh):
+        with jax.set_mesh(mesh):
             g_pipe = jax.jit(jax.grad(pipe_loss))(params)
         g_seq = jax.grad(seq_loss)(params)
         for a, b in zip(jax.tree.leaves(g_pipe), jax.tree.leaves(g_seq)):

@@ -12,7 +12,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from dragonfly2_tpu.parallel.mesh import mesh_context
 from dragonfly2_tpu.parallel import data_parallel_mesh
 from dragonfly2_tpu.parallel.ring_attention import ring_attention
 
@@ -85,7 +84,7 @@ class TestRingAttention:
     def test_grad_matches_dense(self, mesh):
         q, k, v = _qkv((32, 2, 8), seed=4)
 
-        with mesh_context(mesh):
+        with jax.set_mesh(mesh):
             ring_grads = jax.jit(jax.grad(
                 lambda q, k, v: (ring_attention(
                     q, k, v, mesh=mesh, causal=True) ** 2).sum(),

@@ -13,7 +13,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from dragonfly2_tpu.parallel.mesh import mesh_context
 from dragonfly2_tpu.parallel import (
     data_parallel_mesh,
     ring_attention,
@@ -56,7 +55,7 @@ class TestUlyssesAttention:
 
     def test_grad_matches_dense(self, mesh):
         q, k, v = _qkv((32, 8, 4), seed=3)
-        with mesh_context(mesh):
+        with jax.set_mesh(mesh):
             grads = jax.jit(jax.grad(
                 lambda q, k, v: (ulysses_attention(
                     q, k, v, mesh=mesh, causal=True) ** 2).sum(),
