@@ -5,11 +5,17 @@ Usage::
     df2-trace-tool analyze TRACE_DIR [TRACE_DIR...]   # slowest first
     df2-trace-tool analyze --task-id T --json DIR     # one task, JSON
     df2-trace-tool list DIR                           # one line per task
+    df2-trace-tool train DUMP_DIR [--json]            # a train loop's profile
 
-Reads the rotated ``trace-*.jsonl`` files every service writes under
+``analyze`` and ``list`` read the rotated ``trace-*.jsonl`` files every service writes under
 ``--trace-dir`` (tail-sampled: SLO-breaching tasks are always present),
 stitches spans by trace id, and names each task's dominant critical-path
-contributor (docs/OBSERVABILITY.md).
+contributor (docs/OBSERVABILITY.md). ``train`` reads a JAX profiler
+dump of a train loop (``df2-trainer --profile-dir``, or a benchmark
+cell's ``.bench_trace/<cell>/``): device time per step under each
+``df2.*`` scope, the ``df2.train.*`` host spans per thread, and the
+longest device idle gaps by the span the loop was in
+(docs/OBSERVABILITY.md "Training loops").
 """
 
 from __future__ import annotations
@@ -32,7 +38,26 @@ def main(argv=None) -> int:
                        help="machine-readable output")
         p.add_argument("--limit", type=int, default=0,
                        help="at most N traces (0 = all)")
+    p = sub.add_parser("train")
+    p.add_argument("dump", help="profile dir (its newest *.xplane.pb is "
+                                "read) or one .xplane.pb")
+    p.add_argument("--json", action="store_true",
+                   help="machine-readable output")
+    p.add_argument("--scope", default="",
+                   help="pattern of a scope's name in an operation's path "
+                        "(default: the df2.* scopes)")
     args = parser.parse_args(argv)
+
+    if args.command == "train":
+        import re
+
+        from dragonfly2_tpu import traintrace
+
+        more = {"scope": re.compile(args.scope)} if args.scope else {}
+        report = traintrace.analyze(args.dump, **more)
+        print(json.dumps(report, indent=2) if args.json
+              else traintrace.format_report(report))
+        return 0
 
     from dragonfly2_tpu.tracetool import analyze_dirs, format_report
 

@@ -42,9 +42,11 @@ def main(argv=None) -> int:
                              "announcer stream EOF (0 = off; cycles and "
                              "skips counted in TrainerMetrics)")
     parser.add_argument("--profile-dir", default="",
-                        help="run train-step loops under "
-                             "jax.profiler.trace; XPlane dumps land here "
-                             "(inspect with tensorboard/xprof)")
+                        help="run every model job under "
+                             "jax.profiler.trace; XPlane dumps land in "
+                             "<dir>/<model>/ with the loops' df2.train.* "
+                             "spans and df2.* scopes (read with "
+                             "df2-trace-tool train, or tensorboard/xprof)")
     parser.add_argument("--federated-quorum", type=int, default=0,
                         help="K-of-N quorum for federated rounds driven "
                              "from the training cycle (0 = federation "
@@ -101,10 +103,8 @@ def main(argv=None) -> int:
     if args.profile_dir or args.train_gat:
         from dragonfly2_tpu.trainer.training import TrainingConfig
 
-        training_config = TrainingConfig(train_gat_model=args.train_gat)
-        if args.profile_dir:
-            training_config.gnn.profile_dir = args.profile_dir
-            training_config.mlp.profile_dir = args.profile_dir
+        training_config = TrainingConfig(train_gat_model=args.train_gat,
+                                         profile_dir=args.profile_dir)
     service = TrainerService(
         storage,
         Training(storage, registry, config=training_config,

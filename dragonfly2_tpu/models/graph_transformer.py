@@ -356,14 +356,16 @@ def build_inverse_index(nbr: np.ndarray) -> np.ndarray:
 
 
 def _neighbor_gather_impl(table, idx):
-    """[N, h, d] table gathered to [N, K, h, d] by row indices."""
-    if _mesh_empty():
-        return table[idx]
-    # Rows shard over data; head/feature axes keep whatever sharding
-    # the table carries (the 'model' axis under tensor parallelism).
-    tspec = _value_spec(table)
-    spec = P("data", None, *tspec[1:])
-    return table.at[idx].get(out_sharding=spec)
+    """[N, h, d] table gathered to [N, K, h, d] by row indices; the
+    operations carry the scope ``df2.attn.gather``."""
+    with jax.named_scope("df2.attn.gather"):
+        if _mesh_empty():
+            return table[idx]
+        # Rows shard over data; head/feature axes keep whatever sharding
+        # the table carries (the 'model' axis under tensor parallelism).
+        tspec = _value_spec(table)
+        spec = P("data", None, *tspec[1:])
+        return table.at[idx].get(out_sharding=spec)
 
 
 @jax.custom_vjp
@@ -389,6 +391,15 @@ def _neighbor_gather_fwd(table, idx, inv):
 
 
 def _neighbor_gather_bwd(inv, ct):
+    # A scope of its own, opened here: the forward's does not reach a
+    # custom backward, and this is the phase a device trace is most
+    # often asked about (the cotangent's flatten and the inverse-index
+    # gather).
+    with jax.named_scope("df2.attn.gather_bwd"):
+        return _inverse_index_gather(inv, ct)
+
+
+def _inverse_index_gather(inv, ct):
     n, k_width = ct.shape[0], ct.shape[1]
     heads, width = ct.shape[2], ct.shape[3]
     # Gather whole [heads*width]-wide rows: at config #3 head_dim is 32,

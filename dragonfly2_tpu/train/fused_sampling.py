@@ -145,22 +145,57 @@ def sample_and_apply(model: GraphSAGE, params, graph: GraphTables,
 
     ``key`` only seeds two SCALAR salts (tiny replicated threefry); the
     per-slot randomness comes from the counter hash above.
+
+    The phases carry ``df2.*`` scopes (``df2.sample.hop1``,
+    ``df2.sample.hop2``, ``df2.features``, ``df2.model``): metadata on
+    the operations, by which ``df2-trace-tool train`` splits a device
+    trace (docs/OBSERVABILITY.md "Training loops").
     """
     f1, f2 = fanouts
     k1, k2 = jax.random.split(key)
     s1 = jax.random.bits(k1, (), jnp.uint32)
     s2 = jax.random.bits(k2, (), jnp.uint32)
     centers = jnp.stack([src, dst], axis=-1)                     # [B, 2]
-    nbr1, rtt1, mask1 = sample_neighbors(graph, centers, f1, s1, out_sharding)
-    nbr2, rtt2, mask2 = sample_neighbors(graph, nbr1, f2, s2, out_sharding)
-    mask2 = mask2 * mask1[..., None]
-    return model.apply(
-        params,
-        _gather(graph.node_features, centers, out_sharding),
-        _gather(graph.node_features, nbr1, out_sharding), rtt1, mask1,
-        _gather(graph.node_features, nbr2, out_sharding),
-        rtt2 * mask2, mask2,
-    )
+    with jax.named_scope("df2.sample.hop1"):
+        nbr1, rtt1, mask1 = sample_neighbors(
+            graph, centers, f1, s1, out_sharding)
+    with jax.named_scope("df2.sample.hop2"):
+        nbr2, rtt2, mask2 = sample_neighbors(
+            graph, nbr1, f2, s2, out_sharding)
+        mask2 = mask2 * mask1[..., None]
+    with jax.named_scope("df2.features"):
+        feat0 = _gather(graph.node_features, centers, out_sharding)
+        feat1 = _gather(graph.node_features, nbr1, out_sharding)
+        feat2 = _gather(graph.node_features, nbr2, out_sharding)
+    with jax.named_scope("df2.model"):
+        return model.apply(params, feat0, feat1, rtt1, mask1,
+                           feat2, rtt2 * mask2, mask2)
+
+
+def _batch_rows(edges: EdgeTables, edge_ids, out_sharding):
+    """(src, dst, labels) of one id batch, under ``df2.batch``."""
+    with jax.named_scope("df2.batch"):
+        return tuple(_gather(table, edge_ids, out_sharding)
+                     for table in edges)
+
+
+def _fused_update(model: GraphSAGE, state, graph: GraphTables,
+                  edges: EdgeTables, edge_ids, key, fanouts: tuple,
+                  out_sharding):
+    """One optimizer step on one id batch: the body the one-step and the
+    multi-step programs share, so both carry the same scopes."""
+    src, dst, labels = _batch_rows(edges, edge_ids, out_sharding)
+
+    def loss_fn(params):
+        logits = sample_and_apply(
+            model, params, graph, src, dst, key, fanouts, out_sharding)
+        with jax.named_scope("df2.loss"):
+            return optax.sigmoid_binary_cross_entropy(logits, labels).mean()
+
+    loss, grads = jax.value_and_grad(loss_fn)(state.params)
+    with jax.named_scope("df2.optimizer"):
+        state = state.apply_gradients(grads=grads)
+    return state, loss
 
 
 def make_fused_train_step(model: GraphSAGE, mesh: MeshContext,
@@ -174,17 +209,8 @@ def make_fused_train_step(model: GraphSAGE, mesh: MeshContext,
 
     def train_step(state, graph, edges, edge_ids, key):
         key = jax.random.fold_in(key, state.step)
-        src = _gather(edges.src, edge_ids, b)
-        dst = _gather(edges.dst, edge_ids, b)
-        labels = _gather(edges.labels, edge_ids, b)
-
-        def loss_fn(params):
-            logits = sample_and_apply(
-                model, params, graph, src, dst, key, fanouts, b)
-            return optax.sigmoid_binary_cross_entropy(logits, labels).mean()
-
-        loss, grads = jax.value_and_grad(loss_fn)(state.params)
-        return state.apply_gradients(grads=grads), loss
+        return _fused_update(model, state, graph, edges, edge_ids, key,
+                             fanouts, b)
 
     return jax.jit(
         train_step,
@@ -208,24 +234,12 @@ def make_fused_multi_step(model: GraphSAGE, mesh: MeshContext,
     ids_sharding = mesh.shard_spec(None, "data")  # [K, B]: B over data
 
     def multi_step(state, graph, edges, edge_ids_k, key):
-        def body(carry, edge_ids):
-            state = carry
+        def body(state, edge_ids):
             step_key = jax.random.fold_in(key, state.step)
-            src = _gather(edges.src, edge_ids, b)
-            dst = _gather(edges.dst, edge_ids, b)
-            labels = _gather(edges.labels, edge_ids, b)
+            return _fused_update(model, state, graph, edges, edge_ids,
+                                 step_key, fanouts, b)
 
-            def loss_fn(params):
-                logits = sample_and_apply(
-                    model, params, graph, src, dst, step_key, fanouts, b)
-                return optax.sigmoid_binary_cross_entropy(
-                    logits, labels).mean()
-
-            loss, grads = jax.value_and_grad(loss_fn)(state.params)
-            return state.apply_gradients(grads=grads), loss
-
-        state, losses = jax.lax.scan(body, state, edge_ids_k)
-        return state, losses
+        return jax.lax.scan(body, state, edge_ids_k)
 
     return jax.jit(
         multi_step,
@@ -245,9 +259,7 @@ def make_fused_eval_step(model: GraphSAGE, mesh: MeshContext,
     def eval_step(params, graph, edges, edge_ids, weights, key):
         # Caller folds a per-chunk key (slicing a sharded edge_ids inside
         # the program would force an unimplementable reshard).
-        src = _gather(edges.src, edge_ids, b)
-        dst = _gather(edges.dst, edge_ids, b)
-        labels = _gather(edges.labels, edge_ids, b)
+        src, dst, labels = _batch_rows(edges, edge_ids, b)
         logits = sample_and_apply(
             model, params, graph, src, dst, key, fanouts, b)
         pred = (logits > 0).astype(jnp.float32)

@@ -93,6 +93,8 @@ class GATTrainResult:
     accuracy: float
     samples_per_sec: float
     history: list = field(default_factory=list)
+    steps: int = 0
+    compile_seconds: float = 0.0
 
     @property
     def model(self) -> GraphTransformer:
@@ -235,13 +237,20 @@ def train_gat(
         def body(st, batch):
             src, dst, y = batch
 
+            # df2.* scopes: metadata by which ``df2-trace-tool train``
+            # splits a device trace (the attention gathers name
+            # themselves, models/graph_transformer.py).
             def loss_fn(params):
-                logits = st.apply_fn(params, feat, nbr_, val_, src, dst,
-                                     inv=inv_)
-                return optax.sigmoid_binary_cross_entropy(logits, y).mean()
+                with jax.named_scope("df2.model"):
+                    logits = st.apply_fn(params, feat, nbr_, val_, src, dst,
+                                         inv=inv_)
+                with jax.named_scope("df2.loss"):
+                    return optax.sigmoid_binary_cross_entropy(
+                        logits, y).mean()
 
             loss, grads = jax.value_and_grad(loss_fn)(st.params)
-            return st.apply_gradients(grads=grads), loss
+            with jax.named_scope("df2.optimizer"):
+                return st.apply_gradients(grads=grads), loss
 
         return jax.lax.scan(body, state, (src_k, dst_k, y_k))
 
@@ -267,14 +276,17 @@ def train_gat(
     def rep_put(a):
         return jax.device_put(np.asarray(a), rep)
 
-    from dragonfly2_tpu.train.step_budget import StepBudget
+    from dragonfly2_tpu.train.step_budget import StepBudget, epoch_mean
 
     rng = np.random.default_rng((config.seed, 7))
     history = []
     budget = StepBudget(config.max_seconds,
                         on_compile=config.compile_callback,
-                        on_progress=config.progress_callback)
+                        on_progress=config.progress_callback,
+                        step_samples=batch)
+    span = jax.profiler.TraceAnnotation
     stop = False
+    step_num = 0
     # Explicit-sharding mode: the in-model reshards (K/V + embedding
     # all-gathers, block-bias scatter) need the ambient mesh during trace.
     with jax.set_mesh(mesh.mesh):
@@ -285,41 +297,54 @@ def train_gat(
         if steps_per_epoch % k:
             group_sizes.append(steps_per_epoch % k)
         seen_gk: set = set()
-        for _ in range(config.epochs):
+        for epoch in range(config.epochs):
             order = rng.permutation(train_ids)
             losses = []  # per-STEP losses ([gk] arrays), k-invariant
             offset = 0
             for gk in group_sizes:
                 ids = order[offset * batch:(offset + gk) * batch]
-                offset += gk
                 if len(ids) < gk * batch:
                     break
-                ids_k = ids.reshape(gk, batch)
-                # The tail group (k ∤ steps_per_epoch) is a second scan
-                # program; its mid-run compile must be excluded from the
-                # throughput window like the first step's is.
-                new_prog = gk not in seen_gk
-                if new_prog:
-                    seen_gk.add(gk)
-                    budget.sync_point(state.params)
-                state, loss_k = train_step(
-                    state, g_feat, g_nbr, g_val, g_inv,
-                    rep_put(graph.edge_src[ids_k].astype(np.int32)),
-                    rep_put(graph.edge_dst[ids_k].astype(np.int32)),
-                    rep_put(labels_all[ids_k]),
-                )
-                if mesh.serialize_launches:
-                    jax.block_until_ready(loss_k)
-                losses.append(loss_k)
-                if budget.tick(gk * batch, jnp.mean(loss_k),
-                               new_program=new_prog):
-                    stop = True
+                # Host spans on the profiler's clock (free while no
+                # profiler runs); docs/OBSERVABILITY.md "Training loops".
+                with jax.profiler.StepTraceAnnotation(
+                        "df2.train.step", step_num=step_num):
+                    with span("df2.train.input", epoch=epoch, step=offset):
+                        ids_k = ids.reshape(gk, batch)
+                        src_k = rep_put(graph.edge_src[ids_k].astype(np.int32))
+                        dst_k = rep_put(graph.edge_dst[ids_k].astype(np.int32))
+                        y_k = rep_put(labels_all[ids_k])
+                    # The tail group (k ∤ steps_per_epoch) is a second
+                    # scan program; its mid-run compile must be excluded
+                    # from the throughput window like the first step's is.
+                    new_prog = gk not in seen_gk
+                    if new_prog:
+                        seen_gk.add(gk)
+                        budget.sync_point(state.params)
+                    with span("df2.train.dispatch"):
+                        state, loss_k = train_step(
+                            state, g_feat, g_nbr, g_val, g_inv,
+                            src_k, dst_k, y_k)
+                    if mesh.serialize_launches:
+                        jax.block_until_ready(loss_k)
+                    losses.append(loss_k)
+                    # A launch of its own and host work: not the tick's.
+                    mean_loss = jnp.mean(loss_k)
+                    with span("df2.train.tick"):
+                        stop = budget.tick(gk * batch, mean_loss,
+                                           new_program=new_prog)
+                offset += gk
+                step_num += 1
+                if stop:
                     break
             if losses:
-                history.append(float(jnp.mean(jnp.concatenate(losses))))
+                # A host sync: it waits for every queued step.
+                with span("df2.train.epoch_end"):
+                    history.append(epoch_mean(losses))
             if stop:
                 break
-        jax.block_until_ready(state.params)
+        with span("df2.train.drain"):
+            jax.block_until_ready(state.params)
         budget.finish()
 
         # Exact eval in fixed-size chunks with a zero-weighted tail.
@@ -346,4 +371,6 @@ def train_gat(
         accuracy=metrics["accuracy"],
         samples_per_sec=budget.samples_per_sec(batch),
         history=history,
+        steps=budget.steps,
+        compile_seconds=budget.compile_seconds,
     )

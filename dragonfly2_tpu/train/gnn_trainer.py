@@ -31,7 +31,7 @@ from flax.training import train_state
 from dragonfly2_tpu.data.features import Graph
 from dragonfly2_tpu.data.graph_sampler import CSRGraph, EdgeBatchSampler
 from dragonfly2_tpu.data.prefetch import prefetch
-from dragonfly2_tpu.train.step_budget import StepBudget
+from dragonfly2_tpu.train.step_budget import StepBudget, epoch_mean
 from dragonfly2_tpu.models.graphsage import GraphSAGE
 from dragonfly2_tpu.parallel import MeshContext, data_parallel_mesh
 
@@ -79,9 +79,6 @@ class GNNTrainConfig:
     steps_per_call: int = 1
     prefetch_depth: int = 2
     prefetch_workers: int = 2
-    # When set, the step loop runs under jax.profiler.trace writing an
-    # XPlane dump here (the reference's pprof/jaeger flag equivalent).
-    profile_dir: str = ""
 
 
 @dataclass
@@ -299,7 +296,15 @@ def train_gnn(
                     yield epoch, gi, np.stack(
                         [order[s:s + batch_size] for s in chunk])
 
+    span = jax.profiler.TraceAnnotation
+
     def build(task):
+        # On a prefetch worker's thread; (epoch, step) joins the span to
+        # the loop's df2.train.step.
+        with span("df2.train.input", epoch=task[0], step=task[1]):
+            return build_inputs(task)
+
+    def build_inputs(task):
         # Per-task RNG: deterministic regardless of worker interleaving.
         epoch, step, ids = task
         if config.device_sample:
@@ -311,14 +316,13 @@ def train_gnn(
         rng = np.random.default_rng((config.seed, epoch, step, 3))
         return epoch, place(train_sampler.sample_indices(ids, rng))
 
-    import contextlib
-
     history: list = []
     epoch_losses: list = []
     current_epoch = 0
     budget = StepBudget(config.max_seconds,
                         on_compile=config.compile_callback,
-                        on_progress=config.progress_callback)
+                        on_progress=config.progress_callback,
+                        step_samples=batch_size)
     # Multihost: device_put of a host array to a process-spanning
     # sharding runs a cross-process value-equality collective, so
     # PLACEMENT ORDER must be deterministic — concurrent prefetch
@@ -330,28 +334,48 @@ def train_gnn(
     stream = prefetch(train_tasks(), build,
                       depth=config.prefetch_depth,
                       workers=n_workers)
-    profiler = (jax.profiler.trace(config.profile_dir)
-                if config.profile_dir else contextlib.nullcontext())
-    with profiler:
-        for epoch, arrays in stream:
+
+    def end_epoch():
+        # A host sync: it waits for every queued step.
+        with span("df2.train.epoch_end"):
+            history.append(epoch_mean(epoch_losses))
+
+    # Host spans on the profiler's clock (free while no profiler runs);
+    # docs/OBSERVABILITY.md "Training loops".
+    step_num = 0
+    while True:
+        # Nothing to dispatch: the prefetch stream's next item, and what
+        # the task generator does on this thread (an epoch's permutation).
+        with span("df2.train.wait_input"):
+            item = next(stream, None)
+        if item is None:
+            break
+        epoch, arrays = item
+        with jax.profiler.StepTraceAnnotation("df2.train.step",
+                                              step_num=step_num):
             if epoch != current_epoch:
                 if epoch_losses:
-                    history.append(float(jnp.mean(jnp.stack(epoch_losses))))
+                    end_epoch()
                 epoch_losses = []
                 current_epoch = epoch
-            if config.device_sample:
-                state, loss = fused_step(
-                    state, graph_tables, train_edges, arrays, base_key)
-            else:
-                state, loss = train_step(state, nf_dev, *arrays)
+            with span("df2.train.dispatch"):
+                if config.device_sample:
+                    state, loss = fused_step(
+                        state, graph_tables, train_edges, arrays, base_key)
+                else:
+                    state, loss = train_step(state, nf_dev, *arrays)
             if mesh.serialize_launches:
                 jax.block_until_ready(loss)
             epoch_losses.append(jnp.mean(loss) if group > 1 else loss)
-            if budget.tick(batch_size * group, loss):
-                stream.close()
-                break
-        if epoch_losses:
-            history.append(float(jnp.mean(jnp.stack(epoch_losses))))
+            with span("df2.train.tick"):
+                stop = budget.tick(batch_size * group, loss)
+        step_num += 1
+        if stop:
+            stream.close()
+            break
+    if epoch_losses:
+        end_epoch()
+    with span("df2.train.drain"):
         jax.block_until_ready(state.params)
     budget.finish()
 

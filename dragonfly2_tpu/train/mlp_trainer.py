@@ -20,7 +20,7 @@ from flax.training import train_state
 from dragonfly2_tpu.data.pipeline import ArrayDataset
 from dragonfly2_tpu.models.mlp import MLPBandwidthPredictor, Normalizer
 from dragonfly2_tpu.parallel import MeshContext, data_parallel_mesh
-from dragonfly2_tpu.train.step_budget import StepBudget
+from dragonfly2_tpu.train.step_budget import StepBudget, epoch_mean
 
 
 @dataclass(frozen=True)
@@ -41,9 +41,6 @@ class MLPTrainConfig:
     # fires once with the first-step compile seconds.
     progress_callback: object = None
     compile_callback: object = None
-    # When set, the step loop runs under jax.profiler.trace writing an
-    # XPlane dump here (the reference's pprof/jaeger flag equivalent).
-    profile_dir: str = ""
 
 
 @dataclass
@@ -152,28 +149,23 @@ def train_mlp(
                         on_compile=config.compile_callback,
                         on_progress=config.progress_callback)
     stop = False
-    import contextlib
-
-    profiler = (jax.profiler.trace(config.profile_dir)
-                if config.profile_dir else contextlib.nullcontext())
-    with profiler:
-        for epoch in range(config.epochs):
-            losses = []
-            for bx, by in train_ds.batches(batch_size, seed=config.seed,
-                                           epoch=epoch):
-                state, loss = train_step(state, mesh.put_batch(bx),
-                                         mesh.put_batch(by))
-                if mesh.serialize_launches:
-                    jax.block_until_ready(loss)
-                losses.append(loss)
-                if budget.tick(len(bx), loss):
-                    stop = True
-                    break
-            if losses:
-                history.append(float(jnp.mean(jnp.stack(losses))))
-            if stop:
+    for epoch in range(config.epochs):
+        losses = []
+        for bx, by in train_ds.batches(batch_size, seed=config.seed,
+                                       epoch=epoch):
+            state, loss = train_step(state, mesh.put_batch(bx),
+                                     mesh.put_batch(by))
+            if mesh.serialize_launches:
+                jax.block_until_ready(loss)
+            losses.append(loss)
+            if budget.tick(len(bx), loss):
+                stop = True
                 break
-        jax.block_until_ready(state.params)
+        if losses:
+            history.append(epoch_mean(losses))
+        if stop:
+            break
+    jax.block_until_ready(state.params)
     budget.finish()
 
     # Eval in fixed-size chunks (pad the tail by wrapping — metrics are

@@ -12,14 +12,108 @@ seconds so assign-style consumers record the full figure,
 ``on_progress`` fires every ``progress_every`` steps with the current
 steady-state rate — the bench uses these to keep its headline current so a
 watchdog fire emits the latest measured rate instead of zero.
+
+Every budget also feeds the process-wide ``training`` block of
+``/debug/vars`` (:data:`TRAINING`; docs/OBSERVABILITY.md "Training
+loops"): what the loops dispatched, and how many executables JAX built
+or loaded while a loop ran.
 """
 
 from __future__ import annotations
 
+import threading
 import time
+import weakref
 from typing import Callable, Optional
 
 import jax
+import numpy as np
+from jax._src.dispatch import BACKEND_COMPILE_EVENT
+
+from dragonfly2_tpu.utils.debugmon import register_debug_var
+
+
+class TrainingStats:
+    """The ``training`` block: monotonic counters over every
+    :class:`StepBudget` of the process, behind one lock. Nothing here
+    touches the device.
+
+    - ``loops_started``: budgets created (one per train-loop call).
+    - ``dispatches``: ``tick`` calls, one per launched step program.
+    - ``steps``: optimizer steps in them (``steps_per_call`` a dispatch).
+    - ``samples``: samples counted into throughput windows (the first
+      step's and a new program's are excluded with their compile).
+    - ``compile_seconds``: what the budgets excluded as compile time.
+    - ``loop_compiles``: executables JAX built *or loaded from its
+      persistent cache* between a budget's creation and its ``finish``.
+      JAX records one backend-compile event per executable either way
+      (a cache load fires the cache-hit event besides, which is
+      therefore not counted), so a cold and a warm run count the same.
+      At least 1 (the step program); a constant of a loop, so one more
+      is a recompile.
+    - ``steady_compiles``: those of them after the budget's first
+      ``tick``. 0 in a healthy run: anything else compiled while the
+      loop should only have been dispatching.
+    """
+
+    KEYS = ("loops_started", "dispatches", "steps", "samples",
+            "compile_seconds", "loop_compiles", "steady_compiles")
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._counts = dict.fromkeys(self.KEYS, 0)
+        self._counts["compile_seconds"] = 0.0
+        # Budgets between creation and finish. Weak: a loop that raises
+        # never reaches finish, and its budget must not keep counting.
+        self._open = weakref.WeakSet()
+
+    def add(self, **increments) -> None:
+        with self._lock:
+            for key, value in increments.items():
+                self._counts[key] += value
+
+    def loop_started(self, budget) -> None:
+        with self._lock:
+            self._counts["loops_started"] += 1
+            self._open.add(budget)
+
+    def loop_finished(self, budget) -> None:
+        with self._lock:
+            self._open.discard(budget)
+
+    def executable_built(self) -> None:
+        with self._lock:
+            for budget in self._open:
+                self._counts["loop_compiles"] += 1
+                if budget.steps:
+                    self._counts["steady_compiles"] += 1
+
+    def snapshot(self) -> dict:
+        with self._lock:
+            return dict(self._counts)
+
+
+TRAINING = TrainingStats()
+register_debug_var("training", TRAINING.snapshot)
+
+
+def _on_event_duration(event: str, duration_secs: float, **_) -> None:
+    if event == BACKEND_COMPILE_EVENT:
+        TRAINING.executable_built()
+
+
+# Once, at import; the listener does nothing while no budget is open.
+jax.monitoring.register_event_duration_secs_listener(_on_event_duration)
+
+
+def epoch_mean(losses) -> float:
+    """Mean of an epoch's per-step losses (scalars, or one vector per
+    multi-step dispatch), taken on the host: one sync on the last of
+    them and no device program. A ``jnp`` reduction over the list is a
+    new program for every epoch length, compiled mid-run the first time
+    an epoch ends."""
+    return float(np.mean(np.concatenate(
+        [np.ravel(x) for x in jax.device_get(losses)])))
 
 
 class StepBudget:
@@ -29,11 +123,16 @@ class StepBudget:
         on_compile: Optional[Callable[[float], None]] = None,
         on_progress: Optional[Callable[[int, float], None]] = None,
         progress_every: int = 25,
+        step_samples: Optional[int] = None,
     ):
+        """``step_samples``: samples of one optimizer step, where a
+        dispatch may hold several (``steps_per_call``); only the
+        ``training`` block's ``steps`` reads it."""
         self.max_seconds = max_seconds
         self.steps = 0
         self.samples = 0
         self.compile_seconds = 0.0
+        self._step_samples = step_samples
         self._on_compile = on_compile
         self._on_progress = on_progress
         self._progress_every = max(progress_every, 1)
@@ -42,6 +141,7 @@ class StepBudget:
         self._deadline: Optional[float] = None
         self._elapsed: Optional[float] = None
         self._synced = False
+        TRAINING.loop_started(self)
 
     def sync_point(self, prev_output) -> None:
         """Call immediately BEFORE dispatching a program shape that has
@@ -72,10 +172,11 @@ class StepBudget:
         understates steady-state throughput by double digits (observed
         on-chip: 17.2k vs 23.6k edge-samples/sec at the same config).
         """
+        counted, excluded = 0, 0.0
         if self.steps == 0:
             jax.block_until_ready(first_step_output)
             now = time.perf_counter()
-            self.compile_seconds = now - self._start
+            self.compile_seconds = excluded = now - self._start
             self._start = now
             self._last = now
             if self.max_seconds is not None:
@@ -105,8 +206,13 @@ class StepBudget:
                 self._on_compile(self.compile_seconds)
         else:
             self.samples += n_samples
+            counted = n_samples
         self.steps += 1
         self._synced = False
+        TRAINING.add(
+            dispatches=1, samples=counted, compile_seconds=excluded,
+            steps=(n_samples // self._step_samples
+                   if self._step_samples else 1))
         if (self._on_progress is not None and self.samples
                 and self.steps % self._progress_every == 0):
             # Block on the CURRENT step so the published rate counts
@@ -123,6 +229,7 @@ class StepBudget:
     def finish(self) -> None:
         """Freeze the throughput window (call after the final block)."""
         self._elapsed = max(time.perf_counter() - self._start, 1e-9)
+        TRAINING.loop_finished(self)
 
     def samples_per_sec(self, batch_size: int) -> float:
         """Steady-state throughput; single-step runs have no post-compile

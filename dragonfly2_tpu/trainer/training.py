@@ -11,6 +11,7 @@ host-side pipelines and the two model jobs run back to back on device).
 
 from __future__ import annotations
 
+import contextlib
 import logging
 import os
 import shutil
@@ -96,6 +97,12 @@ class TrainingConfig:
     min_mlp_records: int = 8
     min_gat_records: int = 8
     min_cost_records: int = MIN_COST_EXAMPLES
+    # The one profile switch (``df2-trainer --profile-dir``): when set,
+    # every model job runs under ``jax.profiler.trace`` writing an XPlane
+    # dump here, with the loops' ``df2.train.*`` host spans and the step
+    # programs' ``df2.*`` scopes in it; read it with
+    # ``df2-trace-tool train`` (docs/OBSERVABILITY.md "Training loops").
+    profile_dir: str = ""
 
 
 @dataclass
@@ -130,6 +137,29 @@ class Training:
         self.metrics = metrics  # TrainerMetrics or None
         # One training job at a time: the device mesh is not re-entrant.
         self._train_lock = threading.Lock()
+
+    @contextlib.contextmanager
+    def _profiled(self, model: str):
+        """One model job under the profile switch: an XPlane dump under
+        ``<profile_dir>/<model>/``. JAX leaves operation metadata out of
+        its persistent-cache key, so an executable cached by another
+        build would bring that build's scope names (or none) into the
+        dump; a profiled job therefore keys its programs with their
+        metadata, and pays a compile the first time."""
+        if not self.config.profile_dir:
+            yield
+            return
+        import jax
+
+        keyed = "jax_compilation_cache_include_metadata_in_key"
+        before = getattr(jax.config, keyed)
+        jax.config.update(keyed, True)
+        try:
+            with jax.profiler.trace(
+                    os.path.join(self.config.profile_dir, model)):
+                yield
+        finally:
+            jax.config.update(keyed, before)
 
     def _observe_job(self, model: str, seconds: float,
                      samples_per_sec: float) -> None:
@@ -215,7 +245,8 @@ class Training:
                         host_id)
             return
         job_start = time.monotonic()
-        result = train_gnn(graph, self.config.gnn, self.mesh)
+        with self._profiled("gnn"):
+            result = train_gnn(graph, self.config.gnn, self.mesh)
         self._observe_job("gnn", time.monotonic() - job_start,
                           result.samples_per_sec)
         evaluation = {
@@ -251,7 +282,8 @@ class Training:
                         host_id)
             return
         job_start = time.monotonic()
-        result = train_gat(graph, self.config.gat, self.mesh)
+        with self._profiled("gat"):
+            result = train_gat(graph, self.config.gat, self.mesh)
         self._observe_job("gat", time.monotonic() - job_start,
                           result.samples_per_sec)
         evaluation = {
@@ -297,7 +329,8 @@ class Training:
             logger.info("skip MLP for %s: %d pair examples", host_id, len(X))
             return
         job_start = time.monotonic()
-        result = train_mlp(X, y, self.config.mlp, self.mesh)
+        with self._profiled("mlp"):
+            result = train_mlp(X, y, self.config.mlp, self.mesh)
         self._observe_job("mlp", time.monotonic() - job_start,
                           result.samples_per_sec)
         evaluation = {"mse": result.mse, "mae": result.mae,
@@ -332,7 +365,8 @@ class Training:
             )
             return
         job_start = time.monotonic()
-        result = train_cost(X, y, self.config.cost, self.mesh)
+        with self._profiled("cost"):
+            result = train_cost(X, y, self.config.cost, self.mesh)
         self._observe_job("cost", time.monotonic() - job_start,
                           result.samples_per_sec)
         evaluation = {"mse": result.mse, "mae": result.mae,

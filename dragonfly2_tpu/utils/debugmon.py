@@ -15,9 +15,10 @@ safe on a serving process):
                                 thread count, python/jax versions
   GET /healthy                  liveness
 
-The JAX/XPlane half of the story is per-trainer (`profile_dir` on the
-train configs runs the step loop under ``jax.profiler.trace``) and the
-``--profile-dir`` CLI flag that forwards to it.
+The JAX/XPlane half of the story is the trainer's one profile switch
+(``df2-trainer --profile-dir`` → ``TrainingConfig.profile_dir``: every
+model job runs under ``jax.profiler.trace``), read with
+``df2-trace-tool train`` (docs/OBSERVABILITY.md "Training loops").
 """
 
 from __future__ import annotations

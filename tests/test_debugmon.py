@@ -102,18 +102,33 @@ class TestDebugMonitor:
 
 @pytest.mark.slow  # real XLA profiler session writing xplane.pb (~20 s)
 class TestTrainerProfileDir:
-    def test_mlp_profile_dir_writes_xplane(self, tmp_path):
-        """profile_dir on the train config produces an XPlane dump the
-        operator can open in xprof/tensorboard."""
-        from dragonfly2_tpu.parallel import data_parallel_mesh
-        from dragonfly2_tpu.train import MLPTrainConfig, train_mlp
+    def test_the_one_profile_switch_dumps_the_loops_spans(self, tmp_path):
+        """``TrainingConfig.profile_dir`` (what ``df2-trainer
+        --profile-dir`` sets) runs a model job under the JAX profiler:
+        the dump lands under ``<dir>/<model>/`` and holds the loop's own
+        ``df2.train.*`` spans, which ``df2-trace-tool train`` reads."""
+        from dragonfly2_tpu import traintrace
+        from dragonfly2_tpu.data import SyntheticCluster
+        from dragonfly2_tpu.train import GNNTrainConfig
+        from dragonfly2_tpu.trainer.training import (
+            TrainOutcome,
+            Training,
+            TrainingConfig,
+        )
 
-        rng = np.random.default_rng(0)
-        X = rng.standard_normal((2048, 11)).astype(np.float32)
-        y = np.abs(rng.standard_normal(2048)).astype(np.float32)
+        graph = SyntheticCluster(n_hosts=100, seed=0).probe_graph(4000)
         out = tmp_path / "xplane"
-        train_mlp(X, y, MLPTrainConfig(
-            epochs=1, batch_size=256, profile_dir=str(out)),
-            data_parallel_mesh())
-        dumped = list(out.rglob("*.xplane.pb"))
-        assert dumped, f"no xplane dump under {out}"
+        training = Training(storage=None, config=TrainingConfig(
+            gnn=GNNTrainConfig(hidden=32, embed=16, batch_size=512,
+                               epochs=1),
+            profile_dir=str(out)))
+        outcome = TrainOutcome(host_id="h")
+        training._train_gnn("127.0.0.1", "host", "h", 0, graph.n_edges,
+                            graph, outcome)
+        assert outcome.gnn_model_id
+        dumped = list((out / "gnn").rglob("*.xplane.pb"))
+        assert dumped, f"no xplane dump under {out}/gnn"
+        report = traintrace.analyze(str(out / "gnn"))
+        (loop,) = [t for t in report["threads"] if t["loop"]]
+        assert loop["spans"]["df2.train.dispatch"]["count"] == (
+            report["host_steps"]) > 0
