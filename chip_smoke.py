@@ -629,10 +629,12 @@ def phase_kernels(sizes: Sizes, seed: int, device,
                                 chunk=cfg.chunk, attention=attention)
 
     with jax.set_mesh(mesh.mesh):
-        params = mesh.put_replicated(model_for("gather").init(
-            jax.random.key(seed), jnp.asarray(feat), jnp.asarray(nbr),
-            jnp.asarray(val), jnp.zeros(2, jnp.int32),
-            jnp.zeros(2, jnp.int32)))
+        # Parameters without the forward (train_gat does the same).
+        params = mesh.put_replicated(model_for("gather").lazy_init(
+            jax.random.key(seed),
+            *(jax.ShapeDtypeStruct(a.shape, a.dtype)
+              for a in (feat, nbr, val)),
+            jnp.zeros(2, jnp.int32), jnp.zeros(2, jnp.int32)))
 
     def run(attention: str, pallas_gather: bool) -> dict:
         model = model_for(attention)

@@ -738,13 +738,18 @@ class GATParentScorer:
                        else self.n_nodes)
         self._id_index = ({h: i for i, h in enumerate(self.node_ids)}
                           if self.node_ids is not None else None)
-        # One full-graph pass; block until the table is resident.
-        emb = model.apply(
-            params,
-            jnp.asarray(node_features), jnp.asarray(neighbors),
-            jnp.asarray(neighbor_vals),
-            method=type(model).node_embeddings)
-        self._emb = jax.device_put(jnp.asarray(emb), self._device)
+        # One full-graph pass, jitted like the trainer's eval pass: run
+        # op by op, gather attention's [N, K, heads] float32
+        # intermediates pad 4 lanes to 128 and a 50,000-host fleet does
+        # not leave room for anything else on a 16 GB chip (PERF.md,
+        # PR 25). Block until the table is resident.
+        def embed(p, feats, nbr, val):
+            return model.apply(p, feats, nbr, val,
+                               method=type(model).node_embeddings)
+
+        self._emb = jax.jit(embed)(*jax.device_put(
+            (self._params, np.asarray(node_features), np.asarray(neighbors),
+             np.asarray(neighbor_vals)), self._device))
         self._emb.block_until_ready()
 
         def forward(p, emb, src, dst):

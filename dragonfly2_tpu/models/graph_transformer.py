@@ -329,16 +329,14 @@ def build_inverse_index(nbr: np.ndarray) -> np.ndarray:
     """Host-side transpose of the neighbor lists: ``inv[j]`` lists the
     flat positions ``i*K + s`` with ``nbr[i, s] == j``, padded with -1
     to the max in-degree. Lets the neighbor-gather BACKWARD be a gather
-    instead of a scatter-add (see :func:`neighbor_gather`): on TPU the
-    duplicate-index scatter the autodiff transpose emits serializes and
-    dominated the measured train step (config #3 on-chip probe: forward
-    124 ms, fwd+bwd 424 ms → 271 ms with the inverse gather,
-    ``artifacts/gat_probe_r5b.json``); the inverse-index gather is
-    parallel and exact — PROVIDED the gathered rows are lane-aligned
-    (``_neighbor_gather_bwd`` flattens to [heads*head_dim]-wide rows;
-    the [4, 32]-fragment layout measured SLOWER than the scatter,
-    ``artifacts/gather_micro_r5.json``). Capped rows keep symmetrized
-    graphs' in-degree near the cap (max 82 at cap 64 on config #3).
+    instead of a scatter-add (see :func:`neighbor_gather`): the
+    duplicate-index scatter that autodiff's transpose emits serializes
+    on a TPU, the inverse-index gather is parallel and exact. A flat
+    position names one whole row of the attention's ``[N·K, 2·hidden]``
+    cotangent, and :func:`gather_graph_attention` writes that cotangent
+    in exactly those rows, so nothing re-lays it out in between. Capped
+    rows keep a symmetrized graph's in-degree near the cap (76 at cap 64
+    in ``gat-fleet50k``).
     """
     n, k_width = nbr.shape
     rows, slots = np.nonzero(nbr != PAD_ID)
@@ -356,15 +354,14 @@ def build_inverse_index(nbr: np.ndarray) -> np.ndarray:
 
 
 def _neighbor_gather_impl(table, idx):
-    """[N, h, d] table gathered to [N, K, h, d] by row indices; the
-    operations carry the scope ``df2.attn.gather``."""
+    """[N, C] table gathered to [N, K, C] by row indices; the operations
+    carry the scope ``df2.attn.gather``."""
     with jax.named_scope("df2.attn.gather"):
         if _mesh_empty():
             return table[idx]
-        # Rows shard over data; head/feature axes keep whatever sharding
-        # the table carries (the 'model' axis under tensor parallelism).
-        tspec = _value_spec(table)
-        spec = P("data", None, *tspec[1:])
+        # Rows shard over data; the lane axis keeps whatever sharding the
+        # table carries (the 'model' axis under tensor parallelism).
+        spec = P("data", None, _value_spec(table)[1])
         return table.at[idx].get(out_sharding=spec)
 
 
@@ -400,35 +397,25 @@ def _neighbor_gather_bwd(inv, ct):
 
 
 def _inverse_index_gather(inv, ct):
-    n, k_width = ct.shape[0], ct.shape[1]
-    heads, width = ct.shape[2], ct.shape[3]
-    # Gather whole [heads*width]-wide rows: at config #3 head_dim is 32,
-    # so per-[h, d]-row picks move 32-lane fragments and ran 2.2× slower
-    # than the very scatter they replace (artifacts/gather_micro_r5.json:
-    # 239 ms vs 143 ms; the flattened 128-lane layout is 111 ms).
+    """``d_table[j] = Σ_t ct.flat[inv[j, t]]`` for a cotangent that
+    arrives as it was gathered: ``[N, K, C]`` with C = 2·hidden lanes.
+    Flattening its two leading axes leaves every (8, 128) tile where it
+    is, so the rows the gather reads are the rows the attention backward
+    wrote; the sum over the index axis runs in float32."""
+    n, k_width, lanes = ct.shape
     padmask = inv < 0
     safe = jnp.where(padmask, 0, inv)
     if _mesh_empty():
-        flat = ct.reshape(n * k_width, heads * width)
-        contrib = flat[safe]
+        contrib = ct.reshape(n * k_width, lanes)[safe]
     else:
-        # Explicit-sharding reshape merges one axis group at a time and
-        # wants the output spec spelled out: rows keep the data axis,
-        # and a head axis sharded by tensor parallelism stays the major
-        # half of the merged [heads*width] axis (contiguous per device).
+        # Explicit sharding wants the output specs spelled out: rows keep
+        # the data axis, lanes a tensor-parallel 'model' axis.
         cspec = _value_spec(ct)
-        flat = jnp.reshape(ct, (n * k_width, heads, width),
-                           out_sharding=P(cspec[0], *cspec[2:]))
-        flat = jnp.reshape(flat, (n * k_width, heads * width),
+        flat = jnp.reshape(ct, (n * k_width, lanes),
                            out_sharding=P(cspec[0], cspec[2]))
         contrib = flat.at[safe].get(out_sharding=P("data", None, cspec[2]))
     contrib = jnp.where(padmask[..., None], 0.0, contrib)
     d_table = contrib.sum(axis=1, dtype=jnp.float32).astype(ct.dtype)
-    if _mesh_empty():
-        d_table = d_table.reshape(n, heads, width)
-    else:
-        d_table = jnp.reshape(d_table, (n, heads, width),
-                              out_sharding=P("data", cspec[2], None))
     # The table is full-width (its cotangent must match): gather the
     # row-sharded partials back to full width under a mesh.
     d_table = replicate(d_table)
@@ -477,65 +464,105 @@ def _pallas_gather_enabled(table) -> bool:
         return False
     from dragonfly2_tpu.ops.table_gather import pallas_path_feasible
 
-    n, heads, width = table.shape
-    return pallas_path_feasible(n, heads * width, table.dtype)
+    return pallas_path_feasible(*table.shape, table.dtype)
 
 
-def gather_graph_attention(q, k, v, nbr, val, inv=None):
+def _head_indicator(heads: int, head_dim: int, dtype):
+    """0/1 ``[heads·head_dim, heads]``: lane c belongs to head c // head_dim.
+    A product with it sums lanes within each head; one with its
+    transpose spreads a per-head number over the head's lanes."""
+    return jnp.asarray(
+        np.kron(np.eye(heads), np.ones((head_dim, 1))), dtype)
+
+
+def gather_graph_attention(q, k, v, nbr, val, inv=None, *, heads):
     """Neighbor-gather attention: each query row attends to exactly its
-    ≤K listed neighbors — O(N·K·H) compute AND memory.
+    ≤K listed neighbors — O(N·K·H) compute AND memory. Scoring all N
+    key columns per row (what block attention does) spends an N/K factor
+    masking columns that can never attend; on a degree-capped probe
+    graph (K ≤ 128 vs N = 100k+) that is ~1000× the work.
 
-    Attention is *already* restricted to the neighbor list, so scoring
-    all N key columns per row (what block attention does) wastes an
-    N/K factor of FLOPs masking columns that can never attend; on a
-    degree-capped probe graph (K ≤ 128 vs N = 100k+) the gather
-    formulation is ~1000× less work. Per local row: gather its
-    neighbors' K/V rows from the full-width table ([rows, K, h, d]),
-    one batched dot per slot, masked softmax over the K axis (every
-    row holds a self slot, so the denominator is never empty).
+    Everything ``[N, K, …]`` stays **lane-dense**: ``hidden`` =
+    heads·head_dim lanes (128 at the trainer's widths, one TPU tile
+    row), heads side by side, never split into ``[heads, head_dim]``.
+    One gather of the ``[k | v]`` table (two ``hidden``-wide halves, so
+    both slices are tile-aligned) gives ``[N, K, 2·hidden]`` rows. A
+    head's score is the sum of its lanes of q·k: a product with the 0/1
+    :func:`_head_indicator` matrix, in float32 from the MXU. Bias, pad
+    mask and softmax run over K on ``[N, K, heads]``; the probabilities
+    go back to lanes by the indicator's transpose, are multiplied into
+    the v lanes and summed over K in float32. The backward of all of it
+    is elementwise products and the same two indicator products, so the
+    cotangent of the gathered rows is written ``[N, K, 2·hidden]`` as
+    :func:`_inverse_index_gather` reads it. (With the head axis split —
+    as ``einsum("nhd,nkhd->nhk")`` or as broadcasts over
+    ``[N, K, heads, head_dim]`` alike — the v5e compiler lays that
+    cotangent out heads-major and re-lays it for the gather in a
+    256-iteration ``while``: a third of ``gat-fleet50k.train``'s step
+    until PR 25; ``tests/test_chip_compile.py`` keeps it from coming
+    back.)
 
-    q: [N, heads, d] row-sharded; k/v: [N, heads, d] full-width;
-    nbr/val: [N, K] row-sharded. Returns [N, heads, d].
+    q: ``[N, hidden]`` row-sharded; k/v: ``[N, hidden]`` full-width;
+    nbr/val: ``[N, K]`` row-sharded; ``inv``: :func:`build_inverse_index`
+    of ``nbr`` (training) or None. Returns ``[N, hidden]``. Every row
+    holds a self slot, so the softmax denominator is never empty.
     """
-    n, heads, head_dim = q.shape
+    lanes = None if _mesh_empty() else _value_spec(q)[1]
+    if lanes is None:
+        return _gather_attention(q, k, v, nbr, val, inv, heads=heads)
+    # Tensor parallelism: the projections came out with their lanes (so
+    # their heads) split over ``lanes``. Heads never mix, so each shard
+    # runs the same arithmetic on the heads it holds.
+    mesh = jax.sharding.get_abstract_mesh()
+    local = partial(_gather_attention, heads=heads // mesh.shape[lanes])
+    cols = P(None, lanes)
+    return jax.shard_map(
+        local, mesh=mesh, axis_names={lanes},
+        in_specs=(cols, cols, cols, P(), P(), P()), out_specs=cols,
+    )(q, k, v, nbr, val, inv)
+
+
+def _gather_attention(q, k, v, nbr, val, inv, *, heads):
+    """:func:`gather_graph_attention` over the heads one device holds."""
+    n, hidden = q.shape
+    head_dim = hidden // heads
     scale = 1.0 / np.sqrt(head_dim)
     pad = nbr >= n                     # PAD_ID (and nothing else) is ≥ N
     idx = jnp.where(pad, 0, nbr)
-    # ONE gather of the concatenated [k|v] table instead of two: the
-    # neighbor gather is far from byte-bound (a 10 MB table moves at
-    # ~8 GB/s effective, gather_micro_r5.json), so if it is row-count
-    # bound, double-width rows halve the row count in forward AND
-    # backward at identical byte volume — gather_micro's fused_kv rows
-    # quantify this on-chip. Concat along head_dim keeps a
-    # tensor-parallel head axis intact.
-    kv = jnp.concatenate([k, v], axis=-1)  # [N, heads, 2d]
+    # ONE gather of the [k | v] table instead of two: a row gather's
+    # cost follows the number of rows, not their bytes, in the forward
+    # and in the backward alike.
+    kv = jnp.concatenate([k, v], axis=-1)          # [N, 2·hidden]
     if _pallas_gather_enabled(kv):
         # Opt-in (DF2_PALLAS_GATHER=1) single-device path: both gather
         # directions are VMEM-resident pallas kernels (the table fits),
-        # replacing XLA's one-HBM-DMA-per-row lowering AND the inverse
-        # index (the backward is a VMEM scatter-add). Default stays XLA
-        # until the on-chip A/B (gather_micro_r5b) proves this faster.
+        # the backward a VMEM scatter-add in place of the inverse index.
         from dragonfly2_tpu.ops.table_gather import neighbor_gather_pallas
 
-        def gather_rows(kv_, idx_):
-            wide = neighbor_gather_pallas(
-                kv_.reshape(kv_.shape[0], -1), idx_)   # [n, K, heads·2d]
-            return wide.reshape(*idx_.shape, heads, 2 * head_dim)
-
-        kvg = _per_device(gather_rows, (P(), P("data")), P("data"))(kv, idx)
+        kvg = _per_device(neighbor_gather_pallas,
+                          (P(), P("data")), P("data"))(kv, idx)
     elif inv is not None:
         # Scatter-free training path: custom backward via the host-built
-        # inverse index (config #3 step 424 ms autodiff → 271 ms,
-        # artifacts/gat_probe_r5b.json).
+        # inverse index.
         kvg = neighbor_gather(kv, idx, inv)
     else:
-        kvg = _neighbor_gather_impl(kv, idx)  # [N, K, heads, 2d]
-    kg, vg = kvg[..., :head_dim], kvg[..., head_dim:]
-    s = jnp.einsum("nhd,nkhd->nhk", q, kg).astype(jnp.float32) * scale
-    s = s + val[:, None, :]
-    s = jnp.where(pad[:, None, :], NEG_INF, s)
-    p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
-    return jnp.einsum("nhk,nkhd->nhd", p, vg)
+        kvg = _neighbor_gather_impl(kv, idx)       # [N, K, 2·hidden]
+    kg, vg = kvg[..., :hidden], kvg[..., hidden:]
+    ind = _head_indicator(heads, head_dim, q.dtype)
+    f32 = jnp.float32
+    # The lane products are exact in float32 and rounded to the compute
+    # dtype because the head sum is an MXU product, whose operands are
+    # bfloat16 (a float32 operand is truncated to it, or costs six
+    # passes at "highest"); the sum itself is float32.
+    qk = (q.astype(f32)[:, None, :] * kg.astype(f32)).astype(q.dtype)
+    s = jnp.einsum("nkc,ch->nkh", qk, ind,
+                   preferred_element_type=f32) * scale
+    s = s + val[:, :, None]
+    s = jnp.where(pad[:, :, None], NEG_INF, s)
+    p = jax.nn.softmax(s, axis=1).astype(q.dtype)  # [N, K, heads]
+    pl = jnp.einsum("nkh,ch->nkc", p, ind)         # [N, K, hidden]
+    out = (pl.astype(f32) * vg.astype(f32)).sum(axis=1)
+    return out.astype(q.dtype)
 
 
 def blocks_graph_attention(q, k, v, nbr, val, chunk):
@@ -731,17 +758,19 @@ class GraphAttentionBlock(nn.Module):
             # Queries keep their row sharding; K/V go full-width (O(N·H)
             # all-gather over ICI) and are consumed per-neighbor or
             # block-by-block.
-            q, k, v = split(q), replicate(split(k)), replicate(split(v))
+            k, v = replicate(k), replicate(v)
             if self.attention == "gather":
-                out = gather_graph_attention(q, k, v, nbr, val, inv)
+                out = gather_graph_attention(q, k, v, nbr, val, inv,
+                                             heads=self.heads)
             elif self.attention == "flash":
                 # Force the pallas kernel (interpret-mode off TPU) —
                 # hermetic kernel tests and A/B benchmarks use this.
                 out = _graph_flash(
-                    q, k, v, nbr, val, self.chunk,
+                    split(q), split(k), split(v), nbr, val, self.chunk,
                     interpret=jax.devices()[0].platform != "tpu")
             else:
-                out = blocks_graph_attention(q, k, v, nbr, val, self.chunk)
+                out = blocks_graph_attention(split(q), split(k), split(v),
+                                             nbr, val, self.chunk)
         out = out.reshape(-1, self.hidden)
         out = TPDense(self.hidden, dtype=self.dtype, name="Dense_3")(out)
         h = h + out

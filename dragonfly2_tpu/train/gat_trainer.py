@@ -191,9 +191,18 @@ def train_gat(
     model = GraphTransformer(hidden=config.hidden, embed=config.embed,
                              layers=config.layers, heads=config.heads,
                              chunk=config.chunk, attention=config.attention)
-    params = model.init(
+    # flax's lazy_init: the parameters are drawn as ``model.init`` draws
+    # them, operation by operation (so bit-equal to it on any backend,
+    # which one compiled init program is not on the v5e), and the
+    # forward is traced over shapes, never run. Run op by op over a
+    # 50,000-host fleet it holds 15.4 GB of a 16 GB chip (PERF.md, PR 25).
+    def shape_of(a):
+        return jax.ShapeDtypeStruct(
+            a.shape, jax.dtypes.canonicalize_dtype(a.dtype))
+
+    params = model.lazy_init(
         jax.random.key(config.seed),
-        jnp.asarray(node_features), jnp.asarray(nbr), jnp.asarray(val),
+        shape_of(node_features), shape_of(nbr), shape_of(val),
         jnp.zeros(2, jnp.int32), jnp.zeros(2, jnp.int32),
     )
 
@@ -215,8 +224,8 @@ def train_gat(
 
     # Gather mode trains through the scatter-free backward: the
     # host-built inverse neighbor index turns the attention gathers'
-    # VJP into 128-lane-row gathers too (build_inverse_index — config #3
-    # step 424 ms autodiff-scatter → 271 ms, artifacts/gat_probe_r5b.json).
+    # VJP into gathers of whole lane-dense rows too (build_inverse_index;
+    # autodiff's duplicate-index scatter-add serializes on a TPU).
     inv = (build_inverse_index(nbr)
            if config.attention == "gather" else None)
 

@@ -14,6 +14,7 @@ happen in the test's own process, and these tests stay in this ONE file.
 """
 
 import importlib
+import math
 import os
 
 import jax
@@ -169,3 +170,73 @@ def test_kernels_inside_the_trainers_trace(topo, as_tpu_program, monkeypatch,
             s((batch,), jnp.int32, rep), s((batch,), jnp.int32, rep),
             s((batch,), jnp.float32, rep))
     assert compiled.as_text().count("tpu_custom_call") == kernels
+
+
+def test_gather_attention_backward_has_no_relayout(topo):
+    """``gat-fleet50k.train``'s attention at the cell's own shapes, under
+    the one-device mesh the trainer sets. Until PR 25 the attention
+    cotangent came out heads-major and the compiler re-laid it for the
+    inverse-index gather in a 256-iteration ``while`` (147 ms of a 443 ms
+    step, a third of it, under no scope a trace could name). The
+    lane-dense form hands ``[N, K, 2·hidden]`` from the attention
+    backward to that gather as it is: no loop, and nothing that copies
+    or reshapes a tensor of the gathered rows' size."""
+    import re
+
+    from dragonfly2_tpu.models.graph_transformer import gather_graph_attention
+    from dragonfly2_tpu.parallel import data_parallel_mesh
+
+    n, k, heads, hidden, inv_width = 50_000, 64, 4, 128, 76
+    mesh = data_parallel_mesh(devices=topo.devices[:1])
+    row, rep = _struct(mesh.shard_spec("data")), _struct(mesh.replicated)
+
+    def loss(q, k_, v, nbr, val, inv):
+        out = gather_graph_attention(q, k_, v, nbr, val, inv, heads=heads)
+        return out.astype(jnp.float32).sum()
+
+    with jax.set_mesh(mesh.mesh):
+        compiled = _compile(
+            jax.grad(loss, argnums=(0, 1, 2, 4)),
+            row((n, hidden), jnp.bfloat16), rep((n, hidden), jnp.bfloat16),
+            rep((n, hidden), jnp.bfloat16), row((n, k), jnp.int32),
+            row((n, k), jnp.float32), row((n, inv_width), jnp.int32))
+    text = compiled.as_text()
+    assert " while(" not in text
+    # Instructions of the entry computation run on their own; the same
+    # words inside a fused computation are free.
+    entry = text[text.index("\nENTRY "):]
+    entry = entry[:entry.index("\n}")]
+    moved = [m.group(0) for m in re.finditer(
+        r"= \w+\[([\d,]+)\]\S* (?:copy|reshape)\(", entry)
+        if math.prod(map(int, m.group(1).split(","))) == n * k * 2 * hidden]
+    assert not moved, moved
+    # 6.55 GB with the loop and the forward's transposed copies.
+    assert compiled.memory_analysis().temp_size_in_bytes < 5e9
+
+
+def test_embedding_pass_at_model_load_leaves_the_chip_room(topo):
+    """``GATParentScorer`` (and ``train_gat``'s eval pass) run the
+    forward over the whole fleet under jit. Run op by op at the cell's
+    50,000 rows it held 15.4 GB of the chip's 16 (PERF.md, PR 25: every
+    ``[N, K, heads]`` float32 intermediate pads 4 lanes to 128 when
+    nothing fuses it); compiled, its temporaries are what is held."""
+    from dragonfly2_tpu.models.graph_transformer import GraphTransformer
+    from dragonfly2_tpu.parallel import data_parallel_mesh
+
+    n, k, feat = 50_000, 64, 8
+    mesh = data_parallel_mesh(devices=topo.devices[:1])
+    rep = _struct(mesh.replicated)
+    model = GraphTransformer()
+    shapes = (rep((n, feat), jnp.float32), rep((n, k), jnp.int32),
+              rep((n, k), jnp.float32))
+    params = jax.tree.map(
+        lambda x: rep(x.shape, x.dtype),
+        jax.eval_shape(model.init, jax.random.key(0), *shapes,
+                       jnp.zeros(2, jnp.int32), jnp.zeros(2, jnp.int32)))
+
+    def embed(p, feats, nbr, val):
+        return model.apply(p, feats, nbr, val,
+                           method=GraphTransformer.node_embeddings)
+
+    memory = _compile(embed, params, *shapes).memory_analysis()
+    assert memory.temp_size_in_bytes < 2.5e9     # 1.82 GB, PR 25

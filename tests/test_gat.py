@@ -244,6 +244,37 @@ class TestTraining:
         assert np.isfinite(result.history[-1])
         assert result.history[-1] < result.history[0]
 
+    @pytest.mark.parametrize("attention",
+                             ["gather", "blocks", "ring", "flash"])
+    def test_lazy_init_is_the_eager_init(self, attention):
+        """``train_gat`` (and ``chip_smoke``) draw parameters with
+        ``model.lazy_init`` over the graph's shapes: ``model.init``'s
+        parameters bit for bit (the benchmark's reference draws its own
+        eagerly and compares at a limit of 0), without a forward over
+        the fleet. The shapes here are a fleet's; the eager call runs
+        on 256 rows."""
+        import jax.numpy as jnp
+
+        rng = np.random.default_rng(0)
+        n, fleet, cap = 256, 51_200, 64
+        nbr, val = build_neighbor_lists(
+            n, rng.integers(0, n, 2000), rng.integers(0, n, 2000),
+            rng.integers(1_000_000, 90_000_000, 2000), cap=16)
+        feats = rng.normal(size=(n, 8)).astype(np.float32)
+        model = GraphTransformer(hidden=32, embed=16, heads=4, chunk=64,
+                                 attention=attention)
+        key, pair = jax.random.key(3), jnp.zeros(2, jnp.int32)
+
+        eager = model.init(key, jnp.asarray(feats), jnp.asarray(nbr),
+                           jnp.asarray(val), pair, pair)
+        lazy = model.lazy_init(
+            key, jax.ShapeDtypeStruct((fleet, 8), jnp.float32),
+            jax.ShapeDtypeStruct((fleet, cap), jnp.int32),
+            jax.ShapeDtypeStruct((fleet, cap), jnp.float32), pair, pair)
+        assert jax.tree.structure(eager) == jax.tree.structure(lazy)
+        for a, b in zip(jax.tree.leaves(eager), jax.tree.leaves(lazy)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
     def test_multi_step_scan_matches_single_step(self):
         """steps_per_call=K runs K optimizer steps per dispatch under
         lax.scan (the GNN path's amortization, ported per the round-5
@@ -438,6 +469,177 @@ class TestInverseIndex:
         l1, g1 = self._grads(use_inv=True, mesh=mesh)
         assert abs(float(l0) - float(l1)) < 1e-5
         self._assert_close(g0, g1)
+
+
+class TestLaneDenseGatherAttention:
+    """``gather_graph_attention`` keeps q, the gathered [k|v] rows and
+    their cotangents ``heads·head_dim`` lanes wide and never splits the
+    head axis; a plain float32 per-head implementation, autodiff's
+    scatter-add backward included, says what it has to compute."""
+
+    N, HIDDEN, CAP = 120, 128, 12
+
+    def _inputs(self, dtype):
+        import jax.numpy as jnp
+
+        rng = np.random.default_rng(11)
+        src = rng.integers(0, self.N, 600)
+        dst = rng.integers(0, self.N, 600)
+        rtt = rng.integers(1_000_000, 90_000_000, 600)
+        nbr, val = build_neighbor_lists(self.N, src, dst, rtt, cap=self.CAP)
+        filled = (nbr != PAD_ID).sum(axis=1)
+        assert nbr.shape[1] == self.CAP
+        assert (filled == self.CAP).any() and (filled < self.CAP).any()
+        q, k, v, w = (
+            jnp.asarray(rng.normal(size=(self.N, self.HIDDEN)), dtype)
+            for _ in range(4))
+        return q, k, v, jnp.asarray(nbr), jnp.asarray(val), w
+
+    @staticmethod
+    def _per_head(q, k, v, nbr, val, heads):
+        import jax.numpy as jnp
+
+        n, hidden = q.shape
+        d = hidden // heads
+        qh, kh, vh = (t.astype(jnp.float32).reshape(n, heads, d)
+                      for t in (q, k, v))
+        pad = nbr >= n
+        idx = jnp.where(pad, 0, nbr)
+        s = jnp.einsum("nhd,nkhd->nhk", qh, kh[idx],
+                       precision="highest") / np.sqrt(d)
+        s = jnp.where(pad[:, None, :], -1e9, s + val[:, None, :])
+        p = jax.nn.softmax(s, axis=-1)
+        out = jnp.einsum("nhk,nkhd->nhd", p, vh[idx], precision="highest")
+        return out.reshape(n, hidden)
+
+    @staticmethod
+    def _out_and_grads(attend, q, k, v, val, w):
+        import jax.numpy as jnp
+
+        def weighted(q_, k_, v_, val_):
+            out = attend(q_, k_, v_, val_)
+            return (out.astype(jnp.float32) * w.astype(jnp.float32)).sum()
+
+        grads = jax.jit(jax.grad(weighted, argnums=(0, 1, 2, 3)))(
+            q, k, v, val)
+        return jax.jit(attend)(q, k, v, val), grads
+
+    @staticmethod
+    def _assert_close(got, want, tol):
+        for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+            assert np.abs(a - b).max() <= tol * np.abs(b).max() + 1e-6
+
+    @pytest.mark.parametrize("dtype,tol", [("float32", 1e-5),
+                                           ("bfloat16", 2e-2)])
+    @pytest.mark.parametrize("heads,head_dim", [(4, 32), (2, 64), (1, 128)])
+    def test_matches_per_head_float32(self, heads, head_dim, dtype, tol):
+        from dragonfly2_tpu.models.graph_transformer import (
+            build_inverse_index,
+            gather_graph_attention,
+        )
+
+        assert heads * head_dim == self.HIDDEN
+        q, k, v, nbr, val, w = self._inputs(dtype)
+        inv = jax.numpy.asarray(build_inverse_index(np.asarray(nbr)))
+        got = self._out_and_grads(
+            lambda *a: gather_graph_attention(*a[:3], nbr, a[3], inv,
+                                              heads=heads),
+            q, k, v, val, w)
+        want = self._out_and_grads(
+            lambda *a: self._per_head(*a[:3], nbr, a[3], heads),
+            q, k, v, val, w)
+        assert got[0].shape == (self.N, self.HIDDEN)
+        assert got[0].dtype == q.dtype
+        self._assert_close(got, want, tol)
+
+    @pytest.mark.parametrize("heads,head_dim", [(4, 32), (1, 128)])
+    def test_bfloat16_error_no_worse_than_split_form(self, heads, head_dim):
+        """What bfloat16 costs, as a root-mean-square distance from the
+        float32 answer on the same (bfloat16) inputs: no more than in
+        the per-head form this one replaced (bfloat16 einsums, the score
+        rounded to bfloat16 once), but for the score path's gradients:
+        q·k and the cotangent of the expanded probabilities are rounded
+        per lane here, as MXU operands, and the score not at all. A form
+        that dropped a float32 sum would show in this where the 2e-2 of
+        ``test_matches_per_head_float32`` lets it by."""
+        import jax.numpy as jnp
+
+        from dragonfly2_tpu.models.graph_transformer import (
+            build_inverse_index,
+            gather_graph_attention,
+        )
+
+        def split_form(q, k, v, val):
+            n, hidden = q.shape
+            qh, kh, vh = (t.reshape(n, heads, head_dim) for t in (q, k, v))
+            pad = nbr >= n
+            idx = jnp.where(pad, 0, nbr)
+            s = jnp.einsum("nhd,nkhd->nhk", qh, kh[idx]).astype(
+                jnp.float32) / np.sqrt(head_dim)
+            s = jnp.where(pad[:, None, :], -1e9, s + val[:, None, :])
+            p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
+            return jnp.einsum("nhk,nkhd->nhd", p, vh[idx]).reshape(n, hidden)
+
+        q, k, v, nbr, val, w = self._inputs("bfloat16")
+        inv = jnp.asarray(build_inverse_index(np.asarray(nbr)))
+        want = self._out_and_grads(
+            lambda *a: self._per_head(*a[:3], nbr, a[3], heads),
+            q, k, v, val, w)
+        dense = self._out_and_grads(
+            lambda *a: gather_graph_attention(*a[:3], nbr, a[3], inv,
+                                              heads=heads),
+            q, k, v, val, w)
+        split = self._out_and_grads(split_form, q, k, v, val, w)
+
+        def rms(got):
+            return [float(np.sqrt(np.mean(
+                (np.asarray(a, np.float32) - np.asarray(b, np.float32)) ** 2)))
+                for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want))]
+
+        # Read here: out 0.98, dk 0.79-0.81, dv 0.68-0.69 of the split
+        # form's error; dq 1.04-1.10 and dval 1.13-1.17, the price of
+        # 32 rounded addends a score where it rounded one sum.
+        room = {"out": 1.05, "dq": 1.25, "dk": 1.05, "dv": 1.05,
+                "dval": 1.25}
+        for name, new, old in zip(room, rms(dense), rms(split)):
+            assert new <= room[name] * old, (name, new, old)
+
+    @pytest.mark.parametrize("model_parallel", [1, 2])
+    def test_sharded_matches_unsharded(self, model_parallel):
+        """Rows over ``data``; with ``model_parallel`` 2 the lanes (so
+        the heads) over ``model`` as well, as the tensor-parallel
+        projections leave them: the same numbers as on one device."""
+        from jax.sharding import NamedSharding
+        from jax.sharding import PartitionSpec as P
+
+        from dragonfly2_tpu.models.graph_transformer import (
+            build_inverse_index,
+            gather_graph_attention,
+        )
+
+        q, k, v, nbr, val, w = self._inputs("bfloat16")
+        inv = jax.numpy.asarray(build_inverse_index(np.asarray(nbr)))
+
+        def attend(nbr_, inv_):
+            return lambda q_, k_, v_, val_: gather_graph_attention(
+                q_, k_, v_, nbr_, val_, inv_, heads=4)
+
+        want = self._out_and_grads(attend(nbr, inv), q, k, v, val, w)
+
+        mesh = data_parallel_mesh(model_parallel=model_parallel)
+        lanes = "model" if model_parallel > 1 else None
+
+        def put(x, *spec):
+            return jax.device_put(x, NamedSharding(mesh.mesh, P(*spec)))
+
+        with jax.set_mesh(mesh.mesh):
+            got = self._out_and_grads(
+                attend(put(nbr, "data"), put(inv, "data")),
+                put(q, "data", lanes), put(k, None, lanes),
+                put(v, None, lanes), put(val, "data"), put(w, "data", lanes))
+            assert jax.typeof(got[0]).sharding.spec == P("data", lanes)
+        self._assert_close(got, want, 2e-2)
 
 
 @pytest.mark.slow  # 16k-100k-node scale runs; minutes on a small box

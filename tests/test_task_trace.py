@@ -530,7 +530,10 @@ class TestAnnounceStreamPropagation:
                     if s["name"].startswith("sched.")]
 
             deadline = time.monotonic() + 5
-            while (len({s["name"] for s in server_spans()}) < 2
+            # sched.filter, a child, is written before sched.schedule:
+            # wait for the two names asserted, not for any two.
+            while (not {"sched.register", "sched.schedule"}
+                   <= {s["name"] for s in server_spans()}
                    and time.monotonic() < deadline):
                 time.sleep(0.05)
             spans = server_spans()
