@@ -27,19 +27,21 @@ number it is held to (PERF.md section 2 has the readings behind them):
   cancel, |g| alone is too small a yardstick and the uncancelled scale
   alone too large; their geometric mean reads steady from seed to seed.
   It sees precision.
-- ``row_weight_gap``: where the program's gradients lie between the
-  whole batch's mean (0) and the mean over one half of its rows alone
-  (1). At each step on the initial parameters (the first two: the
-  schedule's first learning rate is 0) the reference gives both, g and
-  g_half;
-  the program's g_prog - g is fitted by least squares as a·g + t·(g_half
-  - g), a taking up a common scale (one rounding that every row shares)
-  step by step and t being one for all the steps, and the number is |t|.
-  Rows that do not weigh the same in the mean move the gradient along
-  g_half - g and nothing else (either half left out: t = ±1). Rounding
-  has a component there too, since it acts much as a small random
-  re-weighting of the rows does (PERF.md section 2), which is why the
-  two steps are pooled.
+- ``row_weight_step_gap``: where the change of the program's gradient
+  from step 1 to step 2 lies between the whole batch's (0) and that of
+  the mean over one half of the rows alone (1). The two steps run on the
+  initial parameters (the schedule's first learning rate is 0), and at
+  each the reference gives both gradients, g and g_half. With a =
+  g_prog - g and d = g_half - g, a_2 - a_1 is fitted by least squares
+  as t·(d_2 - d_1), and the number is |t|. Rows that do not weigh the
+  same in the mean move each gradient along its d and nothing else
+  (either half left out: t = ±1). The program's rounding has a
+  component along d too, and at one step alone as large a one as the
+  fault's (PERF.md section 2); but it is a function of the parameters
+  far more than of the rows, so between two steps on the same
+  parameters nearly all of it cancels, as the loss's does in
+  ``loss_step_gap``, while the rows' part, drawn anew at each step,
+  does not.
 - ``change_gap``: as ``grad_gap`` for the norm of each leaf's change over
   the compared steps. Leaves whose reference gradient is under a
   thousandth of the median leaf's (a key bias under softmax) move under
@@ -53,23 +55,48 @@ import numpy as np
 ADAM_B1 = 0.9
 
 
-def flatten(tree, prefix="") -> dict:
-    """Nested parameter dicts → ``{"a/b/kernel": array}``; a top-level
+def leaves(tree, prefix="") -> dict:
+    """Nested parameter dicts → ``{"a/b/kernel": array}``, each array
+    as it is held (nothing copied, nothing converted); a top-level
     ``params`` collection is dropped."""
     out = {}
     for key, value in tree.items():
         name = f"{prefix}/{key}" if prefix else str(key)
         if isinstance(value, dict) or hasattr(value, "items"):
-            out.update(flatten(value, name))
+            out.update(leaves(value, name))
         else:
-            out[name] = np.asarray(value, np.float64)
+            out[name] = value
     if not prefix and out and all(k.startswith("params/") for k in out):
         out = {k[len("params/"):]: v for k, v in out.items()}
     return out
 
 
-def _norms(tree: dict) -> dict:
-    return {k: float(np.linalg.norm(v)) for k, v in tree.items()}
+def _f64(leaf):
+    """One leaf as a float64 vector of this function's own, so that the
+    caller may work in place."""
+    return np.array(leaf, np.float64).ravel()
+
+
+def _flat(leaf):
+    """One leaf as a vector in its own type, nothing copied."""
+    return np.asarray(leaf).reshape(-1)
+
+
+def _norm(v) -> float:
+    return float(np.sqrt(v @ v))
+
+
+def _gradient(moments: list, t: int, k: str):
+    """Leaf ``k`` of the gradient of step ``t`` as the optimizer got it,
+    from Adam's first moment after each step:
+    g_t = (m_t − β₁·m_{t−1}) / (1 − β₁)."""
+    g = _f64(moments[t][k])
+    if t:
+        before = _f64(moments[t - 1][k])
+        before *= ADAM_B1
+        g -= before
+    g /= 1.0 - ADAM_B1
+    return g
 
 
 def _worst_norm_gap(ours: dict, theirs: dict, leaves) -> tuple[float, str]:
@@ -82,31 +109,28 @@ def _worst_norm_gap(ours: dict, theirs: dict, leaves) -> tuple[float, str]:
     return worst, at
 
 
-def gradients(moments: list) -> list:
-    """Adam's first moment after each step → the gradient of each step
-    as the optimizer got it: g_t = (m_t − β₁·m_{t−1}) / (1 − β₁)."""
-    out, before = [], None
-    for moment in map(flatten, moments):
-        out.append({k: (v - (ADAM_B1 * before[k] if before else 0.0))
-                    / (1.0 - ADAM_B1) for k, v in moment.items()})
-        before = moment
-    return out
-
-
 def numbers(program: dict, reference: dict) -> dict:
     """``program``: params_before, moments, params_after, losses.
-    ``reference``: params_before, grads, params_after, losses.
-    Returns each number with the leaf or step that set it."""
-    p0, r0 = flatten(program["params_before"]), flatten(
+    ``reference``: params_before, grads, params_after, losses,
+    logit_grad, grads_first_half.
+    Returns each number with the leaf or step that set it.
+
+    Every number is a function of per-leaf norms and inner products, so
+    the trees are walked once, one leaf of each in float64 at a time
+    (three such vectors at the most): what this holds does not grow
+    with the model beyond its largest leaf."""
+    p0, r0 = leaves(program["params_before"]), leaves(
         reference["params_before"])
     if sorted(p0) != sorted(r0):
         raise ValueError(
             "the program's and the reference's parameters differ in name: "
             f"{sorted(set(p0) ^ set(r0))}")
-    out = {}
-    init = {k: float(np.max(np.abs(p0[k] - r0[k]))) for k in r0}
-    at = max(init, key=init.get)
-    out["init_gap"] = (init[at], at)
+    p1, r1 = leaves(program["params_after"]), leaves(
+        reference["params_after"])
+    moments = [leaves(m) for m in program["moments"]]
+    grads_ref = [leaves(g) for g in reference["grads"]]
+    halves = [leaves(g) for g in reference["grads_first_half"]]
+    logit = leaves(reference["logit_grad"])
 
     lp, lr = program["losses"], reference["losses"]
     if len(lp) != len(lr):
@@ -114,46 +138,74 @@ def numbers(program: dict, reference: dict) -> dict:
     gaps = [abs(a - b) / max(abs(b), 1e-30) for a, b in zip(lp, lr)]
     gaps = [g if np.isfinite(g) else 1e30 for g in gaps]
     step = int(np.argmax(gaps))
-    out["loss_gap"] = (float(gaps[step]), f"step {step + 1}")
+    loss_gap = (float(gaps[step]), f"step {step + 1}")
 
     # Steps 1 and 2 run on the same parameters (the schedule's first
     # learning rate is 0), so what differs between them is the rows
     # alone, and rounding that the two steps share cancels.
     step = (lp[1] - lp[0]) - (lr[1] - lr[0])
-    out["loss_step_gap"] = (abs(step) / max(abs(lr[0]), 1e-30),
-                            "step 2 - step 1")
+    loss_step_gap = (abs(step) / max(abs(lr[0]), 1e-30), "step 2 - step 1")
 
-    grads_ref = [flatten(g) for g in reference["grads"]]
-    grads_prog = gradients(program["moments"])
-    grad_ref, grad_prog = grads_ref[0], grads_prog[0]
-    g_ref, g_prog = _norms(grad_ref), _norms(grad_prog)
+    if min(len(moments), len(grads_ref), len(halves)) < 2:
+        raise ValueError("the comparison needs two steps on the initial "
+                         "parameters, each with its first-half gradient")
+    init, moved_prog, moved_ref, g_prog, g_ref = {}, {}, {}, {}, {}
+    diff_sq = logit_sq = ref_sq = 0.0
+    # With a = g_prog - g and d = g_half - g at each of the two steps on
+    # the initial parameters: (a_2 - a_1)·(d_2 - d_1) and |d_2 - d_1|²,
+    # over all leaves.
+    along = length = 0.0
+    for k in r0:
+        ours, theirs = _f64(p0[k]), _f64(r0[k])
+        gap = ours - theirs
+        init[k] = float(np.max(np.abs(gap, out=gap)))
+        del gap
+        after = _f64(p1[k])
+        after -= ours
+        moved_prog[k] = _norm(after)
+        del ours
+        after = _f64(r1[k])
+        after -= theirs
+        moved_ref[k] = _norm(after)
+        del theirs, after
+
+        scale = _f64(logit[k])
+        logit_sq += float(scale @ scale)
+        del scale
+        # ``a`` and ``d`` become the two differences in place; the other
+        # trees' leaves come in as they are held.
+        a = _gradient(moments, 1, k)
+        first, g = _gradient(moments, 0, k), _f64(grads_ref[0][k])
+        g_prog[k], g_ref[k] = _norm(first), _norm(g)
+        ref_sq += float(g @ g)
+        a -= first
+        first -= g
+        diff_sq += float(first @ first)
+        del first
+        a += g
+        d = _f64(halves[1][k])
+        d -= _flat(halves[0][k])
+        d += g
+        del g
+        g = _f64(grads_ref[1][k])
+        a -= g
+        d -= g
+        del g
+        along += float(a @ d)
+        length += float(d @ d)
+        del a, d
+
+    at = max(init, key=init.get)
+    out = {"init_gap": (init[at], at), "loss_gap": loss_gap,
+           "loss_step_gap": loss_step_gap}
     out["grad_gap"] = _worst_norm_gap(g_prog, g_ref, g_ref)
+    scale = np.sqrt(np.sqrt(ref_sq) * np.sqrt(logit_sq))
+    out["grad_diff_scaled"] = (float(np.sqrt(diff_sq)) / max(scale, 1e-30),
+                               "all leaves")
+    out["row_weight_step_gap"] = (
+        abs(along) / max(length, 1e-300),
+        "first half of the rows, step 2 - step 1")
 
-    every = lambda t: np.concatenate([t[k].ravel() for k in sorted(t)])  # noqa: E731
-    norm = lambda t: float(np.linalg.norm(every(t)))  # noqa: E731
-    scale = np.sqrt(norm(grad_ref) * norm(flatten(reference["logit_grad"])))
-    out["grad_diff_scaled"] = (
-        norm({k: grad_prog[k] - grad_ref[k] for k in grad_ref})
-        / max(scale, 1e-30), "all leaves")
-
-    # One t over the steps that have a g_half, a scale of its own for
-    # each: with g taken out of both sides step by step, t is a ratio of
-    # two sums.
-    along, length = 0.0, 0.0
-    for ours, whole, half in zip(grads_prog, grads_ref,
-                                 reference["grads_first_half"]):
-        whole = every(whole)
-        off = lambda v: v - whole * (v @ whole) / (whole @ whole)  # noqa: E731
-        towards_half = off(every(flatten(half)) - whole)
-        along += off(every(ours) - whole) @ towards_half
-        length += towards_half @ towards_half
-    out["row_weight_gap"] = (abs(float(along)) / max(float(length), 1e-300),
-                             "first half of the rows, steps 1-2")
-
-    p1, r1 = flatten(program["params_after"]), flatten(
-        reference["params_after"])
-    moved_prog = _norms({k: p1[k] - p0[k] for k in r0})
-    moved_ref = _norms({k: r1[k] - r0[k] for k in r0})
     median_grad = float(np.median(list(g_ref.values())))
     live = [k for k in r0 if g_ref[k] >= 1e-3 * median_grad]
     out["change_gap"] = _worst_norm_gap(moved_prog, moved_ref, live)

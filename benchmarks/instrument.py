@@ -1,9 +1,9 @@
 """What the harness puts around the program's train loops.
 
-The window drives ``train_gat`` / ``train_gnn`` themselves: one call
-builds the state, compiles the step, runs the set-up steps and then the
-measured window, all on that one object, with ``max_seconds`` set from
-``--seconds``. The program exposes no hook for that yet (PERF.md lists
+The window drives the program's own entry (``train_gat``,
+``train_gnn``; a kind's runner names it): one call builds the state,
+compiles the step, runs the set-up steps and then the measured window,
+all on that one object, with ``max_seconds`` set from ``--seconds``. The program exposes no hook for that yet (PERF.md lists
 what the ``tracing`` issue should add), so two of its names are swapped
 for the length of the call:
 
@@ -17,11 +17,11 @@ for the length of the call:
   Besides that the subclass keeps the harness's clock beside the
   program's and holds the host to :data:`MAX_IN_FLIGHT` steps ahead of
   the device, which the program does not do.
-- the jitted train step → :class:`StepObserver`, which copies what the
-  comparison needs out of the first steps' states before the next call
-  donates them, and otherwise only forwards the call. Where the program
-  renames ``train_step`` no observer is built, and the run fails saying
-  so (``run.py``) rather than carry on unobserved.
+- the jitted train step → :class:`StepObserver`, which fetches what the
+  comparison needs out of the first steps' states to the host before
+  the next call donates them, and otherwise only forwards the call.
+  Where the program renames ``train_step`` no observer is built, and
+  the run fails saying so (``run.py``) rather than carry on unobserved.
 
 Neither changes an argument, a result or the order of calls.
 """
@@ -33,7 +33,7 @@ import contextlib
 import time
 
 import jax
-import jax.numpy as jnp
+import numpy as np
 
 # Steps the host may be ahead of the device. The program's loops have
 # no such limit: the runtime lets dozens of steps queue, the program's
@@ -133,30 +133,32 @@ def observed_budget(program_budget, plan: WindowPlan):
     return ObservedBudget
 
 
-def _copy(tree):
-    return jax.tree.map(jnp.copy, tree)
+def _fetch(tree):
+    """The tree on the host, sharing nothing with the device: on the CPU
+    backend ``device_get`` hands out views of the buffers, and a view
+    keeps a buffer alive that the next call would donate."""
+    return jax.tree.map(np.array, jax.device_get(tree))
 
 
 class StepObserver:
     """The program's own jitted step. Forwards every call; around the
-    first ``compare_steps`` of them it keeps device copies of what the
-    comparison reads: the parameters before step 1, Adam's first moment
-    after each step (the gradients as the optimizer got them), each
-    step's loss, and the parameters after the last compared step. The
-    copies are taken before the next call donates the state."""
+    first ``compare_steps`` of them it fetches to the host what the
+    comparison reads: the parameters before step 1 (from the arguments,
+    before the call donates them), each step's loss, Adam's first moment
+    after each step (the gradients as the optimizer got them) and the
+    parameters after the last compared step (from the returned state,
+    before it is handed back). It keeps nothing on the device, so the
+    peak the run reports is the program's alone; the waits are set-up's,
+    the compared steps lying before the window."""
 
-    def __init__(self, jitted, compare_steps: int, analyse: bool = False,
-                 after_first=None):
+    def __init__(self, jitted, compare_steps: int):
         self.jitted = jitted
         self.compare_steps = int(compare_steps)
-        self.analyse = analyse
-        self.after_first = after_first
         self.calls = 0
         self.params_before = None
         self.moments = []
         self.params_after = None
         self.losses = []
-        self.memory_analysis = None
 
     def __call__(self, *args):
         self.calls += 1
@@ -165,35 +167,20 @@ class StepObserver:
             with jax.profiler.TraceAnnotation("bench.dispatch"):
                 return self.jitted(*args)
         if n == 1:
-            self.params_before = _copy(args[0].params)
-            if self.analyse:
-                self.memory_analysis = _memory_analysis(self.jitted, args)
+            self.params_before = _fetch(args[0].params)
         state, loss = self.jitted(*args)
-        self.losses.append(loss)
-        self.moments.append(_copy(_adam_mu(state.opt_state)))
-        if n == 1:
-            if self.after_first is not None:
-                # Set-up: programs the loop will meet later in the run
-                # (its end-of-epoch reductions) compile here, not there.
-                self.after_first(loss)
+        self.losses.append(float(np.mean(jax.device_get(loss))))
+        self.moments.append(_fetch(_adam_mu(state.opt_state)))
         if n == self.compare_steps:
-            self.params_after = _copy(state.params)
+            self.params_after = _fetch(state.params)
         return state, loss
 
     def readings(self) -> dict:
-        """Host copies, once the run is over."""
-        import numpy as np
-
         if self.calls < self.compare_steps:
             raise RuntimeError(f"the loop made {self.calls} steps; the "
                                f"comparison needs {self.compare_steps}")
-        get = jax.device_get
-        return {
-            "params_before": get(self.params_before),
-            "moments": [get(m) for m in self.moments],
-            "params_after": get(self.params_after),
-            "losses": [float(np.mean(get(x))) for x in self.losses],
-        }
+        return {"params_before": self.params_before, "moments": self.moments,
+                "params_after": self.params_after, "losses": self.losses}
 
 
 def _adam_mu(opt_state):
@@ -202,22 +189,6 @@ def _adam_mu(opt_state):
         if hasattr(part, "mu"):
             return part.mu
     raise RuntimeError("no Adam state in the optimizer state")
-
-
-def _memory_analysis(jitted, args) -> dict | None:
-    """The compiler's own byte counts for the step as the loop is about
-    to call it (a second, cached compile; traced runs only)."""
-    try:
-        analysis = jitted.lower(*args).compile().memory_analysis()
-    except Exception as exc:  # a reading for PERF.md, never a reason to fail
-        return {"error": f"{type(exc).__name__}: {exc}"}
-    if analysis is None:
-        return None
-    names = ("temp_size_in_bytes", "argument_size_in_bytes",
-             "output_size_in_bytes", "alias_size_in_bytes",
-             "generated_code_size_in_bytes")
-    return {n: int(getattr(analysis, n)) for n in names
-            if hasattr(analysis, n)}
 
 
 class _JaxWithObservedJit:

@@ -6,7 +6,9 @@ temporaries (the compiler's ``temp_size``, held from the first step
 on), which is nearly all of a train step's memory (PERF.md section 6).
 ``run.py`` reports the same number as ``memory_peak_bytes``. Layer:
 device. Moves ``train_samples_per_s`` (room for a larger batch or
-fleet)."""
+fleet). The CPU backend keeps no memory statistics."""
+
+chip_only = True
 
 
 def read(ctx):

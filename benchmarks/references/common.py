@@ -161,31 +161,32 @@ def follow(params: dict, batches, loss_and_grad, optimizer: dict,
     ``batches`` under AdamW and return what the comparison reads (host
     arrays). ``frozen`` plants the fault "a step that returns its state
     unchanged" for the control's readings."""
+    # Each tree goes to the host as it is made: the device holds the
+    # parameters, Adam's moments and one gradient, whatever the steps.
+    get = lambda tree: {k: np.asarray(v) for k, v in tree.items()}  # noqa: E731
     adam = AdamW(params, optimizer["weight_decay"])
-    start = params
+    start = get(params)
     # The gradient of the first batch's mean logit: the scale of a
     # gradient before the rows' residuals cancel in it.
-    scale = logit_grad(params, batches[0]) if logit_grad else {}
+    scale = get(logit_grad(params, batches[0])) if logit_grad else {}
     losses, all_grads, halves = [], [], []
     at_start = True
     for step, batch in enumerate(batches):
         loss, grads = loss_and_grad(params, batch, step)
-        all_grads.append(grads)
+        all_grads.append(get(grads))
         if at_start:
             # The same gradient over the first half of its rows alone:
             # the direction a gradient moves in when rows do not weigh
             # the same. Read while the parameters are the initial ones
             # (the schedule's first learning rate is 0: two steps).
-            halves.append(
-                loss_and_grad(params, batch, step, rows=len(batch) // 2)[1])
+            halves.append(get(
+                loss_and_grad(params, batch, step, rows=len(batch) // 2)[1]))
         losses.append(float(loss))
         lr = learning_rate(step, optimizer["learning_rate"],
                            optimizer["warmup"], optimizer["total_steps"])
         at_start = at_start and lr == 0.0
         if not frozen:
             params = adam.update(params, grads, lr)
-    get = lambda tree: {k: np.asarray(v) for k, v in tree.items()}  # noqa: E731
-    return {"params_before": get(start), "grads": [get(g) for g in all_grads],
+    return {"params_before": start, "grads": all_grads,
             "params_after": get(params), "losses": losses,
-            "logit_grad": get(scale),
-            "grads_first_half": [get(g) for g in halves]}
+            "logit_grad": scale, "grads_first_half": halves}

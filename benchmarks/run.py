@@ -18,6 +18,7 @@ import argparse  # noqa: E402
 import importlib  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
+import resource  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
 
@@ -88,6 +89,10 @@ def enable_compilation_cache() -> None:
     jax.config.update("jax_compilation_cache_dir", where)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    # The ``df2.*`` scopes that metrics read are operation metadata,
+    # which JAX leaves out of the key unless told: without this a
+    # program cached by another build comes back with that build's names.
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
 
 
 def run_cell(name: str, seed: int, seconds: float, trace: bool,
@@ -98,7 +103,7 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool,
     ``study`` (limits.py) gets the two sets of readings in a dict."""
     import jax
 
-    from benchmarks import compare, instrument, peaks, traffic
+    from benchmarks import compare, instrument, peaks
     from benchmarks import trace as tracing
 
     bench, cell, workload, spec = load_cell(name, rehearse)
@@ -114,10 +119,10 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool,
     from dragonfly2_tpu.parallel import data_parallel_mesh
     mesh = data_parallel_mesh(devices=devices)
 
-    # The program's own seed arguments end in 32-bit keys; the graph
-    # takes the whole seed.
+    # The program's own seed arguments end in 32-bit keys; the kind's
+    # traffic takes the whole seed.
     program_seed = seed % (2**31 - 2)
-    arrays = traffic.probe_graph(spec["fleet"], seed)
+    arrays = runner.traffic(spec, seed)
 
     trace_dir = os.path.join(ROOT, ".bench_trace", name)
     run = {"chips": chips}
@@ -156,22 +161,18 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool,
         if wrap_fault is not None:
             jitted = wrap_fault(jitted)
         observers.append(instrument.StepObserver(
-            jitted, workload["compare_steps"], analyse=trace,
-            after_first=runner.warm_epoch_end(spec, arrays)))
+            jitted, workload["compare_steps"]))
         return observers[-1]
 
     runner.drive(spec, arrays, program_seed, plan, mesh, wrap_step)
     if len(observers) != 1:
         raise RuntimeError(f"{len(observers)} step programs were built; the "
                            "runner observes exactly one")
-    observer = observers.pop()
     run.update(steps=plan.steps, samples=plan.samples,
                window_seconds=plan.window_seconds,
                setup_seconds=plan.t_open - T0,
                compile_seconds=plan.compile_seconds)
-    program = observer.readings()
-    analysis = observer.memory_analysis
-    del observer  # the program's state went with the entry's return
+    program = observers.pop().readings()
 
     t_ref = time.perf_counter()
     followed = reference.readings(
@@ -208,11 +209,14 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool,
         device["busy_s"] = reduced.busy_s
         device["window_s"] = run["window_seconds"]
         result["breakdown"] = tracing.breakdown(reduced, selects)
+    # What the host held at the most (the observer's and the reference's
+    # trees are host memory): kilobytes on Linux.
+    run["host_peak_rss_bytes"] = 1024 * resource.getrusage(
+        resource.RUSAGE_SELF).ru_maxrss
     result["run"] = {k: run[k] for k in (
         "steps", "samples", "window_seconds", "setup_seconds",
-        "compile_seconds", "reference_seconds")}
-    if analysis is not None:
-        result["run"]["step_memory_analysis"] = analysis
+        "compile_seconds", "reference_seconds", "host_peak_rss_bytes")}
+    if trace:
         result["run"]["memory_stats"] = run["memory_stats"]
     if study is not None:
         result["study"] = study({

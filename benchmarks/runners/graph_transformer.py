@@ -8,6 +8,14 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmarks import instrument
+from benchmarks.traffic import probe_graph
+
+
+def traffic(spec: dict, seed: int) -> dict:
+    """The cell's inputs from the seed: the probe graph of the
+    configuration's ``fleet`` group. Same seed, same arrays; every seed
+    the same sizes (README.md, "A model kind")."""
+    return probe_graph(spec["fleet"], seed)
 
 
 def drive(spec: dict, arrays: dict, seed: int, plan, mesh, wrap_step) -> None:
@@ -40,12 +48,3 @@ def drive(spec: dict, arrays: dict, seed: int, plan, mesh, wrap_step) -> None:
             instrument.observed_jit(gat_trainer, "train_step", wrap_step):
         gat_trainer.train_gat(graph, config, mesh)
 
-
-def warm_epoch_end(spec: dict, arrays: dict):
-    """What ``train_gat`` runs on the host's side at each epoch's end,
-    on a first step's loss: compiled in set-up."""
-    per_epoch = max(len(arrays["edge_src"]) // spec["batch"], 1)
-
-    def warm(loss_k):
-        float(jnp.mean(jnp.concatenate([loss_k] * per_epoch)))
-    return warm

@@ -8,6 +8,14 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmarks import instrument
+from benchmarks.traffic import probe_graph
+
+
+def traffic(spec: dict, seed: int) -> dict:
+    """The cell's inputs from the seed: the probe graph of the
+    configuration's ``fleet`` group. Same seed, same arrays; every seed
+    the same sizes (README.md, "A model kind")."""
+    return probe_graph(spec["fleet"], seed)
 
 
 def drive(spec: dict, arrays: dict, seed: int, plan, mesh, wrap_step) -> None:
@@ -42,12 +50,3 @@ def drive(spec: dict, arrays: dict, seed: int, plan, mesh, wrap_step) -> None:
             instrument.observed_jit(fused_sampling, "train_step", wrap_step):
         gnn_trainer.train_gnn(graph, config, mesh)
 
-
-def warm_epoch_end(spec: dict, arrays: dict):
-    """What ``train_gnn`` runs on the host's side at each epoch's end,
-    on a first step's loss: compiled in set-up."""
-    per_epoch = max(len(arrays["edge_src"]) // spec["batch"], 1)
-
-    def warm(loss):
-        float(jnp.mean(jnp.stack([loss] * per_epoch)))
-    return warm

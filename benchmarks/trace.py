@@ -1,15 +1,20 @@
 """Reduction of a profiler trace (``.xplane.pb``) to what the per-layer
 metrics read: the device's operation intervals, their union (busy
-time), per-operation sums, and the longest idle gaps with what the host
-was doing in each.
+time), per-operation sums, the time under each of the program's
+``df2.*`` device scopes and under none, and the longest idle gaps with
+what the host was doing in each.
 
-Read with ``jax.profiler.ProfileData`` alone. On a TPU each chip is a
-plane ``/device:TPU:<n>`` whose ``XLA Ops`` line holds one event per
-executed HLO operation; the host is the plane ``/host:CPU`` with one
-line per thread. A rehearsal on the CPU backend has no device plane:
+Read with ``jax.profiler.ProfileData``, but for the scopes: they are in
+each operation's ``tf_op`` path, a stat of the event's metadata, which
+``ProfileData`` hides and ``xplane.py`` beside this file decodes. On a
+TPU each chip is a plane ``/device:TPU:<n>`` whose ``XLA Ops`` line
+holds one event per executed HLO operation; the host is the plane
+``/host:CPU`` with one line per thread. A rehearsal on the CPU backend has no device plane:
 there the operations run on XLA's own threads of the host plane
 (``tf_XLA...``), and those lines stand in so that the reduction and the
-readers can be rehearsed. Such a run is never a reading.
+readers can be rehearsed. Such a run is never a reading, and it has no
+scope path: ``scope_seconds`` is empty there, ``scoped_s`` and
+``unscoped_s`` are None, and their readers find nothing to read.
 """
 
 from __future__ import annotations
@@ -17,6 +22,7 @@ from __future__ import annotations
 import dataclasses
 import glob
 import os
+import re
 
 DEVICE_PLANE = "/device:TPU:"
 OPS_LINE = "XLA Ops"
@@ -26,6 +32,10 @@ CPU_OP_LINES = "tf_XLA"
 # every operation.
 _FRAMES = ("ThreadpoolListener", "ThunkExecutor", "SlinkyThreadPool",
            "end: ", "$")
+# A scope in an operation's path. JAX wraps a path's first scope in the
+# transformations it went through (``transpose(jvp(df2.model))``), so a
+# scope is matched as a name, not as a whole path component.
+SCOPE = re.compile(r"(?<![\w.])df2\.[A-Za-z_][\w.]*")
 
 
 @dataclasses.dataclass
@@ -36,6 +46,16 @@ class Reduced:
     busy_s: float                 # union of op intervals, mean over chips
     op_seconds: dict              # op name -> summed seconds, mean over chips
     gaps: list                    # [(host event name, seconds)], longest first
+    # scope -> seconds under it (the union of the intervals of the
+    # operations whose path holds it: a ``while`` and the operations of
+    # its body are both events, and a scope inside another counts for
+    # both), the busy seconds under any scope and those under none (by
+    # the same decoder, so the two add up to its busy time; ``busy_s``
+    # is ``ProfileData``'s, which cuts every time to a whole ns); each a
+    # mean over chips.
+    scope_seconds: dict = dataclasses.field(default_factory=dict)
+    scoped_s: float | None = None
+    unscoped_s: float | None = None
 
     def seconds_where(self, selects) -> float:
         """Summed device seconds of the operations that ``selects(name)``
@@ -121,7 +141,44 @@ def reduce(path: str, rehearse: bool = False, n_gaps: int = 5) -> Reduced:
                         (ev.start_ns, ev.start_ns + ev.duration_ns, ev.name))
     longest = sorted(all_gaps, key=lambda g: g[0] - g[1])[:n_gaps]
     gaps = [(_host_in(host_events, a, b), (b - a) * 1e-9) for a, b in longest]
-    return Reduced(chips, first, last, busy / chips, op_seconds, gaps)
+    return Reduced(chips, first, last, busy / chips, op_seconds, gaps,
+                   *scopes(path))
+
+
+def scopes(path: str) -> tuple[dict, float | None, float | None]:
+    """``Reduced``'s ``scope_seconds``, ``scoped_s`` and ``unscoped_s``
+    of the dump at ``path``, as ``df2-trace-tool train`` reckons a
+    scope's time. Without a device plane: ``({}, None, None)``."""
+    from benchmarks import xplane
+
+    under, inside, bare, chips = {}, 0.0, 0.0, 0
+    for plane in xplane.read_xspace(path):
+        if not plane.name.startswith(DEVICE_PLANE):
+            continue
+        chips += 1
+        every, scoped, by_scope = [], [], {}
+        for line in plane.lines:
+            if line.name != OPS_LINE:
+                continue
+            for ev in line.events:
+                if ev.duration_ns <= 0:
+                    continue
+                span = (ev.start_ns, ev.start_ns + ev.duration_ns)
+                every.append(span)
+                found = set(SCOPE.findall(ev.stats.get("tf_op") or ""))
+                if found:
+                    scoped.append(span)
+                for name in found:
+                    by_scope.setdefault(name, []).append(span)
+        for name, spans in by_scope.items():
+            under[name] = under.get(name, 0.0) + _union(spans)[0] * 1e-9
+        covered = _union(scoped)[0]
+        inside += covered * 1e-9
+        bare += (_union(every)[0] - covered) * 1e-9
+    if not chips:
+        return {}, None, None
+    return ({k: v / chips for k, v in under.items()}, inside / chips,
+            bare / chips)
 
 
 def _host_in(host_events, a: float, b: float) -> str:
