@@ -34,6 +34,14 @@ def main(argv=None) -> int:
     parser.add_argument("--train-gat", action="store_true",
                         help="also train + register the GraphTransformer "
                              "(BASELINE config #3) each cycle")
+    parser.add_argument("--train-seq", default="", metavar="CONFIG_JSON",
+                        help="also train + register a sequence model "
+                             "(lfm2_moe family) from the host's token "
+                             "segments each cycle; the file holds the "
+                             "model's published config.json keys and may "
+                             "add what is held here and the job's "
+                             "settings (train/seq_trainer.py "
+                             "config_from_dict)")
     parser.add_argument("--train-interval", type=float, default=0.0,
                         help="seconds between periodic retrain cycles: "
                              "every interval, hosts with NEW closed "
@@ -100,11 +108,19 @@ def main(argv=None) -> int:
     storage = TrainerStorage(args.data_dir)
     metrics = TrainerMetrics(version=__version__)
     training_config = None
-    if args.profile_dir or args.train_gat:
+    if args.profile_dir or args.train_gat or args.train_seq:
         from dragonfly2_tpu.trainer.training import TrainingConfig
 
         training_config = TrainingConfig(train_gat_model=args.train_gat,
                                          profile_dir=args.profile_dir)
+        if args.train_seq:
+            import json
+
+            from dragonfly2_tpu.train.seq_trainer import config_from_dict
+
+            with open(args.train_seq, encoding="utf-8") as fh:
+                training_config.seq = config_from_dict(json.load(fh))
+            training_config.train_seq_model = True
     service = TrainerService(
         storage,
         Training(storage, registry, config=training_config,

@@ -8,7 +8,7 @@ annotate, XLA lays out the collectives.
 """
 
 from dragonfly2_tpu.parallel.mesh import MeshContext, data_parallel_mesh
-from dragonfly2_tpu.parallel.moe import moe_apply
+from dragonfly2_tpu.parallel.moe import expert_layer
 from dragonfly2_tpu.parallel.multihost import (
     MultihostMeshContext,
     agree,
@@ -24,6 +24,6 @@ from dragonfly2_tpu.parallel.ring_attention import ring_attention
 from dragonfly2_tpu.parallel.ulysses import ulysses_attention
 
 __all__ = ["MeshContext", "MultihostMeshContext", "agree",
-           "data_parallel_mesh", "init_multihost", "moe_apply",
+           "data_parallel_mesh", "expert_layer", "init_multihost",
            "multihost_mesh", "pipeline_apply", "ring_attention",
            "stack_stage_params", "sync", "ulysses_attention"]

@@ -14,6 +14,13 @@ from dragonfly2_tpu.train.cost_trainer import (
 from dragonfly2_tpu.train.gat_trainer import GATTrainConfig, GATTrainResult, train_gat
 from dragonfly2_tpu.train.gnn_trainer import GNNTrainConfig, GNNTrainResult, train_gnn
 from dragonfly2_tpu.train.mlp_trainer import MLPTrainConfig, MLPTrainResult, train_mlp
+from dragonfly2_tpu.train.seq_trainer import (
+    SeqCorpus,
+    SeqTrainConfig,
+    SeqTrainResult,
+    pack_documents,
+    train_seq,
+)
 
 __all__ = [
     "CostTrainConfig",
@@ -24,8 +31,13 @@ __all__ = [
     "GNNTrainResult",
     "MLPTrainConfig",
     "MLPTrainResult",
+    "SeqCorpus",
+    "SeqTrainConfig",
+    "SeqTrainResult",
+    "pack_documents",
     "train_cost",
     "train_gat",
     "train_gnn",
     "train_mlp",
+    "train_seq",
 ]

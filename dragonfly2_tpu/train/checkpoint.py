@@ -96,6 +96,18 @@ def gat_from_tree(tree: dict) -> tuple:
             node_ids)
 
 
+def seq_tree(params: Any, routing_counts: np.ndarray) -> dict:
+    """Sequence-model checkpoint: params + the assignments each expert
+    got over the run (``[expert layers, experts]``; what a later run
+    would set a selection bias from)."""
+    return {"params": params,
+            "routing_counts": np.asarray(routing_counts, np.int64)}
+
+
+def seq_from_tree(tree: dict) -> tuple[Any, np.ndarray]:
+    return tree["params"], np.asarray(tree["routing_counts"])
+
+
 def mlp_tree(params: Any, normalizer: Normalizer, target_norm: Normalizer) -> dict:
     return {
         "params": params,

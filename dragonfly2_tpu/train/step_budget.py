@@ -54,10 +54,19 @@ class TrainingStats:
     - ``steady_compiles``: those of them after the budget's first
       ``tick``. 0 in a healthy run: anything else compiled while the
       loop should only have been dispatching.
+    - ``moe_steps``: optimizer steps of loops whose model routes tokens
+      over experts (``train_seq``), and over them
+      ``moe_assignments_held``: token-to-expert assignments that went to
+      an expert held here, summed over the expert layers, and
+      ``moe_assignments_hottest``: those of each layer's most-assigned
+      held expert, summed over the layers. Hottest over (held / experts
+      held) is the load imbalance the grouped products see. Read from
+      the device once, at a loop's drain.
     """
 
     KEYS = ("loops_started", "dispatches", "steps", "samples",
-            "compile_seconds", "loop_compiles", "steady_compiles")
+            "compile_seconds", "loop_compiles", "steady_compiles",
+            "moe_steps", "moe_assignments_held", "moe_assignments_hottest")
 
     def __init__(self):
         self._lock = threading.Lock()
@@ -114,6 +123,46 @@ def epoch_mean(losses) -> float:
     an epoch ends."""
     return float(np.mean(np.concatenate(
         [np.ravel(x) for x in jax.device_get(losses)])))
+
+
+def step_loop(budget, epochs: int, epoch_steps, dispatch, *,
+              step_samples: int, drain, serialize_launches: bool = False):
+    """The skeleton of a train loop with its host spans
+    (docs/OBSERVABILITY.md "Training loops"): for each epoch, for each
+    ``make_input`` of ``epoch_steps(epoch)``, ``dispatch(make_input())``
+    launches one step and returns its loss; ``budget.tick`` counts
+    ``step_samples`` and says when to stop. ``drain()`` returns what the
+    final wait blocks on. Returns each epoch's mean loss (a host sync at
+    an epoch's end). The spans cost nothing while no profiler runs."""
+    span = jax.profiler.TraceAnnotation
+    history, stop, step_num = [], False, 0
+    for epoch in range(epochs):
+        losses = []
+        for i, make_input in enumerate(epoch_steps(epoch)):
+            with jax.profiler.StepTraceAnnotation(
+                    "df2.train.step", step_num=step_num):
+                with span("df2.train.input", epoch=epoch, step=i):
+                    inputs = make_input()
+                with span("df2.train.dispatch"):
+                    loss = dispatch(inputs)
+                if serialize_launches:
+                    jax.block_until_ready(loss)
+                losses.append(loss)
+                with span("df2.train.tick"):
+                    stop = budget.tick(step_samples, loss)
+            step_num += 1
+            if stop:
+                break
+        if losses:
+            # A host sync: it waits for every queued step.
+            with span("df2.train.epoch_end"):
+                history.append(epoch_mean(losses))
+        if stop:
+            break
+    with span("df2.train.drain"):
+        jax.block_until_ready(drain())
+    budget.finish()
+    return history
 
 
 class StepBudget:

@@ -18,6 +18,7 @@ from dragonfly2_tpu.trainer.service import (
     TrainMlpRequest,
     TrainRequest,
     TrainResponse,
+    TrainSeqRequest,
 )
 
 __all__ = [
@@ -31,4 +32,5 @@ __all__ = [
     "TrainGnnRequest",
     "TrainMlpRequest",
     "TrainResponse",
+    "TrainSeqRequest",
 ]

@@ -20,7 +20,7 @@ class TrainerMetrics:
             namespace=ns, subsystem=sub, registry=self.registry)
         self.dataset_bytes = Counter(
             "dataset_bytes", "Dataset bytes ingested, by type.",
-            labelnames=("type",),  # gnn | mlp | cost
+            labelnames=("type",),  # gnn | mlp | cost | seq
             namespace=ns, subsystem=sub, registry=self.registry)
         self.train_cycles = Counter(
             "train_cycles_total",

@@ -20,6 +20,7 @@ from dragonfly2_tpu.trainer.storage import (
     DOWNLOAD_PREFIX,
     NETWORK_TOPOLOGY_PREFIX,
     REPLAY_PREFIX,
+    TOKENS_PREFIX,
     TrainerStorage,
 )
 from dragonfly2_tpu.trainer.training import Training
@@ -49,6 +50,16 @@ class TrainCostRequest:
     new_file: bool = False
 
 
+@message("trainer.TrainSeqRequest")
+class TrainSeqRequest:
+    """Token-id chunks for the sequence-model job: little-endian uint16
+    ids; ``new_file`` starts the next segment (a segment is one document,
+    or several ended by the job's end-of-document id)."""
+
+    dataset: bytes = b""
+    new_file: bool = False
+
+
 @message("trainer.TrainRequest")
 class TrainRequest:
     host_id: str = ""
@@ -61,6 +72,7 @@ class TrainRequest:
     gnn: Optional[TrainGnnRequest] = None
     mlp: Optional[TrainMlpRequest] = None
     cost: Optional[TrainCostRequest] = None
+    seq: Optional[TrainSeqRequest] = None
 
 
 @message("trainer.TrainResponse")
@@ -165,6 +177,17 @@ class TrainerService:
                     if self.metrics:
                         self.metrics.dataset_bytes.labels(type="cost").inc(
                             len(req.cost.dataset))
+                if req.seq is not None:
+                    written.append(
+                        self.storage.append(
+                            TOKENS_PREFIX, req.host_id,
+                            req.seq.dataset, req.seq.new_file,
+                        )
+                    )
+                    accepted += len(req.seq.dataset)
+                    if self.metrics:
+                        self.metrics.dataset_bytes.labels(type="seq").inc(
+                            len(req.seq.dataset))
         except Exception:
             if self.metrics:
                 self.metrics.train_request_failure.inc()

@@ -4,6 +4,9 @@ Mirrors trainer/storage/storage.go (open/read/clear keyed by host ID), with
 one twist: the announcer streams each rotated CSV file separately (each has
 its own header), so datasets are kept as numbered segment files per host
 rather than one concatenated blob — ``download-<hostID>.0000.csv`` etc.
+Token segments (``tokens-<hostID>.000000.bin``) are not CSV: each is a
+run of little-endian uint16 token ids, one document a segment or several
+ended by an end-of-document id (``train/seq_trainer.py``).
 
 Concurrency contract: segment numbering is a monotonic per-(prefix, host)
 counter (never derived from directory listings), so deleting trained
@@ -20,15 +23,23 @@ import re
 import threading
 from typing import Iterator, List, Tuple, Type
 
+import numpy as np
+
 from dragonfly2_tpu.schema import Download, NetworkTopology, ReplayDecision
 from dragonfly2_tpu.schema.io import read_csv_records
 
 DOWNLOAD_PREFIX = "download"
 NETWORK_TOPOLOGY_PREFIX = "networktopology"
 REPLAY_PREFIX = "replay"
-_PREFIXES = (DOWNLOAD_PREFIX, NETWORK_TOPOLOGY_PREFIX, REPLAY_PREFIX)
+TOKENS_PREFIX = "tokens"
+_PREFIXES = (DOWNLOAD_PREFIX, NETWORK_TOPOLOGY_PREFIX, REPLAY_PREFIX,
+             TOKENS_PREFIX)
 _SAFE_HOST = re.compile(r"[^A-Za-z0-9._-]")
-_SEG_RE = re.compile(r"\.(\d+)\.csv$")
+_SEG_RE = re.compile(r"\.(\d+)\.(?:csv|bin)$")
+
+
+def _suffix(prefix: str) -> str:
+    return "bin" if prefix == TOKENS_PREFIX else "csv"
 
 
 def _safe(host_id: str) -> str:
@@ -61,7 +72,8 @@ class TrainerStorage:
                     entry[0].close()
                 seq = self._next_seq_locked(prefix, host_id)
                 path = os.path.join(
-                    self.base_dir, f"{prefix}-{_safe(host_id)}.{seq:06d}.csv"
+                    self.base_dir,
+                    f"{prefix}-{_safe(host_id)}.{seq:06d}.{_suffix(prefix)}"
                 )
                 entry = (open(path, "ab"), path)
                 self._open_files[key] = entry
@@ -105,7 +117,9 @@ class TrainerStorage:
     def _segments(self, prefix: str, host_id: str) -> List[str]:
         return sorted(
             glob.glob(
-                os.path.join(self.base_dir, f"{prefix}-{_safe(host_id)}.*.csv")
+                os.path.join(
+                    self.base_dir,
+                    f"{prefix}-{_safe(host_id)}.*.{_suffix(prefix)}")
             )
         )
 
@@ -133,10 +147,15 @@ class TrainerStorage:
             self._closed_segments(REPLAY_PREFIX, host_id),
         )
 
+    def token_files(self, host_id: str) -> List[str]:
+        """Closed token segments (the sequence-model job's snapshot)."""
+        return self._closed_segments(TOKENS_PREFIX, host_id)
+
     def has_closed_segments(self, host_id: str) -> bool:
         """Any trainable data for a host? (The interval cycle driver's
         skip predicate — docs/REPLAY.md continuous-learning loop.)"""
-        return any(any(files) for files in self.snapshot(host_id))
+        return (any(any(files) for files in self.snapshot(host_id))
+                or bool(self.token_files(host_id)))
 
     def _records(self, record_type: Type, paths: List[str]) -> Iterator:
         for path in paths:
@@ -158,6 +177,12 @@ class TrainerStorage:
         paths = self.replay_files(host_id) if paths is None else paths
         return list(self._records(ReplayDecision, paths))
 
+    def list_tokens(self, host_id: str,
+                    paths: List[str] | None = None) -> List[np.ndarray]:
+        """Each token segment's ids (little-endian uint16), in order."""
+        paths = self.token_files(host_id) if paths is None else paths
+        return [np.fromfile(path, dtype="<u2") for path in paths]
+
     # -- lifecycle ------------------------------------------------------------
 
     def clear_host(self, host_id: str) -> None:
@@ -172,5 +197,6 @@ class TrainerStorage:
             for entry in self._open_files.values():
                 entry[0].close()
             self._open_files.clear()
-        for path in glob.glob(os.path.join(self.base_dir, "*.csv")):
-            os.remove(path)
+        for suffix in ("csv", "bin"):
+            for path in glob.glob(os.path.join(self.base_dir, f"*.{suffix}")):
+                os.remove(path)

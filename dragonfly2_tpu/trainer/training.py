@@ -29,10 +29,13 @@ from dragonfly2_tpu.train import (
     GATTrainConfig,
     GNNTrainConfig,
     MLPTrainConfig,
+    SeqTrainConfig,
+    pack_documents,
     train_cost,
     train_gat,
     train_gnn,
     train_mlp,
+    train_seq,
 )
 from dragonfly2_tpu.train.cost_trainer import (
     MIN_COST_EXAMPLES,
@@ -45,6 +48,7 @@ from dragonfly2_tpu.train.checkpoint import (
     gnn_tree,
     mlp_tree,
     save_model,
+    seq_tree,
 )
 from dragonfly2_tpu.trainer.storage import TrainerStorage
 from dragonfly2_tpu.utils.idgen import (
@@ -52,6 +56,7 @@ from dragonfly2_tpu.utils.idgen import (
     gat_model_id_v1,
     gnn_model_id_v1,
     mlp_model_id_v1,
+    seq_model_id_v1,
 )
 
 logger = logging.getLogger(__name__)
@@ -60,6 +65,7 @@ MODEL_TYPE_GNN = "gnn"
 MODEL_TYPE_MLP = "mlp"
 MODEL_TYPE_GAT = "gat"
 MODEL_TYPE_COST = "cost"
+MODEL_TYPE_SEQ = "seq"
 
 
 class ModelRegistry(Protocol):
@@ -88,6 +94,11 @@ class TrainingConfig:
     # scale-out model is this framework's extension, so it defaults off.
     gat: GATTrainConfig = field(default_factory=GATTrainConfig)
     train_gat_model: bool = False
+    # A sequence model (``train/seq_trainer.py``) over the host's token
+    # segments, opt-in as the GraphTransformer is; ``seq`` names the
+    # model and has no default.
+    seq: Optional[SeqTrainConfig] = None
+    train_seq_model: bool = False
     # Learned piece-cost predictor over replay-plane decision corpora
     # (docs/REPLAY.md) — trained whenever replay segments arrive.
     cost: CostTrainConfig = field(default_factory=CostTrainConfig)
@@ -112,6 +123,8 @@ class TrainOutcome:
     mlp_model_id: Optional[str] = None
     gat_model_id: Optional[str] = None
     cost_model_id: Optional[str] = None
+    seq_model_id: Optional[str] = None
+    seq_evaluation: dict = field(default_factory=dict)
     gnn_evaluation: dict = field(default_factory=dict)
     mlp_evaluation: dict = field(default_factory=dict)
     gat_evaluation: dict = field(default_factory=dict)
@@ -183,6 +196,8 @@ class Training:
         with self._train_lock:
             (download_files, topology_files,
              replay_files) = self.storage.snapshot(host_id)
+            token_files = (self.storage.token_files(host_id)
+                           if self.config.train_seq_model else [])
             # Both graph jobs consume the identical topology snapshot:
             # parse the records and build the Graph ONCE per cycle.
             n_topology, graph = 0, None
@@ -224,8 +239,15 @@ class Training:
             except Exception as exc:  # noqa: BLE001
                 logger.exception("trainCost failed for %s", host_id)
                 outcome.errors.append(f"cost: {exc}")
+            if self.config.train_seq_model:
+                try:
+                    self._train_seq(ip, hostname, host_id, scheduler_id,
+                                    token_files, outcome)
+                except Exception as exc:  # noqa: BLE001
+                    logger.exception("trainSeq failed for %s", host_id)
+                    outcome.errors.append(f"seq: {exc}")
             self.storage.discard_files(
-                download_files + topology_files + replay_files)
+                download_files + topology_files + replay_files + token_files)
         return outcome
 
     # -- jobs -----------------------------------------------------------------
@@ -383,6 +405,46 @@ class Training:
         outcome.cost_model_id = model_id
         outcome.loss_history["cost"] = list(result.history)
         outcome.cost_evaluation = evaluation
+
+    def _train_seq(self, ip, hostname, host_id, scheduler_id, files,
+                   outcome: TrainOutcome) -> None:
+        """Sequence-model job: token segments -> packed sequences ->
+        ``train_seq``, registered as type 'seq'."""
+        config = self.config.seq
+        if config is None:
+            raise ValueError("train_seq_model needs TrainingConfig.seq")
+        documents = self.storage.list_tokens(host_id, files)
+        n_tokens = sum(len(d) for d in documents)
+        if n_tokens < config.seq_len:
+            logger.info("skip seq model for %s: %d tokens < one sequence "
+                        "of %d", host_id, n_tokens, config.seq_len)
+            return
+        corpus = pack_documents(documents, config.seq_len,
+                                config.document_end_id)
+        job_start = time.monotonic()
+        with self._profiled("seq"):
+            result = train_seq(corpus, config, self.mesh)
+        self._observe_job("seq", time.monotonic() - job_start,
+                          result.samples_per_sec)
+        evaluation = {"loss": result.loss, "n_samples": int(corpus.tokens.size)}
+        model_id = seq_model_id_v1(ip, hostname)
+        model = config.model
+        self._register(
+            model_id,
+            MODEL_TYPE_SEQ,
+            host_id, ip, hostname, scheduler_id,
+            evaluation,
+            tree=seq_tree(result.params, result.routing_counts),
+            config={"layer_types": list(model.layer_types),
+                    "layers": list(model.kept_layers),
+                    "hidden_size": model.hidden_size,
+                    "num_experts": model.num_experts,
+                    "experts_held": list(model.held_experts),
+                    "vocab_held": list(model.held_vocab)},
+        )
+        outcome.seq_model_id = model_id
+        outcome.loss_history["seq"] = list(result.history)
+        outcome.seq_evaluation = evaluation
 
     def _register(self, model_id, model_type, host_id, ip, hostname,
                   scheduler_id, evaluation, tree, config) -> None:

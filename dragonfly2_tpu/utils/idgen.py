@@ -134,6 +134,11 @@ def gat_model_id_v1(ip: str, hostname: str) -> str:
     return sha256_from_strings(ip, hostname, "GAT")
 
 
+def seq_model_id_v1(ip: str, hostname: str) -> str:
+    """Sequence models (``train/seq_trainer.py``), the same scheme."""
+    return sha256_from_strings(ip, hostname, "SEQ")
+
+
 def cost_model_id_v1(ip: str, hostname: str) -> str:
     """Learned piece-cost predictor (replay plane, docs/REPLAY.md)."""
     return sha256_from_strings(ip, hostname, "COST")

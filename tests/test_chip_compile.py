@@ -52,6 +52,7 @@ def one_chip(topo):
 
 class _Tpu:
     platform = "tpu"
+    device_kind = "TPU v5 lite"
 
 
 @pytest.fixture
@@ -240,3 +241,63 @@ def test_embedding_pass_at_model_load_leaves_the_chip_room(topo):
 
     memory = _compile(embed, params, *shapes).memory_analysis()
     assert memory.temp_size_in_bytes < 2.5e9     # 1.82 GB, PR 25
+
+
+# ``lfm2-24b-a2b-ep8.train``: one packed 8k sequence at the published
+# widths (hidden 2048, 32 query heads on 8 key-value heads of 64, expert
+# width 1536, 8 experts held of 64, top-4).
+SEQ, HIDDEN = 8192, 2048
+
+
+def test_expert_layer_is_grouped_products_for_the_chip(one_chip,
+                                                       as_tpu_program):
+    """The expert layer's forward and backward at the cell's widths: the
+    three grouped products and their transposes are the megablox kernel
+    (not one masked dense product per expert, and not XLA's own lowering
+    of ``ragged_dot``, whose operations lose the ``df2.*`` scope), and no
+    scatter of rows is left (rows move by gathers)."""
+    from dragonfly2_tpu.parallel.moe import expert_layer
+
+    s = _struct(one_chip)
+    held, width = 8, 1536
+
+    def loss(x, router, w1, w3, w2):
+        out, _ = expert_layer(x, router, jnp.zeros(64), w1, w3, w2,
+                              (0, held), top_k=4)
+        return out.sum()
+
+    compiled = _compile(
+        jax.grad(loss, argnums=(0, 1, 2, 3, 4)),
+        s((SEQ, HIDDEN), jnp.bfloat16), s((HIDDEN, 64), jnp.float32),
+        s((held, HIDDEN, width), jnp.float32),
+        s((held, HIDDEN, width), jnp.float32),
+        s((held, width, HIDDEN), jnp.float32))
+    text = compiled.as_text()
+    # 3 products forward, 2 transposes each backward.
+    assert text.count("tpu_custom_call") >= 9
+    assert "ragged-dot" not in text
+    kernels = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert all("df2.moe.experts" in ln for ln in kernels)
+    big = [ln for ln in text.splitlines()
+           if " scatter(" in ln and f"{HIDDEN}]" in ln]
+    assert not big, big[:2]
+
+
+def test_sequence_attention_kernel_at_the_cells_shape(one_chip):
+    """Grouped-query causal attention over packed documents through the
+    splash-attention kernel, forward and backward, heads of 64."""
+    from dragonfly2_tpu.models.lfm2_moe import kernel_attention
+
+    s = _struct(one_chip)
+    heads, kv_heads, hd = 32, 8, 64
+
+    def loss(q, k, v, segments):
+        return kernel_attention(q, k, v, segments).astype(jnp.float32).sum()
+
+    compiled = _compile(
+        jax.grad(loss, argnums=(0, 1, 2)),
+        s((SEQ, heads, hd), jnp.bfloat16), s((SEQ, kv_heads, hd), jnp.bfloat16),
+        s((SEQ, kv_heads, hd), jnp.bfloat16), s((SEQ,), jnp.int32))
+    assert compiled.as_text().count("tpu_custom_call") >= 2
+    # No [heads, S, S] score matrix: 32 x 8192 x 8192 float32 is 8.6 GB.
+    assert compiled.memory_analysis().temp_size_in_bytes < 2e9

@@ -1,116 +1,207 @@
-"""Expert-parallel (Switch top-1) routing on the 8-device mesh.
-
-The routing must be a pure distribution detail when capacity is ample:
-every token's output equals gate_prob * expert_fn(its expert, token),
-computed against a direct dense reference.
-"""
-
-from __future__ import annotations
+"""The expert layer (``parallel/moe.py``): top-k routing over all
+experts, computed for the experts held here, against a dense form that
+applies every expert to every token and masks by the selection."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from dragonfly2_tpu.parallel.moe import moe_apply
-from dragonfly2_tpu.parallel.pipeline import stack_stage_params
+from dragonfly2_tpu.parallel.moe import expert_layer, route
+
+T, D, F, E, K = 48, 16, 24, 16, 4
 
 
-def expert_fn(params, x):
-    return jnp.tanh(x @ params["w"]) + params["b"]
-
-
-def dense_reference(params, x, gate_logits):
-    probs = jax.nn.softmax(gate_logits.astype(np.float32), axis=-1)
-    idx = np.argmax(gate_logits, axis=-1)
-    out = np.zeros_like(x)
-    for t in range(x.shape[0]):
-        e = int(idx[t])
-        p_e = {k: v[e] for k, v in params.items()}
-        out[t] = np.asarray(
-            expert_fn(p_e, x[t][None, :]))[0] * probs[t, e]
-    return out
-
-
-@pytest.fixture(scope="module")
-def mesh():
-    return jax.make_mesh((jax.device_count(),), ("expert",))
-
-
-def make_experts(n, d, seed=0):
+def make(seed=0, experts=E):
     rng = np.random.default_rng(seed)
-    return stack_stage_params([
-        {"w": (rng.standard_normal((d, d)) / np.sqrt(d)).astype(np.float32),
-         "b": rng.standard_normal(d).astype(np.float32) * 0.1}
-        for _ in range(n)
-    ])
+    return {
+        "x": jnp.asarray(rng.standard_normal((T, D)), jnp.float32),
+        "router": jnp.asarray(rng.standard_normal((D, experts)) * 0.5,
+                              jnp.float32),
+        "bias": jnp.asarray(rng.standard_normal(experts) * 0.1, jnp.float32),
+        "w1": jnp.asarray(rng.standard_normal((experts, D, F)) * 0.2,
+                          jnp.float32),
+        "w3": jnp.asarray(rng.standard_normal((experts, D, F)) * 0.2,
+                          jnp.float32),
+        "w2": jnp.asarray(rng.standard_normal((experts, F, D)) * 0.2,
+                          jnp.float32),
+    }
 
 
-class TestMoE:
-    def test_matches_dense_reference(self, mesh):
-        d, t = 16, 64
-        rng = np.random.default_rng(1)
-        params = make_experts(8, d)
-        x = rng.standard_normal((t, d)).astype(np.float32)
-        gates = rng.standard_normal((t, 8)).astype(np.float32)
-        # Ample capacity: nothing drops, so routed == dense.
-        out = jax.jit(lambda p, x, g: moe_apply(
-            expert_fn, p, x, g, mesh=mesh, capacity_factor=8.0))(
-            params, x, gates)
-        ref = dense_reference(params, x, gates)
-        np.testing.assert_allclose(np.asarray(out), ref,
-                                   rtol=1e-4, atol=1e-5)
+def dense_layer(p, bias=None, top_k=K):
+    """Every expert on every token, masked by the selection: the whole
+    layer's result, and the per-expert assignment counts."""
+    bias = p["bias"] if bias is None else bias
+    scores = jax.nn.sigmoid(jnp.matmul(p["x"], p["router"],
+                                       precision="highest"))
+    _, chosen = jax.lax.top_k(scores + bias, top_k)
+    weights = jnp.take_along_axis(scores, chosen, -1)
+    weights = weights / (weights.sum(-1, keepdims=True) + 1e-6)
+    out = 0.0
+    for e in range(p["router"].shape[1]):
+        w_e = jnp.where(chosen == e, weights, 0.0).sum(-1)
+        hidden = jax.nn.silu(p["x"] @ p["w1"][e]) * (p["x"] @ p["w3"][e])
+        out = out + w_e[:, None] * (hidden @ p["w2"][e])
+    counts = np.bincount(np.asarray(chosen).ravel(),
+                         minlength=p["router"].shape[1])
+    return out, counts
 
-    def test_capacity_drops_excess_tokens(self, mesh):
-        """Every token gated to ONE expert with capacity 1 per device:
-        exactly one token per device survives, the rest output zero —
-        the documented Switch drop semantics, not silent corruption."""
-        d, t = 8, 64
-        params = make_experts(8, d)
-        x = np.ones((t, d), np.float32)
-        gates = np.full((t, 8), -10.0, np.float32)
-        gates[:, 3] = 10.0                       # everyone wants expert 3
-        out = np.asarray(jax.jit(lambda p, x, g: moe_apply(
-            expert_fn, p, x, g, mesh=mesh, capacity_factor=1.0))(
-            params, x, gates))
-        t_loc = t // 8
-        kept = 0
-        for dev in range(8):
-            rows = out[dev * t_loc:(dev + 1) * t_loc]
-            nonzero = np.abs(rows).sum(axis=1) > 0
-            # capacity = ceil(t_loc/8 * 1.0) = 1 survivor per device
-            assert nonzero.sum() == 1, nonzero
-            kept += int(nonzero.sum())
-        assert kept == 8
 
-    def test_grads_flow_to_experts_and_gates(self, mesh):
-        d, t = 8, 32
-        rng = np.random.default_rng(2)
-        params = make_experts(8, d, seed=3)
-        x = rng.standard_normal((t, d)).astype(np.float32)
-        gates = rng.standard_normal((t, 8)).astype(np.float32)
+def share(p, first, count, bias=None, top_k=K):
+    rows = slice(first, first + count)
+    return expert_layer(
+        p["x"], p["router"], p["bias"] if bias is None else bias,
+        p["w1"][rows], p["w3"][rows], p["w2"][rows], (first, count),
+        top_k=top_k)
 
-        def loss(p, g):
-            return (moe_apply(expert_fn, p, x, g, mesh=mesh,
-                              capacity_factor=8.0) ** 2).sum()
 
-        with jax.set_mesh(mesh):
-            gp, gg = jax.jit(jax.grad(loss, argnums=(0, 1)))(params, gates)
-        assert all(np.isfinite(np.asarray(l)).all()
-                   for l in jax.tree.leaves(gp))
-        # The straight-through combine gives the gate a real gradient.
-        assert np.abs(np.asarray(gg)).sum() > 0
+@pytest.mark.parametrize("count", [2, 4, 16])
+def test_the_shares_add_up_to_the_whole_layer(count):
+    """What each device of the group computes for the experts it holds
+    sums to the uncut layer; selection and weights are over all experts
+    on every device."""
+    p = make()
+    whole, counts = dense_layer(p)
+    total = 0.0
+    for first in range(0, E, count):
+        out, assigned = jax.jit(
+            lambda p, first=first: share(p, first, count))(p)
+        total = total + out
+        np.testing.assert_array_equal(np.asarray(assigned), counts)
+    np.testing.assert_allclose(np.asarray(total), np.asarray(whole),
+                               rtol=2e-5, atol=2e-6)
+    assert counts.sum() == T * K
 
-    def test_rejects_bad_shapes(self, mesh):
-        params = make_experts(8, 8)
-        with pytest.raises(ValueError, match="flatten batch"):
-            moe_apply(expert_fn, params,
-                      np.zeros((2, 16, 8), np.float32),
-                      np.zeros((2, 8), np.float32), mesh=mesh)
-        with pytest.raises(ValueError, match="gate_logits"):
-            moe_apply(expert_fn, params, np.zeros((16, 8), np.float32),
-                      np.zeros((16, 4), np.float32), mesh=mesh)
-        with pytest.raises(ValueError, match="experts"):
-            moe_apply(expert_fn, make_experts(4, 8),
-                      np.zeros((16, 8), np.float32),
-                      np.zeros((16, 8), np.float32), mesh=mesh)
+
+def test_nothing_is_dropped_when_every_token_goes_to_held_experts():
+    """The worst case: a bias sends all T·k assignments to the experts
+    held here, and the result is still the whole layer's."""
+    p = make(1)
+    bias = jnp.where(jnp.arange(E) < K, 100.0, 0.0)
+    whole, counts = dense_layer(p, bias)
+    out, assigned = share(p, 0, K, bias)
+    assert int(assigned[:K].sum()) == T * K and counts[:K].sum() == T * K
+    np.testing.assert_allclose(np.asarray(out), np.asarray(whole),
+                               rtol=2e-5, atol=2e-6)
+
+
+@pytest.mark.parametrize("second", [-10.0, 0.0])
+def test_the_short_and_the_long_row_buffer_agree(second):
+    """Two experts of 16 are held: their assignments end within twice
+    their expected number (48 rows of 192) in an ordinary step, and the
+    row buffers are that short. Every token on expert 0 and none on
+    expert 1 is exactly 48; any token on expert 1 besides is more, and
+    the step takes the worst-case buffers. Either way the result is the
+    dense form's."""
+    p = make(8)
+    bias = jnp.zeros(E).at[0].set(10.0).at[1].set(second)
+    whole, counts = dense_layer(p, bias)
+    assert counts[0] == T
+    assert (counts[1] == 0) if second else (counts[1] > 0)
+    held = (jnp.arange(E) < 2)[:, None, None]
+    want, _ = dense_layer(dict(p, w2=jnp.where(held, p["w2"], 0.0)), bias)
+    out, assigned = jax.jit(lambda p: share(p, 0, 2, bias))(p)
+    np.testing.assert_array_equal(np.asarray(assigned), counts)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               rtol=2e-5, atol=2e-6)
+    grads = jax.grad(lambda x: (share(dict(p, x=x), 0, 2, bias)[0] ** 2
+                                ).sum())(p["x"])
+    want_grads = jax.grad(lambda x: (dense_layer(dict(
+        p, x=x, w2=jnp.where(held, p["w2"], 0.0)), bias)[0] ** 2).sum())(
+        p["x"])
+    np.testing.assert_allclose(np.asarray(grads), np.asarray(want_grads),
+                               rtol=2e-4, atol=2e-6)
+
+
+def test_no_token_for_the_held_experts_gives_zero():
+    p = make(2)
+    bias = jnp.where(jnp.arange(E) < K, -100.0, 0.0)
+    out, assigned = share(p, 0, K, bias)
+    assert int(assigned[:K].sum()) == 0
+    np.testing.assert_array_equal(np.asarray(out), 0.0)
+    grads = jax.grad(lambda x: share(dict(p, x=x), 0, K, bias)[0].sum())(
+        p["x"])
+    assert np.isfinite(np.asarray(grads)).all()
+
+
+def test_the_selection_bias_selects_and_does_not_weigh():
+    """The bias changes who is selected; a selected expert's weight is
+    its score without the bias, normalised over the selected."""
+    p = make(3)
+    scores = jax.nn.sigmoid(jnp.matmul(p["x"], p["router"],
+                                       precision="highest"))
+    plain, w_plain = route(p["x"], p["router"], jnp.zeros(E), top_k=K)
+    # A constant bias moves no selection and no weight.
+    same, w_same = route(p["x"], p["router"], jnp.full(E, 0.7), top_k=K)
+    np.testing.assert_array_equal(np.asarray(plain), np.asarray(same))
+    np.testing.assert_array_equal(np.asarray(w_plain), np.asarray(w_same))
+    # A large bias on expert 5 selects it for every token, at the weight
+    # its own score gives it.
+    biased, w_biased = route(p["x"], p["router"],
+                             jnp.zeros(E).at[5].set(10.0), top_k=K)
+    assert (np.asarray(biased) == 5).any(-1).all()
+    assert not (np.asarray(plain) == 5).any(-1).all()
+    picked = jnp.take_along_axis(scores, biased, -1)
+    np.testing.assert_allclose(
+        np.asarray(w_biased),
+        np.asarray(picked / (picked.sum(-1, keepdims=True) + 1e-6)),
+        rtol=1e-6)
+
+
+@pytest.mark.parametrize("first,count", [(4, 8), (6, 2)])
+@pytest.mark.parametrize("name", ["x", "router", "w1", "w3", "w2"])
+def test_gradients_against_the_dense_form(name, first, count):
+    """The gather-only backward (custom VJPs of the two row moves)
+    against autodiff of the dense form, for a share of the experts: 8
+    of 16 (one length of row buffer) and 2 of 16 (the short buffer of an
+    ordinary step, under ``lax.cond``)."""
+    p = make(4)
+    probe = jnp.asarray(np.random.default_rng(9).standard_normal((T, D)),
+                        jnp.float32)
+
+    def ours(value):
+        q = dict(p, **{name: value})
+        return (share(q, first, count)[0] * probe).sum()
+
+    def dense(value):
+        q = dict(p, **{name: value})
+        held = jnp.arange(E)
+        held = (held >= first) & (held < first + count)
+        # The held experts' part of the dense form: the other experts'
+        # weights are zeroed where they enter the sum.
+        q = dict(q, w2=jnp.where(held[:, None, None], q["w2"], 0.0))
+        return (dense_layer(q)[0] * probe).sum()
+
+    got, want = jax.grad(ours)(p[name]), jax.grad(dense)(p[name])
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-4, atol=2e-6)
+
+
+@pytest.mark.parametrize("top_k", [1, 2])
+def test_other_top_k(top_k):
+    p = make(5)
+    whole, counts = dense_layer(p, top_k=top_k)
+    out, assigned = share(p, 0, E, top_k=top_k)
+    np.testing.assert_array_equal(np.asarray(assigned), counts)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(whole),
+                               rtol=2e-5, atol=2e-6)
+
+
+def test_products_run_in_the_tokens_dtype():
+    p = make(6)
+    low = dict(p, x=p["x"].astype(jnp.bfloat16))
+    out, _ = share(low, 0, E)
+    whole, _ = dense_layer(p)
+    assert out.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(out), np.asarray(whole),
+                               rtol=0.1, atol=0.05)
+
+
+def test_held_must_match_the_stacked_weights():
+    p = make(7)
+    with pytest.raises(ValueError, match="held"):
+        expert_layer(p["x"], p["router"], p["bias"], p["w1"][:4],
+                     p["w3"][:4], p["w2"][:4], (0, 8), top_k=K)
+    with pytest.raises(ValueError, match="held"):
+        expert_layer(p["x"], p["router"], p["bias"], p["w1"][:4],
+                     p["w3"][:4], p["w2"][:4], (14, 4), top_k=K)

@@ -1,0 +1,259 @@
+"""The ``lfm2_moe`` sequence-model family (``models/lfm2_moe.py``)
+against the plain reference the benchmark keeps
+(``benchmarks/references/lfm2_moe.py``: the published equations in
+float32, importing nothing of the program), at a small size."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmarks.compare import leaves
+from benchmarks.references import lfm2_moe as reference
+from dragonfly2_tpu.models import lfm2_moe
+from dragonfly2_tpu.models.lfm2_moe import Lfm2MoeConfig
+
+# The published pattern's start: two leading dense layers, a period of
+# one attention layer and three convolution layers.
+SPEC = {
+    "layer_types": ["conv", "conv", "full_attention", "conv", "conv", "conv"],
+    "num_dense_layers": 2, "hidden_size": 64, "intermediate_size": 96,
+    "moe_intermediate_size": 32, "num_experts_per_tok": 4,
+    "num_attention_heads": 8, "num_key_value_heads": 2, "conv_L_cache": 3,
+    "conv_bias": False, "norm_eps": 1e-5, "norm_topk_prob": True,
+    "use_expert_bias": True, "routed_scaling_factor": 1,
+    "rope_parameters": {"rope_theta": 1000000, "rope_type": "default"},
+    "num_experts": 8, "vocab_size": 96,
+    "published": {"num_experts": 32, "vocab_size": 768},
+    "deployment": {"layers_kept": [0, 2, 3, 4, 5], "experts_held": [8, 8],
+                   "vocab_rows_held": [96, 96]},
+    "router_bias": {"beta": 0.05, "period": 8},
+}
+LENGTHS = [10, 30, 5, 19]
+S = sum(LENGTHS)
+
+
+def config(dtype="float32", **over):
+    held = SPEC["deployment"]
+    return Lfm2MoeConfig.from_published(
+        dict(SPEC, **over), num_experts=SPEC["published"]["num_experts"],
+        vocab_size=SPEC["published"]["vocab_size"],
+        layers=tuple(held["layers_kept"]),
+        experts_held=tuple(held["experts_held"]),
+        vocab_held=tuple(held["vocab_rows_held"]), compute_dtype=dtype)
+
+
+def sequence(seed=0, lengths=LENGTHS):
+    rng = np.random.default_rng(seed)
+    first, rows = SPEC["deployment"]["vocab_rows_held"]
+    tokens = first + rng.integers(0, rows, sum(lengths))
+    segments = np.repeat(np.arange(len(lengths)), lengths)
+    positions = np.concatenate([np.arange(n) for n in lengths])
+    return tuple(jnp.asarray(a, jnp.int32)
+                 for a in (tokens, segments, positions))
+
+
+def bias_rows(cfg):
+    return jnp.tile(jnp.asarray(reference.selection_bias(SPEC)),
+                    (len(cfg.expert_layers), 1))
+
+
+def test_parameters_are_the_references_own():
+    """Same names, same shapes, the same draws from the seed: the
+    benchmark's ``init_gap`` limit is 0."""
+    cfg = config()
+    ours = leaves(lfm2_moe.init_params(jax.random.key(5), cfg))
+    theirs = reference.init_params(5, reference.sizes(SPEC))
+    assert sorted(ours) == sorted(theirs)
+    for name in ours:
+        np.testing.assert_array_equal(np.asarray(ours[name]),
+                                      np.asarray(theirs[name]), name)
+
+
+def test_parameter_count_of_the_benchmarks_configuration():
+    """``benchmarks/configs/lfm2-24b-a2b-ep8.json``: 469,284,992
+    parameters at the published widths, by part."""
+    import json
+    import os
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    spec = json.load(open(os.path.join(
+        root, "benchmarks", "configs", "lfm2-24b-a2b-ep8.json")))
+    held = spec["deployment"]
+    cfg = Lfm2MoeConfig.from_published(
+        spec, num_experts=spec["published"]["num_experts"],
+        vocab_size=spec["published"]["vocab_size"],
+        layers=tuple(held["layers_kept"]),
+        experts_held=tuple(held["experts_held"]),
+        vocab_held=tuple(held["vocab_rows_held"]))
+    by_layer = {}
+    for path, shape, _ in lfm2_moe.param_shapes(cfg):
+        by_layer[path[0]] = by_layer.get(path[0], 0) + int(np.prod(shape))
+    assert by_layer == {
+        "embed": 16_777_216, "final_norm": 2_048, "layer_0": 89_139_200,
+        "layer_2": 86_118_528, "layer_3": 92_416_000, "layer_4": 92_416_000,
+        "layer_5": 92_416_000}
+    assert sum(by_layer.values()) == 469_284_992
+    assert cfg.expert_layers == (2, 3, 4, 5) and cfg.head_dim == 64
+
+
+@pytest.mark.parametrize("seed", [7, 8])
+def test_loss_and_gradients_against_the_plain_reference(seed):
+    cfg = config()
+    params = lfm2_moe.init_params(jax.random.key(seed), cfg)
+    tokens, segments, positions = sequence(seed)
+    sizes = reference.sizes(SPEC)
+
+    def ours(p):
+        return lfm2_moe.sequence_loss(
+            p, bias_rows(cfg), tokens, segments, positions, cfg=cfg)
+
+    def theirs(p):
+        return reference.forward_sums(
+            p, tokens, segments, positions,
+            jnp.asarray(reference.selection_bias(SPEC)), 1.0, sizes,
+            lambda x: x)
+
+    (loss, counts), grads = jax.value_and_grad(ours, has_aux=True)(params)
+    (want, n), want_grads = jax.value_and_grad(theirs, has_aux=True)(
+        reference.init_params(seed, sizes))
+    assert int(n) == int(lfm2_moe.target_positions(segments).sum()) == S - 4
+    np.testing.assert_allclose(float(loss), float(want), rtol=1e-5)
+    for name, got in leaves(grads).items():
+        scale = float(jnp.abs(want_grads[name]).max())
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(want_grads[name]),
+            rtol=1e-3, atol=2e-5 * scale, err_msg=name)
+    # Top-4 of 32 for every token in each of the four expert layers.
+    assert counts.shape == (4, 32) and (np.asarray(counts).sum(1) == 4 * S).all()
+
+
+def test_the_batch_is_the_sum_of_its_sequences():
+    cfg = config()
+    params = lfm2_moe.init_params(jax.random.key(1), cfg)
+    rows = [sequence(seed, lengths) for seed, lengths in
+            ((0, LENGTHS), (1, [64]), (2, [1, 1, 2, 60]))]
+    batch = [jnp.stack(part) for part in zip(*rows)]
+    loss, counts = lfm2_moe.batch_loss(
+        params, bias_rows(cfg), *batch, cfg=cfg)
+    each = [lfm2_moe.sequence_loss(params, bias_rows(cfg), *row, cfg=cfg)
+            for row in rows]
+    np.testing.assert_allclose(float(loss), sum(float(e[0]) for e in each),
+                               rtol=1e-6)
+    np.testing.assert_array_equal(np.asarray(counts),
+                                  sum(np.asarray(e[1]) for e in each))
+
+
+def test_nothing_crosses_a_document_boundary():
+    """Other tokens in one document leave every other document's loss
+    terms bit-equal: no convolution tap, attention score or position
+    reaches across."""
+    cfg = config()
+    params = lfm2_moe.init_params(jax.random.key(3), cfg)
+    tokens, segments, positions = sequence()
+    first = SPEC["deployment"]["vocab_rows_held"][0]
+    start, stop = LENGTHS[0], LENGTHS[0] + LENGTHS[1]
+    changed = tokens.at[start:stop].set(
+        first + (tokens[start:stop] - first + 7) % 96)
+
+    def per_position(tok):
+        """Each target position's loss term."""
+        dt = jnp.float32
+        x = lfm2_moe.embedding_rows(params["embed"], tok - first, dt)
+        for i in cfg.kept_layers:
+            routed = i in cfg.expert_layers
+            bias = (bias_rows(cfg)[cfg.expert_layers.index(i)]
+                    if routed else None)
+            x, _ = lfm2_moe.block(params[f"layer_{i}"], x, bias, segments,
+                                  positions, cfg=cfg, layer=i)
+        x = lfm2_moe.rms_norm(x, params["final_norm"], cfg.norm_eps)
+        logits = x @ params["embed"].T
+        nll = jax.nn.logsumexp(logits, -1) - jnp.take_along_axis(
+            logits, jnp.roll(tok - first, -1)[:, None], -1)[:, 0]
+        return jnp.where(lfm2_moe.target_positions(segments), nll, 0.0)
+
+    before, after = per_position(tokens), per_position(changed)
+    other = np.ones(S, bool)
+    other[start:stop] = False
+    np.testing.assert_array_equal(np.asarray(before)[other],
+                                  np.asarray(after)[other])
+    assert (np.asarray(before)[start:stop - 1]
+            != np.asarray(after)[start:stop - 1]).all()
+
+
+def test_grouped_query_heads_against_repeated_key_value_heads():
+    """Query heads 4j..4j+3 read key-value head j: the same as ordinary
+    attention over key-value heads repeated four times."""
+    rng = np.random.default_rng(0)
+    heads, kv_heads, hd = 8, 2, 16
+    q = jnp.asarray(rng.standard_normal((S, heads, hd)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((S, kv_heads, hd)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((S, kv_heads, hd)), jnp.float32)
+    _, segments, _ = sequence()
+    got = lfm2_moe.dense_attention(q, k, v, segments)
+    k_all, v_all = (jnp.repeat(a, heads // kv_heads, axis=1) for a in (k, v))
+    at = jnp.arange(S)
+    seen = (at[:, None] >= at[None, :]) & (
+        segments[:, None] == segments[None, :])
+    scores = jnp.einsum("shd,thd->hst", q, k_all)
+    probs = jax.nn.softmax(jnp.where(seen, scores, -jnp.inf), -1)
+    want = jnp.einsum("hst,thd->shd", probs, v_all)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-6)
+
+
+def test_the_attention_kernel_is_the_plain_attention():
+    """The TPU kernel's path (here in interpret mode) against the plain
+    form, with documents and grouped heads, values and gradients."""
+    rng = np.random.default_rng(1)
+    s, heads, kv_heads, hd = 256, 4, 2, 64
+    q, k, v = (jnp.asarray(rng.standard_normal((s, h, hd)) * 0.3, jnp.float32)
+               for h in (heads, kv_heads, kv_heads))
+    segments = jnp.asarray(np.repeat([0, 1, 2], [100, 28, 128]), jnp.int32)
+
+    def total(fn, q, k, v):
+        return (fn(q, k, v, segments) ** 2).sum()
+
+    kernel = lambda q, k, v, seg: lfm2_moe.kernel_attention(  # noqa: E731
+        q, k, v, seg, interpret=True)
+    np.testing.assert_allclose(
+        np.asarray(kernel(q, k, v, segments)),
+        np.asarray(lfm2_moe.dense_attention(q, k, v, segments)),
+        rtol=2e-3, atol=2e-3)
+    got = jax.grad(lambda *a: total(kernel, *a), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(lambda *a: total(lfm2_moe.dense_attention, *a),
+                    argnums=(0, 1, 2))(q, k, v)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=2e-2, atol=2e-3)
+
+
+def test_embedding_gradient_is_the_scatter_adds():
+    table = jnp.asarray(np.random.default_rng(0).standard_normal((12, 8)),
+                        jnp.float32)
+    ids = jnp.asarray([3, 3, 0, 11, 3, 7], jnp.int32)
+    probe = jnp.asarray(np.random.default_rng(1).standard_normal((6, 8)),
+                        jnp.float32)
+    got = jax.grad(lambda t: (lfm2_moe.embedding_rows(
+        t, ids, jnp.float32) * probe).sum())(table)
+    want = jax.grad(lambda t: (t[ids] * probe).sum())(table)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-6)
+
+
+def test_bfloat16_compute_stays_near_float32():
+    """The configuration's precision: bfloat16 products, float32
+    parameters, norms, router and loss."""
+    params = lfm2_moe.init_params(jax.random.key(2), config())
+    tokens, segments, positions = sequence(4)
+    losses = [float(lfm2_moe.sequence_loss(
+        params, bias_rows(config(dt)), tokens, segments, positions,
+        cfg=config(dt))[0]) for dt in ("float32", "bfloat16")]
+    assert abs(losses[1] - losses[0]) < 5e-3 * abs(losses[0])
+
+
+def test_config_refuses_what_the_family_does_not_have():
+    with pytest.raises(ValueError, match="conv_bias"):
+        config(conv_bias=True)
+    with pytest.raises(ValueError, match="layer type"):
+        lfm2_moe.param_shapes(config(
+            layer_types=["conv", "conv", "sliding", "conv", "conv", "conv"]))

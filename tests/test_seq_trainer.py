@@ -1,0 +1,191 @@
+"""``train_seq`` (``train/seq_trainer.py``) and the trainer service's
+sequence-model job, at a tiny size: the packer, the loop through
+``StepBudget``, data parallelism against one device, the ``tokens``
+segments through ``TrainerStorage`` and ``Training.train``."""
+
+import jax
+import numpy as np
+import pytest
+
+from dragonfly2_tpu.models.lfm2_moe import Lfm2MoeConfig
+from dragonfly2_tpu.parallel import data_parallel_mesh
+from dragonfly2_tpu.train import step_budget
+from dragonfly2_tpu.train.seq_trainer import (
+    SeqTrainConfig,
+    config_from_dict,
+    pack_documents,
+    train_seq,
+)
+
+MODEL = Lfm2MoeConfig(
+    layer_types=("conv", "full_attention", "conv"), num_dense_layers=1,
+    hidden_size=32, intermediate_size=48, moe_intermediate_size=16,
+    num_experts=16, num_experts_per_tok=4, num_attention_heads=4,
+    num_key_value_heads=2, vocab_size=64, experts_held=(4, 4),
+    vocab_held=(0, 64))
+SEQ = 32
+
+
+def documents(seed=0, n=40):
+    rng = np.random.default_rng(seed)
+    return [rng.integers(0, 64, rng.integers(3, 50)).astype(np.uint16)
+            for _ in range(n)]
+
+
+def one_device():
+    return data_parallel_mesh(devices=jax.devices()[:1])
+
+
+def test_packer_cuts_rows_and_restarts_positions():
+    docs = [np.arange(5), np.arange(10, 14), np.arange(20, 31)]
+    corpus = pack_documents(docs, 8)
+    assert corpus.tokens.shape == (2, 8)         # 20 tokens: 4 left out
+    np.testing.assert_array_equal(
+        corpus.tokens, [[0, 1, 2, 3, 4, 10, 11, 12],
+                        [13, 20, 21, 22, 23, 24, 25, 26]])
+    np.testing.assert_array_equal(
+        corpus.segments, [[0, 0, 0, 0, 0, 1, 1, 1], [1, 2, 2, 2, 2, 2, 2, 2]])
+    # The document cut at the row's end starts anew in the next row.
+    np.testing.assert_array_equal(
+        corpus.positions, [[0, 1, 2, 3, 4, 0, 1, 2], [0, 0, 1, 2, 3, 4, 5, 6]])
+    with pytest.raises(ValueError, match="fill no sequence"):
+        pack_documents(docs, 64)
+
+
+def test_packer_splits_a_stream_at_the_end_id():
+    stream = np.array([5, 6, 9, 7, 9, 9, 8, 1, 2, 3], np.uint16)
+    corpus = pack_documents([stream], 10, end_id=9)
+    np.testing.assert_array_equal(corpus.segments[0],
+                                  [0, 0, 0, 1, 1, 2, 3, 3, 3, 3])
+    np.testing.assert_array_equal(corpus.positions[0],
+                                  [0, 1, 2, 0, 1, 0, 0, 1, 2, 3])
+
+
+def test_train_seq_reaches_finish_without_a_steady_compile():
+    corpus = pack_documents(documents(), SEQ)
+    before = step_budget.TRAINING.snapshot()
+    result = train_seq(corpus, SeqTrainConfig(
+        model=MODEL, batch_size=4, epochs=3, learning_rate=3e-3, seed=3,
+        router_bias=tuple(np.linspace(-0.05, 0.05, 16))), one_device())
+    after = step_budget.TRAINING.snapshot()
+    steps = 3 * (corpus.tokens.shape[0] // 4)
+    assert result.steps == steps and len(result.history) == 3
+    assert result.history[-1] < result.history[0]
+    assert after["steady_compiles"] == before["steady_compiles"]
+    assert after["loop_compiles"] - before["loop_compiles"] == 1
+    assert after["steps"] - before["steps"] == steps
+    # A sample is a token position.
+    assert (after["samples"] - before["samples"]
+            == (steps - 1) * 4 * SEQ)
+    # Top-4 of 16 for every token of every step, in both expert layers.
+    counts = result.routing_counts
+    assert counts.shape == (2, 16)
+    assert (counts.sum(1) == steps * 4 * SEQ * 4).all()
+    held = counts[:, 4:8]
+    assert after["moe_steps"] - before["moe_steps"] == steps
+    assert (after["moe_assignments_held"] - before["moe_assignments_held"]
+            == held.sum())
+    assert (after["moe_assignments_hottest"]
+            - before["moe_assignments_hottest"] == held.max(1).sum())
+
+
+def test_data_parallel_is_one_device():
+    """Two devices, each on half of a step's sequences, against one
+    device on all of them: the same losses."""
+    corpus = pack_documents(documents(1), SEQ)
+    config = SeqTrainConfig(model=MODEL, batch_size=4, epochs=1, seed=5)
+    one = train_seq(corpus, config, one_device())
+    two = train_seq(corpus, config,
+                    data_parallel_mesh(devices=jax.devices()[:2]))
+    np.testing.assert_allclose(two.history, one.history, rtol=2e-3)
+    np.testing.assert_array_equal(two.routing_counts.sum(1),
+                                  one.routing_counts.sum(1))
+    with pytest.raises(ValueError, match="data-parallel"):
+        train_seq(corpus, config,
+                  data_parallel_mesh(devices=jax.devices()[:3]))
+
+
+def test_token_ids_must_lie_in_the_rows_held():
+    corpus = pack_documents([np.full(64, 70, np.uint16)], SEQ)
+    with pytest.raises(ValueError, match="embedding rows held"):
+        train_seq(corpus, SeqTrainConfig(model=MODEL), one_device())
+
+
+def test_config_from_a_published_file():
+    given = {
+        "layer_types": ["conv", "conv", "full_attention", "conv"],
+        "num_dense_layers": 2, "hidden_size": 32, "intermediate_size": 48,
+        "moe_intermediate_size": 16, "num_experts": 16,
+        "num_experts_per_tok": 4, "num_attention_heads": 4,
+        "num_key_value_heads": 2, "vocab_size": 64, "conv_L_cache": 3,
+        "norm_eps": 1e-5, "rope_parameters": {"rope_theta": 10000},
+        "layers": [0, 2, 3], "experts_held": [8, 8], "seq_len": 32,
+        "batch_size": 2, "document_end_id": 63}
+    config = config_from_dict(given)
+    assert config.model.kept_layers == (0, 2, 3)
+    assert config.model.expert_layers == (2, 3)
+    assert config.model.held_experts == (8, 8)
+    assert (config.seq_len, config.batch_size, config.document_end_id) == (
+        32, 2, 63)
+
+
+class _Registry:
+    def __init__(self):
+        self.created = []
+
+    def create_model(self, **kwargs):
+        import os
+
+        kwargs["files"] = sorted(os.listdir(kwargs["artifact_dir"]))
+        self.created.append(kwargs)
+
+
+def test_token_segments_round_trip_and_a_seq_model_is_registered(tmp_path):
+    from dragonfly2_tpu.train.checkpoint import load_model, seq_from_tree
+    from dragonfly2_tpu.trainer import (
+        TrainerStorage,
+        Training,
+        TrainingConfig,
+    )
+    from dragonfly2_tpu.trainer.storage import TOKENS_PREFIX
+
+    storage = TrainerStorage(str(tmp_path / "data"))
+    docs = documents(2, n=30)
+    for doc in docs:
+        # Little-endian uint16 ids, one document a segment, in two chunks.
+        blob = doc.astype("<u2").tobytes()
+        storage.append(TOKENS_PREFIX, "host-1", blob[:6], True)
+        storage.append(TOKENS_PREFIX, "host-1", blob[6:], False)
+    storage.close_host("host-1")
+    back = storage.list_tokens("host-1")
+    assert len(back) == len(docs)
+    for got, want in zip(back, docs):
+        np.testing.assert_array_equal(got, want)
+    assert storage.has_closed_segments("host-1")
+
+    registry = _Registry()
+    saved = {}
+    plain = registry.create_model
+
+    def keep(**kwargs):
+        saved["tree"], saved["meta"] = load_model(kwargs["artifact_dir"])
+        plain(**kwargs)
+
+    registry.create_model = keep
+    training = Training(
+        storage, registry,
+        TrainingConfig(train_seq_model=True, seq=SeqTrainConfig(
+            model=MODEL, batch_size=2, seq_len=SEQ, epochs=1)),
+        mesh=one_device())
+    outcome = training.train("10.0.0.1", "sched-1", "host-1")
+    assert outcome.errors == []
+    assert outcome.seq_model_id and outcome.loss_history["seq"]
+    (created,) = registry.created
+    assert created["model_type"] == "seq"
+    assert created["evaluation"]["n_samples"] % SEQ == 0
+    params, counts = seq_from_tree(saved["tree"])
+    assert saved["meta"].model_type == "seq"
+    assert counts.shape == (2, 16) and "layer_1" in params
+    # The trained segments are gone; nothing is left to train.
+    assert storage.token_files("host-1") == []
+    assert not storage.has_closed_segments("host-1")
