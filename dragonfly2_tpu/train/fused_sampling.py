@@ -1,19 +1,42 @@
 """On-device neighbor sampling: the whole GraphSAGE step in one XLA program.
 
-Round-2 measured 16.1k samples/sec/chip with host-side sampling — the step
-was dominated by numpy fancy-indexing over ~1M positions per batch plus
-~15 MB/step of H2D index/mask traffic, while the chip's matmul work is
-~2 GFLOP/step (<1 ms on a v5e MXU). TPU-first fix: put the CSR adjacency
-(int32 indices + f32 RTTs, ~16 MB at 2M edges) and the node-feature table
-in HBM once, replicated, and do fanout sampling INSIDE the jitted train
-step — threefry bits → mod-degree offsets → position gathers — so
-sampling, gather, and matmuls fuse into one program and the host ships
-only a [B] int32 edge-id slice per step (~32 KB).
+The graph lives in HBM once, replicated, and fan-out sampling runs
+INSIDE the jitted train step (counter-hash bits, modulo the degree), so
+sampling, feature gathers and matmuls are one program and the host ships
+a ``[B]`` int32 edge-id slice a step.
+
+**The tables.** On the v5e a scalar gather costs 7-14 ns an index
+whatever its table; a row gather costs 2.8 ns a row of 1 KB out of the
+chip's fast memory (which holds one table of some 100 MB at a time; the
+compiler moves tables in and out) and 12 ns out of HBM. At batch 131,072
+and fan-outs (10, 5) the CSR sampler's 37.2M scalar gathers were 403 of
+the step's 489 ms (ledger, PR 27: ``sample_ms``; hop 2 alone 359 ms). So
+:func:`put_graph_tables` lays the CSR out as per-host **rows**
+(:class:`RowTables`): neighbour ids and RTTs (the bits of the CSR's
+``float32``, held as ``int32``) padded with zeros to ``row_width``
+lanes, the longest row and one lane for the degree (the ids' last lane)
+rounded up to 128. A hop then fetches one row a *node* from each table
+(5.8M row gathers a step) and picks its ``fanout`` slots inside the row
+on the vector unit (compare against a lane iota, sum over the lanes:
+exact), in slices of the batch under ``lax.map`` so that a device holds
+at most :data:`ROW_CHUNK_BYTES` of fetched rows, and with the two tables
+read one after the other so that each is in fast memory when it is
+read: 54 ms where the CSR path took 403 (PERF.md section 5, PR 28).
+
+**When the CSR path is taken.** Padding pays while rows are of one
+order: where the padded tables would hold more than
+:data:`ROW_PAD_FACTOR` times the CSR's entries *and* more than
+:data:`ROW_PAD_FREE_BYTES` (a power-law graph with one hub),
+``put_graph_tables`` keeps the CSR (:class:`GraphTables`) and
+``sample_neighbors`` its position gathers. Both paths draw the same
+offsets from the same hash: their samples are equal bit for bit
+(``tests/test_fused_sampling.py``). ``sampler_row_width`` of the
+``training`` block says which one a process placed (0: CSR).
 
 Static shapes throughout: every array's shape is a pure function of
-(B, fanouts, F), so XLA compiles exactly one program; sampling uses
-replacement (same estimator as the host sampler, data/graph_sampler.py)
-and zero-degree nodes get masked padded slots.
+(B, fanouts, F, row width), so XLA compiles exactly one program; sampling
+uses replacement (same estimator as the host sampler,
+data/graph_sampler.py) and zero-degree nodes get masked padded slots.
 
 Sharding: edge-id batches shard over ``data``; tables and params
 replicate; every table gather states ``out_sharding`` explicitly (each
@@ -25,6 +48,7 @@ implementation to compare against.
 
 from __future__ import annotations
 
+import math
 from typing import NamedTuple
 
 import jax
@@ -35,15 +59,42 @@ import optax
 from dragonfly2_tpu.data.graph_sampler import CSRGraph
 from dragonfly2_tpu.models.graphsage import GraphSAGE
 from dragonfly2_tpu.parallel import MeshContext
+from dragonfly2_tpu.train.step_budget import TRAINING
+
+LANES = 128
+# The row tables are taken unless padding costs more than this many
+# times the CSR's entries AND more than this many bytes (both tables).
+ROW_PAD_FACTOR = 4
+ROW_PAD_FREE_BYTES = 256 << 20
+# Fetched rows (both tables) a device holds at once; a hop over more
+# nodes than that runs in slices of the batch.
+ROW_CHUNK_BYTES = 512 << 20
 
 
 class GraphTables(NamedTuple):
-    """Device-resident, replicated graph state for fused-sampling steps."""
+    """Device-resident, replicated graph state, CSR form: what a graph
+    whose rows would pad too far keeps (module text)."""
 
     indptr: jax.Array         # [N+1] int32 — CSR row starts
     indices: jax.Array        # [E] int32 — neighbor node ids
     edge_rtt: jax.Array       # [E] float32 — log1p(rtt_ms)
     node_features: jax.Array  # [N, F] float32
+
+    row_width = 0
+
+
+class RowTables(NamedTuple):
+    """Device-resident, replicated graph state, one lane-dense row a
+    host. Lanes past a row's degree hold 0; the ids' last lane holds the
+    degree."""
+
+    nbr_rows: jax.Array       # [N, W] int32 — neighbor node ids | degree
+    rtt_rows: jax.Array       # [N, W] int32 — the bits of float32 log1p(rtt_ms)
+    node_features: jax.Array  # [N, F] float32
+
+    @property
+    def row_width(self) -> int:
+        return self.nbr_rows.shape[1]
 
 
 class EdgeTables(NamedTuple):
@@ -54,17 +105,42 @@ class EdgeTables(NamedTuple):
     labels: jax.Array  # [M] float32
 
 
-def put_graph_tables(csr: CSRGraph, mesh: MeshContext) -> GraphTables:
-    return GraphTables(*(
-        jax.device_put(a, mesh.replicated) for a in (
+def row_width(csr: CSRGraph) -> int:
+    """Lanes of a host's row in :class:`RowTables`, or 0 where the graph
+    keeps its CSR form: the one rule of the module's text."""
+    degree = np.diff(csr.indptr)
+    width = -(-(int(degree.max(initial=0)) + 1) // LANES) * LANES
+    padded = csr.n_nodes * width
+    if (padded > ROW_PAD_FACTOR * len(csr.indices)
+            and 2 * 4 * padded > ROW_PAD_FREE_BYTES):
+        return 0
+    return width
+
+
+def put_graph_tables(csr: CSRGraph, mesh: MeshContext):
+    width = row_width(csr)
+    TRAINING.set(sampler_row_width=width)
+    if not width:
+        tables = GraphTables(
             # int32 row starts: 2G-edge graphs are beyond one chip's HBM
             # anyway, so narrow indptr halves a hot gather's footprint.
-            csr.indptr.astype(np.int32),
-            csr.indices,
-            csr.edge_rtt,
-            csr.node_features,
-        )
-    ))
+            csr.indptr.astype(np.int32), csr.indices, csr.edge_rtt,
+            csr.node_features)
+    else:
+        degree = np.diff(csr.indptr)
+        # Entry e of host h's row goes to lane e - indptr[h] of row h.
+        at = np.arange(len(csr.indices)) + np.repeat(
+            np.arange(csr.n_nodes) * width - csr.indptr[:-1], degree)
+        nbr = np.zeros((csr.n_nodes, width), np.int32)
+        rtt = np.zeros((csr.n_nodes, width), np.float32)
+        nbr.reshape(-1)[at] = csr.indices
+        rtt.reshape(-1)[at] = csr.edge_rtt
+        nbr[:, -1] = degree
+        # As int32: a slot's RTT is picked like its id, and only the
+        # picked slots are read as float32 again (on the chip a bitcast
+        # of the fetched rows is a pass over them).
+        tables = RowTables(nbr, rtt.view(np.int32), csr.node_features)
+    return type(tables)(*(jax.device_put(a, mesh.replicated) for a in tables))
 
 
 def put_edge_tables(src: np.ndarray, dst: np.ndarray, labels: np.ndarray,
@@ -82,6 +158,16 @@ def _gather(table: jax.Array, idx: jax.Array, out_sharding) -> jax.Array:
     return table.at[idx].get(out_sharding=out_sharding)
 
 
+def _reshape(x: jax.Array, shape: tuple, out_sharding, axis: int = 0):
+    """A reshape that cuts or joins batch rows: ``axis`` of the result
+    is the sharded one (a typed sharding cannot guess which factor is)."""
+    if out_sharding is None:
+        return x.reshape(shape)
+    spec = jax.sharding.PartitionSpec(*(None,) * axis, *out_sharding.spec)
+    return jnp.reshape(x, shape, out_sharding=jax.sharding.NamedSharding(
+        out_sharding.mesh, spec))
+
+
 def _lowbias32(x: jax.Array) -> jax.Array:
     """32-bit avalanche hash (lowbias32) — pure elementwise integer ops."""
     x = x ^ (x >> 16)
@@ -90,6 +176,11 @@ def _lowbias32(x: jax.Array) -> jax.Array:
     x = x * jnp.uint32(0x846CA68B)
     x = x ^ (x >> 16)
     return x
+
+
+def _hash_at(salt: jax.Array, idx: jax.Array) -> jax.Array:
+    """The uniform u32 of (salt, position ``idx``): see ``_hashed_bits``."""
+    return _lowbias32(_lowbias32(idx + salt) ^ (salt * jnp.uint32(0x9E3779B9)))
 
 
 def _hashed_bits(salt: jax.Array, shape: tuple) -> jax.Array:
@@ -110,18 +201,62 @@ def _hashed_bits(salt: jax.Array, shape: tuple) -> jax.Array:
     for d in reversed(range(len(shape))):
         idx = idx + jax.lax.broadcasted_iota(jnp.uint32, shape, d) * jnp.uint32(mult)
         mult *= shape[d]
-    return _lowbias32(_lowbias32(idx + salt) ^ (salt * jnp.uint32(0x9E3779B9)))
+    return _hash_at(salt, idx)
 
 
-def sample_neighbors(graph: GraphTables, nodes: jax.Array, fanout: int,
+def sample_neighbors(graph, nodes: jax.Array, fanout: int,
                      salt: jax.Array, out_sharding=None):
     """Fanout-sample WITH replacement for each node; returns
     (nbr_idx, rtt, mask), each ``nodes.shape + (fanout,)``.
 
     Mirrors CSRGraph.sample_neighbors (host half) exactly: padded slots
     (zero-degree nodes) carry index 0 / rtt 0 / mask 0; positive-degree
-    nodes always fill all ``fanout`` replacement-sampled slots.
+    nodes always fill all ``fanout`` replacement-sampled slots. ``graph``
+    is what :func:`put_graph_tables` placed; either form gives the same
+    samples.
     """
+    if isinstance(graph, GraphTables):
+        return _sample_csr(graph, nodes, fanout, salt, out_sharding)
+    lead, rest = nodes.shape[0], nodes.shape[1:]
+    held = (lead if out_sharding is None
+            else out_sharding.shard_shape(nodes.shape)[0])
+    row_bytes = 2 * 4 * graph.nbr_rows.shape[1] * math.prod(rest)
+    chunks = min(-(-held * row_bytes // ROW_CHUNK_BYTES), held)
+    chunks = next(c for c in range(chunks, held + 1) if held % c == 0)
+    # Slice j is rows [j * size, (j + 1) * size) of every device's shard
+    # of the batch: cutting moves nothing, on one device or on several.
+    shards, size = lead // held, held // chunks
+    slices = jnp.moveaxis(
+        _reshape(nodes, (shards, chunks, size) + rest, out_sharding), 1, 0)
+
+    def one(xs):
+        at = _positions(xs[0].shape, xs[1] * jnp.uint32(size), held,
+                        out_sharding)
+        return _sample_rows(graph, _reshape(xs[0], (at.size,), out_sharding),
+                            at, fanout, salt, out_sharding)
+
+    firsts = jnp.arange(chunks, dtype=jnp.uint32)
+    if chunks == 1:
+        nbr, rtt, has = (x[None] for x in one((slices[0], firsts[0])))
+    else:
+        nbr, rtt, has = jax.lax.map(one, (slices, firsts))
+
+    def whole(x, tail):
+        """``[chunks, *tail, nodes of a slice]`` as ``[*nodes.shape, *tail]``:
+        the one relayout of a hop's samples, after the loop."""
+        n = len(tail)
+        x = _reshape(x, (chunks,) + tail + (shards, size) + rest,
+                     out_sharding, axis=1 + n)
+        x = jnp.moveaxis(x, range(1, 1 + n), range(x.ndim - n, x.ndim))
+        return _reshape(jnp.swapaxes(x, 0, 1), nodes.shape + tail,
+                        out_sharding)
+
+    mask = jnp.broadcast_to(whole(has, ())[..., None],
+                            nodes.shape + (fanout,))
+    return whole(nbr, (fanout,)), whole(rtt, (fanout,)), mask
+
+
+def _sample_csr(graph: GraphTables, nodes, fanout, salt, out_sharding):
     start = _gather(graph.indptr, nodes, out_sharding)
     deg = _gather(graph.indptr, nodes + 1, out_sharding) - start
     bits = _hashed_bits(salt, nodes.shape + (fanout,))
@@ -138,7 +273,58 @@ def sample_neighbors(graph: GraphTables, nodes: jax.Array, fanout: int,
     return jnp.where(mask > 0, nbr, 0), rtt * mask, mask
 
 
-def sample_and_apply(model: GraphSAGE, params, graph: GraphTables,
+def _pick(rows: jax.Array, offs: jax.Array) -> jax.Array:
+    """``rows[m, offs[f, m]]`` of int32 ``rows [m, W]`` as ``[f, m]``,
+    with no gather: one lane of each row survives the select, so the sum
+    over the lanes is that lane's value, bit for bit. (Slots-major, as
+    the compiler lays the result out whatever its logical shape.)"""
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, 1, rows.shape[1]), 2)
+    return jnp.sum(jnp.where(offs[:, :, None] == lane, rows[None], 0),
+                   axis=-1)
+
+
+def _positions(shape: tuple, first, held: int, out_sharding) -> jax.Array:
+    """Where the nodes of a slice ``[shards, size, ...]`` stand in the
+    whole hop, flat: ``nodes[s, i]`` is batch row ``s * held + first + i``
+    there. The hash takes a slot's position in the hop, so a slice draws
+    the offsets the CSR path draws."""
+    per_row = math.prod(shape[2:])
+    shard, i, r = (
+        jax.lax.broadcasted_iota(jnp.uint32, shape[:2] + (per_row,), d)
+        for d in range(3))
+    at = (shard * jnp.uint32(held) + first + i) * jnp.uint32(per_row) + r
+    return _reshape(at, (at.size,), out_sharding)
+
+
+def _sample_rows(graph: RowTables, nodes, at, fanout: int, salt,
+                 out_sharding):
+    """One slice of a hop on the row tables, over its ``m`` nodes (flat,
+    ``at`` their positions in the whole hop): ``(ids [fanout, m], RTTs
+    [fanout, m], degree > 0 [m])``."""
+    with jax.named_scope("df2.sample.rows"):
+        ids = _gather(graph.nbr_rows, nodes, out_sharding)
+    with jax.named_scope("df2.sample.pick"):
+        slot = jax.lax.broadcasted_iota(jnp.uint32, (fanout, 1), 0)
+        # The barrier keeps the degrees one column read, not one a use.
+        deg = jax.lax.optimization_barrier(ids[:, -1])
+        # A zero-degree row is all zeros: offset 0 picks index 0, RTT 0.
+        offs = (_hash_at(salt, at[None, :] * jnp.uint32(fanout) + slot)
+                % jnp.maximum(deg, 1).astype(jnp.uint32)[None, :]
+                ).astype(jnp.int32)
+    # The RTT rows are fetched once the offsets are drawn, so the tables
+    # are read one after the other: the chip's fast memory holds one of
+    # them (102 MB) at a time, the compiler moves each in ahead of its
+    # fetch, and a row out of it costs a quarter of one out of HBM.
+    offs, nodes = jax.lax.optimization_barrier((offs, nodes))
+    with jax.named_scope("df2.sample.rows"):
+        rtts = _gather(graph.rtt_rows, nodes, out_sharding)
+    with jax.named_scope("df2.sample.pick"):
+        nbr = _pick(ids, offs)
+        rtt = jax.lax.bitcast_convert_type(_pick(rtts, offs), jnp.float32)
+    return nbr, rtt, (deg > 0).astype(jnp.float32)
+
+
+def sample_and_apply(model: GraphSAGE, params, graph,
                      src, dst, key: jax.Array, fanouts: tuple,
                      out_sharding=None):
     """Sample the 2-hop neighborhood on device and run the forward pass.
@@ -179,7 +365,7 @@ def _batch_rows(edges: EdgeTables, edge_ids, out_sharding):
                      for table in edges)
 
 
-def _fused_update(model: GraphSAGE, state, graph: GraphTables,
+def _fused_update(model: GraphSAGE, state, graph,
                   edges: EdgeTables, edge_ids, key, fanouts: tuple,
                   out_sharding):
     """One optimizer step on one id batch: the body the one-step and the

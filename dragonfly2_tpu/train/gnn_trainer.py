@@ -253,6 +253,9 @@ def train_gnn(
         )
 
         graph_tables = put_graph_tables(csr, mesh)
+        # On every step's span, so that a trace of any window says which
+        # sampler its steps ran (docs/OBSERVABILITY.md "Training loops").
+        step_facts = {"sampler_row_width": graph_tables.row_width}
         # The samplers already hold the sliced/cast split arrays — reuse
         # them instead of re-slicing ~2M-element fancy indexes.
         train_edges = put_edge_tables(
@@ -272,6 +275,7 @@ def train_gnn(
         train_step = None
     else:
         train_step = make_train_step(model, mesh)
+        step_facts = {}
 
     def place(batch) -> tuple:
         return tuple(mesh.put_batch(a) for a in batch.astuple())
@@ -352,7 +356,8 @@ def train_gnn(
             break
         epoch, arrays = item
         with jax.profiler.StepTraceAnnotation("df2.train.step",
-                                              step_num=step_num):
+                                              step_num=step_num,
+                                              **step_facts):
             if epoch != current_epoch:
                 if epoch_losses:
                     end_epoch()

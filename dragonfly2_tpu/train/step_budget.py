@@ -62,11 +62,16 @@ class TrainingStats:
       held expert, summed over the layers. Hottest over (held / experts
       held) is the load imbalance the grouped products see. Read from
       the device once, at a loop's drain.
+    - ``sampler_row_width``: not a counter but the last value set, where
+      ``fused_sampling.put_graph_tables`` places GraphSAGE's tables: the
+      lanes of a host's neighbour row, or 0 where the graph kept its CSR
+      form (and before any table was placed).
     """
 
     KEYS = ("loops_started", "dispatches", "steps", "samples",
             "compile_seconds", "loop_compiles", "steady_compiles",
-            "moe_steps", "moe_assignments_held", "moe_assignments_hottest")
+            "moe_steps", "moe_assignments_held", "moe_assignments_hottest",
+            "sampler_row_width")
 
     def __init__(self):
         self._lock = threading.Lock()
@@ -80,6 +85,10 @@ class TrainingStats:
         with self._lock:
             for key, value in increments.items():
                 self._counts[key] += value
+
+    def set(self, **values) -> None:
+        with self._lock:
+            self._counts.update(values)
 
     def loop_started(self, budget) -> None:
         with self._lock:

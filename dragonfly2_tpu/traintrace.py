@@ -16,6 +16,9 @@ phases (docs/OBSERVABILITY.md "Training loops").
   says which scope's operations ran next on the device, which is
   adjacency, not attribution.
 - Per host thread: the ``df2.train.*`` spans' totals per step.
+- ``step_facts``: what the loop wrote on its ``df2.train.step`` spans
+  besides the step's number (``sampler_row_width``: the lanes of
+  GraphSAGE's per-host neighbour rows, 0 on the CSR sampler).
 - The longest device idle gaps, each with the ``df2.train.*`` span the
   loop's thread was in.
 
@@ -207,8 +210,14 @@ def analyze_planes(planes, n_gaps: int = 5, n_unscoped: int = 5,
     loop_spans = [] if loop is None else [
         (ev.start_ns, ev.start_ns + ev.duration_ns, ev.name)
         for ev in loop.events if ev.name.startswith(HOST_SPAN)]
+    facts = {}
+    for ev in (loop.events if loop is not None else ()):
+        if ev.name == STEP_SPAN:
+            facts.update((k, v) for k, v in ev.stats.items()
+                         if k != "step_num" and not k.startswith("_"))
     return {
         "host_steps": host_steps,
+        "step_facts": facts,
         "devices": [
             _device(plane, loop_spans, host_steps, n_gaps, n_unscoped, scope)
             for plane in planes if plane.name.startswith(DEVICE_PLANE)],
@@ -222,6 +231,8 @@ def format_report(report: dict) -> str:
     out = [f"trace      {report['path']}",
            f"host steps {report['host_steps']} (df2.train.step spans on "
            "the loop's thread)"]
+    out += [f"{name} {value}"
+            for name, value in report["step_facts"].items()]
     if not report["devices"]:
         out.append("no device plane: a CPU trace carries no scope paths")
     for dev in report["devices"]:

@@ -16,6 +16,7 @@ happen in the test's own process, and these tests stay in this ONE file.
 import importlib
 import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -301,3 +302,68 @@ def test_sequence_attention_kernel_at_the_cells_shape(one_chip):
     assert compiled.as_text().count("tpu_custom_call") >= 2
     # No [heads, S, S] score matrix: 32 x 8192 x 8192 float32 is 8.6 GB.
     assert compiled.memory_analysis().temp_size_in_bytes < 2e9
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_fused_graphsage_step_at_the_cells_shapes(topo, chips):
+    """``sage-fleet100k.train`` (and ``.dp4``: the same rows a chip): the
+    whole fused step on the row tables (100,000 hosts, rows 256 lanes
+    wide, 9,999,650 target edges, batch 131,072 a chip, fan-outs (10, 5))
+    fits the chip with room, hop 2 runs in slices, and sampling adds no
+    collective to the data-parallel step's two gradient all-reduces."""
+    import optax
+    from flax.training import train_state
+
+    from dragonfly2_tpu.models.graphsage import GraphSAGE
+    from dragonfly2_tpu.parallel import data_parallel_mesh
+    from dragonfly2_tpu.train import fused_sampling as fs
+
+    hosts, records, batch, feat, width = 100_000, 9_999_650, 131_072, 8, 256
+    mesh = data_parallel_mesh(devices=topo.devices[:chips])
+    rep = _struct(mesh.replicated)
+    model = GraphSAGE()
+
+    def init(key):
+        z = jnp.zeros
+        params = model.init(
+            key, z((2, 2, feat)), z((2, 2, 10, feat)), z((2, 2, 10)),
+            z((2, 2, 10)), z((2, 2, 10, 5, feat)), z((2, 2, 10, 5)),
+            z((2, 2, 10, 5)))
+        return train_state.TrainState.create(
+            apply_fn=model.apply, params=params, tx=optax.adamw(1e-3))
+
+    state = jax.tree.map(lambda x: rep(x.shape, x.dtype),
+                         jax.eval_shape(init, jax.random.key(0)))
+    graph = fs.RowTables(rep((hosts, width), jnp.int32),
+                         rep((hosts, width), jnp.int32),
+                         rep((hosts, feat), jnp.float32))
+    edges = fs.EdgeTables(rep((records,), jnp.int32),
+                          rep((records,), jnp.int32),
+                          rep((records,), jnp.float32))
+    ids = _struct(mesh.batch_sharding)((batch * chips,), jnp.int32)
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    compiled = fs.make_fused_train_step(model, mesh, (10, 5)).lower(
+        state, graph, edges, ids, rep(key.shape, key.dtype)).compile()
+    memory = compiled.memory_analysis()
+    # 0.33 + 9.01 GB, PR 28 (the CSR step: 0.21 + 8.82).
+    assert (memory.argument_size_in_bytes
+            + memory.temp_size_in_bytes) < 14e9
+    text = compiled.as_text()
+    assert "df2.sample.hop2)/while/body" in text
+    assert text.count(f"s32[163840,{width}]") > 0      # a slice's rows
+    for op in ("all-gather(", "collective-permute(", "all-to-all("):
+        assert op not in text, op
+    assert text.count("all-reduce(") == (2 if chips > 1 else 0)
+    # Every row fetch reads its table out of the chip's fast memory
+    # (layout suffix S(1)): the two tables are read one after the other,
+    # so the compiler moves each in ahead of its fetch. Out of HBM a row
+    # costs 12 ns, not 2.8 (PERF.md section 6, PR 28).
+    fetches = re.findall(
+        rf"= s32\[\d+,{width}\]\S* fusion\((%[\w.\-]+), [^)]*\), "
+        r"kind=kCustom", text)
+    assert len(fetches) == 4, fetches
+    for table in fetches:
+        (layout,) = re.findall(
+            rf"^\s*{re.escape(table)} = (s32\[{hosts},{width}\]\S*) ",
+            text, re.M)
+        assert layout.endswith("S(1)}"), (table, layout)

@@ -131,6 +131,157 @@ class TestDeviceSampling:
         assert (bits == bits2).mean() < 0.01
 
 
+def _skewed_csr():
+    """Five hosts whose rows run 0, 1, 3, 40 and 127 long: a zero-degree
+    host in the middle and at the end, and a host whose row fills lane
+    126, the last before the degree's lane of a 128-lane row."""
+    degrees = np.array([3, 0, 127, 1, 40, 0])
+    n = len(degrees)
+    rng = np.random.default_rng(5)
+    indptr = np.zeros(n + 1, np.int64)
+    np.cumsum(degrees, out=indptr[1:])
+    return CSRGraph(
+        indptr=indptr,
+        indices=rng.integers(0, n, indptr[-1]).astype(np.int32),
+        edge_rtt=rng.lognormal(0.0, 1.0, indptr[-1]).astype(np.float32),
+        node_features=rng.normal(size=(n, 8)).astype(np.float32))
+
+
+def _csr_form(fs, monkeypatch, csr, mesh):
+    """The tables of the path no fleet takes: both limits at 0."""
+    with monkeypatch.context() as m:
+        m.setattr(fs, "ROW_PAD_FACTOR", 0)
+        m.setattr(fs, "ROW_PAD_FREE_BYTES", 0)
+        return fs.put_graph_tables(csr, mesh)
+
+
+class TestRowTables:
+    @pytest.mark.parametrize("sliced", [False, True],
+                             ids=["one_piece", "sliced"])
+    @pytest.mark.parametrize("devices", [1, 4])
+    def test_row_path_draws_the_csr_paths_samples(self, monkeypatch,
+                                                  devices, sliced):
+        """Both hops at fan-outs (10, 5), as ``sample_and_apply`` chains
+        them: ids, RTTs and masks equal bit for bit, under jit on one
+        device and under the batch sharding of a four-device mesh, in one
+        piece and in slices of the batch."""
+        import jax
+
+        from dragonfly2_tpu.train import fused_sampling as fs
+
+        mesh = data_parallel_mesh(devices=jax.devices()[:devices])
+        csr = _skewed_csr()
+        rows = fs.put_graph_tables(csr, mesh)
+        assert isinstance(rows, fs.RowTables)
+        assert rows.nbr_rows.shape == rows.rtt_rows.shape == (6, 128)
+        flat = _csr_form(fs, monkeypatch, csr, mesh)
+        assert isinstance(flat, fs.GraphTables)
+        if sliced:
+            # 64 batch rows of 2 and of 20 nodes: 2 and 16 slices.
+            monkeypatch.setattr(fs, "ROW_CHUNK_BYTES", 80 * 1024)
+        b = mesh.batch_sharding if devices > 1 else None
+        centers = np.random.default_rng(1).integers(
+            0, 6, (64, 2)).astype(np.int32)
+        centers[0] = (1, 5)                      # the zero-degree hosts
+        centers[1] = (2, 2)                      # the full row
+
+        def two_hops(graph, centers, s1, s2):
+            nbr1, rtt1, mask1 = fs.sample_neighbors(graph, centers, 10, s1, b)
+            nbr2, rtt2, mask2 = fs.sample_neighbors(graph, nbr1, 5, s2, b)
+            return nbr1, rtt1, mask1, nbr2, rtt2, mask2
+
+        run = jax.jit(two_hops, in_shardings=(
+            mesh.replicated, b or mesh.replicated, None, None))
+        args = (mesh.put_batch(centers) if b else centers,
+                np.uint32(0xDEADBEEF), np.uint32(77))
+        got, want = run(rows, *args), run(flat, *args)
+        for name, x, y in zip("nbr1 rtt1 mask1 nbr2 rtt2 mask2".split(),
+                              got, want):
+            assert x.shape == y.shape and x.dtype == y.dtype, name
+            np.testing.assert_array_equal(
+                np.asarray(x).view(np.int32), np.asarray(y).view(np.int32),
+                err_msg=name)
+        nbr1, _, mask1 = map(np.asarray, got[:3])
+        assert mask1[0].sum() == 0 and nbr1[0].sum() == 0
+        assert mask1[1].sum() == 20
+        # Offsets reach the row's last entry (lane 126 of the full row).
+        last = csr.indices[csr.indptr[3] - 1]
+        assert last in nbr1[1]
+        text = run.lower(rows, *args).compile().as_text()
+        assert ("while" in text) == sliced
+        for op in ("all-gather", "all-reduce", "collective-permute",
+                   "all-to-all"):
+            assert op not in text, f"row-path sampling contains {op}"
+
+    def test_a_hub_keeps_the_csr_tables(self, monkeypatch, mesh):
+        """One host of 300 records among 200 of 2: padding every row to
+        384 lanes is 109 times the CSR. Past both limits the graph keeps
+        its CSR form; under the byte limit alone it pads (a small graph
+        pads for nothing)."""
+        from dragonfly2_tpu.train import fused_sampling as fs
+        from dragonfly2_tpu.train.step_budget import TRAINING
+
+        degrees = np.full(201, 2)
+        degrees[17] = 300
+        indptr = np.zeros(202, np.int64)
+        np.cumsum(degrees, out=indptr[1:])
+        rng = np.random.default_rng(2)
+        csr = CSRGraph(indptr, rng.integers(0, 201, indptr[-1]).astype(
+            np.int32), rng.random(indptr[-1]).astype(np.float32),
+            rng.normal(size=(201, 8)).astype(np.float32))
+        assert fs.row_width(csr) == 384
+        padded_bytes = 2 * 4 * 201 * 384
+        assert 201 * 384 > fs.ROW_PAD_FACTOR * indptr[-1]
+        assert isinstance(fs.put_graph_tables(csr, mesh), fs.RowTables)
+        assert TRAINING.snapshot()["sampler_row_width"] == 384
+        monkeypatch.setattr(fs, "ROW_PAD_FREE_BYTES", padded_bytes - 1)
+        assert fs.row_width(csr) == 0
+        tables = fs.put_graph_tables(csr, mesh)
+        assert isinstance(tables, fs.GraphTables)
+        assert TRAINING.snapshot()["sampler_row_width"] == 0
+        np.testing.assert_array_equal(np.asarray(tables.indices),
+                                      csr.indices)
+        # Rows of one order pad whatever their bytes.
+        monkeypatch.setattr(fs, "ROW_PAD_FACTOR", 200)
+        assert fs.row_width(csr) == 384
+
+    def test_rehearsal_fleet_takes_the_row_path(self, mesh):
+        """``benchmarks/tests/`` rehearse the ``sage`` cells on a fleet of
+        160 hosts: it must run the program the chip runs."""
+        import json
+        import os
+
+        from benchmarks.traffic import probe_graph
+        from dragonfly2_tpu.data.features import Graph
+        from dragonfly2_tpu.train import fused_sampling as fs
+
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        with open(os.path.join(root, "benchmarks", "configs",
+                               "sage-fleet100k.json")) as fh:
+            config = json.load(fh)
+        fleet = {**config["fleet"], **config["rehearse"]["fleet"]}
+        arrays = probe_graph(fleet, seed=3)
+        csr = CSRGraph.from_graph(Graph(
+            node_ids=np.arange(fleet["hosts"]).astype(str),
+            node_features=arrays["node_features"],
+            edge_src=arrays["edge_src"], edge_dst=arrays["edge_dst"],
+            edge_rtt_ns=arrays["edge_rtt_ns"]))
+        assert np.diff(csr.indptr).max() < 128
+        tables = fs.put_graph_tables(csr, mesh)
+        assert isinstance(tables, fs.RowTables)
+        assert tables.nbr_rows.shape == (fleet["hosts"], 128)
+        rows = np.asarray(tables.nbr_rows)
+        np.testing.assert_array_equal(rows[:, -1], np.diff(csr.indptr))
+        host = int(np.argmax(np.diff(csr.indptr)))
+        lo, hi = csr.indptr[host], csr.indptr[host + 1]
+        np.testing.assert_array_equal(rows[host, :hi - lo],
+                                      csr.indices[lo:hi])
+        np.testing.assert_array_equal(
+            np.asarray(tables.rtt_rows)[host, :hi - lo].view(np.float32),
+            csr.edge_rtt[lo:hi])
+        assert not rows[host, hi - lo:-1].any()
+
+
 class TestFusedTraining:
     def test_device_and_host_paths_both_learn(self, graph, mesh):
         cfg = dict(hidden=32, embed=16, batch_size=512, epochs=10,

@@ -452,6 +452,29 @@ def test_a_scope_is_a_union_not_a_sum():
         {"path": "made by hand", **report})
 
 
+def test_step_facts_reach_the_report(traced, capsys):
+    """What ``train_gnn`` writes on its step spans (the sampler's row
+    width) is in the report and in the tool's text; a loop that writes
+    nothing (``train_gat``) leaves it empty."""
+    from dragonfly2_tpu.cmd import tracetool
+
+    def loop(**stats):
+        return xplane.Plane("/host:CPU", [xplane.Line("python3", [
+            xplane.Event("df2.train.step", 0.0, 10.0,
+                         {"_r": 1, "step_num": n, **stats})
+            for n in range(2)])])
+
+    report = traintrace.analyze_planes([loop(sampler_row_width=256)])
+    assert report["step_facts"] == {"sampler_row_width": 256}
+    assert "sampler_row_width 256" in traintrace.format_report(
+        {"path": "made by hand", **report})
+    assert traintrace.analyze_planes([loop()])["step_facts"] == {}
+    # The traced GraphSAGE loop: its longest row is past 127 records.
+    assert tracetool.main(["train", traced["dump"], "--json"]) == 0
+    assert json.loads(capsys.readouterr().out)["step_facts"] == {
+        "sampler_row_width": 256}
+
+
 def test_scopes_on_the_recorded_trace(recorded):
     """The flax path stands in for a ``df2.*`` scope in a trace recorded
     before they existed."""
