@@ -21,7 +21,6 @@ Subpackage map (mirrors SURVEY.md §2 component inventory):
                   (reference: scheduler/storage/types.go)
 - ``data``      — feature extraction + input pipeline (host-side, static shapes)
 - ``models``    — flax models: MLP, GraphSAGE, GAT (reference stubs filled)
-- ``ops``       — pallas kernels for hot ops
 - ``parallel``  — mesh/sharding helpers (ICI/DCN-aware)
 - ``train``     — pjit training loops, orbax checkpointing, federated averaging
 - ``inference`` — batched jit scorer + KServe-style sidecar
@@ -33,7 +32,7 @@ Subpackage map (mirrors SURVEY.md §2 component inventory):
 - ``manager``   — model registry, cluster CRUD, searcher (reference: manager/)
 
 Importing this package is intentionally lightweight: JAX is only imported by
-the subpackages that need it (models/train/inference/parallel/ops), so
+the subpackages that need it (models/train/inference/parallel), so
 control-plane services can run without pulling in an accelerator runtime.
 """
 

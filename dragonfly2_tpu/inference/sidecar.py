@@ -899,13 +899,13 @@ def _gat_scorer_from_artifact(artifact: bytes):
          node_ids) = gat_from_tree(tree)
         params = _maybe_poison_weights(params, MODEL_NAME_GAT)
         cfg = metadata.config
+        # Gather mode whatever mode trained it: the parameters are the
+        # same tree in every mode, and this process holds one device.
         model = GraphTransformer(
             hidden=int(cfg.get("hidden", 128)),
             embed=int(cfg.get("embed", 64)),
             layers=int(cfg.get("layers", 2)),
             heads=int(cfg.get("heads", 4)),
-            attention=str(cfg.get("attention", "gather")),
-            chunk=int(cfg.get("chunk", 1024)),
         )
         return GATParentScorer(model, params, node_features, neighbors,
                                neighbor_vals, node_ids=node_ids)

@@ -1,4 +1,4 @@
-"""GraphTransformer — block-sparse attention over the cluster topology
+"""GraphTransformer — neighbour attention over the cluster topology
 (BASELINE config #3, the scale-out GNN).
 
 Where GraphSAGE (config #2) trains on sampled fixed-fanout subgraphs, this
@@ -6,38 +6,27 @@ model attends over the ENTIRE probe graph at once: every host embedding is
 refined by multi-head attention restricted to its probe neighbors, with the
 measured RTT injected as an additive attention bias.
 
-Scaling design (round 4 — replaces the dense [N, N] bias/mask layout):
-the old layout materialized O(N²) bias, mask, and score tensors, which
-capped full-topology graphs at a few thousand hosts (100k hosts would
-need a 40 GB score matrix per head). The graph structure now lives in
-**padded per-node neighbor lists** — ``nbr [N, K]`` int32 ids and
-``val [N, K]`` float32 RTT biases, K = capped max degree — shared by two
-attention implementations with identical semantics:
+The graph structure lives in **padded per-node neighbor lists** —
+``nbr [N, K]`` int32 ids and ``val [N, K]`` float32 RTT biases, K = capped
+max degree (a dense ``[N, N]`` bias and mask would need a 40 GB score
+matrix per head at 100k hosts). One attention arithmetic reads them, in
+two layouts with identical semantics:
 
 - ``attention="gather"`` (default): neighbor-gather attention, O(N·K·H)
-  compute and memory (``gather_graph_attention``) — the right shape for
-  degree-capped probe graphs, where scoring all N key columns wastes an
-  N/K ≈ 1000× factor masking columns that can never attend.
-- ``attention="blocks"``: flash-style chunked block attention — on a
-  single TPU device this is the pallas ``graph_flash_attention`` kernel
-  (``ops/flash_attention.py``: bias scatter + online softmax fused in
-  VMEM, no HBM bias/mask tensors at all); elsewhere the XLA ``lax.scan``
-  over key blocks (``sparse_graph_attention``) with the [rows, chunk]
-  bias/mask block scattered on device and a ``jax.checkpoint``-ed body
-  keeping backward memory at O(rows·heads·chunk). For graphs dense
-  enough that K ~ N, its MXU-shaped [rows, chunk] matmuls beat per-row
-  gathers. (``attention="flash"`` forces the kernel, interpret-mode off
-  TPU — tests/benchmarks.)
-- ``attention="ring"``: blocks mode where K/V stay row-sharded and
-  rotate around the device ring via ``lax.ppermute``
-  (``ring_graph_attention``) — no full-width K/V at all, for topologies
-  past the point where even the O(N·H) replicated table binds.
+  compute and memory (``gather_graph_attention``), lane-dense, with the
+  gathers' backward a gather too (``build_inverse_index``). The shape of
+  degree-capped probe graphs, where scoring all N key columns would spend
+  an N/K ≈ 1000× factor masking columns that can never attend. One chip,
+  or rows over a ``data`` mesh (and heads over ``model``).
+- ``attention="ring"``: K/V stay row-sharded and rotate around the
+  device ring via ``lax.ppermute`` (``ring_graph_attention``) — no
+  full-width K/V at all; each visiting block is scored against the local
+  rows' lists in ``chunk``-column sub-blocks with an online softmax.
 
 Common sharding: queries/neighbor lists/accumulators are row-sharded
-over the mesh's ``data`` axis (each device owns N/d query rows); in the
-gather/blocks modes K/V go full-width — one O(N·H) all-gather over ICI
-per layer (25 MB at 100k hosts; never the scale cap — the O(N²) dense
-tensors were); ring mode trades that gather for d ppermute hops.
+over the mesh's ``data`` axis (each device owns N/d query rows); in
+gather mode K/V go full-width — one O(N·H) all-gather over ICI per layer
+(25 MB at 100k hosts); ring mode trades that gather for d ppermute hops.
 
 Reference parity: Dragonfly2 leaves GNN training a stub
 (`/root/reference/trainer/training/training.go`); the topology features
@@ -47,7 +36,6 @@ The model/scale targets come from BASELINE.md config #3.
 
 from __future__ import annotations
 
-import math
 from functools import partial
 
 import flax.linen as nn
@@ -60,6 +48,13 @@ NEG_INF = -1e9
 # Neighbor-list pad sentinel: never inside [0, N) for any padded N, so a
 # pad slot is out of range of every key block and scatters nothing.
 PAD_ID = np.int32(2**30)
+
+
+def check_attention(mode: str) -> None:
+    """The two layouts of the one attention; nothing else is a mode."""
+    if mode not in ("gather", "ring"):
+        raise ValueError(
+            f"attention must be 'gather' or 'ring', not {mode!r}")
 
 
 def _mesh_empty() -> bool:
@@ -170,22 +165,11 @@ def pad_graph_sparse(
     return node_features, nbr, val, n
 
 
-def pad_multiple(n_data: int, chunk: int, n_nodes: int) -> int:
-    """Row-pad multiple: rows must shard evenly over ``data`` AND, once
-    the PADDED graph exceeds one key block, split evenly into ``chunk``
-    blocks (the decision must use the post-padding count — mesh padding
-    can push N past ``chunk``, e.g. n_data=6, chunk=1024, N=1023→1026)."""
-    padded = ((n_nodes + n_data - 1) // n_data) * n_data
-    if padded <= chunk:
-        return n_data
-    return n_data * chunk // math.gcd(n_data, chunk)
-
-
 def _divisor_block(n: int, chunk: int) -> int:
     """Largest divisor of ``n`` that is ≤ ``chunk`` (≥ 1). Host-side,
-    static shapes — used by the ring fallback to keep the chunked scan
-    legal for row counts the ring padding rule aligned per-device but
-    not globally (e.g. n=104 over 8 devices with chunk=16)."""
+    static shapes — the ring of one device scans ALL rows, which the
+    ring padding rule aligned per device but not globally (e.g. n=104
+    over 8 devices with chunk=16)."""
     best = 1
     d = 1
     while d * d <= n:
@@ -198,33 +182,19 @@ def _divisor_block(n: int, chunk: int) -> int:
     return best
 
 
-def _block_bias(nbr, val, start, block, local=False):
+def _block_bias(nbr, val, start, block):
     """[rows, block] (bias, mask) for key columns [start, start+block),
     scattered on device from the neighbor lists. Scatter-ADD is exact
     because build_neighbor_lists dedups (row, col) pairs; pad slots
     (PAD_ID) are out of range of every block and contribute nothing.
-    ``local=True`` forces the plain (per-device) scatter path — used
-    inside shard_map bodies, where arrays are already local and the
-    explicit-sharding reshard/out_sharding machinery must not run."""
+    A per-device scatter: its callers hold local arrays (a shard_map
+    body, or no mesh at all)."""
     in_range = (nbr >= start) & (nbr < start + block)
     col = jnp.clip(nbr - start, 0, block - 1)
     rows_iota = jax.lax.broadcasted_iota(jnp.int32, nbr.shape, 0)
     base = jnp.broadcast_to(val[:, :1] * 0, (nbr.shape[0], block))
-    # Row axis follows the OPERANDS' sharding (usually 'data'; None when
-    # the caller runs unsharded inputs under an ambient mesh, e.g. a
-    # model.init on a tiny throwaway graph) — hardcoding 'data' would
-    # force-shard the scatter output and break the scan carry's type.
-    rows_axis = None if local or _mesh_empty() else _value_spec(nbr)[0]
-    if rows_axis is None:
-        bias = base.at[rows_iota, col].add(jnp.where(in_range, val, 0.0))
-        hits = base.at[rows_iota, col].add(in_range.astype(val.dtype))
-    else:
-        spec = P(rows_axis, None)
-        rows_iota = jax.sharding.reshard(rows_iota, spec)
-        bias = base.at[rows_iota, col].add(
-            jnp.where(in_range, val, 0.0), out_sharding=spec)
-        hits = base.at[rows_iota, col].add(
-            in_range.astype(val.dtype), out_sharding=spec)
+    bias = base.at[rows_iota, col].add(jnp.where(in_range, val, 0.0))
+    hits = base.at[rows_iota, col].add(in_range.astype(val.dtype))
     return bias, hits > 0
 
 
@@ -234,95 +204,105 @@ def ring_graph_attention(q, k, v, nbr, val, chunk, axis="data"):
     O(N/d · (heads·head_dim + K)): the layout for topologies past the
     point where even the O(N·H) replicated K/V table binds.
 
-    Same online-softmax algebra as ``sparse_graph_attention``, same ring
-    mechanics as ``parallel/ring_attention.py`` (which handles the
-    sequence/causal case); here each visiting block's bias/mask is
-    scattered from the LOCAL rows' neighbor lists at the block's global
-    offset — all per-device ops, differentiable through ppermute with no
-    custom VJP. Each ring step scans the received block in ``chunk``-
-    column sub-blocks (rematerialized) to bound the score tile.
+    An online softmax over key blocks: each visiting block's bias/mask
+    is scattered from the LOCAL rows' neighbor lists at the block's
+    global offset — all per-device ops, differentiable through ppermute
+    with no custom VJP. Each ring step scans the received block in
+    ``chunk``-column sub-blocks (rematerialized) to bound the score
+    tile at ``[rows, heads, chunk]``.
 
     q/k/v: [N, heads, head_dim] row-sharded over ``axis``; nbr/val:
-    [N, K] row-sharded. Requires an ambient mesh (jax.set_mesh).
+    [N, K] row-sharded. Without an ambient mesh (``model.init`` outside
+    ``jax.set_mesh``, a single-process run) it is the ring of one
+    device: the same scan over all rows, no collectives.
     """
     mesh = jax.sharding.get_abstract_mesh()
     if mesh.empty or axis not in mesh.shape:
-        # No ambient mesh (e.g. model.init outside jax.set_mesh, or a
-        # single-process run): the ring degenerates to the local chunked
-        # scan — same math, no collectives. The GLOBAL row count is only
-        # guaranteed divisible by per-DEVICE chunks (ring padding aligns
-        # n/d, not n, to ``chunk``), so shrink the block to a divisor of
-        # n rather than asserting — this path is a trace-time fallback,
-        # not the hot loop.
-        return sparse_graph_attention(
-            q, k, v, nbr, val, _divisor_block(q.shape[0], chunk))
-    n_dev = mesh.shape[axis]
-    scale = 1.0 / np.sqrt(q.shape[-1])
+        # The GLOBAL row count is only guaranteed divisible by
+        # per-DEVICE chunks (ring padding aligns n/d, not n, to
+        # ``chunk``), so shrink the block to a divisor of n.
+        return _ring_rows(q, k, v, nbr, val, axis=None,
+                          block=_divisor_block(q.shape[0], chunk))
+    n_loc = q.shape[0] // mesh.shape[axis]
+    block = min(chunk, n_loc)
+    assert n_loc % block == 0, (n_loc, block)
     spec3, spec2 = P(axis, None, None), P(axis, None)
+    return jax.shard_map(
+        partial(_ring_rows, axis=axis, block=block),
+        mesh=mesh, in_specs=(spec3, spec3, spec3, spec2, spec2),
+        out_specs=spec3)(q, k, v, nbr, val)
 
-    @partial(jax.shard_map, mesh=mesh,
-             in_specs=(spec3, spec3, spec3, spec2, spec2),
-             out_specs=spec3)
-    def run(ql, kl, vl, nbrl, vall):
-        n_loc = ql.shape[0]
-        block = min(chunk, n_loc)
-        assert n_loc % block == 0, (n_loc, block)
-        my_idx = jax.lax.axis_index(axis)
-        perm = [(i, (i + 1) % n_dev) for i in range(n_dev)]
 
-        m = ql.astype(jnp.float32).sum(-1) * 0 + NEG_INF     # [n_loc, h]
-        l = jnp.zeros_like(m)
-        acc = (ql * 0).astype(jnp.float32)
-        kb, vb = kl, vl
+def _ring_rows(ql, kl, vl, nbrl, vall, *, axis, block):
+    """One device's rows of :func:`ring_graph_attention`: one ring step
+    a device of ``axis``, each scoring the visiting K/V block in
+    ``block``-column sub-blocks. ``axis`` None is the ring of one
+    (nothing to permute)."""
+    n_loc = ql.shape[0]
+    scale = 1.0 / np.sqrt(ql.shape[-1])
+    n_dev = 1 if axis is None else jax.lax.axis_size(axis)
+    my_idx = 0 if axis is None else jax.lax.axis_index(axis)
+    perm = [(i, (i + 1) % n_dev) for i in range(n_dev)]
 
-        # Memory discipline (round 5): the ring loop is a lax.scan whose
-        # CHECKPOINTED body is one whole ring step — the backward saves
-        # only per-ring-step carries (m, l, acc, and the visiting K/V
-        # block: O(n_loc·H) × d steps) and recomputes a step's inner
-        # sub-block scan when it needs that step's gradients. The
-        # round-4 layout (python-unrolled steps, checkpoint on the
-        # sub-block body) let the inner scans save the f32 acc carry at
-        # EVERY sub-block of every step — O(n_loc·H·n_blocks) residents,
-        # measured 3.08 GB vs gather mode's 0.45 GB on a 100k-node
-        # train step; this layout measures 0.33 GB (see
-        # tests/test_gat.py::TestScale::test_ring_memory_below_gather).
-        def ring_step(carry, step_i):
-            m, l, acc, kb, vb = carry
-            src_idx = (my_idx - step_i) % n_dev              # block owner
-            base_pos = src_idx * n_loc
+    m = ql.astype(jnp.float32).sum(-1) * 0 + NEG_INF         # [n_loc, h]
+    l = jnp.zeros_like(m)
+    acc = (ql * 0).astype(jnp.float32)
 
-            def sub(sub_carry, j):
-                m, l, acc = sub_carry
-                kj = jax.lax.dynamic_slice_in_dim(kb, j * block, block, 0)
-                vj = jax.lax.dynamic_slice_in_dim(vb, j * block, block, 0)
-                bias, mask = _block_bias(
-                    nbrl, vall, base_pos + j * block, block, local=True)
-                s = jnp.einsum("nhd,bhd->nhb", ql, kj,
-                               preferred_element_type=jnp.float32) * scale
-                s = s + bias[:, None, :]
-                s = jnp.where(mask[:, None, :], s, NEG_INF)
-                m_new = jnp.maximum(m, s.max(-1))
-                p = jnp.exp(s - m_new[..., None]) * mask[:, None, :]
-                fold = jnp.exp(m - m_new)
-                l = l * fold + p.sum(-1)
-                acc = acc * fold[..., None] + jnp.einsum(
-                    "nhb,bhd->nhd", p.astype(ql.dtype), vj
-                ).astype(jnp.float32)
-                return (m_new, l, acc), None
+    # Memory discipline (round 5): the ring loop is a lax.scan whose
+    # CHECKPOINTED body is one whole ring step — the backward saves
+    # only per-ring-step carries (m, l, acc, and the visiting K/V
+    # block: O(n_loc·H) × d steps) and recomputes a step's inner
+    # sub-block scan when it needs that step's gradients. The
+    # round-4 layout (python-unrolled steps, checkpoint on the
+    # sub-block body) let the inner scans save the f32 acc carry at
+    # EVERY sub-block of every step — O(n_loc·H·n_blocks) residents,
+    # measured 3.08 GB vs gather mode's 0.45 GB on a 100k-node
+    # train step; this layout measures 0.33 GB (see
+    # tests/test_gat.py::TestScale::test_ring_memory_below_gather).
+    def ring_step(carry, step_i):
+        m, l, acc, kb, vb = carry
+        src_idx = (my_idx - step_i) % n_dev                  # block owner
+        base_pos = src_idx * n_loc
 
-            (m, l, acc), _ = jax.lax.scan(
-                jax.checkpoint(sub), (m, l, acc),
-                jnp.arange(n_loc // block))
+        def sub(sub_carry, j):
+            m, l, acc = sub_carry
+            kj = jax.lax.dynamic_slice_in_dim(kb, j * block, block, 0)
+            vj = jax.lax.dynamic_slice_in_dim(vb, j * block, block, 0)
+            bias, mask = _block_bias(
+                nbrl, vall, base_pos + j * block, block)
+            # f32 straight from the MXU, not a bf16 product converted
+            # up: on the v5e (libtpu 0.0.34) the VJP of the row max
+            # below over a converted bf16 dot came back NaN in every
+            # element of dq and dk (found by PR 21's chip run;
+            # tests_tpu pins it).
+            s = jnp.einsum("nhd,bhd->nhb", ql, kj,
+                           preferred_element_type=jnp.float32) * scale
+            s = s + bias[:, None, :]
+            s = jnp.where(mask[:, None, :], s, NEG_INF)
+            m_new = jnp.maximum(m, s.max(-1))
+            # mask multiplication (not just the where) guards
+            # fully-masked rows: exp(NEG_INF − NEG_INF) = 1 would
+            # otherwise pollute l.
+            p = jnp.exp(s - m_new[..., None]) * mask[:, None, :]
+            fold = jnp.exp(m - m_new)
+            l = l * fold + p.sum(-1)
+            acc = acc * fold[..., None] + jnp.einsum(
+                "nhb,bhd->nhd", p.astype(ql.dtype), vj
+            ).astype(jnp.float32)
+            return (m_new, l, acc), None
+
+        (m, l, acc), _ = jax.lax.scan(
+            jax.checkpoint(sub), (m, l, acc),
+            jnp.arange(n_loc // block))
+        if axis is not None:
             kb = jax.lax.ppermute(kb, axis, perm)
             vb = jax.lax.ppermute(vb, axis, perm)
-            return (m, l, acc, kb, vb), None
+        return (m, l, acc, kb, vb), None
 
-        (m, l, acc, _, _), _ = jax.lax.scan(
-            jax.checkpoint(ring_step), (m, l, acc, kb, vb),
-            jnp.arange(n_dev))
-        return (acc / jnp.maximum(l, 1e-20)[..., None]).astype(ql.dtype)
-
-    return run(q, k, v, nbr, val)
+    (m, l, acc, _, _), _ = jax.lax.scan(
+        jax.checkpoint(ring_step), (m, l, acc, kl, vl),
+        jnp.arange(n_dev))
+    return (acc / jnp.maximum(l, 1e-20)[..., None]).astype(ql.dtype)
 
 
 def build_inverse_index(nbr: np.ndarray) -> np.ndarray:
@@ -427,46 +407,6 @@ def _inverse_index_gather(inv, ct):
 neighbor_gather.defvjp(_neighbor_gather_fwd, _neighbor_gather_bwd)
 
 
-def _single_device_tpu() -> bool:
-    """Is this trace a single-device TPU program? (Pallas kernels are
-    per-device; a >1-device mesh keeps the XLA paths that explicit
-    sharding partitions.)"""
-    mesh = jax.sharding.get_abstract_mesh()
-    return ((mesh.empty or mesh.size == 1)
-            and jax.devices()[0].platform == "tpu")
-
-
-def _per_device(fn, in_specs, out_specs):
-    """``fn`` as a per-device program. Under an ambient (explicit) mesh
-    a value's sharding is part of its type, and a pallas kernel refuses
-    to index refs typed as sharded — even over a one-device mesh — so
-    the kernel dispatchers run ``fn`` under ``shard_map``, where every
-    operand is a plain local array. Outside a mesh, ``fn`` itself.
-    ``check_vma`` is off because a kernel mixes row-varying operands
-    with replicated ones and ``pallas_call`` declares no variance."""
-    if _mesh_empty():
-        return fn
-    return jax.shard_map(fn, mesh=jax.sharding.get_abstract_mesh(),
-                         in_specs=in_specs, out_specs=out_specs,
-                         check_vma=False)
-
-
-def _pallas_gather_enabled(table) -> bool:
-    """Gate for the VMEM-resident pallas gather: explicit opt-in, a
-    single-device TPU program, lane-aligned row width, and the f32
-    column chunk BOTH directions keep resident (table forward,
-    accumulator backward) within the VMEM budget."""
-    import os
-
-    if os.environ.get("DF2_PALLAS_GATHER") != "1":
-        return False
-    if not _single_device_tpu():
-        return False
-    from dragonfly2_tpu.ops.table_gather import pallas_path_feasible
-
-    return pallas_path_feasible(*table.shape, table.dtype)
-
-
 def _head_indicator(heads: int, head_dim: int, dtype):
     """0/1 ``[heads·head_dim, heads]``: lane c belongs to head c // head_dim.
     A product with it sums lanes within each head; one with its
@@ -533,15 +473,7 @@ def _gather_attention(q, k, v, nbr, val, inv, *, heads):
     # cost follows the number of rows, not their bytes, in the forward
     # and in the backward alike.
     kv = jnp.concatenate([k, v], axis=-1)          # [N, 2·hidden]
-    if _pallas_gather_enabled(kv):
-        # Opt-in (DF2_PALLAS_GATHER=1) single-device path: both gather
-        # directions are VMEM-resident pallas kernels (the table fits),
-        # the backward a VMEM scatter-add in place of the inverse index.
-        from dragonfly2_tpu.ops.table_gather import neighbor_gather_pallas
-
-        kvg = _per_device(neighbor_gather_pallas,
-                          (P(), P("data")), P("data"))(kv, idx)
-    elif inv is not None:
+    if inv is not None:
         # Scatter-free training path: custom backward via the host-built
         # inverse index.
         kvg = neighbor_gather(kv, idx, inv)
@@ -563,112 +495,6 @@ def _gather_attention(q, k, v, nbr, val, inv, *, heads):
     pl = jnp.einsum("nkh,ch->nkc", p, ind)         # [N, K, hidden]
     out = (pl.astype(f32) * vg.astype(f32)).sum(axis=1)
     return out.astype(q.dtype)
-
-
-def blocks_graph_attention(q, k, v, nbr, val, chunk):
-    """Blocks-mode dispatcher: the pallas graph-flash kernel when the
-    program runs on a single TPU device (the bench/serving hardware —
-    the kernel is a per-device program, so a >1-device mesh keeps the
-    XLA scan whose explicit-sharding scatter XLA already partitions);
-    the ``lax.scan`` online-softmax path otherwise."""
-    import os
-
-    if (_single_device_tpu()
-            and not os.environ.get("DF2_DISABLE_GRAPH_FLASH")):
-        return _graph_flash(q, k, v, nbr, val, chunk, interpret=False)
-    return sparse_graph_attention(q, k, v, nbr, val, chunk)
-
-
-def _graph_flash(q, k, v, nbr, val, chunk, interpret):
-    """The pallas graph-flash kernel over row-local queries and
-    neighbor lists against full-width K/V."""
-    from dragonfly2_tpu.ops.flash_attention import graph_flash_attention
-
-    block = _flash_block(q.shape[0], chunk)
-    rows = P("data")
-    return _per_device(
-        partial(graph_flash_attention, block_q=block, block_k=block,
-                interpret=interpret),
-        (rows, P(), P(), rows, rows), rows)(q, k, v, nbr, val)
-
-
-def _flash_block(n: int, chunk: int) -> int:
-    """Kernel tile size: the kernel pads rows internally, so no
-    divisibility constraint — just avoid padding a small graph up to a
-    huge chunk (cap at n rounded to the 128-lane MXU width)."""
-    return min(chunk, ((n + 127) // 128) * 128)
-
-
-def sparse_graph_attention(q, k, v, nbr, val, chunk):
-    """Flash-style chunked attention over neighbor-masked key blocks.
-
-    q/k/v: [N, heads, head_dim] (q row-sharded, k/v full-width);
-    nbr/val: [N, K] row-sharded. Returns [N, heads, head_dim].
-    Accumulators run in f32; the P·V matmul runs in the compute dtype
-    (bf16 on TPU — MXU-friendly).
-    """
-    n, heads, head_dim = q.shape
-    block = min(chunk, n)
-    assert n % block == 0, (n, block)
-    scale = 1.0 / np.sqrt(head_dim)
-
-    m0 = q.astype(jnp.float32).sum(-1) * 0 + NEG_INF        # [N, heads]
-    l0 = jnp.zeros_like(m0)
-    acc0 = (q * 0).astype(jnp.float32)                      # [N, heads, d]
-
-    def step(carry, j):
-        m, l, acc = carry
-        start = j * block
-        kj = jax.lax.dynamic_slice_in_dim(k, start, block, axis=0)
-        vj = jax.lax.dynamic_slice_in_dim(v, start, block, axis=0)
-        bias, mask = _block_bias(nbr, val, start, block)     # [N, block]
-        # f32 straight from the MXU, not a bf16 product converted up: on
-        # the v5e (libtpu 0.0.34) the VJP of the row max below over a
-        # converted bf16 dot came back NaN in every element of dq and dk
-        # (found by PR 21's chip run; tests_tpu pins it).
-        s = jnp.einsum("nhd,bhd->nhb", q, kj,
-                       preferred_element_type=jnp.float32) * scale
-        s = s + bias[:, None, :]
-        s = jnp.where(mask[:, None, :], s, NEG_INF)
-        m_new = jnp.maximum(m, s.max(-1))
-        # mask multiplication (not just the where) guards fully-masked
-        # rows: exp(NEG_INF − NEG_INF) = 1 would otherwise pollute l.
-        p = jnp.exp(s - m_new[..., None]) * mask[:, None, :]
-        fold = jnp.exp(m - m_new)
-        l = l * fold + p.sum(-1)
-        acc = acc * fold[..., None] + jnp.einsum(
-            "nhb,bhd->nhd", p.astype(q.dtype), vj).astype(jnp.float32)
-        return (m_new, l, acc), None
-
-    # Two-level scan: the backward of a flat checkpointed scan saves the
-    # f32 (m, l, acc) carry at EVERY key block — O(N·H·n_blocks)
-    # residents (measured 3.2 GB for a 100k-node train step at
-    # chunk=128). Grouping ~√n_blocks blocks under a checkpointed outer
-    # body caps residents at O(N·H·√n_blocks): the forward saves one
-    # carry per GROUP, and a group's per-block carries only materialize
-    # transiently while that group's backward recomputes (same layout as
-    # ring_graph_attention's per-ring-step checkpoint). The group size
-    # need not divide n_blocks — the last group's phantom indices are
-    # cond'd into no-ops — so a prime/rough block count cannot silently
-    # degrade back to the flat-scan O(n_blocks) layout.
-    n_blocks = n // block
-    group = max(math.isqrt(n_blocks), 1)
-    n_groups = -(-n_blocks // group)
-
-    def group_step(carry, gi):
-        def sub(c, idx):
-            j = gi * group + idx
-            return jax.lax.cond(j < n_blocks,
-                                lambda c_: step(c_, j)[0],
-                                lambda c_: c_, c), None
-
-        return jax.lax.scan(jax.checkpoint(sub), carry,
-                            jnp.arange(group))
-
-    (m, l, acc), _ = jax.lax.scan(
-        jax.checkpoint(group_step), (m0, l0, acc0),
-        jnp.arange(n_groups))
-    return (acc / jnp.maximum(l, 1e-20)[..., None]).astype(q.dtype)
 
 
 class TPDense(nn.Module):
@@ -717,18 +543,19 @@ class TPDense(nn.Module):
 
 class GraphAttentionBlock(nn.Module):
     """Pre-LN multi-head neighbor-masked attention + MLP, residual
-    throughout. ``attention="gather"`` (default) is O(N·K) neighbor-
-    gather attention; ``"blocks"`` is flash-style chunked block
-    attention (same math — useful when the graph is dense enough that
-    MXU-shaped [rows, chunk] matmuls beat per-row gathers); ``"ring"``
-    is blocks with K/V row-sharded and ppermuted around the mesh (no
-    full-width K/V at all).
+    throughout. Two modes, one arithmetic: ``attention="gather"``
+    (default) is O(N·K) neighbor-gather attention against full-width
+    K/V; ``"ring"`` keeps K/V row-sharded and ppermutes them around the
+    mesh (no full-width K/V at all), scoring each visiting block in
+    ``chunk``-column sub-blocks — the one thing ``chunk`` is for. Any
+    other value raises.
 
     All six Dense layers are :class:`TPDense` under their original
-    ``Dense_i`` names (param trees stay checkpoint-compatible): shard
-    q/k/v + MLP-up kernels column-wise and out/MLP-down row-wise over a
-    ``model`` mesh axis and the block runs Megatron tensor-parallel —
-    heads split across devices, one allreduce per projection pair."""
+    ``Dense_i`` names (param trees stay checkpoint-compatible, and are
+    the same tree in both modes): shard q/k/v + MLP-up kernels
+    column-wise and out/MLP-down row-wise over a ``model`` mesh axis and
+    the block runs Megatron tensor-parallel — heads split across
+    devices, one allreduce per projection pair."""
 
     hidden: int
     heads: int
@@ -741,36 +568,24 @@ class GraphAttentionBlock(nn.Module):
         # h: [N, H] row-sharded; nbr/val: [N, K] row-sharded; inv
         # [N, D] (optional) = host-built inverse neighbor index enabling
         # the scatter-free gather backward (gather mode only)
-        head_dim = self.hidden // self.heads
+        check_attention(self.attention)
         x = nn.LayerNorm(dtype=self.dtype)(h)
         q = TPDense(self.hidden, dtype=self.dtype, name="Dense_0")(x)
         k = TPDense(self.hidden, dtype=self.dtype, name="Dense_1")(x)
         v = TPDense(self.hidden, dtype=self.dtype, name="Dense_2")(x)
 
-        def split(t):  # [N, H] -> [N, heads, head_dim]
-            return t.reshape(-1, self.heads, head_dim)
-
         if self.attention == "ring":
             # K/V stay row-sharded; blocks ppermute around the ring.
+            def split(t):  # [N, H] -> [N, heads, head_dim]
+                return t.reshape(-1, self.heads, self.hidden // self.heads)
+
             out = ring_graph_attention(split(q), split(k), split(v),
                                        nbr, val, self.chunk)
         else:
             # Queries keep their row sharding; K/V go full-width (O(N·H)
-            # all-gather over ICI) and are consumed per-neighbor or
-            # block-by-block.
-            k, v = replicate(k), replicate(v)
-            if self.attention == "gather":
-                out = gather_graph_attention(q, k, v, nbr, val, inv,
-                                             heads=self.heads)
-            elif self.attention == "flash":
-                # Force the pallas kernel (interpret-mode off TPU) —
-                # hermetic kernel tests and A/B benchmarks use this.
-                out = _graph_flash(
-                    split(q), split(k), split(v), nbr, val, self.chunk,
-                    interpret=jax.devices()[0].platform != "tpu")
-            else:
-                out = blocks_graph_attention(split(q), split(k), split(v),
-                                             nbr, val, self.chunk)
+            # all-gather over ICI) and are consumed per neighbor.
+            out = gather_graph_attention(q, replicate(k), replicate(v),
+                                         nbr, val, inv, heads=self.heads)
         out = out.reshape(-1, self.hidden)
         out = TPDense(self.hidden, dtype=self.dtype, name="Dense_3")(out)
         h = h + out

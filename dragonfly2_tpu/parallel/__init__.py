@@ -16,14 +16,7 @@ from dragonfly2_tpu.parallel.multihost import (
     multihost_mesh,
     sync,
 )
-from dragonfly2_tpu.parallel.pipeline import (
-    pipeline_apply,
-    stack_stage_params,
-)
-from dragonfly2_tpu.parallel.ring_attention import ring_attention
-from dragonfly2_tpu.parallel.ulysses import ulysses_attention
 
 __all__ = ["MeshContext", "MultihostMeshContext", "agree",
            "data_parallel_mesh", "expert_layer", "init_multihost",
-           "multihost_mesh", "pipeline_apply", "ring_attention",
-           "stack_stage_params", "sync", "ulysses_attention"]
+           "multihost_mesh", "sync"]

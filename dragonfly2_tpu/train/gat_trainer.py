@@ -10,7 +10,7 @@ collectives):
   gather needs them).
 
 Scale (round 4): the graph is held as padded neighbor lists, not dense
-[N, N] bias/mask, and attention is chunked with an online softmax
+[N, N] bias/mask, and each row attends to its listed neighbours only
 (`models/graph_transformer.py`) — full-topology graphs of 100k+ hosts
 fit, where the dense layout capped out around a few thousand.
 
@@ -35,8 +35,8 @@ from dragonfly2_tpu.models.graph_transformer import (
     GraphTransformer,
     build_inverse_index,
     build_neighbor_lists,
+    check_attention,
     pad_graph_sparse,
-    pad_multiple,
 )
 from dragonfly2_tpu.parallel import MeshContext, data_parallel_mesh
 from dragonfly2_tpu.train.gnn_trainer import edge_split
@@ -56,14 +56,15 @@ class GATTrainConfig:
     seed: int = 0
     eval_fraction: float = 0.1
     rtt_threshold_ns: int = 20_000_000
-    # Key-block width for chunked attention (peak activation memory is
-    # O(rows · heads · chunk)) and per-node neighbor cap (best-K by RTT
-    # bias; self always survives).
+    # Ring mode's sub-block width: a visiting K/V block is scored
+    # ``chunk`` columns at a time (peak activation memory is
+    # O(rows · heads · chunk)); gather mode does not read it. And the
+    # per-node neighbor cap (best-K by RTT bias; self always survives).
     chunk: int = 1024
     neighbor_cap: int = 128
-    # "gather" (O(N·K) neighbor gather, default) | "blocks" (flash-style
-    # chunked, full-width K/V) | "ring" (chunked with K/V row-sharded,
-    # ppermuted around the mesh — no full-width K/V at all)
+    # "gather" (O(N·K) neighbor gather against full-width K/V, default)
+    # | "ring" (K/V row-sharded, ppermuted around the mesh — no
+    # full-width K/V at all). Anything else is a ValueError.
     attention: str = "gather"
     # >1 runs this many optimizer steps per dispatch under lax.scan —
     # the same dispatch amortization the GNN path uses
@@ -150,12 +151,13 @@ def train_gat(
     config: GATTrainConfig = GATTrainConfig(),
     mesh: MeshContext | None = None,
 ) -> GATTrainResult:
+    check_attention(config.attention)
     mesh = mesh or data_parallel_mesh()
     if mesh.n_model > 1:
         if config.attention == "ring":
             raise ValueError("ring attention shards rows only; use "
-                             "attention='gather' or 'blocks' with a "
-                             "model-parallel mesh")
+                             "attention='gather' with a model-parallel "
+                             "mesh")
         if config.heads % mesh.n_model or (2 * config.hidden) % mesh.n_model:
             raise ValueError(
                 f"heads ({config.heads}) and 2*hidden ({2 * config.hidden}) "
@@ -172,13 +174,10 @@ def train_gat(
         graph.edge_rtt_ns[train_ids],
         cap=config.neighbor_cap,
     )
-    # The chunk-divisibility constraint (and its padding cost) only
-    # exists for the chunked modes; gather mode needs mesh rows only.
-    # Ring mode chunks PER-DEVICE rows, so once those exceed a chunk the
-    # row count must be a multiple of n_data·chunk.
-    if config.attention == "blocks":
-        multiple = pad_multiple(mesh.n_data, config.chunk, graph.n_nodes)
-    elif config.attention == "ring":
+    # Gather mode needs rows that shard evenly over the mesh. Ring mode
+    # chunks PER-DEVICE rows, so once those exceed a chunk the row count
+    # must be a multiple of n_data·chunk.
+    if config.attention == "ring":
         per_device = -(-graph.n_nodes // mesh.n_data)
         multiple = (mesh.n_data * config.chunk
                     if per_device > config.chunk else mesh.n_data)
@@ -297,7 +296,7 @@ def train_gat(
     stop = False
     step_num = 0
     # Explicit-sharding mode: the in-model reshards (K/V + embedding
-    # all-gathers, block-bias scatter) need the ambient mesh during trace.
+    # all-gathers, ring mode's shard_map) need the ambient mesh during trace.
     with jax.set_mesh(mesh.mesh):
         # Full-k groups plus one tail dispatch for the remainder — no
         # silently dropped steps when k ∤ steps_per_epoch (the tail is a

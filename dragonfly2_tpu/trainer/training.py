@@ -327,11 +327,9 @@ class Training:
                     "embed": result.config.embed,
                     "layers": result.config.layers,
                     "heads": result.config.heads,
-                    "attention": result.config.attention,
-                    # chunk is structural for blocks/ring modes: serving
-                    # must rebuild with the block size the padded row
-                    # count was sized for.
-                    "chunk": result.config.chunk},
+                    # A record of how it was trained: serving scores
+                    # every model in gather mode (same parameter tree).
+                    "attention": result.config.attention},
         )
         outcome.gat_model_id = model_id
         outcome.loss_history["gat"] = list(result.history)

@@ -1,11 +1,10 @@
-"""The pallas kernels, compiled by the TPU's own compiler for a described
-(not attached) v5e, at the shapes the chip runs them.
+"""The cells' own programs, compiled by the TPU's own compiler for a
+described (not attached) v5e, at the shapes the chip runs them.
 
-Interpret mode (tests/test_flash_attention.py, tests/test_table_gather.py)
-proves the kernels' arithmetic and hides everything Mosaic refuses: before
-PR 21 two of the three kernel families had passed every interpret test and
-had never lowered for a chip. A compile that passes here is a compile, not
-a run — ``chip_smoke.py`` and ``tests_tpu/`` are the runs.
+The CPU tier proves arithmetic and hides everything the chip's compiler
+decides: layouts, relayout loops, which table it holds in fast memory,
+what Mosaic refuses of JAX's kernels. A compile that passes here is a
+compile, not a run — the benchmark's cells and ``tests_tpu/`` are the runs.
 
 libtpu admits one process at a time, and xdist workers each import every
 test file: the topology is therefore described inside a module-scoped
@@ -13,7 +12,6 @@ fixture (never at import, in a ``skipif`` or in ``parametrize``), compiles
 happen in the test's own process, and these tests stay in this ONE file.
 """
 
-import importlib
 import math
 import os
 import re
@@ -22,16 +20,6 @@ import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
-
-from dragonfly2_tpu.ops import table_gather as tg
-
-# ops/__init__ re-exports the flash_attention FUNCTION under the module's
-# name; the kernels' pallas_call wrappers live in the module.
-fa = importlib.import_module("dragonfly2_tpu.ops.flash_attention")
-
-# BASELINE config #3: 20k hosts padded to the 1024 block, 4 heads × 32,
-# cap 64, bf16; its fused [k|v] table is 256 wide.
-N3, HEADS, HEAD_DIM, K3, BLOCK3 = 20_480, 4, 32, 64, 1024
 
 
 @pytest.fixture(scope="module")
@@ -72,108 +60,6 @@ def _struct(sharding):
                                                      sharding=sharding)
 
 
-@pytest.mark.parametrize("causal", [False, True])
-def test_flash_attention_forward(one_chip, causal):
-    s = _struct(one_chip)
-    t, heads, d = 512, 4, 128
-
-    def fwd(q, k, v):
-        return fa._pallas_forward(q, k, v, causal, t, 128, 128, False)
-
-    compiled = _compile(fwd, *[s((heads, t, d), jnp.float32)] * 3)
-    assert "tpu_custom_call" in compiled.as_text()
-
-
-@pytest.mark.parametrize("n,heads,d,k,block,dtype", [
-    (N3, HEADS, HEAD_DIM, K3, BLOCK3, jnp.bfloat16),   # config #3
-    (N3, HEADS, HEAD_DIM, 128, BLOCK3, jnp.bfloat16),  # default neighbor cap
-    (2048, 1, 128, 8, 128, jnp.float32),
-])
-def test_graph_flash_attention_forward(one_chip, as_tpu_program,
-                                       n, heads, d, k, block, dtype):
-    s = _struct(one_chip)
-
-    def fwd(q, k_, v, nbr, val):
-        return fa._graph_fwd(q, k_, v, nbr, val, block, block, False)[0]
-
-    qkv = s((n, heads, d), dtype)
-    compiled = _compile(fwd, qkv, qkv, qkv, s((n, k), jnp.int32),
-                        s((n, k), jnp.float32))
-    assert "tpu_custom_call" in compiled.as_text()
-
-
-@pytest.mark.parametrize("n,d,m,dtype", [
-    (N3, 256, N3 * K3, jnp.bfloat16),     # config #3: fused [k|v] forward
-    (N3, 128, N3 * K3, jnp.bfloat16),
-    (N3, 256, N3 * K3, jnp.float32),      # its f32 cotangent backward
-    (24_576, 256, 8192, jnp.bfloat16),    # the largest tables the gate
-    (24_576, 256, 8192, jnp.float32),     # admits, in both dtypes
-])
-def test_table_gather_and_scatter_add(one_chip, n, d, m, dtype):
-    s = _struct(one_chip)
-    gather = _compile(tg.table_gather, s((n, d), dtype), s((m,), jnp.int32))
-    assert "tpu_custom_call" in gather.as_text()
-    scatter = _compile(lambda ct, idx: tg.table_scatter_add(ct, idx, n),
-                       s((m, d), dtype), s((m,), jnp.int32))
-    assert "tpu_custom_call" in scatter.as_text()
-
-
-def test_gather_gate_admits_what_compiles():
-    """``pallas_path_feasible`` is what routes a table to the kernels:
-    it admits config #3 and the 24,576-row cases compiled above — the
-    rule is the resident f32 column chunk, so the table's dtype does not
-    move it — and nothing larger."""
-    for dtype in (jnp.bfloat16, jnp.float32):
-        assert tg.pallas_path_feasible(N3, 256, dtype)
-        assert tg.pallas_path_feasible(24_576, 256, dtype)
-        assert not tg.pallas_path_feasible(24_584, 256, dtype)
-    assert not tg.pallas_path_feasible(N3, 256, jnp.int32)
-
-
-@pytest.mark.parametrize("attention,pallas_gather,kernels", [
-    ("blocks", False, 1),   # graph-flash forward (backward is the XLA scan)
-    ("gather", True, 2),    # table gather forward + scatter-add backward
-])
-def test_kernels_inside_the_trainers_trace(topo, as_tpu_program, monkeypatch,
-                                           attention, pallas_gather, kernels):
-    """``train_gat`` traces its step under ``jax.set_mesh`` with
-    row-sharded graph tensors, so every value's type carries a sharding —
-    which a pallas kernel's refs must not (``_per_device``). One
-    attention layer at a tenth of config #3's rows keeps this to seconds."""
-    import optax
-
-    from dragonfly2_tpu.models.graph_transformer import GraphTransformer
-    from dragonfly2_tpu.parallel import data_parallel_mesh
-
-    if pallas_gather:
-        monkeypatch.setenv("DF2_PALLAS_GATHER", "1")
-    mesh = data_parallel_mesh(devices=topo.devices[:1])
-    row, rep = mesh.shard_spec("data"), mesh.replicated
-    n, k, batch, feat = 2048, K3, 512, 8
-
-    def s(shape, dtype, sharding):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
-
-    model = GraphTransformer(layers=1, attention=attention)
-    params = jax.eval_shape(
-        model.init, jax.random.key(0), jnp.zeros((n, feat)),
-        jnp.zeros((n, k), jnp.int32), jnp.zeros((n, k)),
-        jnp.zeros(2, jnp.int32), jnp.zeros(2, jnp.int32))
-    params = jax.tree.map(lambda x: s(x.shape, x.dtype, rep), params)
-
-    def loss(p, feat_, nbr, val, src, dst, y):
-        logits = model.apply(p, feat_, nbr, val, src, dst)
-        return optax.sigmoid_binary_cross_entropy(logits, y).mean()
-
-    with jax.set_mesh(mesh.mesh):
-        compiled = _compile(
-            jax.value_and_grad(loss), params, s((n, feat), jnp.float32, row),
-            s((n, k), jnp.int32, row), s((n, k), jnp.float32, row),
-            s((batch,), jnp.int32, rep), s((batch,), jnp.int32, rep),
-            s((batch,), jnp.float32, rep))
-    assert compiled.as_text().count("tpu_custom_call") == kernels
-
-
 def test_gather_attention_backward_has_no_relayout(topo):
     """``gat-fleet50k.train``'s attention at the cell's own shapes, under
     the one-device mesh the trainer sets. Until PR 25 the attention
@@ -182,9 +68,8 @@ def test_gather_attention_backward_has_no_relayout(topo):
     step, a third of it, under no scope a trace could name). The
     lane-dense form hands ``[N, K, 2·hidden]`` from the attention
     backward to that gather as it is: no loop, and nothing that copies
-    or reshapes a tensor of the gathered rows' size."""
-    import re
-
+    or reshapes a tensor of the gathered rows' size. Plain XLA
+    throughout: the program holds no hand-written kernel."""
     from dragonfly2_tpu.models.graph_transformer import gather_graph_attention
     from dragonfly2_tpu.parallel import data_parallel_mesh
 
@@ -203,6 +88,7 @@ def test_gather_attention_backward_has_no_relayout(topo):
             rep((n, hidden), jnp.bfloat16), row((n, k), jnp.int32),
             row((n, k), jnp.float32), row((n, inv_width), jnp.int32))
     text = compiled.as_text()
+    assert "tpu_custom_call" not in text
     assert " while(" not in text
     # Instructions of the entry computation run on their own; the same
     # words inside a fused computation are free.
@@ -240,7 +126,9 @@ def test_embedding_pass_at_model_load_leaves_the_chip_room(topo):
         return model.apply(p, feats, nbr, val,
                            method=GraphTransformer.node_embeddings)
 
-    memory = _compile(embed, params, *shapes).memory_analysis()
+    compiled = _compile(embed, params, *shapes)
+    assert "tpu_custom_call" not in compiled.as_text()
+    memory = compiled.memory_analysis()
     assert memory.temp_size_in_bytes < 2.5e9     # 1.82 GB, PR 25
 
 

@@ -4,9 +4,9 @@ phases rehearsed on the CPU at ``Sizes.tiny()``.
 The scripts themselves have no CPU path (that is the first two tests);
 the rehearsal imports the phase functions and calls them with CPU
 devices, which finds wrong paths, arguments and control flow before a
-chip run is spent on them. It says nothing about the chip: kernels take
-their XLA branches here (tests/test_chip_compile.py compiles them for a
-described v5e), and nothing is timed.
+chip run is spent on them. It says nothing about the chip
+(tests/test_chip_compile.py compiles the cells' programs for a described
+v5e), and nothing is timed.
 """
 
 from __future__ import annotations
@@ -95,22 +95,6 @@ class TestRehearsal:
         assert report["tensors"] == self.sizes.sink_tensors
         assert report["file_bytes"] > (self.sizes.sink_tensors
                                        * self.sizes.sink_tensor_elems * 2)
-
-    def test_kernels_phase_compares_the_three_paths(self):
-        report = chip_smoke.phase_kernels(self.sizes, 0, jax.devices()[0],
-                                          require_kernel=False)
-        assert report["gather"]["loss"] > 0
-        for path in ("blocks_graph_flash", "gather_pallas_table"):
-            assert (report[path]["grad_rel_l2_err"]
-                    < chip_smoke.KERNEL_GRAD_REL_ERR)
-            # No chip here: the dispatchers must NOT have taken a kernel.
-            assert report[path]["custom_calls"] == 0
-
-    def test_kernels_phase_fails_without_the_custom_call(self):
-        """``require_kernel`` is what turns a kernel that quietly gave way
-        to its XLA path into a failed smoke."""
-        with pytest.raises(chip_smoke.SmokeFailure, match="tpu_custom_call"):
-            chip_smoke.phase_kernels(self.sizes, 0, jax.devices()[0])
 
     def test_data_parallel_on_four_virtual_devices(self):
         devices = jax.devices()[:4]

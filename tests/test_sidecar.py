@@ -313,6 +313,43 @@ class TestGATServing:
             server.stop()
             service.stop()
 
+    @pytest.mark.parametrize("registered_as",
+                             ["blocks", "flash", "ring", None])
+    def test_registry_mode_is_not_read(self, gat_registered, tmp_path,
+                                       registered_as):
+        """A model registered by an older trainer as ``blocks``,
+        ``flash`` or ``ring`` (or with no mode at all) loads and scores a
+        fixed announce exactly as the same parameters registered as
+        ``gather`` do: the tree is the same in every mode, and the
+        sidecar builds gather mode whatever the metadata says."""
+        from dragonfly2_tpu.inference.sidecar import _gat_scorer_from_artifact
+        from dragonfly2_tpu.manager.service import _tar_directory
+        from dragonfly2_tpu.train.checkpoint import (
+            ModelMetadata,
+            gat_tree,
+            save_model,
+        )
+
+        result = gat_registered["result"]
+        config = {"hidden": 16, "embed": 8, "layers": 1, "heads": 2,
+                  "chunk": 4}
+        if registered_as is not None:
+            config["attention"] = registered_as
+        save_model(
+            str(tmp_path),
+            gat_tree(result.params, result.node_features, result.neighbors,
+                     result.neighbor_vals,
+                     node_ids=gat_registered["graph"].node_ids),
+            ModelMetadata(model_id="df2-gat-old", model_type="gat",
+                          evaluation={"f1": result.f1}, config=config))
+        pairs = np.array([[0, 1], [2, 3], [5, 4], [7, 7], [23, 0]], np.int32)
+        scores = _gat_scorer_from_artifact(
+            _tar_directory(str(tmp_path))).score(pairs)
+        active = gat_registered["manager"].get_active_model("gat", 0)
+        as_gather = _gat_scorer_from_artifact(active.artifact).score(pairs)
+        assert np.isfinite(scores).all()
+        np.testing.assert_array_equal(scores, as_gather)
+
     def test_out_of_range_pair_rejected(self, gat_registered):
         from dragonfly2_tpu.inference.sidecar import _gat_scorer_from_artifact
 
