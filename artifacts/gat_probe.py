@@ -37,8 +37,8 @@ nbr, val = build_neighbor_lists(
     graph.n_nodes, graph.edge_src[train_ids], graph.edge_dst[train_ids],
     graph.edge_rtt_ns[train_ids], cap=CAP)
 feat, nbr, val, _ = pad_graph_sparse(graph.node_features, nbr, val, 1)
-inv = build_inverse_index(nbr)
-out["inv_shape"] = list(inv.shape)
+inv = build_inverse_index(nbr, val)
+out["inv_shape"] = list(inv.rows.shape)
 
 model = GraphTransformer(hidden=HIDDEN, embed=EMBED, layers=LAYERS,
                          heads=HEADS, attention="gather")
@@ -54,7 +54,7 @@ src = jnp.asarray(graph.edge_src[ids])
 dst = jnp.asarray(graph.edge_dst[ids])
 y = jnp.asarray(labels[ids])
 feat_d, nbr_d, val_d = map(jnp.asarray, (feat, nbr, val))
-inv_d = jnp.asarray(inv)
+inv_d = jax.tree.map(jnp.asarray, inv)
 
 
 def timeit(fn, *args, reps=8):

@@ -41,6 +41,7 @@ from dragonfly2_tpu.models.graph_transformer import (
 from dragonfly2_tpu.parallel import MeshContext, data_parallel_mesh
 from dragonfly2_tpu.train.gnn_trainer import edge_split
 from dragonfly2_tpu.train.metrics import metrics_from_confusion, padded_chunks
+from dragonfly2_tpu.train.step_budget import TRAINING
 
 
 @dataclass(frozen=True)
@@ -221,12 +222,17 @@ def train_gat(
     else:
         state = mesh.put_replicated(state)
 
-    # Gather mode trains through the scatter-free backward: the
-    # host-built inverse neighbor index turns the attention gathers'
-    # VJP into gathers of whole lane-dense rows too (build_inverse_index;
-    # autodiff's duplicate-index scatter-add serializes on a TPU).
-    inv = (build_inverse_index(nbr)
-           if config.attention == "gather" else None)
+    # Gather mode trains through the attention's own backward: with the
+    # host-built transpose of the lists (who lists each host, under
+    # which bias) dk and dv are summed host by host out of one small
+    # table (models/graph_transformer.py: _attention_bwd); autodiff's
+    # duplicate-index scatter-add serializes on a TPU. The graph is
+    # fixed for the run, so the transpose is built and placed once.
+    inv = None
+    if config.attention == "gather":
+        inv = build_inverse_index(nbr, val, model.dtype)
+        TRAINING.set(attn_inverse_slots=inv.rows.size,
+                     attn_inverse_filled=int((inv.rows >= 0).sum()))
 
     # Graph tensors: rows sharded over data; placed once, reused each step.
     row = mesh.shard_spec("data")

@@ -66,12 +66,18 @@ class TrainingStats:
       ``fused_sampling.put_graph_tables`` places GraphSAGE's tables: the
       lanes of a host's neighbour row, or 0 where the graph kept its CSR
       form (and before any table was placed).
+    - ``attn_inverse_slots``, ``attn_inverse_filled``: the last values
+      set too, where ``train_gat`` builds the GraphTransformer's inverse
+      index: its slots (hosts times its width) and those of them that
+      name a listing. Their ratio is the share of the attention
+      backward's source-major pass that is not padding.
     """
 
     KEYS = ("loops_started", "dispatches", "steps", "samples",
             "compile_seconds", "loop_compiles", "steady_compiles",
             "moe_steps", "moe_assignments_held", "moe_assignments_hottest",
-            "sampler_row_width")
+            "sampler_row_width", "attn_inverse_slots",
+            "attn_inverse_filled")
 
     def __init__(self):
         self._lock = threading.Lock()

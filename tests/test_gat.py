@@ -374,9 +374,10 @@ def _graph_100k(n_edges=400_000, cap=32):
 
 
 class TestInverseIndex:
-    """The scatter-free gather backward (build_inverse_index +
-    neighbor_gather custom VJP): exactness of the host transpose and
-    gradient parity with autodiff's scatter-add, on and off the mesh."""
+    """The attention's hand-written backward (build_inverse_index + the
+    custom VJP over the whole attention): exactness of the host
+    transpose and gradient parity with autodiff's scatter-add, on and
+    off the mesh."""
 
     def _graph(self, n=220, e=2400, cap=12, seed=3):
         rng = np.random.default_rng(seed)
@@ -388,27 +389,39 @@ class TestInverseIndex:
         feats, nbr, val, _ = pad_graph_sparse(feats, nbr, val, 8)
         return feats, nbr, val, src, dst, rtt
 
-    def test_inverse_index_is_exact_transpose(self):
+    @staticmethod
+    def _plain_transpose(nbr, val):
+        """Who lists host j, and under which bias, by a loop."""
+        listed = [[] for _ in range(nbr.shape[0])]
+        for i, row in enumerate(nbr):
+            for s, j in enumerate(row):
+                if j != PAD_ID:
+                    listed[j].append((i, val[i, s]))
+        return listed
+
+    @pytest.mark.parametrize("dtype,tile", [("bfloat16", 16),
+                                            ("float32", 8)])
+    def test_inverse_index_is_exact_transpose(self, dtype, tile):
         from dragonfly2_tpu.models.graph_transformer import (
             build_inverse_index,
         )
 
-        _, nbr, _, _, _, _ = self._graph()
-        inv = build_inverse_index(nbr)
-        n, k_width = nbr.shape
-        # Every non-pad (i, s) appears exactly once in inv[nbr[i, s]].
-        seen = {}
-        for j in range(inv.shape[0]):
-            for t in range(inv.shape[1]):
-                flat = inv[j, t]
-                if flat < 0:
-                    continue
-                i, s = divmod(int(flat), k_width)
-                assert nbr[i, s] == j, (i, s, j)
-                assert flat not in seen
-                seen[flat] = j
-        expected = int((nbr != PAD_ID).sum())
-        assert len(seen) == expected
+        _, nbr, val, _, _, _ = self._graph()
+        inv = build_inverse_index(nbr, val, dtype)
+        listed = self._plain_transpose(nbr, val)
+        widest = max(map(len, listed))
+        # The width is the widest in-degree, rounded up to the dtype's
+        # sublane tile and no further.
+        assert inv.rows.shape == inv.vals.shape
+        assert inv.rows.shape[1] % tile == 0
+        assert 0 <= inv.rows.shape[1] - widest < tile
+        assert inv.rows.dtype == np.int32 and inv.vals.dtype == np.float32
+        for j, pairs in enumerate(listed):
+            d = len(pairs)
+            assert inv.rows[j, :d].tolist() == [i for i, _ in pairs]
+            assert inv.vals[j, :d].tolist() == [b for _, b in pairs]
+            assert (inv.rows[j, d:] == -1).all()
+            assert (inv.vals[j, d:] == 0).all()
 
     @staticmethod
     def _lists(shape):
@@ -433,17 +446,18 @@ class TestInverseIndex:
             n, cap = 40, 16
             src = dst = np.zeros(0, np.int64)
         rtt = rng.integers(1_000_000, 90_000_000, len(src))
-        nbr, _ = build_neighbor_lists(n, src, dst, rtt, cap=cap)
-        return nbr, cap
+        nbr, val = build_neighbor_lists(n, src, dst, rtt, cap=cap)
+        return nbr, val, cap
 
-    @pytest.mark.parametrize("shape",
-                             ["regular", "skew", "hub", "self_only"])
+    DEGREE_SHAPES = ["regular", "skew", "hub", "self_only"]
+
+    @pytest.mark.parametrize("shape", DEGREE_SHAPES)
     def test_inverse_index_transposes_every_degree_shape(self, shape):
         from dragonfly2_tpu.models.graph_transformer import (
             build_inverse_index,
         )
 
-        nbr, cap = self._lists(shape)
+        nbr, val, cap = self._lists(shape)
         n, k_width = nbr.shape
         filled = (nbr != PAD_ID).sum(axis=1)
         in_degree = np.bincount(nbr[nbr != PAD_ID], minlength=n)
@@ -457,14 +471,17 @@ class TestInverseIndex:
         else:
             assert k_width == 1 and (in_degree == 1).all()
 
-        inv = build_inverse_index(nbr)
-        assert inv.shape == (n, in_degree.max())
-        # Row j holds exactly the flat positions of the slots naming j.
-        rows, slots = np.nonzero(inv >= 0)
-        flat = inv[rows, slots]
-        assert (nbr.reshape(-1)[flat] == rows).all()
-        assert len(np.unique(flat)) == len(flat) == filled.sum()
-        assert ((inv >= 0).sum(axis=1) == in_degree).all()
+        inv = build_inverse_index(nbr, val, "float32")
+        assert inv.rows.shape == (n, -(-in_degree.max() // 8) * 8)
+        # Row j holds exactly the hosts whose lists name j, each once,
+        # with the bias of that listing.
+        hosts, slots = np.nonzero(inv.rows >= 0)
+        listing = inv.rows[hosts, slots]
+        at = (nbr[listing] == hosts[:, None]).argmax(axis=1)
+        assert (nbr[listing, at] == hosts).all()
+        assert (val[listing, at] == inv.vals[hosts, slots]).all()
+        assert len(np.unique(listing * n + hosts)) == len(hosts) == filled.sum()
+        assert ((inv.rows >= 0).sum(axis=1) == in_degree).all()
 
     def _grads(self, use_inv, mesh=None):
         import jax.numpy as jnp
@@ -475,7 +492,7 @@ class TestInverseIndex:
         )
 
         feats, nbr, val, src, dst, rtt = self._graph()
-        inv = build_inverse_index(nbr) if use_inv else None
+        inv = build_inverse_index(nbr, val) if use_inv else None
         model = GraphTransformer(hidden=32, embed=16, layers=2, heads=4,
                                  attention="gather")
         params = model.init(
@@ -493,8 +510,7 @@ class TestInverseIndex:
         grad_fn = jax.jit(jax.value_and_grad(loss))
         if mesh is None:
             return grad_fn(params, jnp.asarray(feats), jnp.asarray(nbr),
-                           jnp.asarray(val),
-                           None if inv is None else jnp.asarray(inv))
+                           jnp.asarray(val), jax.tree.map(jnp.asarray, inv))
         row = mesh.shard_spec("data")
         args = (jax.device_put(params, mesh.replicated),
                 jax.device_put(feats, row), jax.device_put(nbr, row),
@@ -550,6 +566,15 @@ class TestLaneDenseGatherAttention:
         return q, k, v, jnp.asarray(nbr), jnp.asarray(val), w
 
     @staticmethod
+    def _inverse(nbr, val, dtype):
+        from dragonfly2_tpu.models.graph_transformer import (
+            build_inverse_index,
+        )
+
+        return jax.tree.map(jax.numpy.asarray, build_inverse_index(
+            np.asarray(nbr), np.asarray(val), dtype))
+
+    @staticmethod
     def _per_head(q, k, v, nbr, val, heads):
         import jax.numpy as jnp
 
@@ -589,13 +614,12 @@ class TestLaneDenseGatherAttention:
     @pytest.mark.parametrize("heads,head_dim", [(4, 32), (2, 64), (1, 128)])
     def test_matches_per_head_float32(self, heads, head_dim, dtype, tol):
         from dragonfly2_tpu.models.graph_transformer import (
-            build_inverse_index,
             gather_graph_attention,
         )
 
         assert heads * head_dim == self.HIDDEN
         q, k, v, nbr, val, w = self._inputs(dtype)
-        inv = jax.numpy.asarray(build_inverse_index(np.asarray(nbr)))
+        inv = self._inverse(nbr, val, dtype)
         got = self._out_and_grads(
             lambda *a: gather_graph_attention(*a[:3], nbr, a[3], inv,
                                               heads=heads),
@@ -670,22 +694,22 @@ class TestLaneDenseGatherAttention:
                                           what, dtype):
         """``gather_graph_attention`` against the dense reference, its
         output and its gradients with respect to q, k, v and the bias,
-        by plain autodiff (``inv=None``) and by the inverse index. 76 is
-        a width that is no multiple of 16, as ``gat-fleet50k``'s inverse
-        index is. In bfloat16 the inverse index sums a host's cotangent
-        rows in float32 (read: 0.7% of the largest element at the most)
-        where autodiff's scatter-add sums them in bfloat16 (dk 2.5% for
-        the hosts 150 lists name), hence its wider bound."""
+        by plain autodiff (``inv=None``) and by the attention's own
+        backward over the inverse index. 76 is a list width that is no
+        multiple of 16, as ``gat-fleet50k``'s widest in-degree is. In
+        bfloat16 the inverse index sums a host's dk and dv in float32
+        (read: 0.7% of the largest element at the most) where autodiff's
+        scatter-add sums them in bfloat16 (dk 2.5% for the hosts 150
+        lists name), hence its wider bound."""
         import jax.numpy as jnp
 
         from dragonfly2_tpu.models.graph_transformer import (
-            build_inverse_index,
             gather_graph_attention,
         )
 
         hidden = self.HIDDEN
         nbr, val = self._masked_lists(n, k_width, seed=n + k_width)
-        inv = (jnp.asarray(build_inverse_index(nbr))
+        inv = (self._inverse(nbr, val, dtype)
                if backward == "inverse_index" else None)
         rng = np.random.default_rng(heads)
         q, k, v, w = (jnp.asarray(rng.normal(size=(n, hidden)), dtype)
@@ -711,15 +735,16 @@ class TestLaneDenseGatherAttention:
         """What bfloat16 costs, as a root-mean-square distance from the
         float32 answer on the same (bfloat16) inputs: no more than in
         the per-head form this one replaced (bfloat16 einsums, the score
-        rounded to bfloat16 once), but for the score path's gradients:
-        q·k and the cotangent of the expanded probabilities are rounded
-        per lane here, as MXU operands, and the score not at all. A form
-        that dropped a float32 sum would show in this where the 2e-2 of
+        rounded to bfloat16 once), in the output and in every gradient:
+        the hand-written backward keeps the probabilities and the
+        cotangent of the scores in float32 (autodiff rounded the
+        probabilities' cotangent to bfloat16) and sums dk and dv over a
+        host's listings in float32. A form that dropped a float32 sum
+        would show in this where the 2e-2 of
         ``test_matches_per_head_float32`` lets it by."""
         import jax.numpy as jnp
 
         from dragonfly2_tpu.models.graph_transformer import (
-            build_inverse_index,
             gather_graph_attention,
         )
 
@@ -735,7 +760,7 @@ class TestLaneDenseGatherAttention:
             return jnp.einsum("nhk,nkhd->nhd", p, vh[idx]).reshape(n, hidden)
 
         q, k, v, nbr, val, w = self._inputs("bfloat16")
-        inv = jnp.asarray(build_inverse_index(np.asarray(nbr)))
+        inv = self._inverse(nbr, val, "bfloat16")
         want = self._out_and_grads(
             lambda *a: self._per_head(*a[:3], nbr, a[3], heads),
             q, k, v, val, w)
@@ -750,29 +775,33 @@ class TestLaneDenseGatherAttention:
                 (np.asarray(a, np.float32) - np.asarray(b, np.float32)) ** 2)))
                 for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want))]
 
-        # Read here: out 0.98, dk 0.79-0.81, dv 0.68-0.69 of the split
-        # form's error; dq 1.04-1.10 and dval 1.13-1.17, the price of
-        # 32 rounded addends a score where it rounded one sum.
-        room = {"out": 1.05, "dq": 1.25, "dk": 1.05, "dv": 1.05,
-                "dval": 1.25}
+        # Read here: out 0.98-0.99, dq 0.92-0.95, dk 0.62-0.67, dv
+        # 0.58-0.59 and dval 0.87-0.93 of the split form's error (until
+        # PR 30, by autodiff through the lane-dense forward and an
+        # inverse-index gather of the cotangent: dq 1.04-1.10, dk 0.79-0.81,
+        # dv 0.68-0.69, dval 1.13-1.17).
+        room = {"out": 1.05, "dq": 1.05, "dk": 0.8, "dv": 0.8,
+                "dval": 1.05}
         for name, new, old in zip(room, rms(dense), rms(split)):
             assert new <= room[name] * old, (name, new, old)
 
-    @pytest.mark.parametrize("model_parallel", [1, 2])
-    def test_sharded_matches_unsharded(self, model_parallel):
-        """Rows over ``data``; with ``model_parallel`` 2 the lanes (so
-        the heads) over ``model`` as well, as the tensor-parallel
-        projections leave them: the same numbers as on one device."""
+    @pytest.mark.parametrize("devices,model_parallel", [
+        (1, 1), (2, 1), (4, 1), (8, 1), (2, 2), (8, 2)])
+    def test_sharded_matches_unsharded(self, devices, model_parallel):
+        """Rows over ``data`` on 1-8 devices; with ``model_parallel`` 2
+        the lanes (so the heads) over ``model`` as well, as the
+        tensor-parallel projections leave them: the same output and the
+        same dq, dk, dv and dval as on one device, the backward's
+        ``[q | dO | statistics]`` table gone full-width as k and v go."""
         from jax.sharding import NamedSharding
         from jax.sharding import PartitionSpec as P
 
         from dragonfly2_tpu.models.graph_transformer import (
-            build_inverse_index,
             gather_graph_attention,
         )
 
         q, k, v, nbr, val, w = self._inputs("bfloat16")
-        inv = jax.numpy.asarray(build_inverse_index(np.asarray(nbr)))
+        inv = self._inverse(nbr, val, "bfloat16")
 
         def attend(nbr_, inv_):
             return lambda q_, k_, v_, val_: gather_graph_attention(
@@ -780,7 +809,8 @@ class TestLaneDenseGatherAttention:
 
         want = self._out_and_grads(attend(nbr, inv), q, k, v, val, w)
 
-        mesh = data_parallel_mesh(model_parallel=model_parallel)
+        mesh = data_parallel_mesh(devices=jax.devices()[:devices],
+                                  model_parallel=model_parallel)
         lanes = "model" if model_parallel > 1 else None
 
         def put(x, *spec):
@@ -793,6 +823,105 @@ class TestLaneDenseGatherAttention:
                 put(v, None, lanes), put(val, "data"), put(w, "data", lanes))
             assert jax.typeof(got[0]).sharding.spec == P("data", lanes)
         self._assert_close(got, want, 2e-2)
+
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    @pytest.mark.parametrize("heads", [1, 4])
+    def test_forward_is_the_one_without_the_inverse_index(self, heads,
+                                                          dtype):
+        """The inverse index changes the backward alone: the output is
+        ``inv=None``'s to the bit."""
+        from dragonfly2_tpu.models.graph_transformer import (
+            gather_graph_attention,
+        )
+
+        q, k, v, nbr, val, _ = self._inputs(dtype)
+        inv = self._inverse(nbr, val, dtype)
+        attend = jax.jit(gather_graph_attention, static_argnames="heads")
+        with_inv = attend(q, k, v, nbr, val, inv, heads=heads)
+        without = attend(q, k, v, nbr, val, None, heads=heads)
+        assert with_inv.dtype == without.dtype == q.dtype
+        assert (np.asarray(with_inv, np.float32)
+                == np.asarray(without, np.float32)).all()
+
+    @pytest.mark.parametrize("dtype,tol", [("float32", 1e-5),
+                                           ("bfloat16", 2e-2)])
+    @pytest.mark.parametrize("heads", [1, 4])
+    @pytest.mark.parametrize("shape", TestInverseIndex.DEGREE_SHAPES)
+    def test_backward_on_every_degree_shape(self, shape, heads, dtype, tol):
+        """dq, dk, dv and dval by the attention's own backward against
+        the dense reference's, where every host lists 7 and is listed by
+        7, where in-degrees pass the cap, where 150 lists name one host
+        (its dk within 0.7% in bfloat16: 150 addends, summed in
+        float32) and where every host lists itself alone."""
+        import jax.numpy as jnp
+
+        from dragonfly2_tpu.models.graph_transformer import (
+            gather_graph_attention,
+        )
+
+        nbr, val, _ = TestInverseIndex._lists(shape)
+        n, hidden = nbr.shape[0], self.HIDDEN
+        inv = self._inverse(nbr, val, dtype)
+        rng = np.random.default_rng(heads)
+        q, k, v, w = (jnp.asarray(rng.normal(size=(n, hidden)), dtype)
+                      for _ in range(4))
+        nbr, val = jnp.asarray(nbr), jnp.asarray(val)
+        got = self._out_and_grads(
+            lambda *a: gather_graph_attention(*a[:3], nbr, a[3], inv,
+                                              heads=heads),
+            q, k, v, val, w)
+        want = self._out_and_grads(
+            lambda *a: self._dense_masked_softmax(*a[:3], nbr, a[3], heads),
+            q, k, v, val, w)
+        self._assert_close(got, want, tol)
+        if shape == "hub" and dtype == "bfloat16":
+            hub_dk = np.asarray(got[1][1], np.float32)[0]
+            ref_dk = np.asarray(want[1][1], np.float32)
+            assert np.abs(hub_dk - ref_dk[0]).max() <= 7e-3 * np.abs(
+                ref_dk).max()
+
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    def test_padded_inverse_slots_add_exactly_nothing(self, dtype):
+        """A pad slot of the inverse index is masked like a pad slot of
+        a list: whatever row it fetches and whatever bias it carries, it
+        adds exactly 0.0. Hosts whose inverse rows are blanked get dk =
+        dv = 0.0, the others' sums do not move, and a wider index of
+        nothing but more pads gives the same gradients."""
+        from dragonfly2_tpu.models.graph_transformer import (
+            InverseIndex,
+            gather_graph_attention,
+        )
+
+        q, k, v, nbr, val, w = self._inputs(dtype)
+        inv = self._inverse(nbr, val, dtype)
+
+        def grads(inv_):
+            return self._out_and_grads(
+                lambda *a: gather_graph_attention(*a[:3], nbr, a[3], inv_,
+                                                  heads=4),
+                q, k, v, val, w)[1]
+
+        whole = grads(inv)
+        blank = np.arange(0, self.N, 3)
+        rows = np.asarray(inv.rows).copy()
+        vals = np.asarray(inv.vals).copy()
+        rows[blank] = -1
+        vals[blank] = 1e30                      # a pad's bias is not read
+        cut = grads(InverseIndex(jax.numpy.asarray(rows),
+                                 jax.numpy.asarray(vals)))
+        kept = np.setdiff1d(np.arange(self.N), blank)
+        for full, part in zip(whole[1:3], cut[1:3]):      # dk, dv
+            full, part = (np.asarray(t, np.float32) for t in (full, part))
+            assert (part[blank] == 0.0).all()
+            assert (part[kept] == full[kept]).all()
+        # dq and dval are the listing rows' own.
+        for full, part in zip(whole[::3], cut[::3]):
+            assert (np.asarray(full, np.float32)
+                    == np.asarray(part, np.float32)).all()
+        wider = InverseIndex(
+            jax.numpy.pad(inv.rows, ((0, 0), (0, 16)), constant_values=-1),
+            jax.numpy.pad(inv.vals, ((0, 0), (0, 16)), constant_values=7.0))
+        self._assert_close(grads(wider), whole, 1e-6)
 
 
 @pytest.mark.slow  # 16k-100k-node scale runs; minutes on a small box
