@@ -36,9 +36,10 @@ def main(argv=None) -> int:
                              "(BASELINE config #3) each cycle")
     parser.add_argument("--train-seq", default="", metavar="CONFIG_JSON",
                         help="also train + register a sequence model "
-                             "(lfm2_moe family) from the host's token "
-                             "segments each cycle; the file holds the "
-                             "model's published config.json keys and may "
+                             "from the host's token segments each cycle; "
+                             "the file holds the model's published "
+                             "config.json keys, its model_type naming "
+                             "the family (lfm2_moe or laguna), and may "
                              "add what is held here and the job's "
                              "settings (train/seq_trainer.py "
                              "config_from_dict)")

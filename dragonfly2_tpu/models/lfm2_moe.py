@@ -32,26 +32,36 @@ embedding rows ``vocab_held = (first, count)`` (token ids outside them
 have no row here; the logits and the loss are over the rows held).
 Parameters are float32 in a plain nested dict; products run in
 ``compute_dtype`` (bfloat16), norms, softmax, router and loss in float32.
+What the family shares with the other sequence families (norm, RoPE,
+attention, gated FFN, head, loss) is ``models/seq_layers.py``'s.
 """
 
 from __future__ import annotations
 
 import math
 from dataclasses import dataclass
-from functools import partial
 
 import jax
 import jax.numpy as jnp
 
+from dragonfly2_tpu.models.seq_layers import (
+    HeldShare,
+    attention,
+    gated_ffn,
+    rms_norm,
+    rope,
+    rope_frequencies,
+)
 from dragonfly2_tpu.parallel.moe import expert_layer
-
-INIT_STD = 0.02
 
 
 @dataclass(frozen=True)
-class Lfm2MoeConfig:
+class Lfm2MoeConfig(HeldShare):
     """The published keys, plus which published layers run and what of a
     layer is held here."""
+
+    model_type = "lfm2_moe"
+    attention_window = 0             # every attention layer sees all
 
     layer_types: tuple
     num_dense_layers: int
@@ -97,22 +107,9 @@ class Lfm2MoeConfig:
             **{k: config[k] for k in keys if k in config}, **held)
 
     @property
-    def kept_layers(self) -> tuple:
-        return (tuple(range(len(self.layer_types)))
-                if self.layers is None else tuple(self.layers))
-
-    @property
     def expert_layers(self) -> tuple:
         return tuple(i for i in self.kept_layers
                      if i >= self.num_dense_layers)
-
-    @property
-    def held_experts(self) -> tuple:
-        return self.experts_held or (0, self.num_experts)
-
-    @property
-    def held_vocab(self) -> tuple:
-        return self.vocab_held or (0, self.vocab_size)
 
     @property
     def head_dim(self) -> int:
@@ -157,65 +154,6 @@ def param_shapes(cfg: Lfm2MoeConfig) -> list:
     return out
 
 
-def init_params(key, cfg: Lfm2MoeConfig) -> dict:
-    """Parameters drawn operation by operation (a compiled init rounds
-    differently on a v5e; PERF.md, PR 25): leaf ``n`` of
-    :func:`param_shapes` is ``normal(fold_in(key, n)) · 0.02``, a norm's
-    weight is ones."""
-    params: dict = {}
-    for n, (path, shape, kind) in enumerate(param_shapes(cfg)):
-        node = params
-        for part in path[:-1]:
-            node = node.setdefault(part, {})
-        node[path[-1]] = (
-            jnp.ones(shape, jnp.float32) if kind == "ones" else
-            jax.random.normal(jax.random.fold_in(key, n), shape, jnp.float32)
-            * jnp.float32(INIT_STD))
-    return params
-
-
-def rms_norm(x, weight, eps: float):
-    x32 = x.astype(jnp.float32)
-    scale = jax.lax.rsqrt(jnp.mean(x32 * x32, -1, keepdims=True) + eps)
-    return (x32 * scale * weight).astype(x.dtype)
-
-
-def rope(x, positions, theta: float):
-    """Rotate-half rotary embedding of ``x`` [S, heads, head] at
-    ``positions`` [S], in float32."""
-    half = x.shape[-1] // 2
-    inv_freq = 1.0 / theta ** (jnp.arange(half, dtype=jnp.float32) / half)
-    angle = positions.astype(jnp.float32)[:, None] * inv_freq
-    cos = jnp.concatenate([jnp.cos(angle)] * 2, -1)[:, None, :]
-    sin = jnp.concatenate([jnp.sin(angle)] * 2, -1)[:, None, :]
-    x32 = x.astype(jnp.float32)
-    turned = jnp.concatenate([-x32[..., half:], x32[..., :half]], -1)
-    return (x32 * cos + turned * sin).astype(x.dtype)
-
-
-@partial(jax.custom_vjp, nondiff_argnums=(2,))
-def embedding_rows(table, ids, dtype):
-    """``table[ids]`` in ``dtype``. Backward: the table's gradient as one
-    product with the ids' one-hot matrix, not a scatter-add (token ids
-    repeat, and duplicate indices serialize on a TPU)."""
-    return table[ids].astype(dtype)
-
-
-def _embedding_rows_fwd(table, ids, dtype):
-    return table[ids].astype(dtype), (ids, table.shape[0])
-
-
-def _embedding_rows_bwd(dtype, saved, g):
-    ids, rows = saved
-    with jax.named_scope("df2.seq.embed"):
-        one_hot = (ids[None, :] == jnp.arange(rows)[:, None]).astype(dtype)
-        return jnp.matmul(one_hot, g.astype(dtype),
-                          preferred_element_type=jnp.float32), None
-
-
-embedding_rows.defvjp(_embedding_rows_fwd, _embedding_rows_bwd)
-
-
 def conv_operator(p, a, segments, cfg: Lfm2MoeConfig):
     dt = a.dtype
     b, c, u = jnp.split(a @ p["in_proj"].astype(dt), 3, axis=-1)
@@ -231,54 +169,6 @@ def conv_operator(p, a, segments, cfg: Lfm2MoeConfig):
     return (c * out) @ p["out_proj"].astype(dt)
 
 
-def dense_attention(q, k, v, segments):
-    """Causal same-document attention, scores held whole: q [S, H, hd]
-    already scaled, k and v [S, KV, hd]. The plain form, for sizes at
-    which ``[H, S, S]`` fits."""
-    s, h, hd = q.shape
-    group = h // k.shape[1]
-    q = q.reshape(s, k.shape[1], group, hd)
-    scores = jnp.einsum("sjgd,tjd->jgst", q, k,
-                        preferred_element_type=jnp.float32)
-    at = jnp.arange(s)
-    seen = (at[:, None] >= at[None, :]) & (
-        segments[:, None] == segments[None, :])
-    probs = jax.nn.softmax(jnp.where(seen, scores, -1e30), axis=-1)
-    out = jnp.einsum("jgst,tjd->sjgd", probs.astype(v.dtype), v)
-    return out.reshape(s, h, hd)
-
-
-# Rows and columns of a score tile of the TPU kernel.
-ATTENTION_BLOCK = 1024
-
-
-def kernel_attention(q, k, v, segments, interpret: bool = False):
-    """The same attention through JAX's splash-attention kernel (TPU):
-    no score matrix in HBM, tiles above the diagonal skipped, one
-    key-value head shared by its group of query heads."""
-    from jax.experimental.pallas.ops.tpu.splash_attention import (
-        splash_attention_kernel as kernel,
-        splash_attention_mask as masks,
-    )
-
-    s, h, hd = q.shape
-    kv = k.shape[1]
-    group = h // kv
-    block = min(ATTENTION_BLOCK, s)
-    sizes = kernel.BlockSizes(
-        block_q=block, block_kv=block, block_kv_compute=block,
-        block_q_dkv=block, block_kv_dkv=block, block_kv_dkv_compute=block,
-        use_fused_bwd_kernel=True)
-    attend = kernel.make_splash_mqa_single_device(
-        masks.MultiHeadMask([masks.CausalMask((s, s))] * group),
-        block_sizes=sizes, interpret=interpret)
-    ids = kernel.SegmentIds(q=segments, kv=segments)
-    out = jax.vmap(lambda q_, k_, v_: attend(q_, k_, v_, segment_ids=ids))(
-        q.reshape(s, kv, group, hd).transpose(1, 2, 0, 3),
-        k.transpose(1, 0, 2), v.transpose(1, 0, 2))
-    return out.transpose(2, 0, 1, 3).reshape(s, h, hd)
-
-
 def attention_operator(p, a, segments, positions, cfg: Lfm2MoeConfig):
     dt, s, hd = a.dtype, a.shape[0], cfg.head_dim
     with jax.named_scope("df2.seq.attn_proj"):
@@ -286,25 +176,13 @@ def attention_operator(p, a, segments, positions, cfg: Lfm2MoeConfig):
         k = (a @ p["k"].astype(dt)).reshape(s, cfg.num_key_value_heads, hd)
         v = (a @ p["v"].astype(dt)).reshape(s, cfg.num_key_value_heads, hd)
         q = rope(rms_norm(q, p["q_norm"], cfg.norm_eps), positions,
-                 cfg.rope_theta)
+                 rope_frequencies(cfg.rope_theta, hd))
         k = rope(rms_norm(k, p["k_norm"], cfg.norm_eps), positions,
-                 cfg.rope_theta)
+                 rope_frequencies(cfg.rope_theta, hd))
         q = (q.astype(jnp.float32) / math.sqrt(hd)).astype(dt)
-    with jax.named_scope("df2.seq.attn"):
-        # The kernel needs whole 128-wide tiles; below that, and off the
-        # TPU, the plain form.
-        if jax.devices()[0].platform == "tpu" and s % 128 == 0:
-            out = kernel_attention(q, k, v, segments)
-        else:
-            out = dense_attention(q, k, v, segments)
+    out = attention(q, k, v, segments)
     with jax.named_scope("df2.seq.attn_proj"):
         return out.reshape(s, -1) @ p["o"].astype(dt)
-
-
-def gated_ffn(p, a):
-    dt = a.dtype
-    return (jax.nn.silu(a @ p["w1"].astype(dt)) * (a @ p["w3"].astype(dt))
-            ) @ p["w2"].astype(dt)
 
 
 def block(p, x, router_bias, segments, positions, *, cfg: Lfm2MoeConfig,
@@ -329,61 +207,3 @@ def block(p, x, router_bias, segments, positions, *, cfg: Lfm2MoeConfig,
         norm_topk_prob=cfg.norm_topk_prob,
         scaling_factor=cfg.routed_scaling_factor)
     return h + out.astype(h.dtype), assigned
-
-
-def head_loss(embed, final_norm, x, local, segments, *, cfg: Lfm2MoeConfig):
-    """The summed cross-entropy of one sequence's next tokens, over the
-    positions whose next token is in the same document. ``local``: token
-    ids as rows of ``embed``."""
-    dt = x.dtype
-    x = rms_norm(x, final_norm, cfg.norm_eps)
-    logits = jnp.matmul(x, embed.astype(dt).T,
-                        preferred_element_type=jnp.float32)
-    target = jnp.roll(local, -1)
-    hit = jnp.arange(embed.shape[0])[None, :] == target[:, None]
-    nll = jax.nn.logsumexp(logits, -1) - jnp.where(hit, logits, 0).sum(-1)
-    return jnp.where(target_positions(segments), nll, 0).sum()
-
-
-def target_positions(segments):
-    """Where a position's next token is in the same document (last
-    axis: the sequence)."""
-    same = jnp.roll(segments, -1, axis=-1) == segments
-    return same & (jnp.arange(segments.shape[-1]) < segments.shape[-1] - 1)
-
-
-def sequence_loss(params, router_bias, tokens, segments, positions, *,
-                  cfg: Lfm2MoeConfig):
-    """One packed sequence ``[S]``: the summed cross-entropy over
-    :func:`target_positions` and each expert layer's assignment counts
-    ``[expert layers, E]``. ``router_bias``: ``[expert layers, E]``.
-    Each block, and the head with the loss, keeps its input alone for
-    the backward pass and is computed again there."""
-    local = tokens - cfg.held_vocab[0]
-    with jax.named_scope("df2.seq.embed"):
-        x = embedding_rows(params["embed"], local,
-                           jnp.dtype(cfg.compute_dtype))
-    counts = []
-    for i in cfg.kept_layers:
-        routed = i in cfg.expert_layers
-        bias = router_bias[cfg.expert_layers.index(i)] if routed else None
-        x, assigned = jax.checkpoint(partial(block, cfg=cfg, layer=i))(
-            params[f"layer_{i}"], x, bias, segments, positions)
-        if routed:
-            counts.append(assigned)
-    with jax.named_scope("df2.loss"):
-        loss = jax.checkpoint(partial(head_loss, cfg=cfg))(
-            params["embed"], params["final_norm"], x, local, segments)
-    return loss, (jnp.stack(counts) if counts else jnp.zeros(
-        (0, cfg.num_experts), jnp.int32))
-
-
-def batch_loss(params, router_bias, tokens, segments, positions, *,
-               cfg: Lfm2MoeConfig):
-    """:func:`sequence_loss` over a batch ``[B, S]``, one sequence at a
-    time (a sequence is the unit of memory: the batch costs residuals of
-    ``B`` block inputs a layer and no more). Returns the two sums."""
-    def one(args):
-        return sequence_loss(params, router_bias, *args, cfg=cfg)
-    loss, counts = jax.lax.map(one, (tokens, segments, positions))
-    return loss.sum(), counts.sum(0)

@@ -22,9 +22,10 @@ caller's ``df2.*`` scope on its operations; XLA's own lowering of
 elsewhere ``jax.lax.ragged_dot``. The products' work grows with the rows
 that are there. The row buffers, and with them the gathers and the
 elementwise work, are as long as twice the expected number of held
-assignments in any ordinary step, and as long as the worst case (every
-token sent to held experts, which is exact too) only in a step that
-needs it: ``jax.lax.cond`` takes the one or the other from the count.
+assignments in any ordinary step, and longer only in a step that needs
+it: four times as long each time, up to the worst case (every token sent
+to held experts, which is exact too); ``jax.lax.cond`` (two lengths) or
+``jax.lax.switch`` takes the one that the count needs.
 
 Rows move by gathers only, forward and backward: an assignment's place
 in the sorted order and its inverse are both known, so the backward of
@@ -224,11 +225,19 @@ def expert_layer(x, router_w, router_bias, w1, w3, w2, held, *,
 
     # The held assignments come first in the sorted order. Twice their
     # expected number (a share count / E of all) is where they end in
-    # any ordinary step: the row buffers are that long, and as long as
-    # the worst case, every assignment held here, only in a step whose
-    # held assignments pass that.
-    usual = -(-2 * every * count // n_experts // 8) * 8
-    if usual >= every:
+    # any ordinary step: the row buffers are that long, and longer (four
+    # times each, up to the worst case, every assignment held here) only
+    # in a step whose held assignments pass that.
+    lengths = [-(-2 * every * count // n_experts // 8) * 8]
+    while lengths[-1] * 4 < every:
+        lengths.append(lengths[-1] * 4)
+    if lengths[-1] >= every:
         return part(every), assigned
-    return jax.lax.cond(sizes.sum() <= usual, lambda: part(usual),
-                        lambda: part(every)), assigned
+    lengths.append(every)
+    if len(lengths) == 2:
+        return jax.lax.cond(sizes.sum() <= lengths[0],
+                            lambda: part(lengths[0]),
+                            lambda: part(every)), assigned
+    passed = sum((sizes.sum() > n).astype(jnp.int32) for n in lengths[:-1])
+    return jax.lax.switch(passed, [partial(part, n) for n in lengths]
+                          ), assigned

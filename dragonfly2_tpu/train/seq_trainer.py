@@ -1,4 +1,5 @@
-"""Training of ``lfm2_moe`` sequence models on packed token sequences.
+"""Training of sequence models (the families of ``FAMILIES``, chosen by
+a config's ``model_type``) on packed token sequences.
 
 The corpus is what a packer emits: ``[R, S]`` integer arrays of token
 ids, document ids (``segments``) and positions within the document. It
@@ -27,10 +28,22 @@ import optax
 from flax.training import train_state
 from jax.sharding import PartitionSpec as P
 
-from dragonfly2_tpu.models import lfm2_moe
+from dragonfly2_tpu.models import laguna, lfm2_moe, seq_layers
+from dragonfly2_tpu.models.laguna import LagunaConfig
 from dragonfly2_tpu.models.lfm2_moe import Lfm2MoeConfig
 from dragonfly2_tpu.parallel import MeshContext, data_parallel_mesh
 from dragonfly2_tpu.train.step_budget import TRAINING, StepBudget, step_loop
+
+
+# A family by the ``model_type`` of its published ``config.json``: the
+# module (``param_shapes``, ``block``) and its config.
+FAMILIES = {"lfm2_moe": (lfm2_moe, Lfm2MoeConfig),
+            "laguna": (laguna, LagunaConfig)}
+
+
+def family_of(cfg):
+    """The module of a model config's family."""
+    return FAMILIES[cfg.model_type][0]
 
 
 @dataclass(frozen=True)
@@ -78,11 +91,11 @@ def pack_documents(documents, seq_len: int,
 class SeqTrainConfig:
     """Recomputation is not an option: each block keeps its input alone
     for the backward pass and one sequence is in flight at a time
-    (``lfm2_moe.batch_loss``), which is what lets a 0.47B-parameter
+    (``seq_layers.batch_loss``), which is what lets a 0.47B-parameter
     model's state (16 bytes a parameter) and four 8k sequences share a
     16 GB chip."""
 
-    model: Lfm2MoeConfig
+    model: Lfm2MoeConfig | LagunaConfig
     batch_size: int = 4              # sequences a step
     # For whoever packs the corpus (``trainer/training.py``): the rows'
     # length, and the id that ends a document inside a token segment
@@ -101,10 +114,16 @@ class SeqTrainConfig:
 
 def config_from_dict(given: dict) -> SeqTrainConfig:
     """From a published ``config.json``'s keys (``df2-trainer
-    --train-seq FILE``), beside which the file may state what is held
-    here (``layers``, ``experts_held``, ``vocab_held``: pairs of first
-    and count) and the job's own settings under this config's field
-    names."""
+    --train-seq FILE``; ``model_type`` names the family, ``lfm2_moe``
+    where the file has none), beside which the file may state what is
+    held here (``layers``, ``experts_held``, ``vocab_held``: pairs of
+    first and count) and the job's own settings under this config's
+    field names."""
+    model_type = given.get("model_type", "lfm2_moe")
+    if model_type not in FAMILIES:
+        raise ValueError(f"model_type {model_type!r}: the sequence job "
+                         f"trains {sorted(FAMILIES)}")
+    _, config_class = FAMILIES[model_type]
     held = {k: tuple(given[k])
             for k in ("layers", "experts_held", "vocab_held") if k in given}
     job = {k: given[k] for k in (
@@ -113,7 +132,7 @@ def config_from_dict(given: dict) -> SeqTrainConfig:
     if "router_bias" in given:
         job["router_bias"] = tuple(given["router_bias"])
     return SeqTrainConfig(
-        model=Lfm2MoeConfig.from_published(given, **held), **job)
+        model=config_class.from_published(given, **held), **job)
 
 
 class SeqTrainState(train_state.TrainState):
@@ -139,12 +158,13 @@ class SeqTrainResult:
     routing_counts: np.ndarray = None
 
 
-def build_train_step(cfg: Lfm2MoeConfig, mesh: MeshContext):
+def build_train_step(cfg, mesh: MeshContext):
     """The jitted step ``train_step(state, tokens, segments, seq_ids,
-    positions) -> (state, loss)``: the state donated, the corpus
-    replicated, ``seq_ids`` (this step's rows of it) sharded over
-    ``data``."""
+    positions) -> (state, loss)`` of ``cfg``'s family: the state donated,
+    the corpus replicated, ``seq_ids`` (this step's rows of it) sharded
+    over ``data``."""
     rep = mesh.replicated
+    block = family_of(cfg).block
 
     def loss_and_grads(params, router_bias, tokens, segments, positions,
                        seq_ids):
@@ -153,11 +173,11 @@ def build_train_step(cfg: Lfm2MoeConfig, mesh: MeshContext):
         over ``data``."""
         tok, seg, pos = tokens[seq_ids], segments[seq_ids], positions[seq_ids]
         n = jnp.maximum(jax.lax.psum(
-            lfm2_moe.target_positions(seg).sum(), "data"), 1)
+            seq_layers.target_positions(seg).sum(), "data"), 1)
 
         def mean(p):
-            loss, counts = lfm2_moe.batch_loss(
-                p, router_bias, tok, seg, pos, cfg=cfg)
+            loss, counts = seq_layers.batch_loss(
+                p, router_bias, tok, seg, pos, cfg=cfg, block=block)
             return loss / n, counts
 
         (loss, counts), grads = jax.value_and_grad(mean, has_aux=True)(params)
@@ -191,7 +211,6 @@ def build_train_step(cfg: Lfm2MoeConfig, mesh: MeshContext):
         donate_argnums=(0,))
 
 
-
 def train_seq(
     corpus: SeqCorpus,
     config: SeqTrainConfig,
@@ -221,7 +240,9 @@ def train_seq(
     ) else np.asarray(config.router_bias, np.float32)
     state = SeqTrainState.create(
         apply_fn=None,
-        params=lfm2_moe.init_params(jax.random.key(config.seed), cfg),
+        params=seq_layers.init_params(
+            jax.random.key(config.seed),
+            family_of(cfg).param_shapes(cfg)),
         tx=optax.adamw(schedule, weight_decay=config.weight_decay),
         router_bias=jnp.tile(bias, (n_moe, 1)),
         routing_counts=jnp.zeros((n_moe, cfg.num_experts), jnp.uint32))
@@ -232,6 +253,9 @@ def train_seq(
         for a in (corpus.tokens, corpus.segments, corpus.positions))
 
     train_step = build_train_step(cfg, mesh)
+    # Last value set, and on every step's span: which attention the
+    # loop's sliding layers ran (docs/OBSERVABILITY.md).
+    TRAINING.set(seq_attn_window=cfg.attention_window)
 
     budget = StepBudget(config.max_seconds, step_samples=batch * seq_len)
     rng = np.random.default_rng((config.seed, 11))
@@ -250,7 +274,8 @@ def train_seq(
     history = step_loop(
         budget, config.epochs, epoch_steps, dispatch,
         step_samples=batch * seq_len, drain=lambda: state.params,
-        serialize_launches=mesh.serialize_launches)
+        serialize_launches=mesh.serialize_launches,
+        step_facts={"seq_attn_window": cfg.attention_window})
 
     # One read of the routing counts, after the drain.
     routing = np.asarray(jax.device_get(state.routing_counts), np.int64)

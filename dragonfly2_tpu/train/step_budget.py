@@ -71,13 +71,16 @@ class TrainingStats:
       index: its slots (hosts times its width) and those of them that
       name a listing. Their ratio is the share of the attention
       backward's source-major pass that is not padding.
+    - ``seq_attn_window``: the last value set too, where ``train_seq``
+      builds its step: the window of the model's sliding-attention
+      layers in tokens, or 0 for a family (or a cut) without one.
     """
 
     KEYS = ("loops_started", "dispatches", "steps", "samples",
             "compile_seconds", "loop_compiles", "steady_compiles",
             "moe_steps", "moe_assignments_held", "moe_assignments_hottest",
             "sampler_row_width", "attn_inverse_slots",
-            "attn_inverse_filled")
+            "attn_inverse_filled", "seq_attn_window")
 
     def __init__(self):
         self._lock = threading.Lock()
@@ -141,21 +144,25 @@ def epoch_mean(losses) -> float:
 
 
 def step_loop(budget, epochs: int, epoch_steps, dispatch, *,
-              step_samples: int, drain, serialize_launches: bool = False):
+              step_samples: int, drain, serialize_launches: bool = False,
+              step_facts: Optional[dict] = None):
     """The skeleton of a train loop with its host spans
     (docs/OBSERVABILITY.md "Training loops"): for each epoch, for each
     ``make_input`` of ``epoch_steps(epoch)``, ``dispatch(make_input())``
     launches one step and returns its loss; ``budget.tick`` counts
     ``step_samples`` and says when to stop. ``drain()`` returns what the
     final wait blocks on. Returns each epoch's mean loss (a host sync at
-    an epoch's end). The spans cost nothing while no profiler runs."""
+    an epoch's end). ``step_facts`` are written on every
+    ``df2.train.step`` span beside the step's number. The spans cost
+    nothing while no profiler runs."""
     span = jax.profiler.TraceAnnotation
     history, stop, step_num = [], False, 0
     for epoch in range(epochs):
         losses = []
         for i, make_input in enumerate(epoch_steps(epoch)):
             with jax.profiler.StepTraceAnnotation(
-                    "df2.train.step", step_num=step_num):
+                    "df2.train.step", step_num=step_num,
+                    **(step_facts or {})):
                 with span("df2.train.input", epoch=epoch, step=i):
                     inputs = make_input()
                 with span("df2.train.dispatch"):

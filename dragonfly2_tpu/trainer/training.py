@@ -433,7 +433,8 @@ class Training:
             host_id, ip, hostname, scheduler_id,
             evaluation,
             tree=seq_tree(result.params, result.routing_counts),
-            config={"layer_types": list(model.layer_types),
+            config={"model_type": model.model_type,
+                    "layer_types": list(model.layer_types),
                     "layers": list(model.kept_layers),
                     "hidden_size": model.hidden_size,
                     "num_experts": model.num_experts,

@@ -18,7 +18,9 @@ phases (docs/OBSERVABILITY.md "Training loops").
 - Per host thread: the ``df2.train.*`` spans' totals per step.
 - ``step_facts``: what the loop wrote on its ``df2.train.step`` spans
   besides the step's number (``sampler_row_width``: the lanes of
-  GraphSAGE's per-host neighbour rows, 0 on the CSR sampler).
+  GraphSAGE's per-host neighbour rows, 0 on the CSR sampler;
+  ``seq_attn_window``: the window of a sequence model's sliding layers,
+  0 where it has none, whose kernel is ``df2.seq.attn_window``).
 - The longest device idle gaps, each with the ``df2.train.*`` span the
   loop's thread was in.
 

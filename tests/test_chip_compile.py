@@ -162,15 +162,20 @@ def test_embedding_pass_at_model_load_leaves_the_chip_room(topo):
     assert memory.temp_size_in_bytes < 2.5e9     # 1.82 GB, PR 25
 
 
-# ``lfm2-24b-a2b-ep8.train``: one packed 8k sequence at the published
-# widths (hidden 2048, 32 query heads on 8 key-value heads of 64, expert
-# width 1536, 8 experts held of 64, top-4).
+# The two sequence cells: one packed 8k sequence at the published
+# widths. ``lfm2-24b-a2b-ep8.train``: hidden 2048, 32 query heads on 8
+# key-value heads of 64, expert width 1536, 8 experts held of 64, top-4.
+# ``laguna-xs2-ep32.train``: 48 (full layers) and 64 (sliding layers,
+# window 512) query heads on 8 key-value heads of 128, expert width 512,
+# 8 held of 256, top-8.
 SEQ, HIDDEN = 8192, 2048
 
 
-def test_expert_layer_is_grouped_products_for_the_chip(one_chip,
-                                                       as_tpu_program):
-    """The expert layer's forward and backward at the cell's widths: the
+@pytest.mark.parametrize("experts,width,top_k", [(64, 1536, 4), (256, 512, 8)],
+                         ids=["lfm2-24b-a2b-ep8", "laguna-xs2-ep32"])
+def test_expert_layer_is_grouped_products_for_the_chip(
+        one_chip, as_tpu_program, experts, width, top_k):
+    """The expert layer's forward and backward at a cell's widths: the
     three grouped products and their transposes are the megablox kernel
     (not one masked dense product per expert, and not XLA's own lowering
     of ``ragged_dot``, whose operations lose the ``df2.*`` scope), and no
@@ -178,16 +183,16 @@ def test_expert_layer_is_grouped_products_for_the_chip(one_chip,
     from dragonfly2_tpu.parallel.moe import expert_layer
 
     s = _struct(one_chip)
-    held, width = 8, 1536
+    held = 8
 
     def loss(x, router, w1, w3, w2):
-        out, _ = expert_layer(x, router, jnp.zeros(64), w1, w3, w2,
-                              (0, held), top_k=4)
+        out, _ = expert_layer(x, router, jnp.zeros(experts), w1, w3, w2,
+                              (0, held), top_k=top_k)
         return out.sum()
 
     compiled = _compile(
         jax.grad(loss, argnums=(0, 1, 2, 3, 4)),
-        s((SEQ, HIDDEN), jnp.bfloat16), s((HIDDEN, 64), jnp.float32),
+        s((SEQ, HIDDEN), jnp.bfloat16), s((HIDDEN, experts), jnp.float32),
         s((held, HIDDEN, width), jnp.float32),
         s((held, HIDDEN, width), jnp.float32),
         s((held, width, HIDDEN), jnp.float32))
@@ -202,24 +207,36 @@ def test_expert_layer_is_grouped_products_for_the_chip(one_chip,
     assert not big, big[:2]
 
 
-def test_sequence_attention_kernel_at_the_cells_shape(one_chip):
+@pytest.mark.parametrize("heads,hd,window", [
+    (32, 64, None), (48, 128, None), (64, 128, 512)],
+    ids=["lfm2-24b-a2b-ep8", "laguna-xs2-ep32-full",
+         "laguna-xs2-ep32-sliding"])
+def test_sequence_attention_kernel_at_the_cells_shape(one_chip, heads, hd,
+                                                      window):
     """Grouped-query causal attention over packed documents through the
-    splash-attention kernel, forward and backward, heads of 64."""
-    from dragonfly2_tpu.models.lfm2_moe import kernel_attention
+    splash-attention kernel, forward and backward, at each cell's heads
+    (groups of 4, 6 and 8 on 8 key-value heads), the sliding layers'
+    with the window in the kernel's own mask."""
+    from dragonfly2_tpu.models.seq_layers import kernel_attention
 
     s = _struct(one_chip)
-    heads, kv_heads, hd = 32, 8, 64
+    kv_heads = 8
 
     def loss(q, k, v, segments):
-        return kernel_attention(q, k, v, segments).astype(jnp.float32).sum()
+        return kernel_attention(q, k, v, segments, window).astype(
+            jnp.float32).sum()
 
     compiled = _compile(
         jax.grad(loss, argnums=(0, 1, 2)),
         s((SEQ, heads, hd), jnp.bfloat16), s((SEQ, kv_heads, hd), jnp.bfloat16),
         s((SEQ, kv_heads, hd), jnp.bfloat16), s((SEQ,), jnp.int32))
     assert compiled.as_text().count("tpu_custom_call") >= 2
-    # No [heads, S, S] score matrix: 32 x 8192 x 8192 float32 is 8.6 GB.
-    assert compiled.memory_analysis().temp_size_in_bytes < 2e9
+    # No [heads, S, S] score matrix (32 x 8192 x 8192 float32 is 8.6
+    # GB): 0.63, 0.86 and 2.30 GB, the sliding layers' the most because
+    # the fused backward keeps a partial dq for each of its 16 key
+    # blocks of 512 (8 of 1,024 in the other two).
+    assert compiled.memory_analysis().temp_size_in_bytes < (
+        2e9 if window is None else 3e9)
 
 
 @pytest.mark.parametrize("chips", [1, 4])

@@ -7,15 +7,26 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from dragonfly2_tpu.parallel.moe import expert_layer, route
+from dragonfly2_tpu.parallel.moe import (
+    ROW_TILE,
+    expert_layer,
+    group_tiles,
+    route,
+)
 
 T, D, F, E, K = 48, 16, 24, 16, 4
+# The second expert cell's shape class (``laguna-xs2-ep32``): top-8 of
+# 256 fine-grained experts, 8 held, one 8k sequence, so that a held
+# expert's group is around one ``ROW_TILE`` of rows (65,536 assignments,
+# 2,048 of them held on average, 256 an expert) and the ordinary row
+# buffer is 4,096 rows. The widths stay small: the CPU computes them.
+MANY = {"tokens": 8192, "experts": 256, "top_k": 8, "held": (16, 8)}
 
 
-def make(seed=0, experts=E):
+def make(seed=0, experts=E, tokens=T):
     rng = np.random.default_rng(seed)
     return {
-        "x": jnp.asarray(rng.standard_normal((T, D)), jnp.float32),
+        "x": jnp.asarray(rng.standard_normal((tokens, D)), jnp.float32),
         "router": jnp.asarray(rng.standard_normal((D, experts)) * 0.5,
                               jnp.float32),
         "bias": jnp.asarray(rng.standard_normal(experts) * 0.1, jnp.float32),
@@ -28,9 +39,10 @@ def make(seed=0, experts=E):
     }
 
 
-def dense_layer(p, bias=None, top_k=K):
-    """Every expert on every token, masked by the selection: the whole
-    layer's result, and the per-expert assignment counts."""
+def dense_layer(p, bias=None, top_k=K, held=None):
+    """Every expert (or those of ``held = (first, count)`` alone) on
+    every token, masked by the selection: the whole layer's result (the
+    held experts' part of it), and the per-expert assignment counts."""
     bias = p["bias"] if bias is None else bias
     scores = jax.nn.sigmoid(jnp.matmul(p["x"], p["router"],
                                        precision="highest"))
@@ -38,7 +50,8 @@ def dense_layer(p, bias=None, top_k=K):
     weights = jnp.take_along_axis(scores, chosen, -1)
     weights = weights / (weights.sum(-1, keepdims=True) + 1e-6)
     out = 0.0
-    for e in range(p["router"].shape[1]):
+    first, count = held or (0, p["router"].shape[1])
+    for e in range(first, first + count):
         w_e = jnp.where(chosen == e, weights, 0.0).sum(-1)
         hidden = jax.nn.silu(p["x"] @ p["w1"][e]) * (p["x"] @ p["w3"][e])
         out = out + w_e[:, None] * (hidden @ p["w2"][e])
@@ -113,6 +126,63 @@ def test_the_short_and_the_long_row_buffer_agree(second):
                                rtol=2e-4, atol=2e-6)
 
 
+def many_experts(crowd: float):
+    """The inputs of the ``MANY`` shape class: held expert 19 gets a
+    bias that empties it, and the others of the 8 held ``crowd`` (0: an
+    ordinary step's load, the 4,096-row buffer; 100: every token on all
+    seven, 57,344 rows, the worst-case buffer of 65,536; -100: every
+    token on the first of them alone, some 10,000 rows, the 16,384-row
+    buffer between the two)."""
+    p = make(11, MANY["experts"], MANY["tokens"])
+    first, count = MANY["held"]
+    at = jnp.arange(MANY["experts"])
+    if crowd < 0:
+        return p, jnp.zeros(MANY["experts"]).at[first].set(100.0).at[19].set(
+            -100.0)
+    bias = jnp.where((at >= first) & (at < first + count), crowd, 0.0)
+    return p, bias.at[19].set(-100.0)
+
+
+@pytest.mark.parametrize("crowd,rows", [
+    (0.0, "usual"), (-100.0, "between"), (100.0, "every")])
+def test_many_small_experts_one_of_them_empty(crowd, rows):
+    """Top-8 of 256 with 8 held, groups around one row tile, one held
+    expert with no row at all, under each length of row buffer: the
+    result is the dense form's part of the held experts."""
+    p, bias = many_experts(crowd)
+    first, count = MANY["held"]
+    want, counts = dense_layer(p, bias, MANY["top_k"], MANY["held"])
+    out, assigned = jax.jit(lambda p: share(
+        p, first, count, bias, MANY["top_k"]))(p)
+    np.testing.assert_array_equal(np.asarray(assigned), counts)
+    held = counts[first:first + count]
+    every = MANY["tokens"] * MANY["top_k"]
+    usual = 2 * every * count // MANY["experts"]
+    assert counts.sum() == every and held[19 - first] == 0
+    if rows == "usual":
+        # Groups of about one row tile each, within the ordinary buffer.
+        assert held.sum() <= usual == 4096
+        assert 0.5 * ROW_TILE < np.delete(held, 19 - first).mean() < 1.5 * ROW_TILE
+    elif rows == "between":
+        assert usual < held.sum() <= 4 * usual and held[0] == MANY["tokens"]
+    else:
+        assert held.sum() == 7 * MANY["tokens"] > 4 * usual
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               rtol=2e-5, atol=2e-6)
+
+
+@pytest.mark.parametrize("m,k,n,tiles", [
+    # lfm2-24b-a2b-ep8: 2048 x 1536 experts; the backward's transposes.
+    (4096, 2048, 1536, (256, 1024, 512)), (4096, 1536, 2048, (256, 512, 1024)),
+    # laguna-xs2-ep32: 2048 x 512 experts, 4,096- and 65,536-row buffers.
+    (4096, 2048, 512, (256, 1024, 512)), (65536, 512, 2048, (256, 512, 1024)),
+    # A width that no tile divides: no kernel (the plain grouped product).
+    (4096, 2048, 24, (256, 1024, None)),
+])
+def test_group_tiles(m, k, n, tiles):
+    assert group_tiles(m, k, n) == tiles
+
+
 def test_no_token_for_the_held_experts_gives_zero():
     p = make(2)
     bias = jnp.where(jnp.arange(E) < K, -100.0, 0.0)
@@ -148,33 +218,39 @@ def test_the_selection_bias_selects_and_does_not_weigh():
         rtol=1e-6)
 
 
-@pytest.mark.parametrize("first,count", [(4, 8), (6, 2)])
+@pytest.mark.parametrize("first,count,crowd", [
+    (4, 8, None), (6, 2, None), (16, 8, 0.0), (16, 8, -100.0),
+    (16, 8, 100.0)],
+    ids=["8_of_16", "2_of_16", "8_of_256_usual", "8_of_256_between",
+         "8_of_256_every"])
 @pytest.mark.parametrize("name", ["x", "router", "w1", "w3", "w2"])
-def test_gradients_against_the_dense_form(name, first, count):
+def test_gradients_against_the_dense_form(name, first, count, crowd):
     """The gather-only backward (custom VJPs of the two row moves)
     against autodiff of the dense form, for a share of the experts: 8
-    of 16 (one length of row buffer) and 2 of 16 (the short buffer of an
-    ordinary step, under ``lax.cond``)."""
-    p = make(4)
-    probe = jnp.asarray(np.random.default_rng(9).standard_normal((T, D)),
-                        jnp.float32)
+    of 16 (one length of row buffer), 2 of 16 (the short buffer of an
+    ordinary step, under ``lax.cond``), and the ``MANY`` shape class
+    (top-8, 8 of 256, one held expert empty) under each of its three
+    buffers."""
+    if crowd is None:
+        p, bias, top_k = make(4), None, K
+    else:
+        (p, bias), top_k = many_experts(crowd), MANY["top_k"]
+    probe = jnp.asarray(np.random.default_rng(9).standard_normal(
+        p["x"].shape), jnp.float32)
 
     def ours(value):
         q = dict(p, **{name: value})
-        return (share(q, first, count)[0] * probe).sum()
+        return (share(q, first, count, bias, top_k)[0] * probe).sum()
 
     def dense(value):
+        # The held experts' part of the dense form.
         q = dict(p, **{name: value})
-        held = jnp.arange(E)
-        held = (held >= first) & (held < first + count)
-        # The held experts' part of the dense form: the other experts'
-        # weights are zeroed where they enter the sum.
-        q = dict(q, w2=jnp.where(held[:, None, None], q["w2"], 0.0))
-        return (dense_layer(q)[0] * probe).sum()
+        return (dense_layer(q, bias, top_k, (first, count))[0] * probe).sum()
 
     got, want = jax.grad(ours)(p[name]), jax.grad(dense)(p[name])
+    scale = float(jnp.abs(want).max())
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=2e-4, atol=2e-6)
+                               rtol=2e-4, atol=2e-6 * max(scale, 1.0))
 
 
 @pytest.mark.parametrize("top_k", [1, 2])

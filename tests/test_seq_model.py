@@ -10,7 +10,7 @@ import pytest
 
 from benchmarks.compare import leaves
 from benchmarks.references import lfm2_moe as reference
-from dragonfly2_tpu.models import lfm2_moe
+from dragonfly2_tpu.models import lfm2_moe, seq_layers
 from dragonfly2_tpu.models.lfm2_moe import Lfm2MoeConfig
 
 # The published pattern's start: two leading dense layers, a period of
@@ -53,6 +53,16 @@ def sequence(seed=0, lengths=LENGTHS):
                  for a in (tokens, segments, positions))
 
 
+def init_params(seed, cfg):
+    return seq_layers.init_params(jax.random.key(seed),
+                                  lfm2_moe.param_shapes(cfg))
+
+
+def sequence_loss(params, bias, *sequence, cfg):
+    return seq_layers.sequence_loss(params, bias, *sequence, cfg=cfg,
+                                    block=lfm2_moe.block)
+
+
 def bias_rows(cfg):
     return jnp.tile(jnp.asarray(reference.selection_bias(SPEC)),
                     (len(cfg.expert_layers), 1))
@@ -62,7 +72,7 @@ def test_parameters_are_the_references_own():
     """Same names, same shapes, the same draws from the seed: the
     benchmark's ``init_gap`` limit is 0."""
     cfg = config()
-    ours = leaves(lfm2_moe.init_params(jax.random.key(5), cfg))
+    ours = leaves(init_params(5, cfg))
     theirs = reference.init_params(5, reference.sizes(SPEC))
     assert sorted(ours) == sorted(theirs)
     for name in ours:
@@ -100,12 +110,12 @@ def test_parameter_count_of_the_benchmarks_configuration():
 @pytest.mark.parametrize("seed", [7, 8])
 def test_loss_and_gradients_against_the_plain_reference(seed):
     cfg = config()
-    params = lfm2_moe.init_params(jax.random.key(seed), cfg)
+    params = init_params(seed, cfg)
     tokens, segments, positions = sequence(seed)
     sizes = reference.sizes(SPEC)
 
     def ours(p):
-        return lfm2_moe.sequence_loss(
+        return sequence_loss(
             p, bias_rows(cfg), tokens, segments, positions, cfg=cfg)
 
     def theirs(p):
@@ -117,7 +127,7 @@ def test_loss_and_gradients_against_the_plain_reference(seed):
     (loss, counts), grads = jax.value_and_grad(ours, has_aux=True)(params)
     (want, n), want_grads = jax.value_and_grad(theirs, has_aux=True)(
         reference.init_params(seed, sizes))
-    assert int(n) == int(lfm2_moe.target_positions(segments).sum()) == S - 4
+    assert int(n) == int(seq_layers.target_positions(segments).sum()) == S - 4
     np.testing.assert_allclose(float(loss), float(want), rtol=1e-5)
     for name, got in leaves(grads).items():
         scale = float(jnp.abs(want_grads[name]).max())
@@ -130,13 +140,13 @@ def test_loss_and_gradients_against_the_plain_reference(seed):
 
 def test_the_batch_is_the_sum_of_its_sequences():
     cfg = config()
-    params = lfm2_moe.init_params(jax.random.key(1), cfg)
+    params = init_params(1, cfg)
     rows = [sequence(seed, lengths) for seed, lengths in
             ((0, LENGTHS), (1, [64]), (2, [1, 1, 2, 60]))]
     batch = [jnp.stack(part) for part in zip(*rows)]
-    loss, counts = lfm2_moe.batch_loss(
-        params, bias_rows(cfg), *batch, cfg=cfg)
-    each = [lfm2_moe.sequence_loss(params, bias_rows(cfg), *row, cfg=cfg)
+    loss, counts = seq_layers.batch_loss(
+        params, bias_rows(cfg), *batch, cfg=cfg, block=lfm2_moe.block)
+    each = [sequence_loss(params, bias_rows(cfg), *row, cfg=cfg)
             for row in rows]
     np.testing.assert_allclose(float(loss), sum(float(e[0]) for e in each),
                                rtol=1e-6)
@@ -149,7 +159,7 @@ def test_nothing_crosses_a_document_boundary():
     terms bit-equal: no convolution tap, attention score or position
     reaches across."""
     cfg = config()
-    params = lfm2_moe.init_params(jax.random.key(3), cfg)
+    params = init_params(3, cfg)
     tokens, segments, positions = sequence()
     first = SPEC["deployment"]["vocab_rows_held"][0]
     start, stop = LENGTHS[0], LENGTHS[0] + LENGTHS[1]
@@ -159,18 +169,18 @@ def test_nothing_crosses_a_document_boundary():
     def per_position(tok):
         """Each target position's loss term."""
         dt = jnp.float32
-        x = lfm2_moe.embedding_rows(params["embed"], tok - first, dt)
+        x = seq_layers.embedding_rows(params["embed"], tok - first, dt)
         for i in cfg.kept_layers:
             routed = i in cfg.expert_layers
             bias = (bias_rows(cfg)[cfg.expert_layers.index(i)]
                     if routed else None)
             x, _ = lfm2_moe.block(params[f"layer_{i}"], x, bias, segments,
                                   positions, cfg=cfg, layer=i)
-        x = lfm2_moe.rms_norm(x, params["final_norm"], cfg.norm_eps)
+        x = seq_layers.rms_norm(x, params["final_norm"], cfg.norm_eps)
         logits = x @ params["embed"].T
         nll = jax.nn.logsumexp(logits, -1) - jnp.take_along_axis(
             logits, jnp.roll(tok - first, -1)[:, None], -1)[:, 0]
-        return jnp.where(lfm2_moe.target_positions(segments), nll, 0.0)
+        return jnp.where(seq_layers.target_positions(segments), nll, 0.0)
 
     before, after = per_position(tokens), per_position(changed)
     other = np.ones(S, bool)
@@ -190,7 +200,7 @@ def test_grouped_query_heads_against_repeated_key_value_heads():
     k = jnp.asarray(rng.standard_normal((S, kv_heads, hd)), jnp.float32)
     v = jnp.asarray(rng.standard_normal((S, kv_heads, hd)), jnp.float32)
     _, segments, _ = sequence()
-    got = lfm2_moe.dense_attention(q, k, v, segments)
+    got = seq_layers.dense_attention(q, k, v, segments)
     k_all, v_all = (jnp.repeat(a, heads // kv_heads, axis=1) for a in (k, v))
     at = jnp.arange(S)
     seen = (at[:, None] >= at[None, :]) & (
@@ -202,30 +212,65 @@ def test_grouped_query_heads_against_repeated_key_value_heads():
                                rtol=1e-5, atol=1e-6)
 
 
-def test_the_attention_kernel_is_the_plain_attention():
+@pytest.mark.parametrize("window,groups", [(None, 2), (100, 2), (100, 3)],
+                         ids=["causal", "window", "window_groups_of_3"])
+def test_the_attention_kernel_is_the_plain_attention(window, groups,
+                                                     monkeypatch):
     """The TPU kernel's path (here in interpret mode) against the plain
-    form, with documents and grouped heads, values and gradients."""
+    form, with documents and grouped heads, values and gradients;
+    with a window (the kernel's local mask, 128-wide tiles so that some
+    lie wholly before it and are skipped) the documents are both
+    shorter and longer than it."""
     rng = np.random.default_rng(1)
-    s, heads, kv_heads, hd = 256, 4, 2, 64
+    s, kv_heads, hd = 256, 2, 64
+    heads = groups * kv_heads
     q, k, v = (jnp.asarray(rng.standard_normal((s, h, hd)) * 0.3, jnp.float32)
                for h in (heads, kv_heads, kv_heads))
     segments = jnp.asarray(np.repeat([0, 1, 2], [100, 28, 128]), jnp.int32)
 
     def total(fn, q, k, v):
-        return (fn(q, k, v, segments) ** 2).sum()
+        return (fn(q, k, v, segments, window) ** 2).sum()
 
-    kernel = lambda q, k, v, seg: lfm2_moe.kernel_attention(  # noqa: E731
-        q, k, v, seg, interpret=True)
+    def kernel(q, k, v, seg, window):
+        return seq_layers.kernel_attention(q, k, v, seg, window,
+                                           interpret=True)
+
+    monkeypatch.setattr(seq_layers, "WINDOW_BLOCK", 128)
     np.testing.assert_allclose(
-        np.asarray(kernel(q, k, v, segments)),
-        np.asarray(lfm2_moe.dense_attention(q, k, v, segments)),
+        np.asarray(kernel(q, k, v, segments, window)),
+        np.asarray(seq_layers.dense_attention(q, k, v, segments, window)),
         rtol=2e-3, atol=2e-3)
     got = jax.grad(lambda *a: total(kernel, *a), argnums=(0, 1, 2))(q, k, v)
-    want = jax.grad(lambda *a: total(lfm2_moe.dense_attention, *a),
+    want = jax.grad(lambda *a: total(seq_layers.dense_attention, *a),
                     argnums=(0, 1, 2))(q, k, v)
     for g, w in zip(got, want):
         np.testing.assert_allclose(np.asarray(g), np.asarray(w),
                                    rtol=2e-2, atol=2e-3)
+
+
+def test_a_window_is_the_causal_mask_cut_at_its_length():
+    """``t - s < window`` and the token itself counts: a window as long
+    as the sequence changes nothing, a window of 1 returns each token's
+    own value, and a key ``window`` back is not seen."""
+    rng = np.random.default_rng(2)
+    q, k, v = (jnp.asarray(rng.standard_normal((S, h, 16)), jnp.float32)
+               for h in (4, 2, 2))
+    _, segments, _ = sequence()
+    whole = seq_layers.dense_attention(q, k, v, segments)
+    np.testing.assert_array_equal(
+        np.asarray(seq_layers.dense_attention(q, k, v, segments, S)),
+        np.asarray(whole))
+    own = seq_layers.dense_attention(q, k, v, segments, 1)
+    np.testing.assert_allclose(np.asarray(own),
+                               np.asarray(jnp.repeat(v, 2, axis=1)),
+                               rtol=1e-6)
+    # Position 25 lies in the second document (10 .. 39): with a window
+    # of 8 it sees keys 18 .. 25, so key 17 moves nothing and 18 does.
+    for key, moves in ((17, False), (18, True)):
+        other = v.at[key].add(1.0)
+        out = seq_layers.dense_attention(q, k, other, segments, 8)
+        base = seq_layers.dense_attention(q, k, v, segments, 8)
+        assert bool(jnp.any(out[25] != base[25])) is moves
 
 
 def test_embedding_gradient_is_the_scatter_adds():
@@ -234,7 +279,7 @@ def test_embedding_gradient_is_the_scatter_adds():
     ids = jnp.asarray([3, 3, 0, 11, 3, 7], jnp.int32)
     probe = jnp.asarray(np.random.default_rng(1).standard_normal((6, 8)),
                         jnp.float32)
-    got = jax.grad(lambda t: (lfm2_moe.embedding_rows(
+    got = jax.grad(lambda t: (seq_layers.embedding_rows(
         t, ids, jnp.float32) * probe).sum())(table)
     want = jax.grad(lambda t: (t[ids] * probe).sum())(table)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-6)
@@ -243,9 +288,9 @@ def test_embedding_gradient_is_the_scatter_adds():
 def test_bfloat16_compute_stays_near_float32():
     """The configuration's precision: bfloat16 products, float32
     parameters, norms, router and loss."""
-    params = lfm2_moe.init_params(jax.random.key(2), config())
+    params = init_params(2, config())
     tokens, segments, positions = sequence(4)
-    losses = [float(lfm2_moe.sequence_loss(
+    losses = [float(sequence_loss(
         params, bias_rows(config(dt)), tokens, segments, positions,
         cfg=config(dt))[0]) for dt in ("float32", "bfloat16")]
     assert abs(losses[1] - losses[0]) < 5e-3 * abs(losses[0])

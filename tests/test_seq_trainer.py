@@ -7,6 +7,7 @@ import jax
 import numpy as np
 import pytest
 
+from dragonfly2_tpu.models.laguna import LagunaConfig, Rope
 from dragonfly2_tpu.models.lfm2_moe import Lfm2MoeConfig
 from dragonfly2_tpu.parallel import data_parallel_mesh
 from dragonfly2_tpu.train import step_budget
@@ -23,6 +24,25 @@ MODEL = Lfm2MoeConfig(
     num_experts=16, num_experts_per_tok=4, num_attention_heads=4,
     num_key_value_heads=2, vocab_size=64, experts_held=(4, 4),
     vocab_held=(0, 64))
+# The second family the job trains: a full layer with a dense FFN, two
+# sliding layers (window 8, groups of 3) with a shared expert and 4 of
+# 16 routed ones held.
+LAGUNA = LagunaConfig(
+    layer_types=("full_attention", "sliding_attention", "sliding_attention"),
+    mlp_layer_types=("dense", "sparse", "sparse"),
+    num_attention_heads_per_layer=(4, 6, 6), hidden_size=32,
+    intermediate_size=48, moe_intermediate_size=16,
+    shared_expert_intermediate_size=16, num_experts=16,
+    num_experts_per_tok=4, num_key_value_heads=2, head_dim=8, vocab_size=64,
+    sliding_window=8, moe_routed_scaling_factor=2.5,
+    rope=(("full_attention", Rope(
+        rope_theta=500000, rope_type="yarn", factor=64,
+        original_max_position_embeddings=4096, beta_fast=64,
+        partial_rotary_factor=0.5, attention_factor=1.4158883)),
+        ("sliding_attention", Rope(rope_theta=10000))),
+    experts_held=(4, 4), vocab_held=(0, 64))
+FAMILIES = pytest.mark.parametrize(
+    "model,window", [(MODEL, 0), (LAGUNA, 8)], ids=["lfm2_moe", "laguna"])
 SEQ = 32
 
 
@@ -61,13 +81,18 @@ def test_packer_splits_a_stream_at_the_end_id():
                                   [0, 1, 2, 0, 1, 0, 0, 1, 2, 3])
 
 
-def test_train_seq_reaches_finish_without_a_steady_compile():
+@FAMILIES
+def test_train_seq_reaches_finish_without_a_steady_compile(model, window):
+    """Both families through the one loop: the same counters, the same
+    one step program, and the loop's last-value entry for the window of
+    the sliding layers (0 for a family without one)."""
     corpus = pack_documents(documents(), SEQ)
     before = step_budget.TRAINING.snapshot()
     result = train_seq(corpus, SeqTrainConfig(
-        model=MODEL, batch_size=4, epochs=3, learning_rate=3e-3, seed=3,
+        model=model, batch_size=4, epochs=3, learning_rate=3e-3, seed=3,
         router_bias=tuple(np.linspace(-0.05, 0.05, 16))), one_device())
     after = step_budget.TRAINING.snapshot()
+    assert after["seq_attn_window"] == window
     steps = 3 * (corpus.tokens.shape[0] // 4)
     assert result.steps == steps and len(result.history) == 3
     assert result.history[-1] < result.history[0]
@@ -89,11 +114,12 @@ def test_train_seq_reaches_finish_without_a_steady_compile():
             - before["moe_assignments_hottest"] == held.max(1).sum())
 
 
-def test_data_parallel_is_one_device():
+@FAMILIES
+def test_data_parallel_is_one_device(model, window):
     """Two devices, each on half of a step's sequences, against one
     device on all of them: the same losses."""
     corpus = pack_documents(documents(1), SEQ)
-    config = SeqTrainConfig(model=MODEL, batch_size=4, epochs=1, seed=5)
+    config = SeqTrainConfig(model=model, batch_size=4, epochs=1, seed=5)
     one = train_seq(corpus, config, one_device())
     two = train_seq(corpus, config,
                     data_parallel_mesh(devices=jax.devices()[:2]))
@@ -129,6 +155,30 @@ def test_config_from_a_published_file():
         32, 2, 63)
 
 
+def test_config_from_a_published_file_names_its_family():
+    """``model_type`` picks the family; the published laguna keys and
+    what is held here come out of the one file."""
+    import json
+    import os
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "laguna-xs2-ep32.json")) as fh:
+        given = json.load(fh)
+    given = dict(given, num_experts=256, vocab_size=100352,
+                 layers=[0, 1, 2, 3, 4], experts_held=[0, 8],
+                 vocab_held=[0, 12544], batch_size=4, seq_len=8192)
+    config = config_from_dict(given)
+    assert isinstance(config.model, LagunaConfig)
+    assert config.model.expert_layers == (1, 2, 3, 4)
+    assert config.model.held_experts == (0, 8)
+    assert config.model.attention_window == 512
+    assert dict(config.model.rope)["full_attention"].factor == 64
+    assert (config.seq_len, config.batch_size) == (8192, 4)
+    with pytest.raises(ValueError, match="model_type"):
+        config_from_dict(dict(given, model_type="mamba"))
+
+
 class _Registry:
     def __init__(self):
         self.created = []
@@ -140,7 +190,9 @@ class _Registry:
         self.created.append(kwargs)
 
 
-def test_token_segments_round_trip_and_a_seq_model_is_registered(tmp_path):
+@FAMILIES
+def test_token_segments_round_trip_and_a_seq_model_is_registered(
+        tmp_path, model, window):
     from dragonfly2_tpu.train.checkpoint import load_model, seq_from_tree
     from dragonfly2_tpu.trainer import (
         TrainerStorage,
@@ -175,7 +227,7 @@ def test_token_segments_round_trip_and_a_seq_model_is_registered(tmp_path):
     training = Training(
         storage, registry,
         TrainingConfig(train_seq_model=True, seq=SeqTrainConfig(
-            model=MODEL, batch_size=2, seq_len=SEQ, epochs=1)),
+            model=model, batch_size=2, seq_len=SEQ, epochs=1)),
         mesh=one_device())
     outcome = training.train("10.0.0.1", "sched-1", "host-1")
     assert outcome.errors == []
@@ -185,6 +237,7 @@ def test_token_segments_round_trip_and_a_seq_model_is_registered(tmp_path):
     assert created["evaluation"]["n_samples"] % SEQ == 0
     params, counts = seq_from_tree(saved["tree"])
     assert saved["meta"].model_type == "seq"
+    assert saved["meta"].config["model_type"] == model.model_type
     assert counts.shape == (2, 16) and "layer_1" in params
     # The trained segments are gone; nothing is left to train.
     assert storage.token_files("host-1") == []
