@@ -24,6 +24,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 INIT_STD = 0.02
 
@@ -156,15 +157,73 @@ def dense_attention(q, k, v, segments, window: int | None = None):
 # writes a partial dq for every key block and sums them, which a window
 # that leaves most blocks empty does not repay (without a window it
 # does: 23.9 against 28.5 ms).
+# Without a window a tile that no document reaches (`document_tiles`)
+# is skipped as the tiles above the diagonal are. On the benchmark's
+# corpus (documents of median 700 tokens packed into 8k rows) a document
+# reaches 59.8% of a row's 36 causal tiles of 1,024 (59.1-60.5% by
+# seed) and 48.2% of its 136 of 512, where the same-document pairs are
+# 35.5% of the causal ones; of the sliding layers' 31 tiles 99.96% are
+# reached, so nothing is skipped there. Read on eight rows of that
+# corpus (PERF.md, PR 32; 48 heads of 128): 5.73 and 16.3 ms at 1,024
+# with 55.9% of the tiles kept (8.47 and 23.9 with none skipped), 7.14
+# and 21.5 at 512 with 44.1% kept (10.3 and 30.9): a grid step costs
+# some 0.7 us whether it computes or not, so the kernel's time is about
+# 0.27 + 0.73 x the kept share of what it was, and the smaller tile's
+# four times as many steps cost more than its emptier grid saves.
 ATTENTION_BLOCK, WINDOW_BLOCK = 1024, 512
+
+
+def document_tiles(segments, block: int):
+    """Which ``block`` x ``block`` score tiles on or under the diagonal
+    may hold a pair of one document: ``keep[..., i, j]`` for query block
+    ``i`` and key block ``j`` of ``segments`` ``[..., S]``, where the two
+    blocks' ranges of document ids overlap. Blocks with disjoint ranges
+    share no id, so no tile that holds a pair is dropped, whatever the
+    ids' order; for a packer's non-decreasing ids the rule is exact.
+    ``numpy`` in, ``numpy`` out; a ``jax`` array, traced or not, ``jax``
+    out."""
+    xp = jnp if isinstance(segments, jax.Array) else np
+    blocks = segments.reshape(*segments.shape[:-1], -1, block)
+    lo, hi = blocks.min(-1), blocks.max(-1)
+    at = xp.arange(lo.shape[-1])
+    return ((at[:, None] >= at[None, :])
+            & (hi[..., None, :] >= lo[..., :, None])
+            & (lo[..., None, :] <= hi[..., :, None]))
+
+
+def _next_kept(keep):
+    """For each tile of ``keep`` ``[rows, columns]``, in row-major
+    order, the column of the next kept tile at or after it; 0 past the
+    last."""
+    at = jnp.arange(keep.size)
+    following = jax.lax.cummin(
+        jnp.where(keep.ravel(), at, keep.size), reverse=True)
+    column = jnp.where(following < keep.size, following % keep.shape[1], 0)
+    return column.reshape(keep.shape)
+
+
+def document_tile_tables(segments, block: int):
+    """The splash kernel's run-time tables for a causal mask over this
+    sequence's documents, ``[n, n]`` over ``[query block, key block]``:
+    ``keep`` (:func:`document_tiles`) and the two ``data_next`` tables,
+    laid out as ``splash_attention_mask_info.process_mask`` and
+    ``process_mask_dkv`` (unshrunk, as the fused backward takes it) lay
+    out theirs. Forward: the key block of the next kept tile along the
+    forward grid (query blocks outer, key blocks inner), which the
+    kernel fetches while a dropped tile passes. Backward: the query
+    block of the next kept tile along the dkv grid (key blocks outer,
+    query blocks inner)."""
+    keep = document_tiles(segments, block)
+    return keep, _next_kept(keep), _next_kept(keep.T).T
 
 
 def kernel_attention(q, k, v, segments, window: int | None = None,
                      interpret: bool = False):
     """The same attention through JAX's splash-attention kernel (TPU):
-    no score matrix in HBM, tiles above the diagonal and tiles wholly
-    before the window skipped, one key-value head shared by its group of
-    query heads."""
+    no score matrix in HBM, tiles above the diagonal, tiles wholly
+    before the window and (without a window) tiles that no document
+    reaches skipped, one key-value head shared by its group of query
+    heads."""
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as kernel,
         splash_attention_mask as masks,
@@ -189,6 +248,21 @@ def kernel_attention(q, k, v, segments, window: int | None = None,
     attend = kernel.make_splash_mqa_single_device(
         masks.MultiHeadMask([mask] * group),
         block_sizes=sizes, interpret=interpret)
+    if window is None:
+        # The same kernel with the tiles that none of this sequence's
+        # documents reaches switched off in its run-time tables: a kept
+        # tile is computed as before, a dropped one held masked scores
+        # alone, which add exact zeros.
+        keep, forward_next, dkv_next = document_tile_tables(segments, block)
+
+        def kept(info, data_next):
+            return info._replace(
+                block_mask=jnp.where(keep, info.block_mask, 0),
+                data_next=data_next[None].astype(info.data_next.dtype))
+
+        attend = kernel.SplashAttentionKernel(
+            kept(attend.fwd_mask_info, forward_next), None,
+            kept(attend.dkv_mask_info, dkv_next), **attend.kwargs)
     ids = kernel.SegmentIds(q=segments, kv=segments)
     out = jax.vmap(lambda q_, k_, v_: attend(q_, k_, v_, segment_ids=ids))(
         q.reshape(s, kv, group, hd).transpose(1, 2, 0, 3),

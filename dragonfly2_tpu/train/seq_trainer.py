@@ -253,9 +253,19 @@ def train_seq(
         for a in (corpus.tokens, corpus.segments, corpus.positions))
 
     train_step = build_train_step(cfg, mesh)
-    # Last value set, and on every step's span: which attention the
-    # loop's sliding layers ran (docs/OBSERVABILITY.md).
-    TRAINING.set(seq_attn_window=cfg.attention_window)
+    # Last values set (docs/OBSERVABILITY.md): which attention the
+    # loop's sliding layers ran (on every step's span too), and of the
+    # corpus's causal tiles at the full-attention kernel's tile those
+    # that a document reaches, which are the ones the kernel computes.
+    tiles = tiles_kept = 0
+    block = min(seq_layers.ATTENTION_BLOCK, seq_len)
+    if seq_len % block == 0 and any(
+            cfg.layer_types[i] == "full_attention" for i in cfg.kept_layers):
+        keep = seq_layers.document_tiles(corpus.segments, block)
+        tiles = rows * keep.shape[-1] * (keep.shape[-1] + 1) // 2
+        tiles_kept = int(keep.sum())
+    TRAINING.set(seq_attn_window=cfg.attention_window,
+                 seq_attn_tiles=tiles, seq_attn_tiles_kept=tiles_kept)
 
     budget = StepBudget(config.max_seconds, step_samples=batch * seq_len)
     rng = np.random.default_rng((config.seed, 11))

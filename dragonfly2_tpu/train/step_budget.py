@@ -74,13 +74,23 @@ class TrainingStats:
     - ``seq_attn_window``: the last value set too, where ``train_seq``
       builds its step: the window of the model's sliding-attention
       layers in tokens, or 0 for a family (or a cut) without one.
+    - ``seq_attn_tiles``, ``seq_attn_tiles_kept``: the last values set
+      there too, from the corpus's document ids on the host: the score
+      tiles on or under the diagonal of the corpus's rows at the
+      full-attention kernel's tile (rows times n(n+1)/2 for n tiles a
+      row), and those of them that a document reaches
+      (``seq_layers.document_tiles``), which are the ones the TPU kernel
+      computes; the rest it skips. Both 0 for a cut without a
+      full-attention layer (and for rows that are no whole number of
+      tiles, which the kernel does not take).
     """
 
     KEYS = ("loops_started", "dispatches", "steps", "samples",
             "compile_seconds", "loop_compiles", "steady_compiles",
             "moe_steps", "moe_assignments_held", "moe_assignments_hottest",
             "sampler_row_width", "attn_inverse_slots",
-            "attn_inverse_filled", "seq_attn_window")
+            "attn_inverse_filled", "seq_attn_window", "seq_attn_tiles",
+            "seq_attn_tiles_kept")
 
     def __init__(self):
         self._lock = threading.Lock()

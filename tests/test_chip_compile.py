@@ -230,7 +230,27 @@ def test_sequence_attention_kernel_at_the_cells_shape(one_chip, heads, hd,
         jax.grad(loss, argnums=(0, 1, 2)),
         s((SEQ, heads, hd), jnp.bfloat16), s((SEQ, kv_heads, hd), jnp.bfloat16),
         s((SEQ, kv_heads, hd), jnp.bfloat16), s((SEQ,), jnp.int32))
-    assert compiled.as_text().count("tpu_custom_call") >= 2
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 2
+    if window is None:
+        # The full layers' tile tables (block_mask and data_next, the
+        # kernels' first two operands) are computed from the segments:
+        # behind any copy or bitcast stands a fusion, not a constant
+        # baked into the program as the static causal mask's were.
+        made_by = dict(re.findall(
+            r"^\s*(%[\w.\-]+) = \S+ ([\w\-]+)\(", text, re.M))
+        source = {name: arg for name, op, arg in re.findall(
+            r"^\s*(%[\w.\-]+) = \S+ (copy|bitcast)\((%[\w.\-]+)\)",
+            text, re.M)}
+        calls = re.findall(
+            r"= \([^\n]*? custom-call\((%[\w.\-]+), (%[\w.\-]+),[^\n]*"
+            r"operand_layout_constraints=\{s8\[1,8,8\]\{2,1,0\}, "
+            r"s8\[1,8,8\]", text)
+        assert len(calls) == 2, calls
+        for table in (t for call in calls for t in call):
+            while table in source:
+                table = source[table]
+            assert made_by[table] == "fusion", (table, made_by[table])
     # No [heads, S, S] score matrix (32 x 8192 x 8192 float32 is 8.6
     # GB): 0.63, 0.86 and 2.30 GB, the sliding layers' the most because
     # the fused backward keeps a partial dq for each of its 16 key

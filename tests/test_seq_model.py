@@ -248,6 +248,156 @@ def test_the_attention_kernel_is_the_plain_attention(window, groups,
                                    rtol=2e-2, atol=2e-3)
 
 
+# Six documents in 1,024 tokens: at 128-wide tiles they reach 17 of the
+# 36 tiles on or under the diagonal.
+DOCUMENTS = [100, 28, 300, 40, 200, 356]
+
+
+def _library_tables(mask, block, backward):
+    """``block_mask`` and ``data_next`` as the kernel's own mask
+    processing makes them for a static mask, the backward's unshrunk as
+    the fused kernel takes it."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_mask as masks,
+        splash_attention_mask_info as mask_info,
+    )
+
+    if isinstance(mask, np.ndarray):
+        mask = masks.NumpyMask(mask)
+    heads = masks.MultiHeadMask([mask] * 2)
+    info, _ = (mask_info.process_mask_dkv(heads, (block, block),
+                                          shrink_grid=False)
+               if backward else
+               mask_info.process_mask(heads, (block, block),
+                                      shrink_grid=not isinstance(
+                                          mask, masks.NumpyMask)))
+    return np.asarray(info.block_mask), np.asarray(info.data_next)
+
+
+@pytest.mark.parametrize("backward", [False, True], ids=["forward", "dkv"])
+@pytest.mark.parametrize("block", [128, 256, 512])
+def test_one_documents_tile_tables_are_the_librarys(block, backward):
+    """A row that is one document: every causal tile kept, and the
+    computed ``data_next`` is ``process_mask``'s (forward) and
+    ``process_mask_dkv``'s (backward) for the static causal mask,
+    element for element."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_mask as masks,
+    )
+
+    s = 1024
+    keep, *following = seq_layers.document_tile_tables(
+        jnp.full(s, 7, jnp.int32), block)
+    block_mask, data_next = _library_tables(
+        masks.CausalMask((s, s)), block, backward)
+    np.testing.assert_array_equal(
+        np.where(np.asarray(keep), block_mask, 0), block_mask)
+    np.testing.assert_array_equal(
+        np.asarray(following[backward])[None], data_next)
+
+
+@pytest.mark.parametrize("backward", [False, True], ids=["forward", "dkv"])
+@pytest.mark.parametrize("block", [128, 256])
+def test_several_documents_tile_tables_are_the_librarys(block, backward):
+    """With documents the convention still holds: ``data_next`` equals
+    what the library computes for a static mask whose tiles are the
+    kept ones."""
+    segments = np.repeat(np.arange(len(DOCUMENTS)), DOCUMENTS)
+    keep, *following = seq_layers.document_tile_tables(
+        jnp.asarray(segments, jnp.int32), block)
+    keep = np.asarray(keep)
+    assert keep.sum() == {128: 17, 256: 7}[block]
+    block_mask, data_next = _library_tables(
+        np.kron(keep, np.ones((block, block), bool)), block, backward)
+    np.testing.assert_array_equal(block_mask[0] > 0, keep)
+    np.testing.assert_array_equal(
+        np.asarray(following[backward])[None], data_next)
+
+
+def _tiles_with_a_pair(segments, block):
+    """Brute force: some pair of the two blocks shares an id and is
+    causal."""
+    at = np.arange(len(segments))
+    seen = (at[:, None] >= at[None, :]) & (
+        segments[:, None] == segments[None, :])
+    n = len(segments) // block
+    return seen.reshape(n, block, n, block).any((1, 3))
+
+
+@pytest.mark.parametrize("array", [np.asarray, jnp.asarray],
+                         ids=["numpy", "jax"])
+@pytest.mark.parametrize("ids", ["sorted", "shuffled"])
+@pytest.mark.parametrize("block", [16, 64])
+def test_kept_tiles_against_every_pair(block, ids, array):
+    """``document_tiles`` against the brute force: equal for a packer's
+    non-decreasing ids, a superset (never a tile with a pair dropped)
+    for ids out of order and repeated; the same rule on ``numpy`` and
+    ``jax`` arrays, and over a batch of rows."""
+    rng = np.random.default_rng(block)
+    rows = []
+    for _ in range(6):
+        lengths = rng.integers(1, 120, 40)
+        row = np.repeat(np.arange(40), lengths)[:512].astype(np.int32)
+        if ids == "shuffled":
+            # Ids permuted (out of order) and folded (repeated: two
+            # documents apart share an id).
+            row = rng.permutation(40)[row] % 11
+        rows.append(row)
+    rows = np.stack(rows)
+    keep = seq_layers.document_tiles(array(rows), block)
+    assert isinstance(keep, np.ndarray) == (array is np.asarray)
+    keep = np.asarray(keep)
+    assert keep.shape == (6, 512 // block, 512 // block)
+    for row, kept in zip(rows, keep):
+        pairs = _tiles_with_a_pair(row, block)
+        if ids == "sorted":
+            np.testing.assert_array_equal(kept, pairs)
+        else:
+            assert (kept | ~pairs).all()
+            assert not np.triu(kept, 1).any()
+        np.testing.assert_array_equal(
+            kept, np.asarray(seq_layers.document_tiles(array(row), block)))
+
+
+@pytest.mark.parametrize("ids", ["in_order", "out_of_order"])
+def test_the_attention_kernel_skips_tiles_no_document_reaches(ids,
+                                                              monkeypatch):
+    """The kernel's path with computed tile tables (interpret mode,
+    128-wide tiles, six documents: 17 of 36 tiles kept) against the
+    plain form, values and gradients, and the same row with its ids
+    permuted out of order (the rule keeps more tiles, never fewer than
+    hold a pair)."""
+    rng = np.random.default_rng(3)
+    s, kv_heads, hd, groups = sum(DOCUMENTS), 2, 64, 2
+    q, k, v = (jnp.asarray(rng.standard_normal((s, h, hd)) * 0.3, jnp.float32)
+               for h in (groups * kv_heads, kv_heads, kv_heads))
+    names = (np.arange(len(DOCUMENTS)) if ids == "in_order"
+             else np.array([4, 0, 5, 2, 1, 3]))
+    segments = np.repeat(names, DOCUMENTS).astype(np.int32)
+    monkeypatch.setattr(seq_layers, "ATTENTION_BLOCK", 128)
+    kept = int(seq_layers.document_tiles(segments, 128).sum())
+    assert kept == 17 if ids == "in_order" else 17 < kept < 36
+    segments = jnp.asarray(segments)
+
+    def kernel(q, k, v, seg):
+        return seq_layers.kernel_attention(q, k, v, seg, interpret=True)
+
+    def total(fn, q, k, v):
+        return (fn(q, k, v, segments) ** 2).sum()
+
+    np.testing.assert_allclose(
+        np.asarray(jax.jit(kernel)(q, k, v, segments)),
+        np.asarray(seq_layers.dense_attention(q, k, v, segments)),
+        rtol=2e-3, atol=2e-3)
+    got = jax.jit(jax.grad(lambda *a: total(kernel, *a),
+                           argnums=(0, 1, 2)))(q, k, v)
+    want = jax.grad(lambda *a: total(seq_layers.dense_attention, *a),
+                    argnums=(0, 1, 2))(q, k, v)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=2e-2, atol=2e-3)
+
+
 def test_a_window_is_the_causal_mask_cut_at_its_length():
     """``t - s < window`` and the token itself counts: a window as long
     as the sequence changes nothing, a window of 1 returns each token's

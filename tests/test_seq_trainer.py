@@ -93,6 +93,10 @@ def test_train_seq_reaches_finish_without_a_steady_compile(model, window):
         router_bias=tuple(np.linspace(-0.05, 0.05, 16))), one_device())
     after = step_budget.TRAINING.snapshot()
     assert after["seq_attn_window"] == window
+    # Both cuts run a full-attention layer: every row is one tile at
+    # this length, and a row's own tile is always reached.
+    rows = corpus.tokens.shape[0]
+    assert after["seq_attn_tiles"] == after["seq_attn_tiles_kept"] == rows
     steps = 3 * (corpus.tokens.shape[0] // 4)
     assert result.steps == steps and len(result.history) == 3
     assert result.history[-1] < result.history[0]
@@ -112,6 +116,43 @@ def test_train_seq_reaches_finish_without_a_steady_compile(model, window):
             == held.sum())
     assert (after["moe_assignments_hottest"]
             - before["moe_assignments_hottest"] == held.max(1).sum())
+
+
+@pytest.mark.parametrize("kept_layers,block,tiles", [
+    ((0, 1, 2), 8, 10), ((0, 1, 2), 16, 3), ((0, 2), 8, 0),
+    ((1,), 8, 10), ((0, 1, 2), 12, 0)],
+    ids=["tile_8", "tile_16", "no_full_layer", "full_layer_alone",
+         "rows_no_whole_tiles"])
+def test_train_seq_counts_the_tiles_a_document_reaches(
+        kept_layers, block, tiles, monkeypatch):
+    """``seq_attn_tiles`` / ``seq_attn_tiles_kept``: the corpus's causal
+    tiles at the kernel's tile and those a document reaches, by the rule
+    the kernel's tables are built with; 0 and 0 for a cut that runs no
+    full-attention layer."""
+    import dataclasses
+
+    from dragonfly2_tpu.models import seq_layers
+
+    monkeypatch.setattr(seq_layers, "ATTENTION_BLOCK", block)
+    corpus = pack_documents(documents(4), SEQ)
+    rows = corpus.tokens.shape[0]
+    train_seq(corpus, SeqTrainConfig(
+        model=dataclasses.replace(MODEL, layers=kept_layers), batch_size=4,
+        epochs=1), one_device())
+    after = step_budget.TRAINING.snapshot()
+    assert after["seq_attn_tiles"] == rows * tiles
+    if not tiles:
+        assert after["seq_attn_tiles_kept"] == 0
+        return
+    # A row's diagonal tiles are always reached; documents of 3-49
+    # tokens leave some of the 8-wide tiles under it empty.
+    kept = after["seq_attn_tiles_kept"]
+    assert rows * SEQ // block <= kept <= rows * tiles
+    assert kept == sum(
+        int(seq_layers.document_tiles(row, block).sum())
+        for row in corpus.segments)
+    if block == 8:
+        assert kept < rows * tiles
 
 
 @FAMILIES
