@@ -39,7 +39,7 @@ def main(argv=None) -> int:
                              "from the host's token segments each cycle; "
                              "the file holds the model's published "
                              "config.json keys, its model_type naming "
-                             "the family (lfm2_moe or laguna), and may "
+                             "the family (lfm2_moe, laguna or KeyeVL2), and may "
                              "add what is held here and the job's "
                              "settings (train/seq_trainer.py "
                              "config_from_dict)")

@@ -1,9 +1,12 @@
-"""What the sequence-model families (``lfm2_moe``, ``laguna``) share:
-parameters drawn from a list of shapes, the RMS norm, rotate-half RoPE,
-the embedding lookup with its one-hot backward, causal same-document
+"""What the sequence-model families (``lfm2_moe``, ``laguna``,
+``keye_vl2``) share: parameters drawn from a list of shapes, the RMS
+norm, rotate-half RoPE over one position stream or several, the
+embedding lookup with its one-hot backward, causal same-document
 attention with or without a window (plain, and through JAX's
-splash-attention kernel on a TPU), the gated FFN, the summed next-token
-loss, and the per-sequence recomputed loss of a batch.
+splash-attention kernel on a TPU), attention over a learned selection of
+keys (an indexer's scores, an exact top-k a query, the softmax over the
+kept keys alone), the gated FFN, the summed next-token loss, and the
+per-sequence recomputed loss of a batch.
 
 A family is a module with ``param_shapes(cfg)`` and ``block(p, x,
 router_bias, segments, positions, *, cfg, layer)``, and a config that
@@ -51,9 +54,10 @@ class HeldShare:
 def init_params(key, shapes: list) -> dict:
     """Parameters drawn operation by operation (a compiled init rounds
     differently on a v5e; PERF.md, PR 25): leaf ``n`` of ``shapes``
-    (``[(path, shape, "normal" | "ones")]``, a family's
-    ``param_shapes``) is ``normal(fold_in(key, n)) · 0.02``, a norm's
-    weight is ones."""
+    (``[(path, shape, "normal" | "ones" | "zeros" | std)]``, a family's
+    ``param_shapes``) is ``normal(fold_in(key, n)) · 0.02`` (or times
+    the ``std`` a family gives in the kind's place), a norm's weight is
+    ones and its bias zeros."""
     params: dict = {}
     for n, (path, shape, kind) in enumerate(shapes):
         node = params
@@ -61,8 +65,9 @@ def init_params(key, shapes: list) -> dict:
             node = node.setdefault(part, {})
         node[path[-1]] = (
             jnp.ones(shape, jnp.float32) if kind == "ones" else
+            jnp.zeros(shape, jnp.float32) if kind == "zeros" else
             jax.random.normal(jax.random.fold_in(key, n), shape, jnp.float32)
-            * jnp.float32(INIT_STD))
+            * jnp.float32(INIT_STD if kind == "normal" else kind))
     return params
 
 
@@ -79,13 +84,25 @@ def rope_frequencies(theta: float, lanes: int):
     return 1.0 / theta ** (jnp.arange(half, dtype=jnp.float32) / half)
 
 
-def rope(x, positions, inv_freq, scale: float | None = None):
+def rope(x, positions, inv_freq, scale: float | None = None,
+         sections: tuple | None = None):
     """Rotate-half rotary embedding of ``x`` [S, heads, head] at
     ``positions`` [S], in float32: the first ``2 · len(inv_freq)`` lanes
     of each head are rotated (lane ``i`` with lane ``i + len(inv_freq)``),
-    the rest pass; ``scale`` multiplies cos and sin."""
+    the rest pass; ``scale`` multiplies cos and sin. With ``sections``
+    (how many frequency pairs each position stream turns, in chunks:
+    the first ``sections[0]`` pairs by stream 0, and so on)
+    ``positions`` is ``[streams, S]``."""
     half = inv_freq.shape[0]
-    angle = positions.astype(jnp.float32)[:, None] * inv_freq
+    if sections is not None:
+        if sum(sections) != half or len(sections) != positions.shape[0]:
+            raise ValueError(f"sections {sections} over {half} frequency "
+                             f"pairs and {positions.shape[0]} streams")
+        positions = positions[np.repeat(np.arange(len(sections)),
+                                        sections)].T         # [S, half]
+        angle = positions.astype(jnp.float32) * inv_freq
+    else:
+        angle = positions.astype(jnp.float32)[:, None] * inv_freq
     cos = jnp.concatenate([jnp.cos(angle)] * 2, -1)[:, None, :]
     sin = jnp.concatenate([jnp.sin(angle)] * 2, -1)[:, None, :]
     if scale is not None:
@@ -125,21 +142,24 @@ def _embedding_rows_bwd(dtype, saved, g):
 embedding_rows.defvjp(_embedding_rows_fwd, _embedding_rows_bwd)
 
 
-def dense_attention(q, k, v, segments, window: int | None = None):
+def dense_attention(q, k, v, segments, window: int | None = None,
+                    seen=None):
     """Causal same-document attention, scores held whole: q [S, H, hd]
     already scaled, k and v [S, KV, hd]; query ``t`` sees key ``s`` where
     ``s <= t`` in the same document and, with a ``window``, ``t - s <
-    window``. The plain form, for sizes at which ``[H, S, S]`` fits."""
+    window``; or where ``seen`` ``[S, S]`` says so, if that is given.
+    The plain form, for sizes at which ``[H, S, S]`` fits."""
     s, h, hd = q.shape
     group = h // k.shape[1]
     q = q.reshape(s, k.shape[1], group, hd)
     scores = jnp.einsum("sjgd,tjd->jgst", q, k,
                         preferred_element_type=jnp.float32)
-    at = jnp.arange(s)
-    seen = (at[:, None] >= at[None, :]) & (
-        segments[:, None] == segments[None, :])
-    if window is not None:
-        seen = seen & (at[:, None] - at[None, :] < window)
+    if seen is None:
+        at = jnp.arange(s)
+        seen = (at[:, None] >= at[None, :]) & (
+            segments[:, None] == segments[None, :])
+        if window is not None:
+            seen = seen & (at[:, None] - at[None, :] < window)
     probs = jax.nn.softmax(jnp.where(seen, scores, -1e30), axis=-1)
     out = jnp.einsum("jgst,tjd->sjgd", probs.astype(v.dtype), v)
     return out.reshape(s, h, hd)
@@ -283,25 +303,237 @@ def attention(q, k, v, segments, window: int | None = None):
         return dense_attention(q, k, v, segments, window)
 
 
+# -- attention over a learned selection of keys ------------------------------
+#
+# An indexer scores every earlier token of a query's document, the
+# ``top_k`` best are kept, and the softmax runs over those alone. The
+# selection is a function of the data, exact on the scores computed, and
+# piecewise constant: nothing differentiates through it.
+
+# Queries ranked at a time: a panel's scores ``[panel, heads, keys]``
+# are the unit of memory of the indexer, and the keys a panel reads are
+# the shortest of a few static widths (twice the panel, doubling up to
+# the sequence) that reaches back to where its documents start. Read on
+# a v5e at the benchmark cell's shapes (16 heads of 64, 2,048 kept, 32k
+# rows of six documents of 16k to 1k; PERF.md, PR 33): scores 7.8 ms and
+# ranking 20.4 ms a sequence and layer.
+SELECT_PANEL = 512
+# The selection is held one bit a pair, packed block by block of this
+# many keys (``models/selected_attention.py``: the TPU kernels' tile).
+SELECT_BLOCK = 1024
+# The name under which a block's selection is kept for the backward pass
+# (``sequence_loss``'s ``saved``).
+SELECTION = "df2_selection"
+
+
+def select_block(length: int) -> int:
+    """The packing block of a sequence of ``length``: :data:`SELECT_BLOCK`
+    where that divides it, else the whole sequence (a multiple of 8)."""
+    return SELECT_BLOCK if length % SELECT_BLOCK == 0 else length
+
+
+def index_scores(q, k, w):
+    """``I[t, s] = Σ_j w[t, j] · relu(q[t, j] · k[s])``, accumulated in
+    float32: ``q`` [T, heads, d] the indexer's queries, ``k`` [K, d] its
+    one shared key head, ``w`` [T, heads] float32."""
+    per_head = jnp.einsum("thd,kd->thk", q, k,
+                          preferred_element_type=jnp.float32)
+    return (w[:, :, None] * jax.nn.relu(per_head)).sum(1)
+
+
+def top_k_mask(scores, candidates, top_k: int):
+    """For each row of ``scores`` [T, K] (float32) the ``min(c, top_k)``
+    ``candidates`` [T, K] with the largest score, ``c`` the row's number
+    of candidates, as a mask; ties at the last place go to the lower
+    position (the rule of ``jax.lax.top_k``). Exact: the ``top_k``-th
+    largest score is found bit by bit (32 counts over the row), not by
+    a sort, and nothing is approximated."""
+    # Scores as unsigned integers in the same order; -0.0 is 0.0, and a
+    # key that is no candidate is 0, under every candidate's.
+    bits = jax.lax.bitcast_convert_type(
+        jnp.where(scores == 0, 0.0, scores), jnp.uint32)
+    negative = bits >> 31 == 1
+    key = jnp.where(negative, ~bits, bits | jnp.uint32(1 << 31))
+    key = jnp.where(candidates, jnp.maximum(key, 1), 0)
+
+    def larger_bit(i, least):
+        tried = least | (jnp.uint32(1 << 31) >> i.astype(jnp.uint32))
+        enough = (key >= tried[:, None]).sum(-1, dtype=jnp.int32) >= top_k
+        return jnp.where(enough, tried, least)
+
+    # The largest value that ``top_k`` keys reach: the last kept score.
+    least = jax.lax.fori_loop(0, 32, larger_bit,
+                              jnp.zeros(key.shape[0], jnp.uint32))
+    reach = key >= least[:, None]
+    few = candidates.sum(-1, dtype=jnp.int32) <= top_k
+
+    def with_ties():
+        # More keys reach the last kept score than there are places:
+        # those above it, and of its equals the first by position.
+        above = key > least[:, None]
+        equal = key == least[:, None]
+        left = top_k - above.sum(-1, dtype=jnp.int32)
+        return above | (equal & (jnp.cumsum(equal, -1, dtype=jnp.int32)
+                                 <= left[:, None]))
+
+    tied = (~few & (reach.sum(-1, dtype=jnp.int32) > top_k)).any()
+    kept = jax.lax.cond(tied, with_ties, lambda: reach)
+    return candidates & (few[:, None] | kept)
+
+
+def _panel_widths(length: int, panel: int) -> list:
+    widths, w = [], 2 * panel
+    while w < length:
+        widths.append(w)
+        w *= 2
+    return widths + [length]
+
+
+def select_keys(q, k, w, segments, top_k: int):
+    """The selection of every query of one sequence, as a mask over
+    ``[query, key]`` held one bit a pair (``[S, S / 8]`` uint8,
+    ``selected_attention.pack_mask`` at :func:`select_block`), and how
+    many candidates and members it has (two int32 ``[panels]`` vectors:
+    a panel's counts fit, a sequence's need not). Query ``t``'s
+    candidates are the ``s <= t`` of its document (equal ``segments``),
+    itself among them; its members are the ``min(c_t, top_k)``
+    candidates of the largest :func:`index_scores`.
+
+    Scores are made, ranked and dropped a panel of queries at a time,
+    against the keys from where the panel's documents start (for a
+    packer's non-decreasing ids; for any ids, from the first position
+    whose id lies within the panel's range, which is no later) to the
+    panel's end, in a buffer of the shortest static width that holds
+    them: work follows the documents' lengths, not the sequence's."""
+    from dragonfly2_tpu.models.selected_attention import pack_mask
+
+    length = q.shape[0]
+    panel, block = min(SELECT_PANEL, length), select_block(length)
+    if length % panel or length % 8:
+        raise ValueError(f"{length} positions in panels of {panel}")
+    q, k, w, segments = jax.lax.stop_gradient((q, k, w, segments))
+    widths = _panel_widths(length, panel)
+    rows = segments.reshape(-1, panel)
+    within = ((segments >= rows.min(-1, keepdims=True))
+              & (segments <= rows.max(-1, keepdims=True)))    # [panels, S]
+    ends = (jnp.arange(rows.shape[0], dtype=jnp.int32) + 1) * panel
+    reach = ends - jnp.argmax(within, -1).astype(jnp.int32)
+
+    def ranked(width, end):
+        first = jnp.maximum(end - width, 0)
+        at_q = end - panel + jnp.arange(panel, dtype=jnp.int32)
+        at_k = first + jnp.arange(width, dtype=jnp.int32)
+        seg_q = jax.lax.dynamic_slice(segments, (end - panel,), (panel,))
+        seg_k = jax.lax.dynamic_slice(segments, (first,), (width,))
+        candidates = ((at_k[None, :] <= at_q[:, None])
+                      & (seg_k[None, :] == seg_q[:, None]))
+        with jax.named_scope("df2.seq.index"):
+            scores = index_scores(
+                jax.lax.dynamic_slice_in_dim(q, end - panel, panel),
+                jax.lax.dynamic_slice_in_dim(k, first, width),
+                jax.lax.dynamic_slice_in_dim(w, end - panel, panel))
+        with jax.named_scope("df2.seq.select"):
+            kept = top_k_mask(scores, candidates, top_k)
+            row = jax.lax.dynamic_update_slice(
+                jnp.zeros((panel, length), bool), kept, (0, first))
+            return (pack_mask(row, block), candidates.sum(dtype=jnp.int32),
+                    kept.sum(dtype=jnp.int32))
+
+    def one(args):
+        end, needed = args
+        if len(widths) == 1:
+            return ranked(widths[0], end)
+        rung = sum((needed > w).astype(jnp.int32) for w in widths[:-1])
+        return jax.lax.switch(rung, [partial(ranked, w) for w in widths],
+                              end)
+
+    packed, candidates, members = jax.lax.map(one, (ends, reach))
+    return packed.reshape(length, length // 8), candidates, members
+
+
+def count_limbs(counts):
+    """Non-negative int32 counts as three 16-bit limbs (``[..., 3]``
+    uint32, least first): sums of up to 65,536 of them limb by limb
+    cannot overflow, and :func:`carry_limbs` brings a sum back to 16
+    bits a limb (48 bits in all), where a plain uint32 would wrap
+    within a dozen steps of 32k-token sequences."""
+    counts = counts.astype(jnp.uint32)
+    return jnp.stack([counts & 0xFFFF, counts >> 16,
+                      jnp.zeros_like(counts)], -1)
+
+
+def carry_limbs(limbs):
+    low, mid, high = limbs[..., 0], limbs[..., 1], limbs[..., 2]
+    mid = mid + (low >> 16)
+    return jnp.stack([low & 0xFFFF, mid & 0xFFFF, high + (mid >> 16)], -1)
+
+
+def limbs_value(limbs) -> np.ndarray:
+    """What :func:`count_limbs` limbs hold, as Python-sized integers
+    (host)."""
+    limbs = np.asarray(limbs).astype(object)
+    return limbs[..., 0] + (limbs[..., 1] << 16) + (limbs[..., 2] << 32)
+
+
+def selected_attention(q, k, v, packed):
+    """Softmax attention of q [S, H, hd] (already scaled) over the keys
+    the selection ``packed`` (:func:`select_keys`) keeps for each query,
+    one selection for all heads, under ``df2.seq.attn_sparse``: the
+    kernels of ``models/selected_attention.py`` on a TPU, which read the
+    packed bits; elsewhere, and for a sequence that is no whole number
+    of their tiles, the plain form."""
+    from dragonfly2_tpu.models import selected_attention as kernels
+
+    s, h, hd = q.shape
+    block = select_block(s)
+    with jax.named_scope("df2.seq.attn_sparse"):
+        if jax.devices()[0].platform == "tpu" and block == SELECT_BLOCK:
+            out = kernels.packed_attention(
+                q.transpose(1, 0, 2), k.transpose(1, 0, 2),
+                v.transpose(1, 0, 2), packed, block)
+            return out.transpose(1, 0, 2)
+        return dense_attention(q, k, v, None,
+                               seen=kernels.unpack_mask(packed, block))
+
+
 def gated_ffn(p, a):
     dt = a.dtype
     return (jax.nn.silu(a @ p["w1"].astype(dt)) * (a @ p["w3"].astype(dt))
             ) @ p["w2"].astype(dt)
 
 
+# Positions whose logits are held at a time where a sequence is longer:
+# at 32,768 positions against 18,992 rows one sequence's float32 logits
+# are 2.5 GB; a block's are kept for neither pass and made again in the
+# backward one. A sequence no longer than this is one block, as it was.
+HEAD_BLOCK = 8192
+
+
 def head_loss(head, final_norm, x, local, segments, *, cfg):
     """The summed cross-entropy of one sequence's next tokens, over the
     positions whose next token is in the same document. ``head``: the
     output rows held ``[rows, hidden]``; ``local``: token ids as rows of
-    it."""
-    dt = x.dtype
-    x = rms_norm(x, final_norm, cfg.norm_eps)
-    logits = jnp.matmul(x, head.astype(dt).T,
-                        preferred_element_type=jnp.float32)
-    target = jnp.roll(local, -1)
-    hit = jnp.arange(head.shape[0])[None, :] == target[:, None]
-    nll = jax.nn.logsumexp(logits, -1) - jnp.where(hit, logits, 0).sum(-1)
-    return jnp.where(target_positions(segments), nll, 0).sum()
+    it. A sequence longer than :data:`HEAD_BLOCK` positions is a whole
+    number of blocks of that many, one at a time."""
+    length, dt = x.shape[0], x.dtype
+    if length > HEAD_BLOCK and length % HEAD_BLOCK:
+        raise ValueError(f"{length} positions are neither one block of the "
+                         f"head's loss nor whole blocks of {HEAD_BLOCK}")
+
+    def one(args):
+        x, target, counted = args
+        logits = jnp.matmul(rms_norm(x, final_norm, cfg.norm_eps),
+                            head.astype(dt).T,
+                            preferred_element_type=jnp.float32)
+        hit = jnp.arange(head.shape[0])[None, :] == target[:, None]
+        nll = jax.nn.logsumexp(logits, -1) - jnp.where(hit, logits, 0).sum(-1)
+        return jnp.where(counted, nll, 0).sum()
+
+    whole = (x, jnp.roll(local, -1), target_positions(segments))
+    if length <= HEAD_BLOCK:
+        return one(whole)
+    return jax.lax.map(jax.checkpoint(one), tuple(
+        a.reshape(-1, HEAD_BLOCK, *a.shape[1:]) for a in whole)).sum()
 
 
 def target_positions(segments):
@@ -312,14 +544,20 @@ def target_positions(segments):
 
 
 def sequence_loss(params, router_bias, tokens, segments, positions, *,
-                  cfg, block):
+                  cfg, block, saved=None):
     """One packed sequence ``[S]`` through a family's ``block``s: the
-    summed cross-entropy over :func:`target_positions` and each expert
-    layer's assignment counts ``[expert layers, E]``. ``router_bias``:
-    ``[expert layers, E]``. The logits are against ``lm_head`` where the
-    family has one, else against the embedding rows (tied). Each block,
-    and the head with the loss, keeps its input alone for the backward
-    pass and is computed again there."""
+    summed cross-entropy over :func:`target_positions` and what each
+    expert layer's block counted, stacked over the expert layers: the
+    assignment counts ``[expert layers, E]`` (with whatever else the
+    family's block counts beside them, as a tuple of such stacks).
+    ``router_bias``: ``[expert layers, E]``. The logits are against
+    ``lm_head`` where the family has one, else against the embedding
+    rows (tied). Each block, and the head with the loss, keeps its input
+    alone for the backward pass and is computed again there; ``saved``
+    (names of ``jax.ad_checkpoint.checkpoint_name``) is what a family's
+    blocks keep beside it."""
+    keep = (None if saved is None else
+            jax.checkpoint_policies.save_only_these_names(*saved))
     local = tokens - cfg.held_vocab[0]
     with jax.named_scope("df2.seq.embed"):
         x = embedding_rows(params["embed"], local,
@@ -328,7 +566,8 @@ def sequence_loss(params, router_bias, tokens, segments, positions, *,
     for i in cfg.kept_layers:
         routed = i in cfg.expert_layers
         bias = router_bias[cfg.expert_layers.index(i)] if routed else None
-        x, assigned = jax.checkpoint(partial(block, cfg=cfg, layer=i))(
+        x, assigned = jax.checkpoint(
+            partial(block, cfg=cfg, layer=i), policy=keep)(
             params[f"layer_{i}"], x, bias, segments, positions)
         if routed:
             counts.append(assigned)
@@ -336,16 +575,17 @@ def sequence_loss(params, router_bias, tokens, segments, positions, *,
         loss = jax.checkpoint(partial(head_loss, cfg=cfg))(
             params.get("lm_head", params["embed"]), params["final_norm"], x,
             local, segments)
-    return loss, (jnp.stack(counts) if counts else jnp.zeros(
-        (0, cfg.num_experts), jnp.int32))
+    return loss, (jax.tree.map(lambda *c: jnp.stack(c), *counts)
+                  if counts else jnp.zeros((0, cfg.num_experts), jnp.int32))
 
 
 def batch_loss(params, router_bias, tokens, segments, positions, *,
-               cfg, block):
+               cfg, block, saved=None):
     """:func:`sequence_loss` over a batch ``[B, S]``, one sequence at a
     time (a sequence is the unit of memory: the batch costs residuals of
     ``B`` block inputs a layer and no more). Returns the two sums."""
     def one(args):
-        return sequence_loss(params, router_bias, *args, cfg=cfg, block=block)
+        return sequence_loss(params, router_bias, *args, cfg=cfg, block=block,
+                             saved=saved)
     loss, counts = jax.lax.map(one, (tokens, segments, positions))
-    return loss.sum(), counts.sum(0)
+    return loss.sum(), jax.tree.map(lambda c: c.sum(0), counts)

@@ -47,8 +47,8 @@ import jax.numpy as jnp
 # v5e at the benchmark cell's shapes (8 uneven groups in 4,100 of 8,192
 # rows, 2048 x 1536; forward and backward of the three products): 2.69
 # ms against 3.17 at 512 x 512 x 512 and 2.93 at 128 rows (PERF.md,
-# PR 27).
-ROW_TILE, WIDTH_TILES = 256, (1024, 512)
+# PR 27). 256 last, for a width that neither of the two divides (768).
+ROW_TILE, WIDTH_TILES = 256, (1024, 512, 256)
 
 
 def group_tiles(m: int, k: int, n: int):
@@ -74,25 +74,35 @@ def grouped_product(rows, weights, sizes):
 
 
 def route(x, router_w, router_bias, *, top_k: int,
-          norm_topk_prob: bool = True, scaling_factor: float = 1.0):
+          norm_topk_prob: bool = True, scaling_factor: float = 1.0,
+          scoring: str = "sigmoid"):
     """Selection and weights over all experts, in float32.
 
-    ``s = sigmoid(x · W_g)``; the ``top_k`` experts with the largest
-    ``s + b`` are selected (``b``: the selection bias, a buffer); their
-    weights are ``s`` without the bias, divided by their sum where
-    ``norm_topk_prob``, times ``scaling_factor``. Returns the selected
-    experts ``[T, top_k]`` (int32) and their weights (float32). Only the
-    weights carry a gradient.
+    ``s = sigmoid(x · W_g)``, or with ``scoring="softmax"`` the softmax
+    of ``x · W_g`` over all experts; the ``top_k`` experts with the
+    largest ``s + b`` are selected (``b``: the selection bias, a
+    buffer); their weights are ``s`` without the bias, divided by their
+    sum where ``norm_topk_prob`` (plus 1e-6 under sigmoid scores, which
+    need not sum to anything; plus nothing under softmax shares), times
+    ``scaling_factor``. Returns the selected experts ``[T, top_k]``
+    (int32) and their weights (float32). Only the weights carry a
+    gradient.
     """
-    scores = jax.nn.sigmoid(jnp.matmul(
+    logits = jnp.matmul(
         x.astype(jnp.float32), router_w.astype(jnp.float32),
-        precision=jax.lax.Precision.HIGHEST))
+        precision=jax.lax.Precision.HIGHEST)
+    if scoring == "sigmoid":
+        scores, guard = jax.nn.sigmoid(logits), 1e-6
+    elif scoring == "softmax":
+        scores, guard = jax.nn.softmax(logits, axis=-1), 0.0
+    else:
+        raise ValueError(f"scoring {scoring!r}: sigmoid or softmax")
     _, chosen = jax.lax.top_k(
         jax.lax.stop_gradient(scores) + router_bias.astype(jnp.float32),
         top_k)
     weights = jnp.take_along_axis(scores, chosen, axis=-1)
     if norm_topk_prob:
-        weights = weights / (weights.sum(-1, keepdims=True) + 1e-6)
+        weights = weights / (weights.sum(-1, keepdims=True) + guard)
     return chosen.astype(jnp.int32), weights * scaling_factor
 
 
@@ -180,7 +190,7 @@ def _held_experts_part(x, weights, w1, w3, w2, order, place, sizes,
 
 def expert_layer(x, router_w, router_bias, w1, w3, w2, held, *,
                  top_k: int, norm_topk_prob: bool = True,
-                 scaling_factor: float = 1.0):
+                 scaling_factor: float = 1.0, scoring: str = "sigmoid"):
     """The held experts' part of a gated-FFN expert layer.
 
     ``x``: tokens ``[T, d]``. ``router_w``: ``[d, E]``, ``router_bias``:
@@ -192,7 +202,8 @@ def expert_layer(x, router_w, router_bias, w1, w3, w2, held, *,
       w_e · W2_e (silu(W1_e x) * W3_e x)``, float32 ``[T, d]``;
     - how many assignments each of the ``E`` experts got, int32 ``[E]``.
 
-    The products run in ``x``'s dtype (the weights are cast to it).
+    ``scoring``: the router's scores, :func:`route`'s. The products run
+    in ``x``'s dtype (the weights are cast to it).
     """
     first, count = held
     n_experts = router_w.shape[1]
@@ -202,7 +213,8 @@ def expert_layer(x, router_w, router_bias, w1, w3, w2, held, *,
     with jax.named_scope("df2.moe.route"):
         chosen, weights = route(
             x, router_w, router_bias, top_k=top_k,
-            norm_topk_prob=norm_topk_prob, scaling_factor=scaling_factor)
+            norm_topk_prob=norm_topk_prob, scaling_factor=scaling_factor,
+            scoring=scoring)
         # A count by comparison: a scatter-add of T·top_k ones into E
         # bins is all duplicate indices.
         assigned = (chosen[..., None] == jnp.arange(n_experts)).sum(

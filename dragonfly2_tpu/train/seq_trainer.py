@@ -28,7 +28,8 @@ import optax
 from flax.training import train_state
 from jax.sharding import PartitionSpec as P
 
-from dragonfly2_tpu.models import laguna, lfm2_moe, seq_layers
+from dragonfly2_tpu.models import keye_vl2, laguna, lfm2_moe, seq_layers
+from dragonfly2_tpu.models.keye_vl2 import KeyeVL2Config
 from dragonfly2_tpu.models.laguna import LagunaConfig
 from dragonfly2_tpu.models.lfm2_moe import Lfm2MoeConfig
 from dragonfly2_tpu.parallel import MeshContext, data_parallel_mesh
@@ -38,7 +39,8 @@ from dragonfly2_tpu.train.step_budget import TRAINING, StepBudget, step_loop
 # A family by the ``model_type`` of its published ``config.json``: the
 # module (``param_shapes``, ``block``) and its config.
 FAMILIES = {"lfm2_moe": (lfm2_moe, Lfm2MoeConfig),
-            "laguna": (laguna, LagunaConfig)}
+            "laguna": (laguna, LagunaConfig),
+            "KeyeVL2": (keye_vl2, KeyeVL2Config)}
 
 
 def family_of(cfg):
@@ -92,10 +94,10 @@ class SeqTrainConfig:
     """Recomputation is not an option: each block keeps its input alone
     for the backward pass and one sequence is in flight at a time
     (``seq_layers.batch_loss``), which is what lets a 0.47B-parameter
-    model's state (16 bytes a parameter) and four 8k sequences share a
-    16 GB chip."""
+    model's state (16 bytes a parameter) and four 8k sequences, or a
+    0.31B-parameter one's and two 32k sequences, share a 16 GB chip."""
 
-    model: Lfm2MoeConfig | LagunaConfig
+    model: Lfm2MoeConfig | LagunaConfig | KeyeVL2Config
     batch_size: int = 4              # sequences a step
     # For whoever packs the corpus (``trainer/training.py``): the rows'
     # length, and the id that ends a document inside a token segment
@@ -139,10 +141,15 @@ class SeqTrainState(train_state.TrainState):
     """Outside ``params``, because not trained: the selection bias and
     the assignments each expert got since the loop began (``[expert
     layers, num_experts]``; uint32, which holds 32,768 steps of the
-    worst case, every assignment of a 32,768-token step on one expert)."""
+    worst case, every assignment of a 32,768-token step on one expert).
+    Where the family's attention runs over a selection of keys, also the
+    selections' candidates and members since the loop began, summed over
+    layers (``[2, 3]``: 16-bit limbs, ``seq_layers.count_limbs``; a
+    32k-token sequence has 5e8 causal pairs a layer); else None."""
 
     router_bias: jax.Array = None
     routing_counts: jax.Array = None
+    sparse_counts: jax.Array = None
 
 
 @dataclass
@@ -164,7 +171,8 @@ def build_train_step(cfg, mesh: MeshContext):
     the corpus replicated, ``seq_ids`` (this step's rows of it) sharded
     over ``data``."""
     rep = mesh.replicated
-    block = family_of(cfg).block
+    family = family_of(cfg)
+    block, saved = family.block, getattr(family, "SAVED", None)
 
     def loss_and_grads(params, router_bias, tokens, segments, positions,
                        seq_ids):
@@ -177,7 +185,8 @@ def build_train_step(cfg, mesh: MeshContext):
 
         def mean(p):
             loss, counts = seq_layers.batch_loss(
-                p, router_bias, tok, seg, pos, cfg=cfg, block=block)
+                p, router_bias, tok, seg, pos, cfg=cfg, block=block,
+                saved=saved)
             return loss / n, counts
 
         (loss, counts), grads = jax.value_and_grad(mean, has_aux=True)(params)
@@ -199,10 +208,17 @@ def build_train_step(cfg, mesh: MeshContext):
                 state.params, state.router_bias, tokens, segments,
                 positions, seq_ids)
         with jax.named_scope("df2.optimizer"):
+            more = {}
+            if state.sparse_counts is not None:
+                # Beside the assignments, the selections' candidates and
+                # members of each layer: summed over the layers here.
+                counts, selected = counts
+                more["sparse_counts"] = seq_layers.carry_limbs(
+                    state.sparse_counts + selected.sum(0))
             state = state.apply_gradients(
                 grads=grads,
                 routing_counts=state.routing_counts
-                + counts.astype(jnp.uint32))
+                + counts.astype(jnp.uint32), **more)
         return state, loss
 
     return jax.jit(
@@ -235,6 +251,7 @@ def train_seq(
         0.0, config.learning_rate, min(100, total_steps // 10 + 1),
         total_steps)
     n_moe = len(cfg.expert_layers)
+    sparse_topk = getattr(cfg, "sparse_topk", 0)
     bias = np.zeros(cfg.num_experts, np.float32) if (
         config.router_bias is None or not cfg.use_expert_bias
     ) else np.asarray(config.router_bias, np.float32)
@@ -245,7 +262,9 @@ def train_seq(
             family_of(cfg).param_shapes(cfg)),
         tx=optax.adamw(schedule, weight_decay=config.weight_decay),
         router_bias=jnp.tile(bias, (n_moe, 1)),
-        routing_counts=jnp.zeros((n_moe, cfg.num_experts), jnp.uint32))
+        routing_counts=jnp.zeros((n_moe, cfg.num_experts), jnp.uint32),
+        sparse_counts=(jnp.zeros((2, 3), jnp.uint32) if sparse_topk
+                       else None))
     state = mesh.put_replicated(state)
     rep = mesh.replicated
     tokens, segments, positions = (
@@ -254,9 +273,10 @@ def train_seq(
 
     train_step = build_train_step(cfg, mesh)
     # Last values set (docs/OBSERVABILITY.md): which attention the
-    # loop's sliding layers ran (on every step's span too), and of the
-    # corpus's causal tiles at the full-attention kernel's tile those
-    # that a document reaches, which are the ones the kernel computes.
+    # loop's sliding layers ran and how many keys a learned selection
+    # keeps (both on every step's span too), and of the corpus's causal
+    # tiles at the full-attention kernel's tile those that a document
+    # reaches, which are the ones the kernel computes.
     tiles = tiles_kept = 0
     block = min(seq_layers.ATTENTION_BLOCK, seq_len)
     if seq_len % block == 0 and any(
@@ -265,7 +285,8 @@ def train_seq(
         tiles = rows * keep.shape[-1] * (keep.shape[-1] + 1) // 2
         tiles_kept = int(keep.sum())
     TRAINING.set(seq_attn_window=cfg.attention_window,
-                 seq_attn_tiles=tiles, seq_attn_tiles_kept=tiles_kept)
+                 seq_attn_tiles=tiles, seq_attn_tiles_kept=tiles_kept,
+                 seq_sparse_topk=sparse_topk)
 
     budget = StepBudget(config.max_seconds, step_samples=batch * seq_len)
     rng = np.random.default_rng((config.seed, 11))
@@ -285,7 +306,8 @@ def train_seq(
         budget, config.epochs, epoch_steps, dispatch,
         step_samples=batch * seq_len, drain=lambda: state.params,
         serialize_launches=mesh.serialize_launches,
-        step_facts={"seq_attn_window": cfg.attention_window})
+        step_facts={"seq_attn_window": cfg.attention_window,
+                    "seq_sparse_topk": sparse_topk})
 
     # One read of the routing counts, after the drain.
     routing = np.asarray(jax.device_get(state.routing_counts), np.int64)
@@ -295,6 +317,11 @@ def train_seq(
         TRAINING.add(moe_steps=budget.steps,
                      moe_assignments_held=int(here.sum()),
                      moe_assignments_hottest=int(here.max(1).sum()))
+    if sparse_topk:
+        candidates, members = seq_layers.limbs_value(
+            jax.device_get(state.sparse_counts))
+        TRAINING.add(seq_sparse_candidates=int(candidates),
+                     seq_sparse_selected=int(members))
     return SeqTrainResult(
         params=state.params,
         config=config,

@@ -83,6 +83,16 @@ class TrainingStats:
       computes; the rest it skips. Both 0 for a cut without a
       full-attention layer (and for rows that are no whole number of
       tiles, which the kernel does not take).
+    - ``seq_sparse_topk``: the last value set there too: how many keys a
+      query keeps where the model's attention runs over a learned
+      selection (``sa_config.topk``), or 0 for a family without one;
+      and over such a loop's steps the counters
+      ``seq_sparse_candidates``: the keys its queries could have kept
+      (for each query the earlier tokens of its document and itself),
+      summed over queries, layers and steps, and
+      ``seq_sparse_selected``: those they kept. Counted from the
+      selections' own masks on the device and read once, at a loop's
+      drain.
     """
 
     KEYS = ("loops_started", "dispatches", "steps", "samples",
@@ -90,7 +100,8 @@ class TrainingStats:
             "moe_steps", "moe_assignments_held", "moe_assignments_hottest",
             "sampler_row_width", "attn_inverse_slots",
             "attn_inverse_filled", "seq_attn_window", "seq_attn_tiles",
-            "seq_attn_tiles_kept")
+            "seq_attn_tiles_kept", "seq_sparse_topk",
+            "seq_sparse_candidates", "seq_sparse_selected")
 
     def __init__(self):
         self._lock = threading.Lock()

@@ -20,7 +20,10 @@ phases (docs/OBSERVABILITY.md "Training loops").
   besides the step's number (``sampler_row_width``: the lanes of
   GraphSAGE's per-host neighbour rows, 0 on the CSR sampler;
   ``seq_attn_window``: the window of a sequence model's sliding layers,
-  0 where it has none, whose kernel is ``df2.seq.attn_window``).
+  0 where it has none, whose kernel is ``df2.seq.attn_window``;
+  ``seq_sparse_topk``: the keys a query keeps where attention runs over
+  a learned selection, 0 where it does not, whose scopes are
+  ``df2.seq.index``, ``df2.seq.select`` and ``df2.seq.attn_sparse``).
 - The longest device idle gaps, each with the ``df2.train.*`` span the
   loop's thread was in.
 

@@ -167,14 +167,19 @@ def test_embedding_pass_at_model_load_leaves_the_chip_room(topo):
 # key-value heads of 64, expert width 1536, 8 experts held of 64, top-4.
 # ``laguna-xs2-ep32.train``: 48 (full layers) and 64 (sliding layers,
 # window 512) query heads on 8 key-value heads of 128, expert width 512,
-# 8 held of 256, top-8.
+# 8 held of 256, top-8. ``keye-vl2-30b-a3b-ep16.train``: 32 query heads
+# on 4 key-value heads of 128 over a selection of 2,048 keys on 32k
+# sequences, expert width 768 (in parts of 8,192 tokens), 8 held of 128,
+# top-8 under a softmax router.
 SEQ, HIDDEN = 8192, 2048
 
 
-@pytest.mark.parametrize("experts,width,top_k", [(64, 1536, 4), (256, 512, 8)],
-                         ids=["lfm2-24b-a2b-ep8", "laguna-xs2-ep32"])
+@pytest.mark.parametrize("experts,width,top_k,scoring", [
+    (64, 1536, 4, "sigmoid"), (256, 512, 8, "sigmoid"),
+    (128, 768, 8, "softmax")],
+    ids=["lfm2-24b-a2b-ep8", "laguna-xs2-ep32", "keye-vl2-30b-a3b-ep16"])
 def test_expert_layer_is_grouped_products_for_the_chip(
-        one_chip, as_tpu_program, experts, width, top_k):
+        one_chip, as_tpu_program, experts, width, top_k, scoring):
     """The expert layer's forward and backward at a cell's widths: the
     three grouped products and their transposes are the megablox kernel
     (not one masked dense product per expert, and not XLA's own lowering
@@ -187,7 +192,7 @@ def test_expert_layer_is_grouped_products_for_the_chip(
 
     def loss(x, router, w1, w3, w2):
         out, _ = expert_layer(x, router, jnp.zeros(experts), w1, w3, w2,
-                              (0, held), top_k=top_k)
+                              (0, held), top_k=top_k, scoring=scoring)
         return out.sum()
 
     compiled = _compile(
@@ -257,6 +262,113 @@ def test_sequence_attention_kernel_at_the_cells_shape(one_chip, heads, hd,
     # blocks of 512 (8 of 1,024 in the other two).
     assert compiled.memory_analysis().temp_size_in_bytes < (
         2e9 if window is None else 3e9)
+
+
+def test_selected_attention_kernels_at_the_cells_shape(one_chip):
+    """Attention over a computed selection at ``keye-vl2-30b-a3b-ep16``'s
+    shape: one 32k sequence, 32 query heads on 4 key-value heads of 128,
+    the selection as packed bits. Three kernels of the repo's own
+    (forward, dq, dk and dv), which Mosaic takes at 1,024 x 1,024 tiles
+    with the byte tile unpacked by shifts; nothing ``[S, S]`` wider than
+    a bit a pair: the packed mask is 134 MB where the splash kernel's
+    computed mask would be 4.3 GB of 32-bit words."""
+    from dragonfly2_tpu.models.selected_attention import packed_attention
+
+    s = _struct(one_chip)
+    length, heads, kv_heads, hd = 32_768, 32, 4, 128
+
+    def loss(q, k, v, packed):
+        return packed_attention(q, k, v, packed, 1024).astype(
+            jnp.float32).sum()
+
+    compiled = _compile(
+        jax.grad(loss, argnums=(0, 1, 2)),
+        s((heads, length, hd), jnp.bfloat16),
+        s((kv_heads, length, hd), jnp.bfloat16),
+        s((kv_heads, length, hd), jnp.bfloat16),
+        s((length, length // 8), jnp.uint8))
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 3
+    # 1.5 GB: q, k, v, out and their cotangents, the log-sum-exp and
+    # delta on 128 lanes each; a float32 [S, S] is 4.3 GB.
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.5e9
+
+
+def test_the_third_familys_step_fits_the_chip(topo, as_tpu_program):
+    """``keye-vl2-30b-a3b-ep16.train``'s whole step (0.314B parameters,
+    2 sequences of 32,768 positions, every published width) through
+    ``seq_trainer.build_train_step``, as the benchmark's runner builds
+    it: the chip's compiler refuses a program that does not fit its 16
+    GB, and takes this one. Its kernels are the selection's three (a
+    layer: forward, recomputed forward, dq, dkv) under
+    ``df2.seq.attn_sparse`` and megablox under ``df2.moe.experts``; the
+    selection is kept for the backward pass, not ranked again."""
+    import json
+
+    import optax
+
+    from dragonfly2_tpu.models import keye_vl2
+    from dragonfly2_tpu.parallel import data_parallel_mesh
+    from dragonfly2_tpu.train import seq_trainer
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "keye-vl2-30b-a3b-ep16.json")) as fh:
+        spec = json.load(fh)
+    held, published = spec["deployment"], spec["published"]
+    cfg = keye_vl2.KeyeVL2Config.from_published(
+        spec, num_experts=published["num_experts"],
+        vocab_size=published["vocab_size"],
+        num_hidden_layers=published["num_hidden_layers"],
+        layers=tuple(held["layers_kept"]),
+        experts_held=tuple(held["experts_held"]),
+        vocab_held=tuple(held["vocab_rows_held"]))
+    mesh = data_parallel_mesh(devices=topo.devices[:1])
+    layers = len(cfg.kept_layers)
+
+    def make_state():
+        params = {}
+        for path, shape, _ in keye_vl2.param_shapes(cfg):
+            node = params
+            for part in path[:-1]:
+                node = node.setdefault(part, {})
+            node[path[-1]] = jnp.zeros(shape, jnp.float32)
+        return seq_trainer.SeqTrainState.create(
+            apply_fn=None, params=params,
+            tx=optax.adamw(1e-4, weight_decay=0.1),
+            router_bias=jnp.zeros((layers, cfg.num_experts)),
+            routing_counts=jnp.zeros((layers, cfg.num_experts), jnp.uint32),
+            sparse_counts=jnp.zeros((2, 3), jnp.uint32))
+
+    rep = mesh.replicated
+    state = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=rep),
+        jax.eval_shape(make_state))
+    rows, length = spec["corpus"]["tokens"] // spec["seq_len"], spec["seq_len"]
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(
+            shape, jnp.int32,
+            sharding=mesh.batch_sharding if len(shape) == 1 else rep)
+
+    compiled = seq_trainer.build_train_step(cfg, mesh).lower(
+        state, i32(rows, length), i32(rows, length), i32(spec["batch"]),
+        i32(rows, length)).compile()
+    memory = compiled.memory_analysis()
+    # Parameters and Adam's two moments (12 bytes a parameter) and the
+    # corpus's three arrays.
+    assert 3.7e9 < memory.argument_size_in_bytes < 3.9e9
+    calls = [ln for ln in compiled.as_text().splitlines()
+             if "tpu_custom_call" in ln and " = " in ln]
+    sparse = [ln for ln in calls if "df2.seq.attn_sparse" in ln]
+    assert len(sparse) == 4 * layers and all(
+        "df2.seq.attn_sparse" in ln or "df2.moe.experts" in ln
+        for ln in calls)
+    assert not any("ragged-dot" in ln for ln in calls)
+    # Scored and ranked in the forward pass alone.
+    assert not any("rematted_computation" in ln and (
+        "df2.seq.index" in ln or "df2.seq.select" in ln)
+        for ln in compiled.as_text().splitlines())
 
 
 @pytest.mark.parametrize("chips", [1, 4])

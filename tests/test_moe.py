@@ -176,6 +176,9 @@ def test_many_small_experts_one_of_them_empty(crowd, rows):
     (4096, 2048, 1536, (256, 1024, 512)), (4096, 1536, 2048, (256, 512, 1024)),
     # laguna-xs2-ep32: 2048 x 512 experts, 4,096- and 65,536-row buffers.
     (4096, 2048, 512, (256, 1024, 512)), (65536, 512, 2048, (256, 512, 1024)),
+    # keye-vl2-30b-a3b-ep16: 2048 x 768 experts, which neither 1,024 nor
+    # 512 divides, in 8,192- and 65,536-row buffers.
+    (8192, 2048, 768, (256, 1024, 256)), (65536, 768, 2048, (256, 256, 1024)),
     # A width that no tile divides: no kernel (the plain grouped product).
     (4096, 2048, 24, (256, 1024, None)),
 ])
@@ -281,3 +284,90 @@ def test_held_must_match_the_stacked_weights():
     with pytest.raises(ValueError, match="held"):
         expert_layer(p["x"], p["router"], p["bias"], p["w1"][:4],
                      p["w3"][:4], p["w2"][:4], (14, 4), top_k=K)
+
+
+def softmax_layer(p, top_k=K, held=None):
+    """:func:`dense_layer` under the softmax router: shares over all
+    experts, the ``top_k`` largest, divided by their sum; no bias."""
+    shares = jax.nn.softmax(jnp.matmul(p["x"], p["router"],
+                                       precision="highest"), -1)
+    _, chosen = jax.lax.top_k(shares, top_k)
+    weights = jnp.take_along_axis(shares, chosen, -1)
+    weights = weights / weights.sum(-1, keepdims=True)
+    out = 0.0
+    first, count = held or (0, p["router"].shape[1])
+    for e in range(first, first + count):
+        w_e = jnp.where(chosen == e, weights, 0.0).sum(-1)
+        hidden = jax.nn.silu(p["x"] @ p["w1"][e]) * (p["x"] @ p["w3"][e])
+        out = out + w_e[:, None] * (hidden @ p["w2"][e])
+    return out, np.bincount(np.asarray(chosen).ravel(),
+                            minlength=p["router"].shape[1])
+
+
+def softmax_share(p, first, count):
+    rows = slice(first, first + count)
+    return expert_layer(
+        p["x"], p["router"], jnp.zeros(p["router"].shape[1]), p["w1"][rows],
+        p["w3"][rows], p["w2"][rows], (first, count), top_k=K,
+        scoring="softmax")
+
+
+@pytest.mark.parametrize("count", [4, 16])
+def test_the_shares_add_up_under_the_softmax_router(count):
+    """The third family's router (``scoring="softmax"``): the shares sum
+    to the uncut layer as under the sigmoid one, and the weights of a
+    token's selected experts sum to 1 exactly (no guard term)."""
+    p = make(8)
+    whole, counts = softmax_layer(p)
+    total = 0.0
+    for first in range(0, E, count):
+        out, assigned = jax.jit(softmax_share, static_argnums=(1, 2))(
+            p, first, count)
+        np.testing.assert_array_equal(np.asarray(assigned), counts)
+        total = total + out
+    np.testing.assert_allclose(np.asarray(total), np.asarray(whole),
+                               rtol=2e-5, atol=2e-6)
+    _, weights = route(p["x"], p["router"], jnp.zeros(E), top_k=K,
+                       scoring="softmax")
+    np.testing.assert_allclose(np.asarray(weights.sum(-1)), 1.0, rtol=1e-6)
+
+
+@pytest.mark.parametrize("name", ["x", "router", "w1", "w3", "w2"])
+def test_gradients_under_the_softmax_router(name):
+    p = make(9)
+    probe = jnp.asarray(np.random.default_rng(9).standard_normal(
+        p["x"].shape), jnp.float32)
+
+    def ours(value):
+        return (softmax_share(dict(p, **{name: value}), 4, 8)[0] * probe).sum()
+
+    def dense(value):
+        return (softmax_layer(dict(p, **{name: value}), held=(4, 8))[0]
+                * probe).sum()
+
+    got, want = jax.grad(ours)(p[name]), jax.grad(dense)(p[name])
+    scale = float(jnp.abs(want).max())
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-4, atol=2e-6 * max(scale, 1.0))
+
+
+def test_the_sigmoid_router_is_the_default_and_unchanged():
+    """``scoring`` left at its default is the two older families'
+    router to the bit: sigmoid scores, the guard term in the sum."""
+    p = make(3)
+    scores = jax.nn.sigmoid(jnp.matmul(p["x"], p["router"],
+                                       precision="highest"))
+    _, chosen = jax.lax.top_k(scores + p["bias"], K)
+    picked = jnp.take_along_axis(scores, chosen, -1)
+    got_chosen, got = route(p["x"], p["router"], p["bias"], top_k=K)
+    named_chosen, named = route(p["x"], p["router"], p["bias"], top_k=K,
+                                scoring="sigmoid")
+    np.testing.assert_array_equal(np.asarray(got_chosen), np.asarray(chosen))
+    np.testing.assert_array_equal(
+        np.asarray(got),
+        np.asarray(picked / (picked.sum(-1, keepdims=True) + 1e-6)))
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(named))
+    np.testing.assert_array_equal(np.asarray(got_chosen),
+                                  np.asarray(named_chosen))
+    with pytest.raises(ValueError, match="scoring"):
+        route(p["x"], p["router"], p["bias"], top_k=K, scoring="relu")

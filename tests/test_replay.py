@@ -622,7 +622,12 @@ class TestVectorizedReplay:
         stay bit-identical to the sequential replay."""
         from dragonfly2_tpu.scheduler.replaystore import bucket_candidates
 
-        events = [e for e in recorded["ring"] if e.candidates]
+        # Seq-ordered, as every replay takes its corpus: under load the
+        # swarm's four workers finalize decisions out of seq order, the
+        # columnar packing orders by seq and the sequential replay keeps
+        # the order it is given, so the raw ring's digests differ.
+        events = [e for e in rp.corpus_from_events(recorded["ring"])
+                  if e.candidates]
         k1 = [dataclasses.replace(e, candidates=list(e.candidates[:1]))
               for e in events]
         kmax = []
@@ -641,6 +646,14 @@ class TestVectorizedReplay:
             vec = rp.replay_decisions_vectorized(cc)
             assert seq.digest == vec.digest
             assert seq.full_order == vec.full_order
+        # What a ring out of seq order is promised: the columnar packing
+        # orders by seq, so the ring backwards replays to the digest of
+        # the sequential replay of the seq-ordered corpus.
+        backwards = rp.replay_decisions_vectorized(
+            rp.as_columnar(events[::-1]))
+        ordered = rp.replay_decisions(events, BaseEvaluator())
+        assert backwards.digest == ordered.digest
+        assert backwards.full_order == ordered.full_order
 
     def test_ties_resolved_in_candidate_order(self):
         """Score ties must break by original candidate position in BOTH

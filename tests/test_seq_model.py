@@ -452,3 +452,58 @@ def test_config_refuses_what_the_family_does_not_have():
     with pytest.raises(ValueError, match="layer type"):
         lfm2_moe.param_shapes(config(
             layer_types=["conv", "conv", "sliding", "conv", "conv", "conv"]))
+
+
+@pytest.mark.parametrize("blocks", [2, 4])
+def test_the_head_in_position_blocks_is_the_head(monkeypatch, blocks):
+    """A sequence longer than ``HEAD_BLOCK`` takes its logits and loss a
+    block of positions at a time (the third family's 32k sequences): the
+    loss and every gradient are the whole head's."""
+    cfg = config()
+    rng = np.random.default_rng(3)
+    head = jnp.asarray(rng.standard_normal((96, 64)) * 0.1, jnp.float32)
+    norm = jnp.asarray(1 + 0.1 * rng.standard_normal(64), jnp.float32)
+    x = jnp.asarray(rng.standard_normal((S, 64)), jnp.float32)
+    local = jnp.asarray(rng.integers(0, 96, S), jnp.int32)
+    _, segments, _ = sequence(1)
+
+    def loss(head, norm, x):
+        return seq_layers.head_loss(head, norm, x, local, segments, cfg=cfg)
+
+    whole = jax.value_and_grad(loss, argnums=(0, 1, 2))(head, norm, x)
+    monkeypatch.setattr(seq_layers, "HEAD_BLOCK", S // blocks)
+    parts = jax.value_and_grad(loss, argnums=(0, 1, 2))(head, norm, x)
+    assert "scan" in str(jax.make_jaxpr(loss)(head, norm, x))
+    np.testing.assert_allclose(float(parts[0]), float(whole[0]), rtol=1e-6)
+    for got, want in zip(parts[1], whole[1]):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-5, atol=1e-6)
+
+
+def test_a_length_that_is_no_whole_number_of_head_blocks_is_refused(
+        monkeypatch):
+    """Past one block the head takes whole blocks only: it does not fall
+    back to the whole sequence's logits, which is what the blocks are
+    there to avoid. One block or less is taken as it is."""
+    cfg = config()
+    head, norm = jnp.ones((96, 64)), jnp.ones(64)
+    x, local = jnp.ones((S, 64)), jnp.zeros(S, jnp.int32)
+    _, segments, _ = sequence(1)
+    monkeypatch.setattr(seq_layers, "HEAD_BLOCK", S - 1)
+    with pytest.raises(ValueError, match="whole blocks"):
+        seq_layers.head_loss(head, norm, x, local, segments, cfg=cfg)
+    monkeypatch.setattr(seq_layers, "HEAD_BLOCK", S + 1)
+    assert "scan" not in str(jax.make_jaxpr(
+        lambda x: seq_layers.head_loss(head, norm, x, local, segments,
+                                       cfg=cfg))(x))
+
+
+def test_parameters_are_drawn_set_to_one_or_set_to_zero():
+    """``init_params`` draws ``normal``, sets ``ones`` and ``zeros`` (a
+    layer norm's bias, the third family's)."""
+    params = seq_layers.init_params(jax.random.key(0), [
+        (("a", "w"), (4, 3), "normal"), (("a", "scale"), (3,), "ones"),
+        (("a", "bias"), (3,), "zeros")])
+    assert np.asarray(params["a"]["bias"]).tolist() == [0.0] * 3
+    assert np.asarray(params["a"]["scale"]).tolist() == [1.0] * 3
+    assert np.asarray(params["a"]["w"]).std() > 0

@@ -7,11 +7,13 @@ import jax
 import numpy as np
 import pytest
 
+from dragonfly2_tpu.models.keye_vl2 import KeyeVL2Config
 from dragonfly2_tpu.models.laguna import LagunaConfig, Rope
 from dragonfly2_tpu.models.lfm2_moe import Lfm2MoeConfig
 from dragonfly2_tpu.parallel import data_parallel_mesh
 from dragonfly2_tpu.train import step_budget
 from dragonfly2_tpu.train.seq_trainer import (
+    SeqCorpus,
     SeqTrainConfig,
     config_from_dict,
     pack_documents,
@@ -41,8 +43,18 @@ LAGUNA = LagunaConfig(
         partial_rotary_factor=0.5, attention_factor=1.4158883)),
         ("sliding_attention", Rope(rope_theta=10000))),
     experts_held=(4, 4), vocab_held=(0, 64))
+# The third: two layers whose attention runs over the 6 best keys of an
+# indexer's ranking, 4 of 16 experts held under a softmax router.
+KEYE = KeyeVL2Config(
+    hidden_size=32, moe_intermediate_size=16, num_hidden_layers=2,
+    num_attention_heads=4, num_key_value_heads=2, head_dim=8,
+    num_experts=16, num_experts_per_tok=4, vocab_size=64,
+    rope_theta=10000000, mrope_section=(1, 1, 2), indexer_num_heads=2,
+    indexer_head_dim=8, sparse_topk=6, experts_held=(4, 4),
+    vocab_held=(0, 64))
 FAMILIES = pytest.mark.parametrize(
-    "model,window", [(MODEL, 0), (LAGUNA, 8)], ids=["lfm2_moe", "laguna"])
+    "model,window", [(MODEL, 0), (LAGUNA, 8), (KEYE, 0)],
+    ids=["lfm2_moe", "laguna", "KeyeVL2"])
 SEQ = 32
 
 
@@ -83,9 +95,10 @@ def test_packer_splits_a_stream_at_the_end_id():
 
 @FAMILIES
 def test_train_seq_reaches_finish_without_a_steady_compile(model, window):
-    """Both families through the one loop: the same counters, the same
-    one step program, and the loop's last-value entry for the window of
-    the sliding layers (0 for a family without one)."""
+    """Every family through the one loop: the same counters, the same
+    one step program, and the loop's last-value entries for the window
+    of the sliding layers and for the keys a learned selection keeps (0
+    for a family without one)."""
     corpus = pack_documents(documents(), SEQ)
     before = step_budget.TRAINING.snapshot()
     result = train_seq(corpus, SeqTrainConfig(
@@ -93,9 +106,11 @@ def test_train_seq_reaches_finish_without_a_steady_compile(model, window):
         router_bias=tuple(np.linspace(-0.05, 0.05, 16))), one_device())
     after = step_budget.TRAINING.snapshot()
     assert after["seq_attn_window"] == window
-    # Both cuts run a full-attention layer: every row is one tile at
-    # this length, and a row's own tile is always reached.
-    rows = corpus.tokens.shape[0]
+    assert after["seq_sparse_topk"] == getattr(model, "sparse_topk", 0)
+    # A cut with a full-attention layer: every row is one tile at this
+    # length, and a row's own tile is always reached.
+    rows = corpus.tokens.shape[0] if "full_attention" in model.layer_types \
+        else 0
     assert after["seq_attn_tiles"] == after["seq_attn_tiles_kept"] == rows
     steps = 3 * (corpus.tokens.shape[0] // 4)
     assert result.steps == steps and len(result.history) == 3
@@ -196,6 +211,44 @@ def test_config_from_a_published_file():
         32, 2, 63)
 
 
+def test_the_selections_counters_are_the_corpus_own_arithmetic():
+    """``seq_sparse_candidates`` and ``seq_sparse_selected``: summed on
+    the device over layers, sequences and steps in 16-bit limbs (a plain
+    uint32 would wrap within a dozen steps of 32k sequences) and added
+    once at the drain: for every query its document's tokens up to
+    itself, and the 6 of them it keeps at the most."""
+    corpus = pack_documents(documents(), SEQ)
+    corpus = SeqCorpus(*(a[:8] for a in (
+        corpus.tokens, corpus.segments, corpus.positions)))
+    at = np.arange(SEQ)
+    c = ((at[None, :, None] >= at[None, None, :])
+         & (corpus.segments[:, :, None] == corpus.segments[:, None, :])
+         ).sum(-1)
+    before = step_budget.TRAINING.snapshot()
+    result = train_seq(corpus, SeqTrainConfig(
+        model=KEYE, batch_size=4, epochs=3, seed=1), one_device())
+    after = step_budget.TRAINING.snapshot()
+    assert result.steps == 6
+    # Two layers, three epochs over all eight rows.
+    assert (after["seq_sparse_candidates"] - before["seq_sparse_candidates"]
+            == 2 * 3 * c.sum())
+    assert (after["seq_sparse_selected"] - before["seq_sparse_selected"]
+            == 2 * 3 * np.minimum(c, 6).sum())
+
+
+def test_limbs_carry_past_32_bits():
+    from dragonfly2_tpu.models import seq_layers
+
+    counts = np.array([2**31 - 1, 65_536, 0, 179_322_880], np.int32)
+    limbs = seq_layers.count_limbs(jax.numpy.asarray(counts))
+    total = limbs
+    for _ in range(40):                 # 41 x 2^31: past uint32 and 2^36
+        total = seq_layers.carry_limbs(total + limbs)
+    assert (np.asarray(total) < 65_536).all()
+    assert seq_layers.limbs_value(total).tolist() == [
+        41 * int(v) for v in counts]
+
+
 def test_config_from_a_published_file_names_its_family():
     """``model_type`` picks the family; the published laguna keys and
     what is held here come out of the one file."""
@@ -218,6 +271,30 @@ def test_config_from_a_published_file_names_its_family():
     assert (config.seq_len, config.batch_size) == (8192, 4)
     with pytest.raises(ValueError, match="model_type"):
         config_from_dict(dict(given, model_type="mamba"))
+
+
+def test_config_from_the_third_familys_published_file():
+    """``df2-trainer --train-seq`` with a ``model_type`` ``KeyeVL2``
+    file: the published keys (``sa_config``, ``rope_scaling``) and what
+    is held here come out of the one file."""
+    import json
+    import os
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "keye-vl2-30b-a3b-ep16.json")) as fh:
+        given = json.load(fh)
+    given = dict(given, num_hidden_layers=48, num_experts=128,
+                 vocab_size=151936, layers=[0, 1, 2, 3], experts_held=[0, 8],
+                 vocab_held=[0, 18992], batch_size=2, seq_len=32768)
+    config = config_from_dict(given)
+    assert isinstance(config.model, KeyeVL2Config)
+    assert config.model.expert_layers == (0, 1, 2, 3)
+    assert config.model.held_experts == (0, 8)
+    assert (config.model.sparse_topk, config.model.mrope_section) == (
+        2048, (16, 24, 24))
+    assert len(config.model.layer_types) == 48
+    assert (config.seq_len, config.batch_size) == (32768, 2)
 
 
 class _Registry:
