@@ -646,7 +646,7 @@ class ObservedStep:
             i = ids if ids.ndim == 1 else ids[0]
             centers = jnp.stack([edges.src.at[i].get(out_sharding=b),
                                  edges.dst.at[i].get(out_sharding=b)],
-                                axis=-1)
+                                axis=0)
             return sample_neighbors(graph, centers, f1, jnp.uint32(seed),
                                     b)[0]
 
@@ -654,7 +654,7 @@ class ObservedStep:
         sampled_on = sorted(s.device.id for s in shards)
         rows = self.ids_shard_shape[-1]
         check(sampled_on == self.device_ids
-              and all(s.data.shape[0] == rows for s in shards),
+              and all(s.data.shape[-1] == rows for s in shards),
               f"sampled neighbour index: shards of "
               f"{[s.data.shape for s in shards]} on {sampled_on}")
         return {"state_tables_key_replicated_on": self.device_ids,

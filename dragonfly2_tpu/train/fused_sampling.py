@@ -33,6 +33,16 @@ offsets from the same hash: their samples are equal bit for bit
 (``tests/test_fused_sampling.py``). ``sampler_row_width`` of the
 ``training`` block says which one a process placed (0: CSR).
 
+**The layout.** A hop's ids, RTTs, mask and feature rows have one order
+from the sampler's slices to the model's sums: fan-out axes leading, the
+newest first, and the batch trailing, in the lanes (``[f1, 2, B]``,
+``[f2, f1, 2, B]``: the axes of the host sampler's ``[B, 2, f1, f2]`` in
+reverse; :func:`sample_neighbors` has the contract, models/graphsage.py
+the model's side). The select produces a hop that way and nothing turns
+it into batch order: until PR 34 a transpose of every hop, copies of
+hop 2's ids and two relayouts of its 13.1M feature rows (lane-padded,
+3.4 GB) were 43.5 of the step's 133.4 ms (ledger, PR 33).
+
 Static shapes throughout: every array's shape is a pure function of
 (B, fanouts, F, row width), so XLA compiles exactly one program; sampling
 uses replacement (same estimator as the host sampler,
@@ -67,8 +77,12 @@ LANES = 128
 ROW_PAD_FACTOR = 4
 ROW_PAD_FREE_BYTES = 256 << 20
 # Fetched rows (both tables) a device holds at once; a hop over more
-# nodes than that runs in slices of the batch.
-ROW_CHUNK_BYTES = 512 << 20
+# nodes than that runs in slices of the batch. A slice is also the work
+# that covers the second table's copy into fast memory: at the cells'
+# shapes the compiler starts that copy inside hop 2's loop for slices of
+# 327,680 nodes (8 of them under this limit) and left the table in HBM
+# for 163,840 (tests/test_chip_compile.py holds the four fetches).
+ROW_CHUNK_BYTES = 1 << 30
 
 
 class GraphTables(NamedTuple):
@@ -152,20 +166,32 @@ def put_edge_tables(src: np.ndarray, dst: np.ndarray, labels: np.ndarray,
     )
 
 
-def _gather(table: jax.Array, idx: jax.Array, out_sharding) -> jax.Array:
+def _batch_at(out_sharding, axis: int):
+    """The sharding of an array whose ``axis`` is the batch, given a
+    ``[B]`` vector's (None on one device: nothing to state)."""
+    if out_sharding is None:
+        return None
+    return jax.sharding.NamedSharding(
+        out_sharding.mesh,
+        jax.sharding.PartitionSpec(*(None,) * axis, *out_sharding.spec))
+
+
+def gather_nodes(table: jax.Array, idx: jax.Array, out_sharding) -> jax.Array:
+    """``table[idx]`` for an index array whose LAST axis is the batch (the
+    module's one layout): under a mesh each device gathers its own shard
+    of the batch locally, which a typed sharding has to be told."""
     if out_sharding is None:
         return table[idx]
-    return table.at[idx].get(out_sharding=out_sharding)
+    return table.at[idx].get(
+        out_sharding=_batch_at(out_sharding, idx.ndim - 1))
 
 
-def _reshape(x: jax.Array, shape: tuple, out_sharding, axis: int = 0):
-    """A reshape that cuts or joins batch rows: ``axis`` of the result
-    is the sharded one (a typed sharding cannot guess which factor is)."""
+def _reshape(x: jax.Array, shape: tuple, out_sharding, axis: int):
+    """A reshape that cuts or joins the batch: ``axis`` of the result is
+    the sharded one (a typed sharding cannot guess which factor is)."""
     if out_sharding is None:
         return x.reshape(shape)
-    spec = jax.sharding.PartitionSpec(*(None,) * axis, *out_sharding.spec)
-    return jnp.reshape(x, shape, out_sharding=jax.sharding.NamedSharding(
-        out_sharding.mesh, spec))
+    return jnp.reshape(x, shape, out_sharding=_batch_at(out_sharding, axis))
 
 
 def _lowbias32(x: jax.Array) -> jax.Array:
@@ -179,60 +205,83 @@ def _lowbias32(x: jax.Array) -> jax.Array:
 
 
 def _hash_at(salt: jax.Array, idx: jax.Array) -> jax.Array:
-    """The uniform u32 of (salt, position ``idx``): see ``_hashed_bits``."""
-    return _lowbias32(_lowbias32(idx + salt) ^ (salt * jnp.uint32(0x9E3779B9)))
-
-
-def _hashed_bits(salt: jax.Array, shape: tuple) -> jax.Array:
-    """Deterministic uniform u32s from (salt, global position).
+    """Deterministic uniform u32s from (salt, position ``idx``).
 
     Why not ``jax.random.bits`` here: threefry over a big batch-sharded
     shape makes GSPMD all-gather partial RNG state inside the threefry
     loop on every step — wasted ICI bandwidth, and it deadlocks XLA:CPU's
     in-process collectives under overlapped launches (observed on the
-    8-device virtual mesh). A counter-based hash of the global position
-    is iota + elementwise ops only: partitions over any mesh with ZERO
+    8-device virtual mesh). A counter-based hash of a slot's position is
+    iota + elementwise ops only: partitions over any mesh with ZERO
     collectives, and identical results regardless of device count.
     Threefry stays for the scalar per-step salts, so streams across
     steps/hops remain independent.
     """
-    idx = jnp.zeros(shape, jnp.uint32)
-    mult = 1
-    for d in reversed(range(len(shape))):
-        idx = idx + jax.lax.broadcasted_iota(jnp.uint32, shape, d) * jnp.uint32(mult)
-        mult *= shape[d]
-    return _hash_at(salt, idx)
+    return _lowbias32(_lowbias32(idx + salt) ^ (salt * jnp.uint32(0x9E3779B9)))
+
+
+def _lead_positions(lead: tuple) -> np.ndarray:
+    """Where each leading index of a ``[*lead, B]`` tensor, taken row by
+    row, stands among the trailing axes of the logical ``[B, *reversed
+    lead]`` tensor: node ``(l, b)`` is at ``_lead_positions(lead)[l] +
+    prod(lead) * b`` there."""
+    n = math.prod(lead)
+    return np.arange(n, dtype=np.uint32).reshape(lead[::-1]).T.reshape(n)
 
 
 def sample_neighbors(graph, nodes: jax.Array, fanout: int,
                      salt: jax.Array, out_sharding=None):
-    """Fanout-sample WITH replacement for each node; returns
-    (nbr_idx, rtt, mask), each ``nodes.shape + (fanout,)``.
+    """Fanout-sample WITH replacement for each node of ``nodes [*lead,
+    B]``; returns (nbr_idx, rtt, mask), each ``[fanout, *lead, B]``.
 
-    Mirrors CSRGraph.sample_neighbors (host half) exactly: padded slots
+    **The layout** (models/graphsage.py has the model's side): a hop's
+    tensors carry the batch as their last axis (in the lanes; the one
+    sharded axis, ``out_sharding`` being a ``[B]`` vector's sharding) and
+    every fan-out before it, the newest first: the axes of the host
+    sampler's ``[B, 2, f1, f2]`` in reverse. The row sampler's slices
+    produce a hop that way, and so it leaves: nothing is transposed into
+    batch order, hop 2 runs over hop 1's ids as hop 1 left them, and the
+    features are gathered in that order.
+
+    **The samples** are those of that logical tensor: slot ``k`` of a
+    node hashes ``position * fanout + k``, the node's ``position`` being
+    its row-major index in ``nodes`` with the axes reversed
+    (``benchmarks/references/graphsage.py: _draw``'s definition), so
+    ``result.T`` equals the batch-major sampler's tensor bit for bit.
+    Otherwise as CSRGraph.sample_neighbors (host half): padded slots
     (zero-degree nodes) carry index 0 / rtt 0 / mask 0; positive-degree
     nodes always fill all ``fanout`` replacement-sampled slots. ``graph``
     is what :func:`put_graph_tables` placed; either form gives the same
-    samples.
+    samples in the same layout.
     """
+    lead, batch = nodes.shape[:-1], nodes.shape[-1]
+    rows = math.prod(lead)
     if isinstance(graph, GraphTables):
-        return _sample_csr(graph, nodes, fanout, salt, out_sharding)
-    lead, rest = nodes.shape[0], nodes.shape[1:]
-    held = (lead if out_sharding is None
-            else out_sharding.shard_shape(nodes.shape)[0])
-    row_bytes = 2 * 4 * graph.nbr_rows.shape[1] * math.prod(rest)
+        at = (jnp.asarray(_lead_positions(lead)).reshape(lead + (1,))
+              + jax.lax.broadcasted_iota(
+                  jnp.uint32, nodes.shape, len(lead),
+                  out_sharding=_batch_at(out_sharding, len(lead)))
+              * jnp.uint32(rows))
+        return _sample_csr(graph, nodes, at, fanout, salt, out_sharding)
+    held = (batch if out_sharding is None
+            else out_sharding.shard_shape((batch,))[0])
+    row_bytes = 2 * 4 * graph.nbr_rows.shape[1] * rows
     chunks = min(-(-held * row_bytes // ROW_CHUNK_BYTES), held)
     chunks = next(c for c in range(chunks, held + 1) if held % c == 0)
-    # Slice j is rows [j * size, (j + 1) * size) of every device's shard
-    # of the batch: cutting moves nothing, on one device or on several.
-    shards, size = lead // held, held // chunks
-    slices = jnp.moveaxis(
-        _reshape(nodes, (shards, chunks, size) + rest, out_sharding), 1, 0)
+    # Slice j is batch rows [j * size, (j + 1) * size) of every device's
+    # shard of the batch, under every leading index, shards first so that
+    # it flattens shard by shard: cutting moves nothing, on one device or
+    # on several.
+    shards, size = batch // held, held // chunks
+    slices = jnp.transpose(
+        _reshape(nodes, (rows, shards, chunks, size), out_sharding, axis=1),
+        (2, 1, 0, 3))
+    leads = jnp.asarray(_lead_positions(lead))
 
     def one(xs):
-        at = _positions(xs[0].shape, xs[1] * jnp.uint32(size), held,
+        at = _positions(xs[0].shape, xs[1] * jnp.uint32(size), leads, held,
                         out_sharding)
-        return _sample_rows(graph, _reshape(xs[0], (at.size,), out_sharding),
+        return _sample_rows(graph, _reshape(xs[0], (at.size,), out_sharding, 0),
                             at, fanout, salt, out_sharding)
 
     firsts = jnp.arange(chunks, dtype=jnp.uint32)
@@ -242,58 +291,70 @@ def sample_neighbors(graph, nodes: jax.Array, fanout: int,
         nbr, rtt, has = jax.lax.map(one, (slices, firsts))
 
     def whole(x, tail):
-        """``[chunks, *tail, nodes of a slice]`` as ``[*nodes.shape, *tail]``:
-        the one relayout of a hop's samples, after the loop."""
+        """``[chunks, *tail, nodes of a slice]`` as ``[*tail, *lead, B]``:
+        the slices take their places inside the batch, each a run of
+        ``size`` lanes that moves whole; nothing is transposed into batch
+        order, the batch stays in the lanes."""
         n = len(tail)
-        x = _reshape(x, (chunks,) + tail + (shards, size) + rest,
+        x = _reshape(x, (chunks,) + tail + (shards, rows, size),
                      out_sharding, axis=1 + n)
-        x = jnp.moveaxis(x, range(1, 1 + n), range(x.ndim - n, x.ndim))
-        return _reshape(jnp.swapaxes(x, 0, 1), nodes.shape + tail,
-                        out_sharding)
+        x = jnp.transpose(x, (*range(1, 1 + n), 2 + n, 1 + n, 0, 3 + n))
+        return _reshape(x, tail + nodes.shape, out_sharding,
+                        axis=n + len(lead))
 
-    mask = jnp.broadcast_to(whole(has, ())[..., None],
-                            nodes.shape + (fanout,))
+    mask = jnp.broadcast_to(whole(has, ())[None], (fanout,) + nodes.shape)
     return whole(nbr, (fanout,)), whole(rtt, (fanout,)), mask
 
 
-def _sample_csr(graph: GraphTables, nodes, fanout, salt, out_sharding):
-    start = _gather(graph.indptr, nodes, out_sharding)
-    deg = _gather(graph.indptr, nodes + 1, out_sharding) - start
-    bits = _hashed_bits(salt, nodes.shape + (fanout,))
+def _sample_csr(graph: GraphTables, nodes, at, fanout, salt, out_sharding):
+    """The hop on the CSR tables, ``at`` the nodes' positions."""
+    start = gather_nodes(graph.indptr, nodes, out_sharding)
+    deg = gather_nodes(graph.indptr, nodes + 1, out_sharding) - start
+    slot = jax.lax.broadcasted_iota(
+        jnp.uint32, (fanout,) + (1,) * nodes.ndim, 0)
+    bits = _hash_at(salt, at[None] * jnp.uint32(fanout) + slot)
     safe_deg = jnp.maximum(deg, 1).astype(jnp.uint32)
-    offs = (bits % safe_deg[..., None]).astype(jnp.int32)
-    pos = start[..., None] + offs
+    pos = start[None] + (bits % safe_deg[None]).astype(jnp.int32)
     # Zero-degree tail nodes point at indptr[-1] == E (out of bounds);
     # their mask is 0, any in-bounds position works — clamp.
     pos = jnp.minimum(pos, graph.indices.shape[0] - 1)
-    nbr = _gather(graph.indices, pos, out_sharding)
-    rtt = _gather(graph.edge_rtt, pos, out_sharding)
+    nbr = gather_nodes(graph.indices, pos, out_sharding)
+    rtt = gather_nodes(graph.edge_rtt, pos, out_sharding)
     mask = jnp.broadcast_to(
-        (deg > 0).astype(jnp.float32)[..., None], pos.shape)
+        (deg > 0).astype(jnp.float32)[None], pos.shape)
     return jnp.where(mask > 0, nbr, 0), rtt * mask, mask
 
 
-def _pick(rows: jax.Array, offs: jax.Array) -> jax.Array:
-    """``rows[m, offs[f, m]]`` of int32 ``rows [m, W]`` as ``[f, m]``,
-    with no gather: one lane of each row survives the select, so the sum
-    over the lanes is that lane's value, bit for bit. (Slots-major, as
-    the compiler lays the result out whatever its logical shape.)"""
-    lane = jax.lax.broadcasted_iota(jnp.int32, (1, 1, rows.shape[1]), 2)
-    return jnp.sum(jnp.where(offs[:, :, None] == lane, rows[None], 0),
-                   axis=-1)
+def _pick(tables: tuple, offs: jax.Array) -> tuple:
+    """``rows[m, offs[f, m]]`` as ``[f, m]`` for each int32 ``rows [m,
+    W]`` of ``tables``, with no gather: one lane of each row survives the
+    select, so the sum over the lanes is that lane's value, bit for bit.
+    (Slots leading and nodes in the lanes: the hop's layout.) One reduce
+    over all the tables, so that the lanes are compared with the offsets
+    once whatever the compiler would make of two."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, 1, tables[0].shape[1]), 2)
+    hit = offs[:, :, None] == lane
+    return jax.lax.reduce(
+        tuple(jnp.where(hit, rows[None], 0) for rows in tables),
+        (jnp.int32(0),) * len(tables),
+        lambda x, y: tuple(a + b for a, b in zip(x, y)), (2,))
 
 
-def _positions(shape: tuple, first, held: int, out_sharding) -> jax.Array:
-    """Where the nodes of a slice ``[shards, size, ...]`` stand in the
-    whole hop, flat: ``nodes[s, i]`` is batch row ``s * held + first + i``
-    there. The hash takes a slot's position in the hop, so a slice draws
-    the offsets the CSR path draws."""
-    per_row = math.prod(shape[2:])
-    shard, i, r = (
-        jax.lax.broadcasted_iota(jnp.uint32, shape[:2] + (per_row,), d)
-        for d in range(3))
-    at = (shard * jnp.uint32(held) + first + i) * jnp.uint32(per_row) + r
-    return _reshape(at, (at.size,), out_sharding)
+def _positions(shape: tuple, first, leads, held: int,
+               out_sharding) -> jax.Array:
+    """Where the nodes of a slice ``[shards, rows, size]`` stand in the
+    hop's logical tensor (:func:`sample_neighbors`), flat: ``nodes[s, l,
+    i]`` is batch row ``s * held + first + i`` of the row that stands at
+    ``leads[l]`` among the ``rows`` of a batch row there. The hash takes
+    a slot's position in the whole hop, so a slice draws the offsets the
+    CSR path draws."""
+    # Born sharded: a device counts its own shard's positions only.
+    shard, i = (jax.lax.broadcasted_iota(jnp.uint32, shape, d,
+                                         out_sharding=out_sharding)
+                for d in (0, 2))
+    at = ((shard * jnp.uint32(held) + first + i) * jnp.uint32(shape[1])
+          + leads[:, None])
+    return _reshape(at, (at.size,), out_sharding, 0)
 
 
 def _sample_rows(graph: RowTables, nodes, at, fanout: int, salt,
@@ -302,7 +363,7 @@ def _sample_rows(graph: RowTables, nodes, at, fanout: int, salt,
     ``at`` their positions in the whole hop): ``(ids [fanout, m], RTTs
     [fanout, m], degree > 0 [m])``."""
     with jax.named_scope("df2.sample.rows"):
-        ids = _gather(graph.nbr_rows, nodes, out_sharding)
+        ids = gather_nodes(graph.nbr_rows, nodes, out_sharding)
     with jax.named_scope("df2.sample.pick"):
         slot = jax.lax.broadcasted_iota(jnp.uint32, (fanout, 1), 0)
         # The barrier keeps the degrees one column read, not one a use.
@@ -317,42 +378,57 @@ def _sample_rows(graph: RowTables, nodes, at, fanout: int, salt,
     # fetch, and a row out of it costs a quarter of one out of HBM.
     offs, nodes = jax.lax.optimization_barrier((offs, nodes))
     with jax.named_scope("df2.sample.rows"):
-        rtts = _gather(graph.rtt_rows, nodes, out_sharding)
+        rtts = gather_nodes(graph.rtt_rows, nodes, out_sharding)
     with jax.named_scope("df2.sample.pick"):
-        nbr = _pick(ids, offs)
-        rtt = jax.lax.bitcast_convert_type(_pick(rtts, offs), jnp.float32)
-    return nbr, rtt, (deg > 0).astype(jnp.float32)
+        nbr, rtt = _pick((ids, rtts), offs)
+    return (nbr, jax.lax.bitcast_convert_type(rtt, jnp.float32),
+            (deg > 0).astype(jnp.float32))
 
 
-def sample_and_apply(model: GraphSAGE, params, graph,
-                     src, dst, key: jax.Array, fanouts: tuple,
-                     out_sharding=None):
-    """Sample the 2-hop neighborhood on device and run the forward pass.
+def sample_two_hops(graph, src, dst, key: jax.Array, fanouts: tuple,
+                    out_sharding=None):
+    """The 2-hop neighbourhood of a batch of edges, sampled on device in
+    the module's one layout: ``(centers [2, B], nbr1 [f1, 2, B], rtt1,
+    mask1, nbr2 [f2, f1, 2, B], rtt2, mask2)``, the second hop's mask
+    already zero under a padded first-hop slot.
 
     ``key`` only seeds two SCALAR salts (tiny replicated threefry); the
     per-slot randomness comes from the counter hash above.
-
-    The phases carry ``df2.*`` scopes (``df2.sample.hop1``,
-    ``df2.sample.hop2``, ``df2.features``, ``df2.model``): metadata on
-    the operations, by which ``df2-trace-tool train`` splits a device
-    trace (docs/OBSERVABILITY.md "Training loops").
     """
     f1, f2 = fanouts
     k1, k2 = jax.random.split(key)
     s1 = jax.random.bits(k1, (), jnp.uint32)
     s2 = jax.random.bits(k2, (), jnp.uint32)
-    centers = jnp.stack([src, dst], axis=-1)                     # [B, 2]
+    centers = jnp.stack([src, dst], axis=0)                      # [2, B]
     with jax.named_scope("df2.sample.hop1"):
         nbr1, rtt1, mask1 = sample_neighbors(
             graph, centers, f1, s1, out_sharding)
     with jax.named_scope("df2.sample.hop2"):
         nbr2, rtt2, mask2 = sample_neighbors(
             graph, nbr1, f2, s2, out_sharding)
-        mask2 = mask2 * mask1[..., None]
+        mask2 = mask2 * mask1[None]
+    return centers, nbr1, rtt1, mask1, nbr2, rtt2, mask2
+
+
+def sample_and_apply(model: GraphSAGE, params, graph,
+                     src, dst, key: jax.Array, fanouts: tuple,
+                     out_sharding=None):
+    """Sample the 2-hop neighborhood on device and run the forward pass:
+    ids, RTTs, masks and feature rows go from the sampler's slices to the
+    model's sums in one order (:func:`sample_neighbors`), fan-outs
+    leading and the batch trailing, and are never laid out again.
+
+    The phases carry ``df2.*`` scopes (``df2.sample.hop1``,
+    ``df2.sample.hop2``, ``df2.features``, ``df2.model``): metadata on
+    the operations, by which ``df2-trace-tool train`` splits a device
+    trace (docs/OBSERVABILITY.md "Training loops").
+    """
+    centers, nbr1, rtt1, mask1, nbr2, rtt2, mask2 = sample_two_hops(
+        graph, src, dst, key, fanouts, out_sharding)
     with jax.named_scope("df2.features"):
-        feat0 = _gather(graph.node_features, centers, out_sharding)
-        feat1 = _gather(graph.node_features, nbr1, out_sharding)
-        feat2 = _gather(graph.node_features, nbr2, out_sharding)
+        feat0, feat1, feat2 = (
+            gather_nodes(graph.node_features, idx, out_sharding)
+            for idx in (centers, nbr1, nbr2))
     with jax.named_scope("df2.model"):
         return model.apply(params, feat0, feat1, rtt1, mask1,
                            feat2, rtt2 * mask2, mask2)
@@ -361,7 +437,7 @@ def sample_and_apply(model: GraphSAGE, params, graph,
 def _batch_rows(edges: EdgeTables, edge_ids, out_sharding):
     """(src, dst, labels) of one id batch, under ``df2.batch``."""
     with jax.named_scope("df2.batch"):
-        return tuple(_gather(table, edge_ids, out_sharding)
+        return tuple(gather_nodes(table, edge_ids, out_sharding)
                      for table in edges)
 
 

@@ -31,8 +31,9 @@ from flax.training import train_state
 from dragonfly2_tpu.data.features import Graph
 from dragonfly2_tpu.data.graph_sampler import CSRGraph, EdgeBatchSampler
 from dragonfly2_tpu.data.prefetch import prefetch
+from dragonfly2_tpu.train.fused_sampling import gather_nodes
 from dragonfly2_tpu.train.step_budget import StepBudget, epoch_mean
-from dragonfly2_tpu.models.graphsage import GraphSAGE
+from dragonfly2_tpu.models.graphsage import GraphSAGE, nodes_last
 from dragonfly2_tpu.parallel import MeshContext, data_parallel_mesh
 
 
@@ -127,23 +128,24 @@ def apply_indexed(model: GraphSAGE, params, node_features, center_idx,
     """Forward pass from an IndexEdgeBatch: on-device feature gather from
     the replicated node table, then the dense GraphSAGE graph.
 
+    The host sampler's arrays are batch-major (``[B, 2, f1(, f2)]``); the
+    model takes fan-outs leading and the batch trailing
+    (models/graphsage.py), so this edge turns them over, the indices
+    before their gathers: a transpose of a batch's index arrays, once.
+
     Under a mesh, gathering a replicated table with batch-sharded indices
     needs the output sharding stated explicitly (each device gathers its
     own index shard locally — no collective); single-device jit leaves
     ``out_sharding`` None.
     """
-    if out_sharding is None:
-        def gather(idx):
-            return node_features[idx]
-    else:
-        def gather(idx):
-            return node_features.at[idx].get(out_sharding=out_sharding)
+    def gather(idx):
+        return gather_nodes(node_features, idx.T, out_sharding)
 
     return model.apply(
         params,
         gather(center_idx),
-        gather(nbr1_idx), nbr1_rtt, nbr1_mask,
-        gather(nbr2_idx), nbr2_rtt, nbr2_mask,
+        gather(nbr1_idx), nbr1_rtt.T, nbr1_mask.T,
+        gather(nbr2_idx), nbr2_rtt.T, nbr2_mask.T,
     )
 
 
@@ -233,7 +235,8 @@ def train_gnn(
               else jax.device_put(csr.node_features, mesh.replicated))
     dummy = train_sampler.sample(np.zeros(2, np.int64), np.random.default_rng(0))
     params = model.init(
-        jax.random.key(config.seed), *map(jnp.asarray, dummy.astuple()[:-1])
+        jax.random.key(config.seed),
+        *nodes_last(*map(jnp.asarray, dummy.astuple()[:-1]))
     )
     steps_per_epoch = max(train_sampler.n_edges // batch_size, 1)
     total_steps = max(config.epochs * steps_per_epoch, 2)

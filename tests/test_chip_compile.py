@@ -371,13 +371,33 @@ def test_the_third_familys_step_fits_the_chip(topo, as_tpu_program):
         for ln in compiled.as_text().splitlines())
 
 
+def _runs_on_its_own(text: str) -> str:
+    """The instructions of a compiled program that run on their own: the
+    entry computation's and the loops' bodies' (the same words inside a
+    fused computation are free)."""
+    names = {m.group(1) for m in re.finditer(
+        r"^ENTRY (%[\w.\-]+) ", text, re.M)}
+    names |= set(re.findall(r"\bbody=(%[\w.\-]+)", text))
+    return "\n".join(
+        block for block in text.split("\n\n")
+        if block.lstrip().removeprefix("ENTRY ").split(" ", 1)[0] in names)
+
+
 @pytest.mark.parametrize("chips", [1, 4])
 def test_fused_graphsage_step_at_the_cells_shapes(topo, chips):
     """``sage-fleet100k.train`` (and ``.dp4``: the same rows a chip): the
     whole fused step on the row tables (100,000 hosts, rows 256 lanes
     wide, 9,999,650 target edges, batch 131,072 a chip, fan-outs (10, 5))
     fits the chip with room, hop 2 runs in slices, and sampling adds no
-    collective to the data-parallel step's two gradient all-reduces."""
+    collective to the data-parallel step's two gradient all-reduces.
+
+    **The layout** (PR 34): a hop's tensors go from the sampler's slices
+    through the feature gather to the model's sums fan-outs leading and
+    the batch trailing, so nothing lays hop 2's 13,107,200 feature rows
+    (lane-padded: 3.4 GB) out again. Until then a re-tiling reshape and
+    a copy of ``bf16[131072,2,10,5,8]``, a reshape of the ``s32[13107200]``
+    ids, a transpose of every hop into batch order and a relayout of hop
+    1's rows were 43.5 of the step's 133.4 ms (ledger, PR 33)."""
     import optax
     from flax.training import train_state
 
@@ -386,6 +406,7 @@ def test_fused_graphsage_step_at_the_cells_shapes(topo, chips):
     from dragonfly2_tpu.train import fused_sampling as fs
 
     hosts, records, batch, feat, width = 100_000, 9_999_650, 131_072, 8, 256
+    f1, f2 = fanouts = (10, 5)
     mesh = data_parallel_mesh(devices=topo.devices[:chips])
     rep = _struct(mesh.replicated)
     model = GraphSAGE()
@@ -393,9 +414,9 @@ def test_fused_graphsage_step_at_the_cells_shapes(topo, chips):
     def init(key):
         z = jnp.zeros
         params = model.init(
-            key, z((2, 2, feat)), z((2, 2, 10, feat)), z((2, 2, 10)),
-            z((2, 2, 10)), z((2, 2, 10, 5, feat)), z((2, 2, 10, 5)),
-            z((2, 2, 10, 5)))
+            key, z((2, 2, feat)), z((f1, 2, 2, feat)), z((f1, 2, 2)),
+            z((f1, 2, 2)), z((f2, f1, 2, 2, feat)), z((f2, f1, 2, 2)),
+            z((f2, f1, 2, 2)))
         return train_state.TrainState.create(
             apply_fn=model.apply, params=params, tx=optax.adamw(1e-3))
 
@@ -409,15 +430,16 @@ def test_fused_graphsage_step_at_the_cells_shapes(topo, chips):
                           rep((records,), jnp.float32))
     ids = _struct(mesh.batch_sharding)((batch * chips,), jnp.int32)
     key = jax.eval_shape(lambda: jax.random.key(0))
-    compiled = fs.make_fused_train_step(model, mesh, (10, 5)).lower(
+    compiled = fs.make_fused_train_step(model, mesh, fanouts).lower(
         state, graph, edges, ids, rep(key.shape, key.dtype)).compile()
     memory = compiled.memory_analysis()
-    # 0.33 + 9.01 GB, PR 28 (the CSR step: 0.21 + 8.82).
+    # 0.33 + 4.79 GB, PR 34 (0.33 + 9.01 with the re-tiled copy of hop
+    # 2's feature rows, PR 28; the CSR step: 0.21 + 8.82).
     assert (memory.argument_size_in_bytes
-            + memory.temp_size_in_bytes) < 14e9
+            + memory.temp_size_in_bytes) < 8e9
     text = compiled.as_text()
     assert "df2.sample.hop2)/while/body" in text
-    assert text.count(f"s32[163840,{width}]") > 0      # a slice's rows
+    assert text.count(f"s32[327680,{width}]") > 0      # a slice's rows
     for op in ("all-gather(", "collective-permute(", "all-to-all("):
         assert op not in text, op
     assert text.count("all-reduce(") == (2 if chips > 1 else 0)
@@ -434,3 +456,28 @@ def test_fused_graphsage_step_at_the_cells_shapes(topo, chips):
             rf"^\s*{re.escape(table)} = (s32\[{hosts},{width}\]\S*) ",
             text, re.M)
         assert layout.endswith("S(1)}"), (table, layout)
+    # The layout. Nothing is batch-major any more...
+    assert not re.search(rf"\[{batch},2,{f1}(,{f2})?(,\d+)?\]", text)
+    # ... and of the instructions that run on their own, those that move
+    # a tensor without computing on it (a copy, a transpose, a reshape
+    # that is no bitcast):
+    slots = 2 * batch * f1 * f2
+    moved = [(dtype, math.prod(map(int, dims.split(","))), line)
+             for line, dtype, dims in re.findall(
+                 r"^\s*((?:ROOT )?%[\w.\-]+ = (\w+)\[([\d,]+)\]\S* "
+                 r"(?:copy|transpose|reshape)\()", _runs_on_its_own(text),
+                 re.M)]
+    # at most one over hop 2's feature rows (none today: the masked sum
+    # reads the gather's rows as it wrote them),
+    rows = [line for dtype, size, line in moved
+            if dtype != "s32" and size >= slots * feat]
+    assert len(rows) <= 1, rows
+    # and of hop 2's ids between the sampler and the gather no flat
+    # ``s32[13107200]`` is made again (4.2 ms until PR 34); what is left
+    # moves whole tiles: the slices into their places in the batch and
+    # the ``[.., 2, B]`` tiles into the flat gather's (0.2 ms each by the
+    # compiler's estimate).
+    ids_moved = [line for dtype, size, line in moved
+                 if dtype == "s32" and size == slots]
+    assert not any(f"s32[{slots}]" in line for line in ids_moved), ids_moved
+    assert len(ids_moved) <= 2, ids_moved

@@ -38,23 +38,24 @@ class TestDeviceSampling:
             put_graph_tables, sample_neighbors)
 
         gt = put_graph_tables(csr, mesh)
-        nodes = np.array([[0, 1], [2, 3], [4, 5], [6, 7]], np.int32)
+        # Fan-out leading, the batch trailing: four edges' (src, dst).
+        nodes = np.array([[0, 2, 4, 6], [1, 3, 5, 7]], np.int32)
         nbr, rtt, mask = jax.jit(
             lambda n, s: sample_neighbors(gt, n, 7, s)
         )(mesh.put_replicated(nodes), np.uint32(42))
         nbr, rtt, mask = map(np.asarray, (nbr, rtt, mask))
-        assert nbr.shape == rtt.shape == mask.shape == (4, 2, 7)
-        for i in range(4):
-            for j in range(2):
-                v = nodes[i, j]
+        assert nbr.shape == rtt.shape == mask.shape == (7, 2, 4)
+        for j in range(2):
+            for i in range(4):
+                v = nodes[j, i]
                 real = set(csr.indices[csr.indptr[v]:csr.indptr[v + 1]])
                 deg = len(csr.indices[csr.indptr[v]:csr.indptr[v + 1]])
                 if deg == 0:
-                    assert mask[i, j].sum() == 0
+                    assert mask[:, j, i].sum() == 0
                 else:
-                    assert mask[i, j].sum() == 7  # replacement fills all
+                    assert mask[:, j, i].sum() == 7  # replacement fills all
                     for k in range(7):
-                        assert nbr[i, j, k] in real
+                        assert nbr[k, j, i] in real
 
     def test_zero_degree_last_node_padded(self, graph, mesh):
         """The highest-indexed node with no out-edges hits the CSR
@@ -119,15 +120,17 @@ class TestDeviceSampling:
         sampling: mod-8 buckets of a large draw within 5% of uniform."""
         import jax
 
-        from dragonfly2_tpu.train.fused_sampling import _hashed_bits
+        import jax.numpy as jnp
 
-        bits = np.asarray(jax.jit(
-            lambda s: _hashed_bits(s, (1 << 16,)))(np.uint32(123)))
+        from dragonfly2_tpu.train.fused_sampling import _hash_at
+
+        draw = jax.jit(lambda s: _hash_at(
+            s, jnp.arange(1 << 16, dtype=jnp.uint32)))
+        bits = np.asarray(draw(np.uint32(123)))
         counts = np.bincount(bits % 8, minlength=8) / len(bits)
         assert np.all(np.abs(counts - 1 / 8) < 0.05 / 8 + 0.01)
         # And successive salts decorrelate.
-        bits2 = np.asarray(jax.jit(
-            lambda s: _hashed_bits(s, (1 << 16,)))(np.uint32(124)))
+        bits2 = np.asarray(draw(np.uint32(124)))
         assert (bits == bits2).mean() < 0.01
 
 
@@ -155,29 +158,65 @@ def _csr_form(fs, monkeypatch, csr, mesh):
         return fs.put_graph_tables(csr, mesh)
 
 
+def _lowbias32(x):
+    x = x ^ (x >> np.uint32(16))
+    x = x * np.uint32(0x7FEB352D)
+    x = x ^ (x >> np.uint32(15))
+    x = x * np.uint32(0x846CA68B)
+    return x ^ (x >> np.uint32(16))
+
+
+def _host_draw(csr, nodes, fanout, salt):
+    """The host sampler's tensors ``nodes.shape + (fanout,)`` for the
+    device's hash: a slot's offset is the hash of its row-major position
+    there, modulo the node's degree
+    (``benchmarks/references/graphsage.py: _draw``, in numpy)."""
+    shape = nodes.shape + (fanout,)
+    position = np.arange(np.prod(shape), dtype=np.uint32).reshape(shape)
+    salt = np.uint32(salt)
+    with np.errstate(over="ignore"):
+        bits = _lowbias32(_lowbias32(position + salt)
+                          ^ (salt * np.uint32(0x9E3779B9)))
+    start = csr.indptr[nodes][..., None]
+    deg = (csr.indptr[nodes + 1] - csr.indptr[nodes])[..., None]
+    pos = np.minimum(start + bits % np.maximum(deg, 1).astype(np.uint32),
+                     len(csr.indices) - 1)
+    mask = np.broadcast_to(deg > 0, shape).astype(np.float32)
+    return (np.where(mask > 0, csr.indices[pos], 0).astype(np.int32),
+            (csr.edge_rtt[pos] * mask).astype(np.float32), mask)
+
+
 class TestRowTables:
     @pytest.mark.parametrize("sliced", [False, True],
                              ids=["one_piece", "sliced"])
-    @pytest.mark.parametrize("devices", [1, 4])
-    def test_row_path_draws_the_csr_paths_samples(self, monkeypatch,
-                                                  devices, sliced):
-        """Both hops at fan-outs (10, 5), as ``sample_and_apply`` chains
-        them: ids, RTTs and masks equal bit for bit, under jit on one
-        device and under the batch sharding of a four-device mesh, in one
-        piece and in slices of the batch."""
+    @pytest.mark.parametrize("devices", [1, 4, 8])
+    @pytest.mark.parametrize("form", ["rows", "csr"])
+    def test_both_forms_draw_the_host_samplers_slots(self, monkeypatch, form,
+                                                     devices, sliced):
+        """Both hops at fan-outs (10, 5), as ``sample_two_hops`` chains
+        them, on either form of the graph: fan-outs leading and the batch
+        trailing (``[10, 2, B]``, ``[5, 10, 2, B]``), and slot for slot the
+        ids, RTTs and masks of the host sampler's row-major positions
+        (``result.T`` is its ``[B, 2, 10(, 5)]`` tensor bit for bit),
+        under jit on one device and under the batch sharding of a four-
+        and an eight-device mesh, in one piece and in slices of the
+        batch."""
         import jax
 
         from dragonfly2_tpu.train import fused_sampling as fs
 
         mesh = data_parallel_mesh(devices=jax.devices()[:devices])
         csr = _skewed_csr()
-        rows = fs.put_graph_tables(csr, mesh)
-        assert isinstance(rows, fs.RowTables)
-        assert rows.nbr_rows.shape == rows.rtt_rows.shape == (6, 128)
-        flat = _csr_form(fs, monkeypatch, csr, mesh)
-        assert isinstance(flat, fs.GraphTables)
+        if form == "rows":
+            graph = fs.put_graph_tables(csr, mesh)
+            assert isinstance(graph, fs.RowTables)
+            assert graph.nbr_rows.shape == graph.rtt_rows.shape == (6, 128)
+        else:
+            graph = _csr_form(fs, monkeypatch, csr, mesh)
+            assert isinstance(graph, fs.GraphTables)
         if sliced:
-            # 64 batch rows of 2 and of 20 nodes: 2 and 16 slices.
+            # 64 batch rows of 2 and of 20 nodes: hop 2 in 16 slices on
+            # one device, 4 on four and 2 on eight; hop 1 in 2 on one.
             monkeypatch.setattr(fs, "ROW_CHUNK_BYTES", 80 * 1024)
         b = mesh.batch_sharding if devices > 1 else None
         centers = np.random.default_rng(1).integers(
@@ -185,33 +224,41 @@ class TestRowTables:
         centers[0] = (1, 5)                      # the zero-degree hosts
         centers[1] = (2, 2)                      # the full row
 
-        def two_hops(graph, centers, s1, s2):
-            nbr1, rtt1, mask1 = fs.sample_neighbors(graph, centers, 10, s1, b)
+        def two_hops(graph, src, dst, s1, s2):
+            import jax.numpy as jnp
+
+            nodes = jnp.stack([src, dst], axis=0)
+            nbr1, rtt1, mask1 = fs.sample_neighbors(graph, nodes, 10, s1, b)
             nbr2, rtt2, mask2 = fs.sample_neighbors(graph, nbr1, 5, s2, b)
             return nbr1, rtt1, mask1, nbr2, rtt2, mask2
 
         run = jax.jit(two_hops, in_shardings=(
-            mesh.replicated, b or mesh.replicated, None, None))
-        args = (mesh.put_batch(centers) if b else centers,
-                np.uint32(0xDEADBEEF), np.uint32(77))
-        got, want = run(rows, *args), run(flat, *args)
+            mesh.replicated, b or mesh.replicated, b or mesh.replicated,
+            None, None))
+        s1, s2 = 0xDEADBEEF, 77
+        args = (*(mesh.put_batch(c) if b else c for c in centers.T.copy()),
+                np.uint32(s1), np.uint32(s2))
+        got = run(graph, *args)
+        want1 = _host_draw(csr, centers, 10, s1)
+        want = want1 + _host_draw(csr, want1[0], 5, s2)
         for name, x, y in zip("nbr1 rtt1 mask1 nbr2 rtt2 mask2".split(),
                               got, want):
-            assert x.shape == y.shape and x.dtype == y.dtype, name
+            assert x.shape == y.shape[::-1] and x.dtype == y.dtype, name
             np.testing.assert_array_equal(
-                np.asarray(x).view(np.int32), np.asarray(y).view(np.int32),
+                np.asarray(x).T.view(np.int32), y.view(np.int32),
                 err_msg=name)
-        nbr1, _, mask1 = map(np.asarray, got[:3])
+        nbr1, _, mask1 = (np.asarray(x).T for x in got[:3])
         assert mask1[0].sum() == 0 and nbr1[0].sum() == 0
         assert mask1[1].sum() == 20
         # Offsets reach the row's last entry (lane 126 of the full row).
         last = csr.indices[csr.indptr[3] - 1]
         assert last in nbr1[1]
-        text = run.lower(rows, *args).compile().as_text()
-        assert ("while" in text) == sliced
+        text = run.lower(graph, *args).compile().as_text()
+        if form == "rows":
+            assert ("while" in text) == sliced
         for op in ("all-gather", "all-reduce", "collective-permute",
                    "all-to-all"):
-            assert op not in text, f"row-path sampling contains {op}"
+            assert op not in text, f"{form}-path sampling contains {op}"
 
     def test_a_hub_keeps_the_csr_tables(self, monkeypatch, mesh):
         """One host of 300 records among 200 of 2: padding every row to
@@ -283,6 +330,53 @@ class TestRowTables:
 
 
 class TestFusedTraining:
+    @pytest.mark.parametrize("devices", [1, 8])
+    def test_host_and_device_paths_agree_on_one_batch(self, csr, devices):
+        """One model, one contract: the device path hands its tensors
+        over as it sampled them (fan-outs leading), the host path
+        (``apply_indexed``) takes the same neighbourhood batch-major, as
+        ``data/graph_sampler.py`` makes them, and turns it over at its
+        own edge. Same logits, on one device and on the virtual mesh."""
+        import jax
+        import jax.numpy as jnp
+
+        from dragonfly2_tpu.models.graphsage import GraphSAGE, nodes_last
+        from dragonfly2_tpu.train import fused_sampling as fs
+        from dragonfly2_tpu.train.gnn_trainer import apply_indexed
+
+        mesh = data_parallel_mesh(devices=jax.devices()[:devices])
+        b = mesh.batch_sharding
+        graph = fs.put_graph_tables(csr, mesh)
+        rng = np.random.default_rng(3)
+        src, dst = (mesh.put_batch(rng.integers(0, csr.n_nodes, 64).astype(
+            np.int32)) for _ in range(2))
+        model = GraphSAGE(hidden=16, embed=8, dtype=jnp.float32)
+        fanouts = (10, 5)
+        z = np.zeros
+        params = model.init(jax.random.key(0), *nodes_last(
+            z((2, 2, 8)), z((2, 2, 10, 8)), z((2, 2, 10)), z((2, 2, 10)),
+            z((2, 2, 10, 5, 8)), z((2, 2, 10, 5)), z((2, 2, 10, 5))))
+        key = mesh.put_replicated(jax.random.key(11))
+
+        device = jax.jit(lambda p, g, s, d, k: fs.sample_and_apply(
+            model, p, g, s, d, k, fanouts, b))(params, graph, src, dst, key)
+
+        def host(p, g, s, d, k):
+            *hop1, nbr2, rtt2, mask2 = fs.sample_two_hops(
+                g, s, d, k, fanouts, b)
+            # What the host sampler would have shipped: batch-major, the
+            # second hop's RTTs zero under a padded slot.
+            return apply_indexed(
+                model, p, g.node_features,
+                *(x.T for x in (*hop1, nbr2, rtt2 * mask2, mask2)),
+                out_sharding=b)
+
+        np.testing.assert_allclose(
+            np.asarray(jax.jit(host)(params, graph, src, dst, key)),
+            np.asarray(device), rtol=1e-5, atol=1e-6)
+        assert np.asarray(device).shape == (64,)
+        assert np.abs(np.asarray(device)).max() > 0
+
     def test_device_and_host_paths_both_learn(self, graph, mesh):
         cfg = dict(hidden=32, embed=16, batch_size=512, epochs=10,
                    learning_rate=1e-2)

@@ -108,6 +108,88 @@ class TestSampler:
             np.testing.assert_array_equal(a, b)
 
 
+def _batch_major_logits(params, center, feat1, rtt1, mask1, feat2, rtt2,
+                        mask2):
+    """GraphSAGE as it was written until PR 34, plainly: batch-major
+    tensors, ``[features | rtt]`` concatenated slot by slot and then a
+    masked mean over ``axis=-2``."""
+    import jax
+    import jax.numpy as jnp
+
+    def mean(x, mask):
+        total = jnp.sum(x * mask[..., None], axis=-2)
+        return total / jnp.maximum(jnp.sum(mask, axis=-1), 1.0)[..., None]
+
+    def dense(x, layer):
+        return jnp.matmul(x, layer["kernel"], precision="highest") + layer["bias"]
+
+    p = params["params"]
+    l1, l2 = p["SageLayer_0"]["Dense_0"], p["SageLayer_1"]["Dense_0"]
+    x1 = jnp.concatenate([feat1, rtt1[..., None]], -1)     # [B, 2, f1, F+1]
+    x2 = jnp.concatenate([feat2, rtt2[..., None]], -1)     # [B, 2, f1, f2, F+1]
+    h1_nbr = jax.nn.relu(dense(
+        jnp.concatenate([x1, mean(x2, mask2)], -1), l1))
+    center0 = jnp.concatenate(
+        [center, jnp.zeros(center.shape[:-1] + (1,))], -1)
+    h1_center = jax.nn.relu(dense(
+        jnp.concatenate([center0, mean(x1, mask1)], -1), l1))
+    h2 = jax.nn.relu(dense(
+        jnp.concatenate([h1_center, mean(h1_nbr, mask1)], -1), l2))
+    a, b = h2[:, 0], h2[:, 1]
+    pair = jnp.concatenate([a, b, a * b, jnp.abs(a - b)], -1)
+    z = jax.nn.relu(dense(pair, p["Dense_0"]))
+    return dense(z, p["Dense_1"])[:, 0]
+
+
+class TestModelLayout:
+    @pytest.mark.parametrize("fanouts", [(10, 5), (4, 3)],
+                             ids=["cells_fanouts", "small_fanouts"])
+    def test_fanout_leading_model_is_the_batch_major_formula(self, graph,
+                                                             csr, fanouts):
+        """The model takes fan-outs leading and the batch trailing and
+        aggregates a hop before it concatenates the RTT column; in
+        float32 its logits and parameter gradients are those of the old
+        arithmetic (concatenate, then ``masked_mean`` over ``axis=-2`` of
+        batch-major tensors) on a host-sampled batch with padded slots."""
+        import jax
+        import jax.numpy as jnp
+
+        from dragonfly2_tpu.models.graphsage import GraphSAGE, nodes_last
+
+        s = EdgeBatchSampler(csr, graph.edge_src, graph.edge_dst,
+                             graph.edge_labels(), fanouts)
+        batch = s.sample(np.arange(48), np.random.default_rng(4))
+        args = [jnp.asarray(a) for a in batch.astuple()[:-1]]
+        # Padded slots in both hops, as a zero-degree host leaves them.
+        args[3] = args[3].at[:5, 0].set(0.0).at[7, 1, 1:].set(0.0)
+        args[2] = args[2] * args[3]
+        args[6] = (args[6] * args[3][..., None]).at[9, 0, 0, 1:].set(0.0)
+        args[5] = args[5] * args[6]
+        labels = jnp.asarray(batch.labels)
+        model = GraphSAGE(hidden=16, embed=8, dtype=jnp.float32)
+        params = model.init(jax.random.key(2), *nodes_last(*args))
+        assert set(params["params"]) == {
+            "SageLayer_0", "SageLayer_1", "Dense_0", "Dense_1"}
+        assert params["params"]["SageLayer_0"]["Dense_0"]["kernel"].shape == (
+            18, 16)
+
+        def loss(logits):
+            return jnp.mean(jnp.logaddexp(0.0, logits) - labels * logits)
+
+        got, got_grad = jax.value_and_grad(
+            lambda p: loss(model.apply(p, *nodes_last(*args))))(params)
+        want, want_grad = jax.value_and_grad(
+            lambda p: loss(_batch_major_logits(p, *args)))(params)
+        np.testing.assert_allclose(
+            np.asarray(model.apply(params, *nodes_last(*args))),
+            np.asarray(_batch_major_logits(params, *args)),
+            rtol=2e-5, atol=2e-6)
+        np.testing.assert_allclose(float(got), float(want), rtol=1e-6)
+        for a, b in zip(jax.tree.leaves(got_grad), jax.tree.leaves(want_grad)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=2e-4, atol=2e-6)
+
+
 class TestPrefetch:
     def test_order_preserved_and_all_yielded(self):
         from dragonfly2_tpu.data.prefetch import prefetch
@@ -168,6 +250,7 @@ class TestTrainGNN:
         import jax.numpy as jnp
 
         from dragonfly2_tpu.data.graph_sampler import CSRGraph, EdgeBatchSampler
+        from dragonfly2_tpu.models.graphsage import nodes_last
         from dragonfly2_tpu.train import checkpoint as ckpt
 
         res = train_gnn(
@@ -191,7 +274,7 @@ class TestTrainGNN:
         s = EdgeBatchSampler(csr, graph.edge_src, graph.edge_dst,
                              graph.edge_labels(), res.config.fanouts)
         batch = s.sample(np.arange(32), np.random.default_rng(0))
-        args = tuple(map(jnp.asarray, batch.astuple()[:-1]))
+        args = nodes_last(*map(jnp.asarray, batch.astuple()[:-1]))
         a = res.model.apply(res.params, *args)
         b = res.model.apply(params, *args)
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-6)
