@@ -41,7 +41,7 @@ from dragonfly2_tpu.models.graph_transformer import (
 from dragonfly2_tpu.parallel import MeshContext, data_parallel_mesh
 from dragonfly2_tpu.train.gnn_trainer import edge_split
 from dragonfly2_tpu.train.metrics import metrics_from_confusion, padded_chunks
-from dragonfly2_tpu.train.step_budget import TRAINING
+from dragonfly2_tpu.train.step_budget import TRAINING, setup_phase
 
 
 @dataclass(frozen=True)
@@ -163,34 +163,51 @@ def train_gat(
             raise ValueError(
                 f"heads ({config.heads}) and 2*hidden ({2 * config.hidden}) "
                 f"must be divisible by the model axis ({mesh.n_model})")
-    labels_all = graph.edge_labels(config.rtt_threshold_ns).astype(np.float32)
-    # Pair-level split (shared with gnn_trainer): every sighting of an
-    # eval (src, dst) pair stays out of training AND out of the bias.
-    train_ids, eval_ids = edge_split(graph, config.eval_fraction, config.seed)
-
-    # Attention structure from TRAIN edges only (leakage discipline).
-    nbr, val = build_neighbor_lists(
-        graph.n_nodes,
-        graph.edge_src[train_ids], graph.edge_dst[train_ids],
-        graph.edge_rtt_ns[train_ids],
-        cap=config.neighbor_cap,
-    )
-    # Gather mode needs rows that shard evenly over the mesh. Ring mode
-    # chunks PER-DEVICE rows, so once those exceed a chunk the row count
-    # must be a multiple of n_data·chunk.
-    if config.attention == "ring":
-        per_device = -(-graph.n_nodes // mesh.n_data)
-        multiple = (mesh.n_data * config.chunk
-                    if per_device > config.chunk else mesh.n_data)
-    else:
-        multiple = mesh.n_data
-    node_features, nbr, val, n_real = pad_graph_sparse(
-        graph.node_features, nbr, val, multiple,
-    )
-
     model = GraphTransformer(hidden=config.hidden, embed=config.embed,
                              layers=config.layers, heads=config.heads,
                              chunk=config.chunk, attention=config.attention)
+    # Set-up in three phases (docs/OBSERVABILITY.md "Training loops").
+    with setup_phase("data"):
+        labels_all = graph.edge_labels(
+            config.rtt_threshold_ns).astype(np.float32)
+        # Pair-level split (shared with gnn_trainer): every sighting of an
+        # eval (src, dst) pair stays out of training AND out of the bias.
+        train_ids, eval_ids = edge_split(graph, config.eval_fraction,
+                                         config.seed)
+
+        # Attention structure from TRAIN edges only (leakage discipline).
+        nbr, val = build_neighbor_lists(
+            graph.n_nodes,
+            graph.edge_src[train_ids], graph.edge_dst[train_ids],
+            graph.edge_rtt_ns[train_ids],
+            cap=config.neighbor_cap,
+        )
+        # Gather mode needs rows that shard evenly over the mesh. Ring
+        # mode chunks PER-DEVICE rows, so once those exceed a chunk the
+        # row count must be a multiple of n_data·chunk.
+        if config.attention == "ring":
+            per_device = -(-graph.n_nodes // mesh.n_data)
+            multiple = (mesh.n_data * config.chunk
+                        if per_device > config.chunk else mesh.n_data)
+        else:
+            multiple = mesh.n_data
+        node_features, nbr, val, n_real = pad_graph_sparse(
+            graph.node_features, nbr, val, multiple,
+        )
+
+        # Gather mode trains through the attention's own backward: with
+        # the host-built transpose of the lists (who lists each host,
+        # under which bias) dk and dv are summed host by host out of one
+        # small table (models/graph_transformer.py: _attention_bwd);
+        # autodiff's duplicate-index scatter-add serializes on a TPU. The
+        # graph is fixed for the run, so the transpose is built and
+        # placed once.
+        inv = None
+        if config.attention == "gather":
+            inv = build_inverse_index(nbr, val, model.dtype)
+            TRAINING.set(attn_inverse_slots=inv.rows.size,
+                         attn_inverse_filled=int((inv.rows >= 0).sum()))
+
     # flax's lazy_init: the parameters are drawn as ``model.init`` draws
     # them, operation by operation (so bit-equal to it on any backend,
     # which one compiled init program is not on the v5e), and the
@@ -200,46 +217,36 @@ def train_gat(
         return jax.ShapeDtypeStruct(
             a.shape, jax.dtypes.canonicalize_dtype(a.dtype))
 
-    params = model.lazy_init(
-        jax.random.key(config.seed),
-        shape_of(node_features), shape_of(nbr), shape_of(val),
-        jnp.zeros(2, jnp.int32), jnp.zeros(2, jnp.int32),
-    )
-
     batch = min(config.edge_batch_size, len(train_ids))
     steps_per_epoch = max(len(train_ids) // batch, 1)
-    total_steps = max(config.epochs * steps_per_epoch, 2)
-    schedule = optax.warmup_cosine_decay_schedule(
-        0.0, config.learning_rate, min(100, total_steps // 10 + 1), total_steps,
-    )
-    tx = optax.adamw(schedule, weight_decay=config.weight_decay)
-    state = train_state.TrainState.create(
-        apply_fn=model.apply, params=params, tx=tx)
-    if mesh.n_model > 1:
-        # Weights (and their Adam moments) shard over the model axis;
-        # TPDense reads the placement off the values at trace time.
-        state = jax.device_put(state, tp_state_shardings(state, mesh))
-    else:
-        state = mesh.put_replicated(state)
-
-    # Gather mode trains through the attention's own backward: with the
-    # host-built transpose of the lists (who lists each host, under
-    # which bias) dk and dv are summed host by host out of one small
-    # table (models/graph_transformer.py: _attention_bwd); autodiff's
-    # duplicate-index scatter-add serializes on a TPU. The graph is
-    # fixed for the run, so the transpose is built and placed once.
-    inv = None
-    if config.attention == "gather":
-        inv = build_inverse_index(nbr, val, model.dtype)
-        TRAINING.set(attn_inverse_slots=inv.rows.size,
-                     attn_inverse_filled=int((inv.rows >= 0).sum()))
+    with setup_phase("state") as placed:
+        params = model.lazy_init(
+            jax.random.key(config.seed),
+            shape_of(node_features), shape_of(nbr), shape_of(val),
+            jnp.zeros(2, jnp.int32), jnp.zeros(2, jnp.int32),
+        )
+        total_steps = max(config.epochs * steps_per_epoch, 2)
+        schedule = optax.warmup_cosine_decay_schedule(
+            0.0, config.learning_rate, min(100, total_steps // 10 + 1),
+            total_steps,
+        )
+        tx = optax.adamw(schedule, weight_decay=config.weight_decay)
+        state = train_state.TrainState.create(
+            apply_fn=model.apply, params=params, tx=tx)
+        if mesh.n_model > 1:
+            # Weights (and their Adam moments) shard over the model axis;
+            # TPDense reads the placement off the values at trace time.
+            state = jax.device_put(state, tp_state_shardings(state, mesh))
+        else:
+            state = mesh.put_replicated(state)
+        placed(state)
 
     # Graph tensors: rows sharded over data; placed once, reused each step.
     row = mesh.shard_spec("data")
-    g_feat = jax.device_put(node_features, row)
-    g_nbr = jax.device_put(nbr, row)
-    g_val = jax.device_put(val, row)
-    g_inv = None if inv is None else jax.device_put(inv, row)
+    with setup_phase("tables") as placed:
+        g_feat, g_nbr, g_val, g_inv = placed(tuple(
+            None if a is None else jax.device_put(a, row)
+            for a in (node_features, nbr, val, inv)))
     rep = mesh.replicated
 
     # K optimizer steps per dispatch: a lax.scan over stacked [K, B]
@@ -312,7 +319,8 @@ def train_gat(
             group_sizes.append(steps_per_epoch % k)
         seen_gk: set = set()
         for epoch in range(config.epochs):
-            order = rng.permutation(train_ids)
+            with span("df2.train.epoch_order"):
+                order = rng.permutation(train_ids)
             losses = []  # per-STEP losses ([gk] arrays), k-invariant
             offset = 0
             for gk in group_sizes:

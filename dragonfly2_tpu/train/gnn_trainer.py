@@ -32,7 +32,11 @@ from dragonfly2_tpu.data.features import Graph
 from dragonfly2_tpu.data.graph_sampler import CSRGraph, EdgeBatchSampler
 from dragonfly2_tpu.data.prefetch import prefetch
 from dragonfly2_tpu.train.fused_sampling import gather_nodes
-from dragonfly2_tpu.train.step_budget import StepBudget, epoch_mean
+from dragonfly2_tpu.train.step_budget import (
+    StepBudget,
+    epoch_mean,
+    setup_phase,
+)
 from dragonfly2_tpu.models.graphsage import GraphSAGE, nodes_last
 from dragonfly2_tpu.parallel import MeshContext, data_parallel_mesh
 
@@ -198,97 +202,116 @@ def train_gnn(
     mesh: MeshContext | None = None,
 ) -> GNNTrainResult:
     mesh = mesh or data_parallel_mesh()
-    labels = graph.edge_labels(config.rtt_threshold_ns)
-    train_ids, eval_ids = edge_split(graph, config.eval_fraction, config.seed)
-    batch_size = (min(config.batch_size, len(train_ids)) // mesh.n_data) * mesh.n_data
-    if batch_size == 0:
-        raise ValueError(
-            f"train split of {len(train_ids)} edges can't fill a "
-            f"{mesh.n_data}-way batch"
-        )
-
-    # Message graph contains TRAIN edges only: an eval edge's probe RTT is a
-    # deterministic function of its label, so letting eval targets appear in
-    # sampled neighborhoods would leak the answer and turn the registry f1
-    # into a probe-lookup score instead of a generalization measure.
-    train_graph = Graph(
-        node_ids=graph.node_ids,
-        node_features=graph.node_features,
-        edge_src=graph.edge_src[train_ids],
-        edge_dst=graph.edge_dst[train_ids],
-        edge_rtt_ns=graph.edge_rtt_ns[train_ids],
-    )
-    csr = CSRGraph.from_graph(train_graph)
-    train_sampler = EdgeBatchSampler(
-        csr, graph.edge_src[train_ids], graph.edge_dst[train_ids],
-        labels[train_ids], config.fanouts,
-    )
-    eval_sampler = EdgeBatchSampler(
-        csr, graph.edge_src[eval_ids], graph.edge_dst[eval_ids],
-        labels[eval_ids], config.fanouts,
-    )
-
-    model = GraphSAGE(hidden=config.hidden, embed=config.embed)
-    # Host-sampling path only; the fused path keeps features inside its
-    # replicated GraphTables instead (no second HBM copy).
-    nf_dev = (None if config.device_sample
-              else jax.device_put(csr.node_features, mesh.replicated))
-    dummy = train_sampler.sample(np.zeros(2, np.int64), np.random.default_rng(0))
-    params = model.init(
-        jax.random.key(config.seed),
-        *nodes_last(*map(jnp.asarray, dummy.astuple()[:-1]))
-    )
-    steps_per_epoch = max(train_sampler.n_edges // batch_size, 1)
-    total_steps = max(config.epochs * steps_per_epoch, 2)
-    schedule = optax.warmup_cosine_decay_schedule(
-        0.0, config.learning_rate, min(100, total_steps // 10 + 1), total_steps,
-    )
-    tx = optax.adamw(schedule, weight_decay=config.weight_decay)
-    state = train_state.TrainState.create(apply_fn=model.apply, params=params, tx=tx)
-    state = mesh.put_replicated(state)
-
-    if config.device_sample:
-        from dragonfly2_tpu.train.fused_sampling import (
-            make_fused_eval_step,
-            make_fused_train_step,
-            put_edge_tables,
-            put_graph_tables,
-        )
-
-        graph_tables = put_graph_tables(csr, mesh)
-        # On every step's span, so that a trace of any window says which
-        # sampler its steps ran (docs/OBSERVABILITY.md "Training loops").
-        step_facts = {"sampler_row_width": graph_tables.row_width}
-        # The samplers already hold the sliced/cast split arrays — reuse
-        # them instead of re-slicing ~2M-element fancy indexes.
-        train_edges = put_edge_tables(
-            train_sampler.edge_src, train_sampler.edge_dst,
-            train_sampler.labels, mesh)
-        k = max(int(config.steps_per_call), 1)
-        if k > 1:
-            from dragonfly2_tpu.train.fused_sampling import (
-                make_fused_multi_step,
+    # Set-up in three phases (docs/OBSERVABILITY.md "Training loops").
+    with setup_phase("data"):
+        labels = graph.edge_labels(config.rtt_threshold_ns)
+        train_ids, eval_ids = edge_split(graph, config.eval_fraction,
+                                         config.seed)
+        batch_size = (min(config.batch_size, len(train_ids))
+                      // mesh.n_data) * mesh.n_data
+        if batch_size == 0:
+            raise ValueError(
+                f"train split of {len(train_ids)} edges can't fill a "
+                f"{mesh.n_data}-way batch"
             )
 
-            fused_step = make_fused_multi_step(model, mesh, config.fanouts, k)
-            ids_sharding = mesh.shard_spec(None, "data")
+        # Message graph contains TRAIN edges only: an eval edge's probe
+        # RTT is a deterministic function of its label, so letting eval
+        # targets appear in sampled neighborhoods would leak the answer
+        # and turn the registry f1 into a probe-lookup score instead of a
+        # generalization measure.
+        train_graph = Graph(
+            node_ids=graph.node_ids,
+            node_features=graph.node_features,
+            edge_src=graph.edge_src[train_ids],
+            edge_dst=graph.edge_dst[train_ids],
+            edge_rtt_ns=graph.edge_rtt_ns[train_ids],
+        )
+        csr = CSRGraph.from_graph(train_graph)
+        train_sampler = EdgeBatchSampler(
+            csr, graph.edge_src[train_ids], graph.edge_dst[train_ids],
+            labels[train_ids], config.fanouts,
+        )
+        eval_sampler = EdgeBatchSampler(
+            csr, graph.edge_src[eval_ids], graph.edge_dst[eval_ids],
+            labels[eval_ids], config.fanouts,
+        )
+        dummy = train_sampler.sample(np.zeros(2, np.int64),
+                                     np.random.default_rng(0))
+
+    model = GraphSAGE(hidden=config.hidden, embed=config.embed)
+    with setup_phase("state") as placed:
+        params = model.init(
+            jax.random.key(config.seed),
+            *nodes_last(*map(jnp.asarray, dummy.astuple()[:-1]))
+        )
+        steps_per_epoch = max(train_sampler.n_edges // batch_size, 1)
+        total_steps = max(config.epochs * steps_per_epoch, 2)
+        schedule = optax.warmup_cosine_decay_schedule(
+            0.0, config.learning_rate, min(100, total_steps // 10 + 1),
+            total_steps,
+        )
+        tx = optax.adamw(schedule, weight_decay=config.weight_decay)
+        state = train_state.TrainState.create(
+            apply_fn=model.apply, params=params, tx=tx)
+        state = placed(mesh.put_replicated(state))
+
+    with setup_phase("tables") as placed:
+        # Host-sampling path only; the fused path keeps features inside
+        # its replicated GraphTables instead (no second HBM copy).
+        nf_dev = (None if config.device_sample
+                  else placed(jax.device_put(csr.node_features,
+                                             mesh.replicated)))
+        if config.device_sample:
+            from dragonfly2_tpu.train.fused_sampling import (
+                make_fused_eval_step,
+                make_fused_train_step,
+                put_edge_tables,
+                put_graph_tables,
+            )
+
+            graph_tables = placed(put_graph_tables(csr, mesh))
+            # On every step's span, so that a trace of any window says
+            # which sampler its steps ran (docs/OBSERVABILITY.md
+            # "Training loops").
+            step_facts = {"sampler_row_width": graph_tables.row_width}
+            # The samplers already hold the sliced/cast split arrays —
+            # reuse them instead of re-slicing ~2M-element fancy indexes.
+            train_edges = placed(put_edge_tables(
+                train_sampler.edge_src, train_sampler.edge_dst,
+                train_sampler.labels, mesh))
+            k = max(int(config.steps_per_call), 1)
+            if k > 1:
+                from dragonfly2_tpu.train.fused_sampling import (
+                    make_fused_multi_step,
+                )
+
+                fused_step = make_fused_multi_step(model, mesh,
+                                                   config.fanouts, k)
+                ids_sharding = mesh.shard_spec(None, "data")
+            else:
+                fused_step = make_fused_train_step(model, mesh,
+                                                   config.fanouts)
+            base_key = placed(mesh.put_replicated(
+                jax.random.key(config.seed + 1)))
+            train_step = None
         else:
-            fused_step = make_fused_train_step(model, mesh, config.fanouts)
-        base_key = mesh.put_replicated(jax.random.key(config.seed + 1))
-        train_step = None
-    else:
-        train_step = make_train_step(model, mesh)
-        step_facts = {}
+            train_step = make_train_step(model, mesh)
+            step_facts = {}
 
     def place(batch) -> tuple:
         return tuple(mesh.put_batch(a) for a in batch.astuple())
 
     group = max(int(config.steps_per_call), 1) if config.device_sample else 1
 
+    span = jax.profiler.TraceAnnotation
+
     def train_tasks():
         for epoch in range(config.epochs):
-            order = np.random.default_rng((config.seed, epoch)).permutation(
-                train_sampler.n_edges)
+            # On the loop's thread, inside its df2.train.wait_input.
+            with span("df2.train.epoch_order"):
+                order = np.random.default_rng(
+                    (config.seed, epoch)).permutation(train_sampler.n_edges)
             starts = range(0, train_sampler.n_edges - batch_size + 1,
                            batch_size)
             if group == 1:
@@ -302,8 +325,6 @@ def train_gnn(
                     chunk = starts[gi * group:(gi + 1) * group]
                     yield epoch, gi, np.stack(
                         [order[s:s + batch_size] for s in chunk])
-
-    span = jax.profiler.TraceAnnotation
 
     def build(task):
         # On a prefetch worker's thread; (epoch, step) joins the span to

@@ -33,7 +33,12 @@ from dragonfly2_tpu.models.keye_vl2 import KeyeVL2Config
 from dragonfly2_tpu.models.laguna import LagunaConfig
 from dragonfly2_tpu.models.lfm2_moe import Lfm2MoeConfig
 from dragonfly2_tpu.parallel import MeshContext, data_parallel_mesh
-from dragonfly2_tpu.train.step_budget import TRAINING, StepBudget, step_loop
+from dragonfly2_tpu.train.step_budget import (
+    TRAINING,
+    StepBudget,
+    setup_phase,
+    step_loop,
+)
 
 
 # A family by the ``model_type`` of its published ``config.json``: the
@@ -236,15 +241,30 @@ def train_seq(
     cfg = config.model
     if mesh.n_model > 1:
         raise ValueError("train_seq shards over data only")
-    rows, seq_len = corpus.tokens.shape
-    batch = min(config.batch_size, rows)
-    if batch % mesh.n_data:
-        raise ValueError(f"{batch} sequences a step over {mesh.n_data} "
-                         "data-parallel devices")
-    first, held = cfg.held_vocab
-    if corpus.tokens.min() < first or corpus.tokens.max() >= first + held:
-        raise ValueError(f"token ids outside the embedding rows held here "
-                         f"({first} .. {first + held - 1})")
+    # Set-up in three phases (docs/OBSERVABILITY.md "Training loops").
+    with setup_phase("data"):
+        rows, seq_len = corpus.tokens.shape
+        batch = min(config.batch_size, rows)
+        if batch % mesh.n_data:
+            raise ValueError(f"{batch} sequences a step over {mesh.n_data} "
+                             "data-parallel devices")
+        first, held = cfg.held_vocab
+        if (corpus.tokens.min() < first
+                or corpus.tokens.max() >= first + held):
+            raise ValueError(f"token ids outside the embedding rows held "
+                             f"here ({first} .. {first + held - 1})")
+        # Last values set (docs/OBSERVABILITY.md): of the corpus's causal
+        # tiles at the full-attention kernel's tile those that a document
+        # reaches, which are the ones the kernel computes.
+        tiles = tiles_kept = 0
+        block = min(seq_layers.ATTENTION_BLOCK, seq_len)
+        if seq_len % block == 0 and any(
+                cfg.layer_types[i] == "full_attention"
+                for i in cfg.kept_layers):
+            keep = seq_layers.document_tiles(corpus.segments, block)
+            tiles = rows * keep.shape[-1] * (keep.shape[-1] + 1) // 2
+            tiles_kept = int(keep.sum())
+
     steps_per_epoch = max(rows // batch, 1)
     total_steps = max(config.epochs * steps_per_epoch, 2)
     schedule = optax.warmup_cosine_decay_schedule(
@@ -255,35 +275,28 @@ def train_seq(
     bias = np.zeros(cfg.num_experts, np.float32) if (
         config.router_bias is None or not cfg.use_expert_bias
     ) else np.asarray(config.router_bias, np.float32)
-    state = SeqTrainState.create(
-        apply_fn=None,
-        params=seq_layers.init_params(
-            jax.random.key(config.seed),
-            family_of(cfg).param_shapes(cfg)),
-        tx=optax.adamw(schedule, weight_decay=config.weight_decay),
-        router_bias=jnp.tile(bias, (n_moe, 1)),
-        routing_counts=jnp.zeros((n_moe, cfg.num_experts), jnp.uint32),
-        sparse_counts=(jnp.zeros((2, 3), jnp.uint32) if sparse_topk
-                       else None))
-    state = mesh.put_replicated(state)
+    with setup_phase("state") as placed:
+        state = SeqTrainState.create(
+            apply_fn=None,
+            params=seq_layers.init_params(
+                jax.random.key(config.seed),
+                family_of(cfg).param_shapes(cfg)),
+            tx=optax.adamw(schedule, weight_decay=config.weight_decay),
+            router_bias=jnp.tile(bias, (n_moe, 1)),
+            routing_counts=jnp.zeros((n_moe, cfg.num_experts), jnp.uint32),
+            sparse_counts=(jnp.zeros((2, 3), jnp.uint32) if sparse_topk
+                           else None))
+        state = placed(mesh.put_replicated(state))
     rep = mesh.replicated
-    tokens, segments, positions = (
-        jax.device_put(a, rep)
-        for a in (corpus.tokens, corpus.segments, corpus.positions))
+    with setup_phase("tables") as placed:
+        tokens, segments, positions = placed(tuple(
+            jax.device_put(a, rep)
+            for a in (corpus.tokens, corpus.segments, corpus.positions)))
 
     train_step = build_train_step(cfg, mesh)
-    # Last values set (docs/OBSERVABILITY.md): which attention the
-    # loop's sliding layers ran and how many keys a learned selection
-    # keeps (both on every step's span too), and of the corpus's causal
-    # tiles at the full-attention kernel's tile those that a document
-    # reaches, which are the ones the kernel computes.
-    tiles = tiles_kept = 0
-    block = min(seq_layers.ATTENTION_BLOCK, seq_len)
-    if seq_len % block == 0 and any(
-            cfg.layer_types[i] == "full_attention" for i in cfg.kept_layers):
-        keep = seq_layers.document_tiles(corpus.segments, block)
-        tiles = rows * keep.shape[-1] * (keep.shape[-1] + 1) // 2
-        tiles_kept = int(keep.sum())
+    # Last values set: which attention the loop's sliding layers ran and
+    # how many keys a learned selection keeps (both on every step's span
+    # too), and the tiles counted above.
     TRAINING.set(seq_attn_window=cfg.attention_window,
                  seq_attn_tiles=tiles, seq_attn_tiles_kept=tiles_kept,
                  seq_sparse_topk=sparse_topk)
@@ -292,7 +305,8 @@ def train_seq(
     rng = np.random.default_rng((config.seed, 11))
 
     def epoch_steps(_):
-        order = rng.permutation(rows).astype(np.int32)
+        with jax.profiler.TraceAnnotation("df2.train.epoch_order"):
+            order = rng.permutation(rows).astype(np.int32)
         for i in range(steps_per_epoch):
             ids = order[i * batch:(i + 1) * batch]
             yield lambda ids=ids: jax.device_put(ids, mesh.batch_sharding)

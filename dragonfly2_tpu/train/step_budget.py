@@ -16,11 +16,14 @@ watchdog fire emits the latest measured rate instead of zero.
 Every budget also feeds the process-wide ``training`` block of
 ``/debug/vars`` (:data:`TRAINING`; docs/OBSERVABILITY.md "Training
 loops"): what the loops dispatched, and how many executables JAX built
-or loaded while a loop ran.
+or loaded while a loop ran. What a trainer does before its loop's
+budget exists is measured by :func:`setup_phase`.
 """
 
 from __future__ import annotations
 
+import contextlib
+import gc
 import threading
 import time
 import weakref
@@ -28,7 +31,11 @@ from typing import Callable, Optional
 
 import jax
 import numpy as np
-from jax._src.dispatch import BACKEND_COMPILE_EVENT
+from jax._src.dispatch import (
+    BACKEND_COMPILE_EVENT,
+    JAXPR_TO_MLIR_MODULE_EVENT,
+    JAXPR_TRACE_EVENT,
+)
 
 from dragonfly2_tpu.utils.debugmon import register_debug_var
 
@@ -93,6 +100,19 @@ class TrainingStats:
       ``seq_sparse_selected``: those they kept. Counted from the
       selections' own masks on the device and read once, at a loop's
       drain.
+    - ``setup_data_seconds``, ``setup_state_seconds``,
+      ``setup_tables_seconds``: wall seconds of the trainers' set-up
+      phases (:func:`setup_phase`): host structures from the records;
+      parameters and optimizer state drawn and placed; the graph's or
+      corpus's arrays placed. Each ends when what it placed is on the
+      device.
+    - ``setup_compiles``: executables built or loaded while a set-up
+      phase was open (parameter draws, the optimizer's init, placement
+      programs: one-operation programs, mostly).
+    - ``loop_compile_seconds``: wall seconds of JAX's tracing, lowering
+      and backend compile (or cache load) while a budget was open: the
+      step program's, beside ``loop_compiles``. A trace nested in
+      another (a ``jit`` called while tracing) counts once.
     """
 
     KEYS = ("loops_started", "dispatches", "steps", "samples",
@@ -101,15 +121,25 @@ class TrainingStats:
             "sampler_row_width", "attn_inverse_slots",
             "attn_inverse_filled", "seq_attn_window", "seq_attn_tiles",
             "seq_attn_tiles_kept", "seq_sparse_topk",
-            "seq_sparse_candidates", "seq_sparse_selected")
+            "seq_sparse_candidates", "seq_sparse_selected",
+            "setup_data_seconds", "setup_state_seconds",
+            "setup_tables_seconds", "setup_compiles",
+            "loop_compile_seconds")
+    SECONDS = ("compile_seconds", "setup_data_seconds",
+               "setup_state_seconds", "setup_tables_seconds",
+               "loop_compile_seconds")
 
     def __init__(self):
         self._lock = threading.Lock()
         self._counts = dict.fromkeys(self.KEYS, 0)
-        self._counts["compile_seconds"] = 0.0
+        self._counts.update(dict.fromkeys(self.SECONDS, 0.0))
         # Budgets between creation and finish. Weak: a loop that raises
         # never reaches finish, and its budget must not keep counting.
         self._open = weakref.WeakSet()
+        # The set-up phase open now, if any, and the compile events seen
+        # inside the open loops that no later event has covered yet.
+        self._phase = None
+        self._spans = []
 
     def add(self, **increments) -> None:
         with self._lock:
@@ -122,19 +152,58 @@ class TrainingStats:
 
     def loop_started(self, budget) -> None:
         with self._lock:
+            if self._phase is not None:
+                raise RuntimeError(f"a train loop started inside the "
+                                   f"set-up phase {self._phase!r}")
             self._counts["loops_started"] += 1
             self._open.add(budget)
+            self._spans = []
 
     def loop_finished(self, budget) -> None:
         with self._lock:
             self._open.discard(budget)
 
+    def phase_opened(self, name: str) -> None:
+        if self._open:
+            # A loop that raised leaves its budget to the collector.
+            gc.collect()
+        with self._lock:
+            if self._phase is not None:
+                raise RuntimeError(f"set-up phase {name!r} opened inside "
+                                   f"{self._phase!r}: phases do not nest")
+            if self._open:
+                raise RuntimeError(f"set-up phase {name!r} opened while a "
+                                   "train loop runs")
+            self._phase = name
+
+    def phase_closed(self, name: str, seconds: float) -> None:
+        with self._lock:
+            self._phase = None
+            self._counts[f"setup_{name}_seconds"] += seconds
+
     def executable_built(self) -> None:
         with self._lock:
+            if self._phase is not None:
+                self._counts["setup_compiles"] += 1
             for budget in self._open:
                 self._counts["loop_compiles"] += 1
                 if budget.steps:
                     self._counts["steady_compiles"] += 1
+
+    def compile_span(self, start: float, end: float) -> None:
+        """One tracing, lowering or backend-compile event, reported as it
+        ends: inside an open loop, its seconds less those of the events
+        reported before it that it holds (a ``jit`` traced inside
+        another's trace ends first)."""
+        with self._lock:
+            if not self._open:
+                return
+            inner = 0.0
+            while self._spans and self._spans[-1][0] >= start:
+                a, b = self._spans.pop()
+                inner += b - a
+            self._spans.append((start, end))
+            self._counts["loop_compile_seconds"] += end - start - inner
 
     def snapshot(self) -> dict:
         with self._lock:
@@ -145,13 +214,53 @@ TRAINING = TrainingStats()
 register_debug_var("training", TRAINING.snapshot)
 
 
-def _on_event_duration(event: str, duration_secs: float, **_) -> None:
+_COMPILE_EVENTS = (JAXPR_TRACE_EVENT, JAXPR_TO_MLIR_MODULE_EVENT,
+                   BACKEND_COMPILE_EVENT)
+
+
+def _on_event_span(event: str, start: float, end: float, **_) -> None:
+    if event in _COMPILE_EVENTS:
+        TRAINING.compile_span(start, end)
     if event == BACKEND_COMPILE_EVENT:
         TRAINING.executable_built()
 
 
-# Once, at import; the listener does nothing while no budget is open.
-jax.monitoring.register_event_duration_secs_listener(_on_event_duration)
+# Once, at import; the listener does nothing while no budget or set-up
+# phase is open. JAX reports each of these events' span and duration
+# together; the span tells a nested trace from the one around it.
+jax.monitoring.register_event_time_span_listener(_on_event_span)
+
+
+SETUP_PHASES = ("data", "state", "tables")
+
+
+@contextlib.contextmanager
+def setup_phase(name: str):
+    """One phase of a trainer's set-up, from its entry to its loop's
+    ``StepBudget`` (docs/OBSERVABILITY.md "Training loops"): the host
+    span ``df2.setup.<name>`` on the profiler's clock, and the phase's
+    wall seconds added to the ``training`` block's
+    ``setup_<name>_seconds`` (a phase opened twice adds up). Yields
+    ``placed(x) -> x``: the phase ends with a wait for everything handed
+    to it, so that the device's part of the phase is the phase's and not
+    the first step's. Phases do not nest or overlap, and none opens while
+    a train loop runs (``RuntimeError``)."""
+    if name not in SETUP_PHASES:
+        raise ValueError(f"set-up phase {name!r}: one of {SETUP_PHASES}")
+    placed = []
+
+    def hand(x):
+        placed.append(x)
+        return x
+
+    TRAINING.phase_opened(name)
+    start = time.perf_counter()
+    try:
+        with jax.profiler.TraceAnnotation(f"df2.setup.{name}"):
+            yield hand
+            jax.block_until_ready(placed)
+    finally:
+        TRAINING.phase_closed(name, time.perf_counter() - start)
 
 
 def epoch_mean(losses) -> float:

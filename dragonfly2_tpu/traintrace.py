@@ -15,7 +15,9 @@ phases (docs/OBSERVABILITY.md "Training loops").
   reshape is turned into) carry no path at all; for those the tool
   says which scope's operations ran next on the device, which is
   adjacency, not attribution.
-- Per host thread: the ``df2.train.*`` spans' totals per step.
+- Per host thread: the ``df2.train.*`` spans' totals per step, and
+  the ``df2.setup.*`` spans (a trainer's set-up phases, before its loop:
+  ``data``, ``state``, ``tables``) with their totals alone.
 - ``step_facts``: what the loop wrote on its ``df2.train.step`` spans
   besides the step's number (``sampler_row_width``: the lanes of
   GraphSAGE's per-host neighbour rows, 0 on the CSR sampler;
@@ -24,8 +26,8 @@ phases (docs/OBSERVABILITY.md "Training loops").
   ``seq_sparse_topk``: the keys a query keeps where attention runs over
   a learned selection, 0 where it does not, whose scopes are
   ``df2.seq.index``, ``df2.seq.select`` and ``df2.seq.attn_sparse``).
-- The longest device idle gaps, each with the ``df2.train.*`` span the
-  loop's thread was in.
+- The longest device idle gaps, each with the ``df2.train.*`` or
+  ``df2.setup.*`` span the loop's thread was in.
 
 A CPU trace names its operations by ``hlo_op`` alone, with no scope
 path: there the device part is empty and the host part still reads.
@@ -43,6 +45,8 @@ HOST_PLANE = "/host:CPU"
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
 HOST_SPAN = "df2.train."
+SETUP_SPAN = "df2.setup."
+_SPANS = (HOST_SPAN, SETUP_SPAN)
 STEP_SPAN = "df2.train.step"
 _SCOPE = re.compile(r"(?<![\w.])df2\.[A-Za-z_][\w.]*")
 NO_SPAN = "no df2.train span"
@@ -181,16 +185,19 @@ def _device(plane, loop_spans, host_steps: int, n_gaps: int,
 
 
 def _thread(line, index: int, is_loop: bool, steps: int) -> dict:
-    spans = {}
+    spans, setup = {}, {}
     for ev in line.events:
-        if ev.name.startswith(HOST_SPAN):
-            entry = spans.setdefault(ev.name, {"count": 0, "total_ms": 0.0})
+        if ev.name.startswith(_SPANS):
+            entry = (spans if ev.name.startswith(HOST_SPAN)
+                     else setup).setdefault(
+                ev.name, {"count": 0, "total_ms": 0.0})
             entry["count"] += 1
             entry["total_ms"] += ev.duration_ns * 1e-6
     for entry in spans.values():
         entry["ms_per_step"] = entry["total_ms"] / max(steps, 1)
     return {"thread": f"{line.name} #{index}", "loop": is_loop,
-            "spans": dict(sorted(spans.items()))}
+            "spans": dict(sorted(spans.items())),
+            "setup": dict(sorted(setup.items()))}
 
 
 def analyze(where: str, n_gaps: int = 5, n_unscoped: int = 5,
@@ -207,14 +214,14 @@ def analyze_planes(planes, n_gaps: int = 5, n_unscoped: int = 5,
                    scope=_SCOPE) -> dict:
     host_lines = [line for plane in planes if plane.name == HOST_PLANE
                   for line in plane.lines
-                  if any(ev.name.startswith(HOST_SPAN)
+                  if any(ev.name.startswith(_SPANS)
                          for ev in line.events)]
     loop = _loop_line(host_lines)
     host_steps = (sum(ev.name == STEP_SPAN for ev in loop.events)
                   if loop is not None else 0)
     loop_spans = [] if loop is None else [
         (ev.start_ns, ev.start_ns + ev.duration_ns, ev.name)
-        for ev in loop.events if ev.name.startswith(HOST_SPAN)]
+        for ev in loop.events if ev.name.startswith(_SPANS)]
     facts = {}
     for ev in (loop.events if loop is not None else ()):
         if ev.name == STEP_SPAN:
@@ -268,6 +275,9 @@ def format_report(report: dict) -> str:
         out.append("")
         out.append(f"thread {thread['thread']}"
                    + ("  (the loop)" if thread["loop"] else ""))
+        for name, s in thread["setup"].items():
+            out.append(f"  {name:24} {s['count']:6d} x  "
+                       f"{s['total_ms']:10.3f} ms  (set-up)")
         for name, s in thread["spans"].items():
             out.append(f"  {name:24} {s['count']:6d} x  "
                        f"{s['total_ms']:10.3f} ms  "

@@ -3,6 +3,8 @@ sequence-model job, at a tiny size: the packer, the loop through
 ``StepBudget``, data parallelism against one device, the ``tokens``
 segments through ``TrainerStorage`` and ``Training.train``."""
 
+import time
+
 import jax
 import numpy as np
 import pytest
@@ -93,18 +95,30 @@ def test_packer_splits_a_stream_at_the_end_id():
                                   [0, 1, 2, 0, 1, 0, 0, 1, 2, 3])
 
 
-@FAMILIES
-def test_train_seq_reaches_finish_without_a_steady_compile(model, window):
+@pytest.fixture(scope="module", params=[(MODEL, 0), (LAGUNA, 8), (KEYE, 0)],
+                ids=["lfm2_moe", "laguna", "KeyeVL2"])
+def family_run(request):
+    """One ``train_seq`` call of a family: its model, the window of its
+    sliding layers, the corpus, the result, the ``training`` block before
+    and after, and the call's wall seconds."""
+    model, window = request.param
+    corpus = pack_documents(documents(), SEQ)
+    before = step_budget.TRAINING.snapshot()
+    start = time.perf_counter()
+    result = train_seq(corpus, SeqTrainConfig(
+        model=model, batch_size=4, epochs=3, learning_rate=3e-3, seed=3,
+        router_bias=tuple(np.linspace(-0.05, 0.05, 16))), one_device())
+    wall = time.perf_counter() - start
+    return (model, window, corpus, result, before,
+            step_budget.TRAINING.snapshot(), wall)
+
+
+def test_train_seq_reaches_finish_without_a_steady_compile(family_run):
     """Every family through the one loop: the same counters, the same
     one step program, and the loop's last-value entries for the window
     of the sliding layers and for the keys a learned selection keeps (0
     for a family without one)."""
-    corpus = pack_documents(documents(), SEQ)
-    before = step_budget.TRAINING.snapshot()
-    result = train_seq(corpus, SeqTrainConfig(
-        model=model, batch_size=4, epochs=3, learning_rate=3e-3, seed=3,
-        router_bias=tuple(np.linspace(-0.05, 0.05, 16))), one_device())
-    after = step_budget.TRAINING.snapshot()
+    model, window, corpus, result, before, after, _ = family_run
     assert after["seq_attn_window"] == window
     assert after["seq_sparse_topk"] == getattr(model, "sparse_topk", 0)
     # A cut with a full-attention layer: every row is one tile at this
@@ -131,6 +145,18 @@ def test_train_seq_reaches_finish_without_a_steady_compile(model, window):
             == held.sum())
     assert (after["moe_assignments_hottest"]
             - before["moe_assignments_hottest"] == held.max(1).sum())
+
+
+def test_train_seq_set_up_phases_fit_in_the_call(family_run):
+    """The three set-up phases (id checks and tiles; the state drawn and
+    placed; the corpus placed) each read positive, and with the step's
+    compile they are no more than the call took."""
+    *_, before, after, wall = family_run
+    spent = {key: after[key] - before[key] for key in (
+        "setup_data_seconds", "setup_state_seconds",
+        "setup_tables_seconds", "loop_compile_seconds")}
+    assert all(v > 0 for v in spent.values()), spent
+    assert sum(spent.values()) <= wall
 
 
 @pytest.mark.parametrize("kept_layers,block,tiles", [

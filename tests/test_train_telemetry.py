@@ -10,6 +10,7 @@ One profiler session for the whole file (the ``traced`` fixture): a tiny
 import json
 import os
 import re
+import time
 
 import jax
 import numpy as np
@@ -38,7 +39,8 @@ GAT_SCOPES = ("df2.attn.gather", "df2.attn.gather_bwd", "df2.model",
               "df2.loss", "df2.optimizer")
 GNN_SPANS = ("df2.train.step", "df2.train.wait_input", "df2.train.input",
              "df2.train.dispatch", "df2.train.tick", "df2.train.epoch_end",
-             "df2.train.drain")
+             "df2.train.drain", "df2.train.epoch_order")
+SETUP_PHASES = ("data", "state", "tables")
 GAT_SPANS = tuple(s for s in GNN_SPANS if s != "df2.train.wait_input")
 
 GNN_CONFIG = dict(hidden=32, embed=16, batch_size=512, epochs=2,
@@ -102,20 +104,20 @@ def traced(graph, mesh, tmp_path_factory):
                     graph, GATTrainConfig(**GAT_CONFIG), mesh))):
             recorder, before = _CompiledStepText(), TRAINING.snapshot()
             real, module.jax = module.jax, recorder
+            start = time.perf_counter()
             try:
                 result = run()
             finally:
                 module.jax = real
+            wall = time.perf_counter() - start
             after = TRAINING.snapshot()
             runs[name] = {
-                "result": result, "text": recorder.text,
+                "result": result, "text": recorder.text, "wall": wall,
                 "block": {k: after[k] - before[k] for k in after}}
     finally:
         jax.profiler.stop_trace()
     planes = xplane.read_xspace(xplane.find_xplane(str(out)))
     host = next(p for p in planes if p.name == traintrace.HOST_PLANE)
-    # The two loops ran one after the other on this thread: split its
-    # spans where the second loop's first step starts.
     loop = max(host.lines, key=lambda ln: sum(
         ev.name == "df2.train.step" for ev in ln.events))
     workers = [ln for ln in host.lines if ln is not loop and any(
@@ -123,11 +125,17 @@ def traced(graph, mesh, tmp_path_factory):
     starts = [ev.start_ns for ev in loop.events
               if ev.name == "df2.train.step" and ev.stats["step_num"] == 0]
     assert len(starts) == 2
-    cut = starts[1]
-    runs["gnn"]["loop"] = [ev for ev in loop.events if ev.start_ns < cut
-                           and ev.name.startswith("df2.train.")]
-    runs["gat"]["loop"] = [ev for ev in loop.events if ev.start_ns >= cut
-                           and ev.name.startswith("df2.train.")]
+    # The two loops ran one after the other on this thread: split its
+    # spans where the second call's set-up starts.
+    cut = sorted(ev.start_ns for ev in loop.events
+                 if ev.name == "df2.setup.data")[1]
+    assert cut < starts[1]
+    for name, inside in (("gnn", lambda ev: ev.start_ns < cut),
+                         ("gat", lambda ev: ev.start_ns >= cut)):
+        runs[name]["loop"] = [ev for ev in loop.events if inside(ev)
+                              and ev.name.startswith("df2.train.")]
+        runs[name]["setup"] = [ev for ev in loop.events if inside(ev)
+                               and ev.name.startswith("df2.setup.")]
     runs["gnn"]["workers"] = [ev for ln in workers for ev in ln.events
                               if ev.name == "df2.train.input"]
     runs["gat"]["workers"] = []
@@ -220,6 +228,28 @@ def test_input_span_carries_epoch_and_step(traced, loop):
         assert len(run["workers"]) == len(inputs)
 
 
+@pytest.mark.parametrize("loop", ["gnn", "gat"])
+def test_epoch_order_is_a_span_per_epoch_outside_the_steps(traced, loop):
+    """The epoch's permutation: in ``train_gnn`` the task generator draws
+    it on the loop's thread, inside ``df2.train.wait_input``, so an idle
+    gap at an epoch's edge is named by it and ``input_wait_ms`` still
+    holds it; in no loop is it inside a ``df2.train.step``."""
+    events = traced[loop]["loop"]
+
+    def spans(name):
+        return [(ev.start_ns, ev.start_ns + ev.duration_ns)
+                for ev in events if ev.name == name]
+
+    orders = spans("df2.train.epoch_order")
+    assert len(orders) == 2
+    for a, b in orders:
+        assert not any(lo <= a and b <= hi
+                       for lo, hi in spans("df2.train.step"))
+        inside_wait = any(lo <= a and b <= hi
+                          for lo, hi in spans("df2.train.wait_input"))
+        assert inside_wait == (loop == "gnn")
+
+
 def test_epoch_end_is_a_span_per_epoch(traced):
     for loop in ("gnn", "gat"):
         ends = [ev for ev in traced[loop]["loop"]
@@ -240,6 +270,26 @@ def test_training_block_agrees_with_the_result(traced, loop, batch):
     # The step program (and this file's second look at it) at least.
     assert block["loop_compiles"] >= 1
     assert block["steady_compiles"] == 0
+
+
+@pytest.mark.parametrize("loop", ["gnn", "gat"])
+def test_set_up_phases_fit_in_the_call(traced, loop):
+    """Each phase once, before the loop, on the loop's thread; the
+    block's phase seconds and the loop's compile seconds are positive
+    and together no more than the call took."""
+    block, run = traced[loop]["block"], traced[loop]
+    seconds = [block[f"setup_{p}_seconds"] for p in SETUP_PHASES]
+    assert all(s > 0 for s in seconds)
+    assert 0 < block["loop_compile_seconds"]
+    assert sum(seconds) + block["loop_compile_seconds"] <= run["wall"]
+    setup = run["setup"]
+    assert sorted(ev.name for ev in setup) == sorted(
+        f"df2.setup.{p}" for p in SETUP_PHASES)
+    first_step = min(ev.start_ns for ev in run["loop"])
+    assert all(ev.start_ns + ev.duration_ns <= first_step for ev in setup)
+    for ev in setup:
+        phase = ev.name.split(".")[-1]
+        assert ev.duration_ns * 1e-9 <= block[f"setup_{phase}_seconds"]
 
 
 def test_steps_counts_optimizer_steps_of_a_multi_step_dispatch(graph, mesh):
@@ -523,3 +573,26 @@ def test_trace_tool_train_on_a_cpu_dump(traced, capsys):
     assert loop["spans"]["df2.train.dispatch"]["count"] == steps
     assert any("df2.train.input" in t["spans"] for t in report["threads"]
                if not t["loop"])
+
+
+@pytest.mark.parametrize("as_json", [True, False])
+def test_trace_tool_lists_the_set_up_phases(traced, capsys, as_json):
+    """Beside the loop's spans, each set-up phase of both trainers with
+    its total, not per step."""
+    from dragonfly2_tpu.cmd import tracetool
+
+    argv = ["train", traced["dump"]] + (["--json"] if as_json else [])
+    assert tracetool.main(argv) == 0
+    out = capsys.readouterr().out
+    names = [f"df2.setup.{p}" for p in SETUP_PHASES]
+    if not as_json:
+        lines = [ln for ln in out.splitlines() if "(set-up)" in ln]
+        assert sorted(ln.split()[0] for ln in lines) == names
+        return
+    (loop,) = [t for t in json.loads(out)["threads"] if t["loop"]]
+    assert sorted(loop["setup"]) == names
+    for name in names:
+        assert loop["setup"][name]["count"] == 2
+        assert loop["setup"][name]["total_ms"] > 0
+        assert "ms_per_step" not in loop["setup"][name]
+        assert name not in loop["spans"]
