@@ -232,8 +232,8 @@ def indexer(p, a, positions, cfg: KeyeVL2Config):
 
 def attention_operator(p, a, segments, positions, cfg: KeyeVL2Config):
     """``Attn`` of the normed ``a`` at ``positions`` ``[3, S]``, and the
-    selection's candidates and members as limbs
-    (``seq_layers.count_limbs``, ``[2, 3]``)."""
+    selection's candidates, members and the attention tiles that hold a
+    member as limbs (``seq_layers.count_limbs``, ``[3, 3]``)."""
     dt, s, hd = a.dtype, a.shape[0], cfg.head_dim
     kvh = cfg.num_key_value_heads
     with jax.named_scope("df2.seq.attn_proj"):
@@ -250,9 +250,10 @@ def attention_operator(p, a, segments, positions, cfg: KeyeVL2Config):
     # The scopes of the scores and of the ranking are the selection's own.
     packed, candidates, members = select_keys(*scored, segments,
                                               cfg.sparse_topk)
+    out, held = selected_attention(q, k, v,
+                                   checkpoint_name(packed, SELECTION))
     counted = jnp.stack([count_limbs(candidates).sum(0),
-                         count_limbs(members).sum(0)])
-    out = selected_attention(q, k, v, checkpoint_name(packed, SELECTION))
+                         count_limbs(members).sum(0), count_limbs(held)])
     with jax.named_scope("df2.seq.attn_proj"):
         return out.reshape(s, -1) @ p["attn"]["o"].astype(dt), counted
 
@@ -282,7 +283,8 @@ def block(p, x, router_bias, segments, positions, *, cfg: KeyeVL2Config,
     """One published layer on one sequence; ``positions`` ``[S]`` (text:
     the three streams are equal) or ``[3, S]``. Returns the new ``x``
     and what the layer counted: the expert layer's assignment counts
-    ``[E]`` and the selection's candidates and members ``[2, 3]``."""
+    ``[E]`` and the selection's candidates, members and held tiles
+    ``[3, 3]``."""
     del layer                            # every layer is the same
     if positions.ndim == 1:
         positions = jnp.broadcast_to(positions, (3,) + positions.shape)

@@ -10,10 +10,17 @@ blocked attention: scores of a ``[block, block]`` tile on the MXU, an
 online softmax in float32, nothing ``[S, S]`` in HBM; the backward pass
 recomputes a tile's probabilities from the forward's log-sum-exp, ``dq``
 in one kernel (query blocks outer) and ``dk``, ``dv`` in another (key
-blocks outer, a key-value head's group of query heads summed inside).
-A tile that holds no member is not computed, and its keys are not
-fetched: block indices are clamped to each row's (each column's) range
-of tiles that hold one.
+blocks outer).
+
+A grid step is one ``[block, block]`` tile for all the query heads of
+one key-value head (:func:`heads_per_step` of them: the whole group
+wherever its blocks fit the kernels' memory, as at the chip's shapes).
+The step fetches the key, value and mask tiles once for the group and
+unpacks the mask once; each head's scores, softmax and products then
+run in turn over it, and ``dk``, ``dv`` take the group's heads into the
+same two sums. A tile that holds no member is not computed, and its
+keys are not fetched: block indices are clamped to each row's (each
+column's) range of tiles that hold one.
 
 The packed layout (:func:`pack_mask`): the keys are cut into blocks of
 ``block`` columns, and bit ``b`` of byte ``j`` of a block is its column
@@ -41,6 +48,12 @@ MASK_VALUE = -0.7 * float(np.finfo(np.float32).max)
 _NT = (((1,), (1,)), ((), ()))      # a @ b.T
 _TN = (((0,), (0,)), ((), ()))      # a.T @ b
 _VMEM_LIMIT = 64 * 2**20
+# A head's float32 ``[block, block]`` temporaries in a grid step, which
+# run one head at a time: scores, probabilities, their cotangent, the
+# row statistics on every column. An upper count: at the chip's shape
+# (1,024-key tiles, 8 heads of 128 in bfloat16) it sizes dq's step at
+# 61.3 MB, where Mosaic's own count is 40.6 MB.
+_TILE_TEMPORARIES = 6
 
 
 def pack_mask(mask, block: int):
@@ -78,11 +91,51 @@ def tile_tables(packed, block: int):
             jnp.stack(ends(held) + ends(held.T)).astype(jnp.int32))
 
 
-def _kept(mask_ref):
+def heads_per_step(group: int, block: int, hd: int, itemsize: int) -> int:
+    """The query heads a grid step takes: the largest divisor of
+    ``group`` (a key-value head's query heads) whose blocks,
+    double-buffered, and scratch fit the kernels' memory beside the
+    shared tiles and one head's temporaries. Sized by the ``dq`` kernel,
+    which holds the most a head: its q, do and dq blocks, the
+    log-sum-exp and delta on 128 lanes, a float32 accumulator."""
+    tile = block * block * 4
+    shared = (2 * 2 * block * hd * itemsize        # k, v
+              + 2 * block * block // 8             # the mask's byte tile
+              + tile                               # the mask unpacked
+              + _TILE_TEMPORARIES * tile)
+    per_head = (2 * 3 * block * hd * itemsize      # q, do, dq
+                + 2 * 2 * block * LANES * 4        # log-sum-exp, delta
+                + block * hd * 4)                  # accumulator
+    return max((d for d in range(1, group + 1)
+                if group % d == 0 and shared + d * per_head <= _VMEM_LIMIT),
+               default=1)
+
+
+def _grid(heads, kv_heads, length, hd, itemsize, block):
+    """Query heads a step, steps a key-value head, tiles a row."""
+    group = heads // kv_heads
+    step = heads_per_step(group, block, hd, itemsize)
+    return step, group // step, length // block
+
+
+def grid_steps(heads: int, kv_heads: int, length: int, hd: int, dtype,
+               block: int) -> int:
+    """The grid steps of one kernel call at these shapes (forward, dq
+    and dk with dv alike): a step for each ``[block, block]`` tile and
+    each :func:`heads_per_step` query heads."""
+    step, _, n = _grid(heads, kv_heads, length, hd,
+                       jnp.dtype(dtype).itemsize, block)
+    return heads // step * n * n
+
+
+def _unpack(mask_ref, kept_ref):
     """A byte tile ``[rows, block / 8]`` as the tile's mask ``[rows,
-    block]``."""
+    block]`` (1 where kept) in ``kept_ref``: bit ``b`` is the ``b``-th
+    eighth of the columns."""
     bits = mask_ref[...].astype(jnp.int32)
-    return jnp.concatenate([(bits >> b) & 1 for b in range(8)], axis=1) != 0
+    width = bits.shape[1]
+    for b in range(8):
+        kept_ref[:, b * width:(b + 1) * width] = (bits >> b) & 1
 
 
 def _columns(x, width: int):
@@ -92,8 +145,10 @@ def _columns(x, width: int):
 
 
 def _forward_kernel(held_ref, ends_ref, q_ref, k_ref, v_ref, mask_ref,
-                    o_ref, lse_ref, m_ref, l_ref, acc_ref, *, blocks: int):
+                    o_ref, lse_ref, m_ref, l_ref, acc_ref, kept_ref, *,
+                    blocks: int):
     i, j = pl.program_id(1), pl.program_id(2)
+    heads = q_ref.shape[0]
 
     @pl.when(j == 0)
     def _():
@@ -103,43 +158,52 @@ def _forward_kernel(held_ref, ends_ref, q_ref, k_ref, v_ref, mask_ref,
 
     @pl.when(held_ref[i * blocks + j] != 0)
     def _():
-        scores = jax.lax.dot_general(q_ref[...], k_ref[...], _NT,
-                                     preferred_element_type=jnp.float32)
-        scores = jnp.where(_kept(mask_ref), scores, MASK_VALUE)
-        m_prev, l_prev = m_ref[...], l_ref[...]
-        m_next = jnp.maximum(m_prev, scores.max(-1, keepdims=True))
-        p = jnp.exp(scores - _columns(m_next, scores.shape[1]))
-        alpha = jnp.exp(m_prev - m_next)
-        l_ref[...] = alpha * l_prev + p.sum(-1, keepdims=True)
-        m_ref[...] = m_next
-        acc_ref[...] = (
-            acc_ref[...] * _columns(alpha, acc_ref.shape[1])
-            + jnp.dot(p.astype(v_ref.dtype), v_ref[...],
-                      preferred_element_type=jnp.float32))
+        _unpack(mask_ref, kept_ref)
+
+        @pl.loop(0, heads)
+        def _(h):
+            scores = jax.lax.dot_general(q_ref[h], k_ref[...], _NT,
+                                         preferred_element_type=jnp.float32)
+            scores = jnp.where(kept_ref[...] != 0, scores, MASK_VALUE)
+            m_prev, l_prev = m_ref[h], l_ref[h]
+            m_next = jnp.maximum(m_prev, scores.max(-1, keepdims=True))
+            p = jnp.exp(scores - _columns(m_next, scores.shape[1]))
+            alpha = jnp.exp(m_prev - m_next)
+            l_ref[h] = alpha * l_prev + p.sum(-1, keepdims=True)
+            m_ref[h] = m_next
+            acc_ref[h] = (
+                acc_ref[h] * _columns(alpha, acc_ref.shape[2])
+                + jnp.dot(p.astype(v_ref.dtype), v_ref[...],
+                          preferred_element_type=jnp.float32))
 
     @pl.when(j == blocks - 1)
     def _():
-        l = l_ref[...]
-        o_ref[...] = (acc_ref[...] / _columns(l, acc_ref.shape[1])
-                      ).astype(o_ref.dtype)
-        lse_ref[...] = m_ref[...] + jnp.log(l)
+        @pl.loop(0, heads)
+        def _(h):
+            l = l_ref[h]
+            o_ref[h] = (acc_ref[h] / _columns(l, acc_ref.shape[2])
+                        ).astype(o_ref.dtype)
+            lse_ref[h] = m_ref[h] + jnp.log(l)
 
 
-def _tile_gradients(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref):
-    """Of one tile: the probabilities ``p`` and the scores' cotangent
-    ``ds = p · (do · vᵀ - delta)``, both ``[bq, bk]`` float32."""
-    scores = jax.lax.dot_general(q_ref[...], k_ref[...], _NT,
+def _tile_gradients(h, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                    kept_ref):
+    """Of head ``h`` of the step on one tile: the probabilities ``p``
+    and the scores' cotangent ``ds = p · (do · vᵀ - delta)``, both
+    ``[bq, bk]`` float32."""
+    scores = jax.lax.dot_general(q_ref[h], k_ref[...], _NT,
                                  preferred_element_type=jnp.float32)
     width = scores.shape[1]
-    p = jnp.where(_kept(mask_ref),
-                  jnp.exp(scores - _columns(lse_ref[...], width)), 0.0)
-    dp = jax.lax.dot_general(do_ref[...], v_ref[...], _NT,
+    p = jnp.where(kept_ref[...] != 0,
+                  jnp.exp(scores - _columns(lse_ref[h], width)), 0.0)
+    dp = jax.lax.dot_general(do_ref[h], v_ref[...], _NT,
                              preferred_element_type=jnp.float32)
-    return p, p * (dp - _columns(delta_ref[...], width))
+    return p, p * (dp - _columns(delta_ref[h], width))
 
 
 def _dq_kernel(held_ref, ends_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-               delta_ref, mask_ref, dq_ref, acc_ref, *, blocks: int):
+               delta_ref, mask_ref, dq_ref, acc_ref, kept_ref, *,
+               blocks: int):
     i, j = pl.program_id(1), pl.program_id(2)
 
     @pl.when(j == 0)
@@ -148,10 +212,14 @@ def _dq_kernel(held_ref, ends_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 
     @pl.when(held_ref[i * blocks + j] != 0)
     def _():
-        _, ds = _tile_gradients(q_ref, k_ref, v_ref, do_ref, lse_ref,
-                                delta_ref, mask_ref)
-        acc_ref[...] += jnp.dot(ds.astype(k_ref.dtype), k_ref[...],
-                                preferred_element_type=jnp.float32)
+        _unpack(mask_ref, kept_ref)
+
+        @pl.loop(0, q_ref.shape[0])
+        def _(h):
+            _, ds = _tile_gradients(h, q_ref, k_ref, v_ref, do_ref, lse_ref,
+                                    delta_ref, kept_ref)
+            acc_ref[h] += jnp.dot(ds.astype(k_ref.dtype), k_ref[...],
+                                  preferred_element_type=jnp.float32)
 
     @pl.when(j == blocks - 1)
     def _():
@@ -159,8 +227,8 @@ def _dq_kernel(held_ref, ends_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 
 
 def _dkv_kernel(held_ref, ends_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                delta_ref, mask_ref, dk_ref, dv_ref, dk_acc, dv_acc, *,
-                blocks: int, group: int):
+                delta_ref, mask_ref, dk_ref, dv_ref, dk_acc, dv_acc,
+                kept_ref, *, blocks: int, parts: int):
     j, g, i = pl.program_id(1), pl.program_id(2), pl.program_id(3)
 
     @pl.when((g == 0) & (i == 0))
@@ -170,16 +238,20 @@ def _dkv_kernel(held_ref, ends_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 
     @pl.when(held_ref[i * blocks + j] != 0)
     def _():
-        p, ds = _tile_gradients(q_ref, k_ref, v_ref, do_ref, lse_ref,
-                                delta_ref, mask_ref)
-        dv_acc[...] += jax.lax.dot_general(
-            p.astype(do_ref.dtype), do_ref[...], _TN,
-            preferred_element_type=jnp.float32)
-        dk_acc[...] += jax.lax.dot_general(
-            ds.astype(q_ref.dtype), q_ref[...], _TN,
-            preferred_element_type=jnp.float32)
+        _unpack(mask_ref, kept_ref)
 
-    @pl.when((g == group - 1) & (i == blocks - 1))
+        @pl.loop(0, q_ref.shape[0])
+        def _(h):
+            p, ds = _tile_gradients(h, q_ref, k_ref, v_ref, do_ref, lse_ref,
+                                    delta_ref, kept_ref)
+            dv_acc[...] += jax.lax.dot_general(
+                p.astype(do_ref.dtype), do_ref[h], _TN,
+                preferred_element_type=jnp.float32)
+            dk_acc[...] += jax.lax.dot_general(
+                ds.astype(q_ref.dtype), q_ref[h], _TN,
+                preferred_element_type=jnp.float32)
+
+    @pl.when((g == parts - 1) & (i == blocks - 1))
     def _():
         dk_ref[...] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
@@ -212,22 +284,24 @@ def _query_block(j, i, ends):
 
 def _forward(q, k, v, packed, tables, block, interpret):
     heads, length, hd = q.shape
-    group, n = heads // k.shape[0], length // block
-    query = pl.BlockSpec((None, block, hd), lambda h, i, j, *_: (h, i, 0))
+    step, parts, n = _grid(heads, k.shape[0], length, hd, q.dtype.itemsize,
+                           block)
+    query = pl.BlockSpec((step, block, hd), lambda h, i, j, *_: (h, i, 0))
     keys = pl.BlockSpec((None, block, hd), lambda h, i, j, held, ends: (
-        h // group, _key_block(i, j, ends), 0))
+        h // parts, _key_block(i, j, ends), 0))
     out, lse = _call(
-        partial(_forward_kernel, blocks=n), (heads, n, n),
+        partial(_forward_kernel, blocks=n), (heads // step, n, n),
         [query, keys, keys,
          pl.BlockSpec((block, block // 8), lambda h, i, j, held, ends: (
              i, _key_block(i, j, ends)))],
-        [query, pl.BlockSpec((None, block, LANES),
+        [query, pl.BlockSpec((step, block, LANES),
                              lambda h, i, j, *_: (h, i, 0))],
         [jax.ShapeDtypeStruct(q.shape, q.dtype),
          jax.ShapeDtypeStruct((heads, length, LANES), jnp.float32)],
-        [pltpu.VMEM((block, LANES), jnp.float32),
-         pltpu.VMEM((block, LANES), jnp.float32),
-         pltpu.VMEM((block, hd), jnp.float32)],
+        [pltpu.VMEM((step, block, LANES), jnp.float32),
+         pltpu.VMEM((step, block, LANES), jnp.float32),
+         pltpu.VMEM((step, block, hd), jnp.float32),
+         pltpu.VMEM((block, block), jnp.int32)],
         ("parallel", "parallel", "arbitrary"), interpret,
     )(*tables, q, k, v, jax.lax.bitcast_convert_type(packed, jnp.int8))
     return out, lse[..., 0]
@@ -236,7 +310,8 @@ def _forward(q, k, v, packed, tables, block, interpret):
 def _backward(q, k, v, packed, tables, out, lse, d_out, block, interpret):
     heads, length, hd = q.shape
     kv_heads = k.shape[0]
-    group, n = heads // kv_heads, length // block
+    step, parts, n = _grid(heads, kv_heads, length, hd, q.dtype.itemsize,
+                           block)
     bits = jax.lax.bitcast_convert_type(packed, jnp.int8)
     # One value a row, on every lane of a tile: the forward's
     # log-sum-exp, and delta = rowsum(do · o).
@@ -244,28 +319,29 @@ def _backward(q, k, v, packed, tables, out, lse, d_out, block, interpret):
     delta = jnp.broadcast_to(
         (d_out.astype(jnp.float32) * out.astype(jnp.float32)).sum(
             -1, keepdims=True), (heads, length, LANES))
+    unpacked = pltpu.VMEM((block, block), jnp.int32)
 
-    query = pl.BlockSpec((None, block, hd), lambda h, i, j, *_: (h, i, 0))
-    row = pl.BlockSpec((None, block, LANES), lambda h, i, j, *_: (h, i, 0))
+    query = pl.BlockSpec((step, block, hd), lambda h, i, j, *_: (h, i, 0))
+    row = pl.BlockSpec((step, block, LANES), lambda h, i, j, *_: (h, i, 0))
     keys = pl.BlockSpec((None, block, hd), lambda h, i, j, held, ends: (
-        h // group, _key_block(i, j, ends), 0))
+        h // parts, _key_block(i, j, ends), 0))
     dq = _call(
-        partial(_dq_kernel, blocks=n), (heads, n, n),
+        partial(_dq_kernel, blocks=n), (heads // step, n, n),
         [query, keys, keys, query, row, row,
          pl.BlockSpec((block, block // 8), lambda h, i, j, held, ends: (
              i, _key_block(i, j, ends)))],
         query, jax.ShapeDtypeStruct(q.shape, q.dtype),
-        [pltpu.VMEM((block, hd), jnp.float32)],
+        [pltpu.VMEM((step, block, hd), jnp.float32), unpacked],
         ("parallel", "parallel", "arbitrary"), interpret,
     )(*tables, q, k, v, d_out, lse, delta, bits)
 
-    query = pl.BlockSpec((None, block, hd), lambda c, j, g, i, held, ends: (
-        c * group + g, _query_block(j, i, ends), 0))
-    row = pl.BlockSpec((None, block, LANES), lambda c, j, g, i, held, ends: (
-        c * group + g, _query_block(j, i, ends), 0))
+    query = pl.BlockSpec((step, block, hd), lambda c, j, g, i, held, ends: (
+        c * parts + g, _query_block(j, i, ends), 0))
+    row = pl.BlockSpec((step, block, LANES), lambda c, j, g, i, held, ends: (
+        c * parts + g, _query_block(j, i, ends), 0))
     keys = pl.BlockSpec((None, block, hd), lambda c, j, g, i, *_: (c, j, 0))
     dk, dv = _call(
-        partial(_dkv_kernel, blocks=n, group=group), (kv_heads, n, group, n),
+        partial(_dkv_kernel, blocks=n, parts=parts), (kv_heads, n, parts, n),
         [query, keys, keys, query, row, row,
          pl.BlockSpec((block, block // 8), lambda c, j, g, i, held, ends: (
              _query_block(j, i, ends), j))],
@@ -273,32 +349,38 @@ def _backward(q, k, v, packed, tables, out, lse, d_out, block, interpret):
         [jax.ShapeDtypeStruct(k.shape, k.dtype),
          jax.ShapeDtypeStruct(v.shape, v.dtype)],
         [pltpu.VMEM((block, hd), jnp.float32),
-         pltpu.VMEM((block, hd), jnp.float32)],
+         pltpu.VMEM((block, hd), jnp.float32), unpacked],
         ("parallel", "parallel", "arbitrary", "arbitrary"), interpret,
     )(*tables, q, k, v, d_out, lse, delta, bits)
     return dq, dk, dv
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(4, 5))
-def packed_attention(q, k, v, packed, block: int, interpret: bool = False):
+def packed_attention(q, k, v, packed, block: int, interpret: bool = False,
+                     tables=None):
     """``softmax over the kept s of q_t · k_s`` times ``v_s``: ``q``
     ``[H, S, hd]`` already scaled, ``k`` and ``v`` ``[KV, S, hd]`` (query
     heads ``g·c .. g·c + g - 1`` on key-value head ``c``), ``packed``
     :func:`pack_mask`'s ``[S, S / 8]`` at this ``block``, which is also
-    the kernels' tile. Every query keeps at least one key."""
-    return _forward(q, k, v, packed, tile_tables(packed, block), block,
-                    interpret)[0]
+    the kernels' tile, and ``tables`` its :func:`tile_tables` where the
+    caller has them. Every query keeps at least one key."""
+    if tables is None:
+        tables = tile_tables(packed, block)
+    return _attention(q, k, v, packed, tables, block, interpret)
 
 
-def _packed_attention_fwd(q, k, v, packed, block, interpret):
-    tables = tile_tables(packed, block)
+@partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _attention(q, k, v, packed, tables, block, interpret):
+    return _forward(q, k, v, packed, tables, block, interpret)[0]
+
+
+def _attention_fwd(q, k, v, packed, tables, block, interpret):
     out, lse = _forward(q, k, v, packed, tables, block, interpret)
     return out, (q, k, v, packed, tables, out, lse)
 
 
-def _packed_attention_bwd(block, interpret, saved, d_out):
+def _attention_bwd(block, interpret, saved, d_out):
     with jax.named_scope("df2.seq.attn_sparse"):
-        return _backward(*saved, d_out, block, interpret) + (None,)
+        return _backward(*saved, d_out, block, interpret) + (None, None)
 
 
-packed_attention.defvjp(_packed_attention_fwd, _packed_attention_bwd)
+_attention.defvjp(_attention_fwd, _attention_bwd)

@@ -481,19 +481,23 @@ def selected_attention(q, k, v, packed):
     one selection for all heads, under ``df2.seq.attn_sparse``: the
     kernels of ``models/selected_attention.py`` on a TPU, which read the
     packed bits; elsewhere, and for a sequence that is no whole number
-    of their tiles, the plain form."""
+    of their tiles, the plain form. Returns the attention and how many
+    ``[block, block]`` tiles of the selection hold a member (int32),
+    from the kernels' own tile table."""
     from dragonfly2_tpu.models import selected_attention as kernels
 
     s, h, hd = q.shape
     block = select_block(s)
     with jax.named_scope("df2.seq.attn_sparse"):
+        tables = kernels.tile_tables(packed, block)
+        held = tables[0].sum(dtype=jnp.int32)
         if jax.devices()[0].platform == "tpu" and block == SELECT_BLOCK:
             out = kernels.packed_attention(
                 q.transpose(1, 0, 2), k.transpose(1, 0, 2),
-                v.transpose(1, 0, 2), packed, block)
-            return out.transpose(1, 0, 2)
+                v.transpose(1, 0, 2), packed, block, tables=tables)
+            return out.transpose(1, 0, 2), held
         return dense_attention(q, k, v, None,
-                               seen=kernels.unpack_mask(packed, block))
+                               seen=kernels.unpack_mask(packed, block)), held
 
 
 def gated_ffn(p, a):

@@ -148,9 +148,10 @@ class SeqTrainState(train_state.TrainState):
     layers, num_experts]``; uint32, which holds 32,768 steps of the
     worst case, every assignment of a 32,768-token step on one expert).
     Where the family's attention runs over a selection of keys, also the
-    selections' candidates and members since the loop began, summed over
-    layers (``[2, 3]``: 16-bit limbs, ``seq_layers.count_limbs``; a
-    32k-token sequence has 5e8 causal pairs a layer); else None."""
+    selections' candidates and members and the attention tiles that
+    hold a member since the loop began, summed over layers (``[3, 3]``:
+    16-bit limbs, ``seq_layers.count_limbs``; a 32k-token sequence has
+    5e8 causal pairs a layer); else None."""
 
     router_bias: jax.Array = None
     routing_counts: jax.Array = None
@@ -284,7 +285,7 @@ def train_seq(
             tx=optax.adamw(schedule, weight_decay=config.weight_decay),
             router_bias=jnp.tile(bias, (n_moe, 1)),
             routing_counts=jnp.zeros((n_moe, cfg.num_experts), jnp.uint32),
-            sparse_counts=(jnp.zeros((2, 3), jnp.uint32) if sparse_topk
+            sparse_counts=(jnp.zeros((3, 3), jnp.uint32) if sparse_topk
                            else None))
         state = placed(mesh.put_replicated(state))
     rep = mesh.replicated
@@ -296,10 +297,19 @@ def train_seq(
     train_step = build_train_step(cfg, mesh)
     # Last values set: which attention the loop's sliding layers ran and
     # how many keys a learned selection keeps (both on every step's span
-    # too), and the tiles counted above.
+    # too), the tiles counted above, and the grid steps of one call of
+    # the selection's attention kernels at the step's shapes.
+    grid_steps = 0
+    if sparse_topk:
+        from dragonfly2_tpu.models import selected_attention
+
+        grid_steps = selected_attention.grid_steps(
+            cfg.num_attention_heads, cfg.num_key_value_heads, seq_len,
+            cfg.head_dim, cfg.compute_dtype, seq_layers.select_block(seq_len))
     TRAINING.set(seq_attn_window=cfg.attention_window,
                  seq_attn_tiles=tiles, seq_attn_tiles_kept=tiles_kept,
-                 seq_sparse_topk=sparse_topk)
+                 seq_sparse_topk=sparse_topk,
+                 seq_sparse_grid_steps=grid_steps)
 
     budget = StepBudget(config.max_seconds, step_samples=batch * seq_len)
     rng = np.random.default_rng((config.seed, 11))
@@ -332,10 +342,11 @@ def train_seq(
                      moe_assignments_held=int(here.sum()),
                      moe_assignments_hottest=int(here.max(1).sum()))
     if sparse_topk:
-        candidates, members = seq_layers.limbs_value(
+        candidates, members, held = seq_layers.limbs_value(
             jax.device_get(state.sparse_counts))
         TRAINING.add(seq_sparse_candidates=int(candidates),
-                     seq_sparse_selected=int(members))
+                     seq_sparse_selected=int(members),
+                     seq_sparse_tiles_held=int(held))
     return SeqTrainResult(
         params=state.params,
         config=config,

@@ -97,9 +97,18 @@ class TrainingStats:
       ``seq_sparse_candidates``: the keys its queries could have kept
       (for each query the earlier tokens of its document and itself),
       summed over queries, layers and steps, and
-      ``seq_sparse_selected``: those they kept. Counted from the
-      selections' own masks on the device and read once, at a loop's
-      drain.
+      ``seq_sparse_selected``: those they kept, and
+      ``seq_sparse_tiles_held``: the attention kernels' ``[block,
+      block]`` tiles that hold a member, summed over layers, sequences
+      and steps. Counted from the selections' own masks (the tiles from
+      the kernels' own tile table) on the device and read once, at a
+      loop's drain.
+    - ``seq_sparse_grid_steps``: the last value set where ``train_seq``
+      builds its step: the grid steps of one call of those kernels at
+      the step's shapes (key-value heads x tiles a row x tiles a column
+      where a step takes a whole group of query heads), or 0 for a
+      family without a selection. Over ``seq_sparse_tiles_held`` per
+      call it says how much of the grid computes.
     - ``setup_data_seconds``, ``setup_state_seconds``,
       ``setup_tables_seconds``: wall seconds of the trainers' set-up
       phases (:func:`setup_phase`): host structures from the records;
@@ -122,6 +131,7 @@ class TrainingStats:
             "attn_inverse_filled", "seq_attn_window", "seq_attn_tiles",
             "seq_attn_tiles_kept", "seq_sparse_topk",
             "seq_sparse_candidates", "seq_sparse_selected",
+            "seq_sparse_tiles_held", "seq_sparse_grid_steps",
             "setup_data_seconds", "setup_state_seconds",
             "setup_tables_seconds", "setup_compiles",
             "loop_compile_seconds")

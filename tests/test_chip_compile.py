@@ -269,13 +269,23 @@ def test_selected_attention_kernels_at_the_cells_shape(one_chip):
     shape: one 32k sequence, 32 query heads on 4 key-value heads of 128,
     the selection as packed bits. Three kernels of the repo's own
     (forward, dq, dk and dv), which Mosaic takes at 1,024 x 1,024 tiles
-    with the byte tile unpacked by shifts; nothing ``[S, S]`` wider than
-    a bit a pair: the packed mask is 134 MB where the splash kernel's
-    computed mask would be 4.3 GB of 32-bit words."""
-    from dragonfly2_tpu.models.selected_attention import packed_attention
+    with the byte tile unpacked by shifts, a grid step taking all 8 query
+    heads of a key-value head within the kernels' memory limit (Mosaic
+    counts 32.25 MB for the forward's step and 40.55 MB for dq's): 4 x
+    32 x 32 steps a call; nothing ``[S, S]`` wider than a bit a pair:
+    the packed mask is 134 MB where the splash kernel's computed mask
+    would be 4.3 GB of 32-bit words."""
+    from dragonfly2_tpu.models.selected_attention import (
+        grid_steps,
+        heads_per_step,
+        packed_attention,
+    )
 
     s = _struct(one_chip)
     length, heads, kv_heads, hd = 32_768, 32, 4, 128
+    assert heads_per_step(heads // kv_heads, 1024, hd, 2) == 8
+    assert grid_steps(heads, kv_heads, length, hd, jnp.bfloat16,
+                      1024) == 4 * 32 * 32
 
     def loss(q, k, v, packed):
         return packed_attention(q, k, v, packed, 1024).astype(
@@ -338,7 +348,7 @@ def test_the_third_familys_step_fits_the_chip(topo, as_tpu_program):
             tx=optax.adamw(1e-4, weight_decay=0.1),
             router_bias=jnp.zeros((layers, cfg.num_experts)),
             routing_counts=jnp.zeros((layers, cfg.num_experts), jnp.uint32),
-            sparse_counts=jnp.zeros((2, 3), jnp.uint32))
+            sparse_counts=jnp.zeros((3, 3), jnp.uint32))
 
     rep = mesh.replicated
     state = jax.tree.map(

@@ -237,13 +237,15 @@ def test_loss_and_gradients_against_the_plain_reference(seed):
             np.asarray(got), np.asarray(want_grads[name]),
             rtol=1e-3, atol=2e-5 * scale, err_msg=name)
     # Top-4 of 16 for every token in each of the three layers, and in
-    # each the selections' candidates and members by the lengths' own
-    # arithmetic.
+    # each the selections' candidates, members and held tiles by the
+    # lengths' own arithmetic.
     assert assigned.shape == (3, 16)
     assert (np.asarray(assigned).sum(1) == 4 * S).all()
     c = np.concatenate([np.arange(n) + 1 for n in LENGTHS])
+    # One tile a layer: 64 positions are no whole number of the
+    # kernels' 1,024-key blocks, so the packing block is the sequence.
     assert seq_layers.limbs_value(selected).tolist() == [
-        [c.sum(), np.minimum(c, 8).sum()]] * 3
+        [c.sum(), np.minimum(c, 8).sum(), 1]] * 3
 
 
 @pytest.mark.parametrize("seed,lengths", [
@@ -364,31 +366,71 @@ def test_keeping_every_candidate_is_dense_attention():
     packed, _, members = seq_layers.select_keys(qi, ki, w, segments, 30)
     assert int(members.sum()) == candidates_of(segments).sum()
     np.testing.assert_allclose(
-        np.asarray(seq_layers.selected_attention(q, k, v, packed)),
+        np.asarray(seq_layers.selected_attention(q, k, v, packed)[0]),
         np.asarray(seq_layers.dense_attention(q, k, v, segments)),
         rtol=1e-6, atol=1e-6)
 
 
-def test_the_kernels_are_the_plain_form(monkeypatch):
+def _kernel_mask(kind, rng, s=512, block=128):
+    """A selection ``[s, s]`` for the kernels' tests, 4 x 4 tiles.
+    ``documents``: causal within documents of 256, 128, 64 and 64, 30%
+    kept and the diagonal: tiles partly empty, rows of two to one held
+    tiles. ``one_tile_a_row``: every query block's row holds one tile,
+    the diagonal's in the first three and key block 1 in the last (a
+    row's range of held tiles away from its own block)."""
+    if kind == "documents":
+        segments = np.repeat(np.arange(4), [256, 128, 64, 64])
+        mask = candidates_of(segments) & (rng.random((s, s)) < 0.3)
+        mask[np.arange(s), np.arange(s)] = True
+        return mask
+    mask = np.zeros((s, s), bool)
+    for row, key in enumerate([0, 1, 2, 1]):
+        rows, keys = slice(row * block, (row + 1) * block), slice(
+            key * block, (key + 1) * block)
+        mask[rows, keys] = rng.random((block, block)) < 0.3
+        mask[rows, key * block + np.arange(block)[::-1]] = True
+    return mask
+
+
+@pytest.mark.parametrize("heads,kv_heads,step,kind", [
+    (2, 2, None, "documents"), (4, 2, None, "documents"),
+    (8, 2, None, "documents"), (8, 2, 2, "documents"),
+    (4, 2, None, "one_tile_a_row"), (8, 2, None, "one_tile_a_row"),
+], ids=["group_1", "group_2", "group_4", "group_4_two_a_step",
+        "group_2_one_tile_a_row", "group_4_one_tile_a_row"])
+def test_the_kernels_are_the_plain_form(monkeypatch, heads, kv_heads, step,
+                                        kind):
     """``models/selected_attention.py``'s three kernels (interpreted
     here; the chip compiles them in ``test_chip_compile.py``) against
-    the plain masked softmax, forward and every gradient, on a mask
-    whose tiles are partly empty, with grouped heads."""
+    the plain masked softmax, forward and every gradient, with 1, 2 and
+    4 query heads on each key-value head, a grid step taking the whole
+    group (or, where its blocks would not fit the kernels' memory, a
+    part of it: ``step``), on a mask whose tiles are partly empty and on
+    one whose every query block holds a single tile."""
+    if step is not None:
+        monkeypatch.setattr(selected_attention, "heads_per_step",
+                            lambda group, *_: step)
     s, block = 512, 128
     rng = np.random.default_rng(0)
     q, k, v, weight = (jnp.asarray(rng.standard_normal((s, h, 32)),
-                                   jnp.float32) for h in (4, 2, 2, 4))
-    segments = np.repeat(np.arange(4), [256, 128, 64, 64])
-    mask = candidates_of(segments) & (rng.random((s, s)) < 0.3)
-    mask[np.arange(s), np.arange(s)] = True
+                                   jnp.float32)
+                       for h in (heads, kv_heads, kv_heads, heads))
+    # Already scaled, as the kernels take q: scores of unit deviation.
+    q = q / np.sqrt(32)
+    mask = _kernel_mask(kind, rng)
     packed = selected_attention.pack_mask(jnp.asarray(mask), block)
     np.testing.assert_array_equal(
         np.asarray(selected_attention.unpack_mask(packed, block)), mask)
     held, ends = selected_attention.tile_tables(packed, block)
     want = mask.reshape(4, block, 4, block).any((1, 3))
     np.testing.assert_array_equal(np.asarray(held).reshape(4, 4), want)
-    assert np.asarray(ends).tolist() == [
-        [0, 0, 2, 3], [0, 1, 2, 3], [0, 1, 2, 3], [1, 1, 2, 3]]
+    assert np.asarray(ends).tolist() == {
+        "documents": [[0, 0, 2, 3], [0, 1, 2, 3], [0, 1, 2, 3],
+                      [1, 1, 2, 3]],
+        # Key block 3 holds nothing: its range is every query block,
+        # and none of its tiles is computed.
+        "one_tile_a_row": [[0, 1, 2, 1], [0, 1, 2, 1], [0, 1, 2, 0],
+                           [0, 3, 2, 3]]}[kind]
 
     def ours(q, k, v):
         out = selected_attention.packed_attention(
@@ -406,6 +448,51 @@ def test_the_kernels_are_the_plain_form(monkeypatch):
     for g, w in zip(got[1], want[1]):
         np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-4,
                                    atol=1e-5)
+
+
+def test_a_grid_step_takes_a_key_value_heads_group(monkeypatch):
+    """The three kernels of one gradient (forward, dq, dk with dv) each
+    walk ``kv_heads x n x n`` grid steps, not ``heads x n x n``: 2 x 4 x
+    4 for 8 query heads on 2 key-value heads at 4 tiles a row, and the
+    query-side blocks carry the group of 4 heads. At the chip's shape a
+    step takes a whole group of 8 under the kernels' memory limit, and
+    under a smaller one the largest divisor of the group that fits."""
+    s, block, hd = 512, 128, 32
+    q = jnp.zeros((8, s, hd), jnp.float32)
+    k = v = jnp.zeros((2, s, hd), jnp.float32)
+    packed = selected_attention.pack_mask(jnp.eye(s, dtype=bool), block)
+
+    def loss(q, k, v):
+        return selected_attention.packed_attention(q, k, v, packed, block,
+                                                   True).sum()
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
+    calls = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                calls.append(eqn.params["grid_mapping"])
+                continue
+            for value in eqn.params.values():
+                for sub in value if isinstance(value, tuple) else (value,):
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        walk(sub)
+
+    walk(jaxpr.jaxpr)
+    assert [tuple(g.grid) for g in calls] == [(2, 4, 4), (2, 4, 4),
+                                              (2, 4, 1, 4)]
+    for g in calls:
+        assert tuple(getattr(d, "block_size", d) for d in
+                     g.block_mappings[0].block_shape) == (4, block, hd)
+    assert selected_attention.grid_steps(8, 2, s, hd, jnp.float32,
+                                         block) == 2 * 4 * 4
+    assert selected_attention.heads_per_step(8, 1024, 128, 2) == 8
+    monkeypatch.setattr(selected_attention, "_VMEM_LIMIT", 48 * 2**20)
+    assert selected_attention.heads_per_step(8, 1024, 128, 2) == 4
+    assert selected_attention.grid_steps(32, 4, 32768, 128, jnp.bfloat16,
+                                         1024) == 8 * 32 * 32
 
 
 def test_three_position_streams():

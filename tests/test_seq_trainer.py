@@ -262,6 +262,40 @@ def test_the_selections_counters_are_the_corpus_own_arithmetic():
             == 2 * 3 * np.minimum(c, 6).sum())
 
 
+def test_the_selections_tiles_and_the_kernels_grid(monkeypatch):
+    """``seq_sparse_tiles_held``: the attention's ``[block, block]``
+    tiles that hold a member, summed on the device over layers,
+    sequences and steps from the kernels' tile table; with every
+    candidate kept (``topk`` over the longest document) those are the
+    causal tiles a document reaches. ``seq_sparse_grid_steps``: one
+    kernel call's grid at the step's shapes, a step for each tile and
+    key-value head (the group of 2 query heads in one step); 0 for a
+    family without a selection."""
+    import dataclasses
+
+    from dragonfly2_tpu.models import seq_layers
+
+    monkeypatch.setattr(seq_layers, "SELECT_BLOCK", 8)
+    corpus = pack_documents(documents(), SEQ)
+    corpus = SeqCorpus(*(a[:8] for a in (
+        corpus.tokens, corpus.segments, corpus.positions)))
+    before = step_budget.TRAINING.snapshot()
+    train_seq(corpus, SeqTrainConfig(
+        model=dataclasses.replace(KEYE, sparse_topk=SEQ), batch_size=4,
+        epochs=1, seed=1), one_device())
+    after = step_budget.TRAINING.snapshot()
+    reached = sum(int(seq_layers.document_tiles(row, 8).sum())
+                  for row in corpus.segments)
+    # Two layers, one epoch over all eight rows.
+    assert (after["seq_sparse_tiles_held"] - before["seq_sparse_tiles_held"]
+            == 2 * reached)
+    assert 8 * SEQ // 8 <= reached < 8 * 10
+    assert after["seq_sparse_grid_steps"] == 2 * 4 * 4
+    train_seq(corpus, SeqTrainConfig(model=MODEL, batch_size=4, epochs=1),
+              one_device())
+    assert step_budget.TRAINING.snapshot()["seq_sparse_grid_steps"] == 0
+
+
 def test_limbs_carry_past_32_bits():
     from dragonfly2_tpu.models import seq_layers
 
