@@ -1,18 +1,21 @@
 """What the sequence-model families (``lfm2_moe``, ``laguna``,
-``keye_vl2``) share: parameters drawn from a list of shapes, the RMS
-norm, rotate-half RoPE over one position stream or several, the
+``keye_vl2``, ``ouro``) share: parameters drawn from a list of shapes,
+the RMS norm, rotate-half RoPE over one position stream or several, the
 embedding lookup with its one-hot backward, causal same-document
 attention with or without a window (plain, and through JAX's
 splash-attention kernel on a TPU), attention over a learned selection of
 keys (an indexer's scores, an exact top-k a query, the softmax over the
-kept keys alone), the gated FFN, the summed next-token loss, and the
-per-sequence recomputed loss of a batch.
+kept keys alone), the gated FFN, the next-token loss summed or per
+position, a stack of layers run several times with an exit after each
+pass, and the per-sequence recomputed loss of a batch.
 
 A family is a module with ``param_shapes(cfg)`` and ``block(p, x,
-router_bias, segments, positions, *, cfg, layer)``, and a config that
-says which published layers run and what of a layer is held here
-(``kept_layers``, ``expert_layers``, ``held_experts``, ``held_vocab``,
-``num_experts``, ``norm_eps``, ``compute_dtype``, ``attention_window``).
+router_bias, segments, positions, *, cfg, layer)`` (a looped one also
+with ``exit_gate(p, h)``), and a config that says which published
+layers run and what of a layer is held here (``kept_layers``,
+``expert_layers``, ``held_experts``, ``held_vocab``, ``num_experts``,
+``norm_eps``, ``compute_dtype``, ``attention_window``; a looped one also
+``total_ut_steps`` and ``exit_entropy``).
 
 The job, not the model, packs documents: ``segments`` (a document id per
 position) keeps an attention score from crossing a document boundary,
@@ -513,12 +516,16 @@ def gated_ffn(p, a):
 HEAD_BLOCK = 8192
 
 
-def head_loss(head, final_norm, x, local, segments, *, cfg):
+def head_loss(head, final_norm, x, local, segments, *, cfg,
+              per_position=False):
     """The summed cross-entropy of one sequence's next tokens, over the
     positions whose next token is in the same document. ``head``: the
     output rows held ``[rows, hidden]``; ``local``: token ids as rows of
-    it. A sequence longer than :data:`HEAD_BLOCK` positions is a whole
-    number of blocks of that many, one at a time."""
+    it; ``final_norm``: the norm's weight, or None where ``x`` is normed
+    already. With ``per_position`` each position's term ``[S]`` (0 where
+    it is not counted), whose sum is the sum. A sequence longer than
+    :data:`HEAD_BLOCK` positions is a whole number of blocks of that
+    many, one at a time."""
     length, dt = x.shape[0], x.dtype
     if length > HEAD_BLOCK and length % HEAD_BLOCK:
         raise ValueError(f"{length} positions are neither one block of the "
@@ -526,18 +533,21 @@ def head_loss(head, final_norm, x, local, segments, *, cfg):
 
     def one(args):
         x, target, counted = args
-        logits = jnp.matmul(rms_norm(x, final_norm, cfg.norm_eps),
-                            head.astype(dt).T,
+        if final_norm is not None:
+            x = rms_norm(x, final_norm, cfg.norm_eps)
+        logits = jnp.matmul(x, head.astype(dt).T,
                             preferred_element_type=jnp.float32)
         hit = jnp.arange(head.shape[0])[None, :] == target[:, None]
         nll = jax.nn.logsumexp(logits, -1) - jnp.where(hit, logits, 0).sum(-1)
-        return jnp.where(counted, nll, 0).sum()
+        nll = jnp.where(counted, nll, 0)
+        return nll if per_position else nll.sum()
 
     whole = (x, jnp.roll(local, -1), target_positions(segments))
     if length <= HEAD_BLOCK:
         return one(whole)
-    return jax.lax.map(jax.checkpoint(one), tuple(
-        a.reshape(-1, HEAD_BLOCK, *a.shape[1:]) for a in whole)).sum()
+    out = jax.lax.map(jax.checkpoint(one), tuple(
+        a.reshape(-1, HEAD_BLOCK, *a.shape[1:]) for a in whole))
+    return out.reshape(length) if per_position else out.sum()
 
 
 def target_positions(segments):
@@ -548,7 +558,7 @@ def target_positions(segments):
 
 
 def sequence_loss(params, router_bias, tokens, segments, positions, *,
-                  cfg, block, saved=None):
+                  cfg, block, saved=None, exit_gate=None):
     """One packed sequence ``[S]`` through a family's ``block``s: the
     summed cross-entropy over :func:`target_positions` and what each
     expert layer's block counted, stacked over the expert layers: the
@@ -559,37 +569,104 @@ def sequence_loss(params, router_bias, tokens, segments, positions, *,
     rows (tied). Each block, and the head with the loss, keeps its input
     alone for the backward pass and is computed again there; ``saved``
     (names of ``jax.ad_checkpoint.checkpoint_name``) is what a family's
-    blocks keep beside it."""
+    blocks keep beside it.
+
+    A looped family gives ``exit_gate`` (``(params["exit_gate"], h) ->
+    z``, float32 ``[S]``): its kept layers run ``cfg.total_ut_steps``
+    times with the same weights, and after each pass ``t`` the final
+    norm gives ``h_t``, which feeds pass ``t + 1``, the gate ``λ_t =
+    σ(z_t)`` and that exit's cross-entropy against the head (no second
+    norm); the loss is :func:`exit_mixture`'s. The assignment counts are
+    then summed over the passes, and beside them stands the exit
+    distribution's mass ``[T, 3]`` (:func:`count_limbs` of ``p(t)`` in
+    units of ``2^-EXIT_MASS_BITS`` over the counted positions)."""
     keep = (None if saved is None else
             jax.checkpoint_policies.save_only_these_names(*saved))
     local = tokens - cfg.held_vocab[0]
     with jax.named_scope("df2.seq.embed"):
         x = embedding_rows(params["embed"], local,
                            jnp.dtype(cfg.compute_dtype))
-    counts = []
-    for i in cfg.kept_layers:
-        routed = i in cfg.expert_layers
-        bias = router_bias[cfg.expert_layers.index(i)] if routed else None
-        x, assigned = jax.checkpoint(
-            partial(block, cfg=cfg, layer=i), policy=keep)(
-            params[f"layer_{i}"], x, bias, segments, positions)
-        if routed:
-            counts.append(assigned)
-    with jax.named_scope("df2.loss"):
-        loss = jax.checkpoint(partial(head_loss, cfg=cfg))(
-            params.get("lm_head", params["embed"]), params["final_norm"], x,
-            local, segments)
-    return loss, (jax.tree.map(lambda *c: jnp.stack(c), *counts)
-                  if counts else jnp.zeros((0, cfg.num_experts), jnp.int32))
+
+    def layers(x):
+        counts = []
+        for i in cfg.kept_layers:
+            routed = i in cfg.expert_layers
+            bias = router_bias[cfg.expert_layers.index(i)] if routed else None
+            x, assigned = jax.checkpoint(
+                partial(block, cfg=cfg, layer=i), policy=keep)(
+                params[f"layer_{i}"], x, bias, segments, positions)
+            if routed:
+                counts.append(assigned)
+        return x, counts
+
+    def stacked(counts):
+        return (jax.tree.map(lambda *c: jnp.stack(c), *counts) if counts
+                else jnp.zeros((0, cfg.num_experts), jnp.int32))
+
+    head = params.get("lm_head", params["embed"])
+    if exit_gate is None:
+        x, counts = layers(x)
+        with jax.named_scope("df2.loss"):
+            loss = jax.checkpoint(partial(head_loss, cfg=cfg))(
+                head, params["final_norm"], x, local, segments)
+        return loss, stacked(counts)
+
+    def exit_of(final_norm, head, gate, x):
+        h = rms_norm(x, final_norm, cfg.norm_eps)
+        return h, exit_gate(gate, h), head_loss(
+            head, None, h, local, segments, cfg=cfg, per_position=True)
+
+    def one_pass(x, _):
+        x, counts = layers(x)
+        with jax.named_scope("df2.seq.exit"):
+            # The exit's logits are made again in the backward pass.
+            h, z, nll = jax.checkpoint(exit_of)(
+                params["final_norm"], head, params["exit_gate"], x)
+        return h, (z, nll, stacked(counts))
+
+    # One pass traced once, its weights the same at every step: their
+    # gradients add into one a leaf.
+    _, (z, nll, counts) = jax.lax.scan(one_pass, x,
+                                       length=cfg.total_ut_steps)
+    with jax.named_scope("df2.seq.exit"):
+        counted = target_positions(segments)
+        loss, p = exit_mixture(z, nll, counted, cfg.exit_entropy)
+        mass = count_limbs(jnp.round(jnp.where(
+            counted, p, 0) * 2 ** EXIT_MASS_BITS).astype(jnp.int32)).sum(-2)
+    return loss, (jax.tree.map(lambda c: c.sum(0), counts), mass)
+
+
+# A looped family's exit distribution is counted in units of 2^-16 of a
+# position (one position's p(t) is at most 65,536 units).
+EXIT_MASS_BITS = 16
+
+
+def exit_mixture(z, nll, counted, entropy_weight: float):
+    """The looped objective of one sequence: over the ``counted``
+    positions ``[S]``, ``Σ_t p(t)·CE_t - β·H(p)`` summed, with the exit
+    distribution ``p(t) = λ_t Π_{j<t} (1 - λ_j)`` for ``t < T`` and ``p(T)
+    = Π_{j<T} (1 - λ_j)``, ``λ_t = σ(z_t)``. ``z`` and ``nll`` (each
+    exit's cross-entropy) ``[T, S]`` float32; ``β`` ``entropy_weight``.
+    Returns the sum and ``p`` ``[T, S]``; the last step's gate is not
+    read. In logs, as ``log σ``, so that a gate near 0 or 1 stays
+    finite."""
+    leave = jax.nn.log_sigmoid(z[:-1])
+    stayed = jnp.cumsum(jax.nn.log_sigmoid(-z[:-1]), 0)
+    none = jnp.zeros_like(z[:1])
+    log_p = (jnp.concatenate([none, stayed])
+             + jnp.concatenate([leave, none]))
+    p = jnp.exp(log_p)
+    per_position = (p * (nll + entropy_weight * log_p)).sum(0)
+    return jnp.where(counted, per_position, 0).sum(), p
 
 
 def batch_loss(params, router_bias, tokens, segments, positions, *,
-               cfg, block, saved=None):
+               cfg, block, saved=None, exit_gate=None):
     """:func:`sequence_loss` over a batch ``[B, S]``, one sequence at a
     time (a sequence is the unit of memory: the batch costs residuals of
     ``B`` block inputs a layer and no more). Returns the two sums."""
     def one(args):
         return sequence_loss(params, router_bias, *args, cfg=cfg, block=block,
-                             saved=saved)
+                             saved=saved, exit_gate=exit_gate)
     loss, counts = jax.lax.map(one, (tokens, segments, positions))
     return loss.sum(), jax.tree.map(lambda c: c.sum(0), counts)

@@ -99,13 +99,17 @@ def gat_from_tree(tree: dict) -> tuple:
 def seq_tree(params: Any, routing_counts: np.ndarray) -> dict:
     """Sequence-model checkpoint: params + the assignments each expert
     got over the run (``[expert layers, experts]``; what a later run
-    would set a selection bias from)."""
+    would set a selection bias from). A model without an expert layer
+    has no counts to keep (and the checkpointer writes no empty
+    array)."""
+    counts = np.asarray(routing_counts, np.int64)
     return {"params": params,
-            "routing_counts": np.asarray(routing_counts, np.int64)}
+            **({"routing_counts": counts} if counts.size else {})}
 
 
 def seq_from_tree(tree: dict) -> tuple[Any, np.ndarray]:
-    return tree["params"], np.asarray(tree["routing_counts"])
+    return tree["params"], np.asarray(
+        tree.get("routing_counts", np.zeros((0, 0), np.int64)))
 
 
 def mlp_tree(params: Any, normalizer: Normalizer, target_norm: Normalizer) -> dict:

@@ -28,10 +28,11 @@ import optax
 from flax.training import train_state
 from jax.sharding import PartitionSpec as P
 
-from dragonfly2_tpu.models import keye_vl2, laguna, lfm2_moe, seq_layers
+from dragonfly2_tpu.models import keye_vl2, laguna, lfm2_moe, ouro, seq_layers
 from dragonfly2_tpu.models.keye_vl2 import KeyeVL2Config
 from dragonfly2_tpu.models.laguna import LagunaConfig
 from dragonfly2_tpu.models.lfm2_moe import Lfm2MoeConfig
+from dragonfly2_tpu.models.ouro import OuroConfig
 from dragonfly2_tpu.parallel import MeshContext, data_parallel_mesh
 from dragonfly2_tpu.train.step_budget import (
     TRAINING,
@@ -42,10 +43,12 @@ from dragonfly2_tpu.train.step_budget import (
 
 
 # A family by the ``model_type`` of its published ``config.json``: the
-# module (``param_shapes``, ``block``) and its config.
+# module (``param_shapes``, ``block``; ``exit_gate`` where it is looped)
+# and its config.
 FAMILIES = {"lfm2_moe": (lfm2_moe, Lfm2MoeConfig),
             "laguna": (laguna, LagunaConfig),
-            "KeyeVL2": (keye_vl2, KeyeVL2Config)}
+            "KeyeVL2": (keye_vl2, KeyeVL2Config),
+            "ouro": (ouro, OuroConfig)}
 
 
 def family_of(cfg):
@@ -102,7 +105,7 @@ class SeqTrainConfig:
     model's state (16 bytes a parameter) and four 8k sequences, or a
     0.31B-parameter one's and two 32k sequences, share a 16 GB chip."""
 
-    model: Lfm2MoeConfig | LagunaConfig | KeyeVL2Config
+    model: Lfm2MoeConfig | LagunaConfig | KeyeVL2Config | OuroConfig
     batch_size: int = 4              # sequences a step
     # For whoever packs the corpus (``trainer/training.py``): the rows'
     # length, and the id that ends a document inside a token segment
@@ -151,11 +154,14 @@ class SeqTrainState(train_state.TrainState):
     selections' candidates and members and the attention tiles that
     hold a member since the loop began, summed over layers (``[3, 3]``:
     16-bit limbs, ``seq_layers.count_limbs``; a 32k-token sequence has
-    5e8 causal pairs a layer); else None."""
+    5e8 causal pairs a layer); else None. Where the family is looped,
+    the exit distribution's mass at each pass since the loop began
+    (``[T, 3]`` limbs of ``2^-EXIT_MASS_BITS`` units); else None."""
 
     router_bias: jax.Array = None
     routing_counts: jax.Array = None
     sparse_counts: jax.Array = None
+    exit_mass: jax.Array = None
 
 
 @dataclass
@@ -179,6 +185,7 @@ def build_train_step(cfg, mesh: MeshContext):
     rep = mesh.replicated
     family = family_of(cfg)
     block, saved = family.block, getattr(family, "SAVED", None)
+    exit_gate = getattr(family, "exit_gate", None)
 
     def loss_and_grads(params, router_bias, tokens, segments, positions,
                        seq_ids):
@@ -192,7 +199,7 @@ def build_train_step(cfg, mesh: MeshContext):
         def mean(p):
             loss, counts = seq_layers.batch_loss(
                 p, router_bias, tok, seg, pos, cfg=cfg, block=block,
-                saved=saved)
+                saved=saved, exit_gate=exit_gate)
             return loss / n, counts
 
         (loss, counts), grads = jax.value_and_grad(mean, has_aux=True)(params)
@@ -221,6 +228,11 @@ def build_train_step(cfg, mesh: MeshContext):
                 counts, selected = counts
                 more["sparse_counts"] = seq_layers.carry_limbs(
                     state.sparse_counts + selected.sum(0))
+            if state.exit_mass is not None:
+                # Beside them, the exit distribution's mass at each pass.
+                counts, mass = counts
+                more["exit_mass"] = seq_layers.carry_limbs(
+                    state.exit_mass + mass)
             state = state.apply_gradients(
                 grads=grads,
                 routing_counts=state.routing_counts
@@ -273,6 +285,10 @@ def train_seq(
         total_steps)
     n_moe = len(cfg.expert_layers)
     sparse_topk = getattr(cfg, "sparse_topk", 0)
+    loop_steps = getattr(cfg, "total_ut_steps", 0)
+    if loop_steps > len(TRAINING.POSITIONS):
+        raise ValueError(f"{loop_steps} loop steps; the training block counts "
+                         f"the mass of {len(TRAINING.POSITIONS)} exits")
     bias = np.zeros(cfg.num_experts, np.float32) if (
         config.router_bias is None or not cfg.use_expert_bias
     ) else np.asarray(config.router_bias, np.float32)
@@ -286,7 +302,9 @@ def train_seq(
             router_bias=jnp.tile(bias, (n_moe, 1)),
             routing_counts=jnp.zeros((n_moe, cfg.num_experts), jnp.uint32),
             sparse_counts=(jnp.zeros((3, 3), jnp.uint32) if sparse_topk
-                           else None))
+                           else None),
+            exit_mass=(jnp.zeros((loop_steps, 3), jnp.uint32) if loop_steps
+                       else None))
         state = placed(mesh.put_replicated(state))
     rep = mesh.replicated
     with setup_phase("tables") as placed:
@@ -295,10 +313,11 @@ def train_seq(
             for a in (corpus.tokens, corpus.segments, corpus.positions)))
 
     train_step = build_train_step(cfg, mesh)
-    # Last values set: which attention the loop's sliding layers ran and
-    # how many keys a learned selection keeps (both on every step's span
-    # too), the tiles counted above, and the grid steps of one call of
-    # the selection's attention kernels at the step's shapes.
+    # Last values set: which attention the loop's sliding layers ran, how
+    # many keys a learned selection keeps and how many times a looped
+    # family runs its layers (all three on every step's span too), the
+    # tiles counted above, and the grid steps of one call of the
+    # selection's attention kernels at the step's shapes.
     grid_steps = 0
     if sparse_topk:
         from dragonfly2_tpu.models import selected_attention
@@ -309,7 +328,8 @@ def train_seq(
     TRAINING.set(seq_attn_window=cfg.attention_window,
                  seq_attn_tiles=tiles, seq_attn_tiles_kept=tiles_kept,
                  seq_sparse_topk=sparse_topk,
-                 seq_sparse_grid_steps=grid_steps)
+                 seq_sparse_grid_steps=grid_steps,
+                 seq_loop_steps=loop_steps)
 
     budget = StepBudget(config.max_seconds, step_samples=batch * seq_len)
     rng = np.random.default_rng((config.seed, 11))
@@ -331,7 +351,8 @@ def train_seq(
         step_samples=batch * seq_len, drain=lambda: state.params,
         serialize_launches=mesh.serialize_launches,
         step_facts={"seq_attn_window": cfg.attention_window,
-                    "seq_sparse_topk": sparse_topk})
+                    "seq_sparse_topk": sparse_topk,
+                    "seq_loop_steps": loop_steps})
 
     # One read of the routing counts, after the drain.
     routing = np.asarray(jax.device_get(state.routing_counts), np.int64)
@@ -347,6 +368,12 @@ def train_seq(
         TRAINING.add(seq_sparse_candidates=int(candidates),
                      seq_sparse_selected=int(members),
                      seq_sparse_tiles_held=int(held))
+    if loop_steps:
+        mass = seq_layers.limbs_value(jax.device_get(state.exit_mass))
+        TRAINING.add(**{
+            f"seq_exit_mass_{t + 1}":
+                float(m) / 2 ** seq_layers.EXIT_MASS_BITS
+            for t, m in enumerate(mass)})
     return SeqTrainResult(
         params=state.params,
         config=config,

@@ -109,6 +109,16 @@ class TrainingStats:
       where a step takes a whole group of query heads), or 0 for a
       family without a selection. Over ``seq_sparse_tiles_held`` per
       call it says how much of the grid computes.
+    - ``seq_loop_steps``: the last value set where ``train_seq`` builds
+      its step: how many times a looped family runs its layers with the
+      same weights (``total_ut_steps``), or 0 for a family that runs
+      them once; and over such a loop's steps the counters
+      ``seq_exit_mass_1`` .. ``seq_exit_mass_4``: the exit
+      distribution's ``p(t)`` at each pass, summed over the counted
+      positions, sequences and steps (in positions; summed on the
+      device in fixed point, ``seq_layers.EXIT_MASS_BITS``, and read
+      once, at a loop's drain). Where the last one holds nearly all the
+      positions, the gates leave nothing to the earlier exits.
     - ``setup_data_seconds``, ``setup_state_seconds``,
       ``setup_tables_seconds``: wall seconds of the trainers' set-up
       phases (:func:`setup_phase`): host structures from the records;
@@ -132,17 +142,22 @@ class TrainingStats:
             "seq_attn_tiles_kept", "seq_sparse_topk",
             "seq_sparse_candidates", "seq_sparse_selected",
             "seq_sparse_tiles_held", "seq_sparse_grid_steps",
-            "setup_data_seconds", "setup_state_seconds",
-            "setup_tables_seconds", "setup_compiles",
+            "seq_loop_steps", "seq_exit_mass_1", "seq_exit_mass_2",
+            "seq_exit_mass_3", "seq_exit_mass_4", "setup_data_seconds",
+            "setup_state_seconds", "setup_tables_seconds", "setup_compiles",
             "loop_compile_seconds")
+    # What is counted in fractions: seconds, and positions' shares.
     SECONDS = ("compile_seconds", "setup_data_seconds",
                "setup_state_seconds", "setup_tables_seconds",
                "loop_compile_seconds")
+    POSITIONS = ("seq_exit_mass_1", "seq_exit_mass_2", "seq_exit_mass_3",
+                 "seq_exit_mass_4")
 
     def __init__(self):
         self._lock = threading.Lock()
         self._counts = dict.fromkeys(self.KEYS, 0)
-        self._counts.update(dict.fromkeys(self.SECONDS, 0.0))
+        self._counts.update(dict.fromkeys(self.SECONDS + self.POSITIONS,
+                                          0.0))
         # Budgets between creation and finish. Weak: a loop that raises
         # never reaches finish, and its budget must not keep counting.
         self._open = weakref.WeakSet()

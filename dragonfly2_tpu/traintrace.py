@@ -25,7 +25,9 @@ phases (docs/OBSERVABILITY.md "Training loops").
   0 where it has none, whose kernel is ``df2.seq.attn_window``;
   ``seq_sparse_topk``: the keys a query keeps where attention runs over
   a learned selection, 0 where it does not, whose scopes are
-  ``df2.seq.index``, ``df2.seq.select`` and ``df2.seq.attn_sparse``).
+  ``df2.seq.index``, ``df2.seq.select`` and ``df2.seq.attn_sparse``;
+  ``seq_loop_steps``: how many times a looped sequence model runs its
+  layers, 0 where it runs them once, whose exits are ``df2.seq.exit``).
 - The longest device idle gaps, each with the ``df2.train.*`` or
   ``df2.setup.*`` span the loop's thread was in.
 

@@ -381,6 +381,82 @@ def test_the_third_familys_step_fits_the_chip(topo, as_tpu_program):
         for ln in compiled.as_text().splitlines())
 
 
+def test_the_looped_familys_step_fits_the_chip(topo, as_tpu_program):
+    """``ouro-2.6b-pp12.train``'s whole step (0.407B parameters, 4
+    sequences of 4,096 positions, every published width, all 49,152
+    rows of the head) through ``seq_trainer.build_train_step``, as the
+    benchmark's runner builds it: four layers traced once as one pass
+    of a loop that runs four times, so the kernels appear once a layer
+    in the forward loop and twice in the backward one (the recomputed
+    forward and the fused backward); each pass's logits are made in the
+    pass and again in its backward, never held for all four exits; no
+    pass holds a copy of the weights of its own."""
+    import json
+
+    import optax
+
+    from dragonfly2_tpu.models import ouro
+    from dragonfly2_tpu.parallel import data_parallel_mesh
+    from dragonfly2_tpu.train import seq_trainer
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "ouro-2.6b-pp12.json")) as fh:
+        spec = json.load(fh)
+    held, published = spec["deployment"], spec["published"]
+    cfg = ouro.OuroConfig.from_published(
+        spec, vocab_size=published["vocab_size"],
+        num_hidden_layers=published["num_hidden_layers"],
+        layers=tuple(held["layers_kept"]),
+        vocab_held=tuple(held["vocab_rows_held"]))
+    mesh = data_parallel_mesh(devices=topo.devices[:1])
+
+    def make_state():
+        params = {}
+        for path, shape, _ in ouro.param_shapes(cfg):
+            node = params
+            for part in path[:-1]:
+                node = node.setdefault(part, {})
+            node[path[-1]] = jnp.zeros(shape, jnp.float32)
+        return seq_trainer.SeqTrainState.create(
+            apply_fn=None, params=params,
+            tx=optax.adamw(1e-4, weight_decay=0.1),
+            router_bias=jnp.zeros((0, 0)),
+            routing_counts=jnp.zeros((0, 0), jnp.uint32),
+            exit_mass=jnp.zeros((cfg.total_ut_steps, 3), jnp.uint32))
+
+    rep = mesh.replicated
+    state = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=rep),
+        jax.eval_shape(make_state))
+    rows, length = spec["corpus"]["tokens"] // spec["seq_len"], spec["seq_len"]
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(
+            shape, jnp.int32,
+            sharding=mesh.batch_sharding if len(shape) == 1 else rep)
+
+    compiled = seq_trainer.build_train_step(cfg, mesh).lower(
+        state, i32(rows, length), i32(rows, length), i32(spec["batch"]),
+        i32(rows, length)).compile()
+    memory = compiled.memory_analysis()
+    # Parameters and Adam's two moments (12 bytes a parameter) and the
+    # corpus's three arrays.
+    assert 4.8e9 < memory.argument_size_in_bytes < 5.0e9
+    # 8.1 GB by the compiler's count (it counts high: PERF.md).
+    assert memory.temp_size_in_bytes < 10e9
+    text = compiled.as_text()
+    calls = [ln for ln in text.splitlines()
+             if "tpu_custom_call" in ln and " = " in ln]
+    assert len(calls) == 3 * len(cfg.kept_layers)
+    assert "df2.seq.exit" in text
+    passes = cfg.total_ut_steps
+    for shape in (f"[{passes},{length},{cfg.held_vocab[1]}]",
+                  f"[{passes},{cfg.hidden_size},{cfg.intermediate_size}]",
+                  f"[{passes},{cfg.hidden_size},{cfg.hidden_size}]"):
+        assert shape not in text, shape
+
+
 def _runs_on_its_own(text: str) -> str:
     """The instructions of a compiled program that run on their own: the
     entry computation's and the loops' bodies' (the same words inside a

@@ -507,3 +507,51 @@ def test_parameters_are_drawn_set_to_one_or_set_to_zero():
     assert np.asarray(params["a"]["bias"]).tolist() == [0.0] * 3
     assert np.asarray(params["a"]["scale"]).tolist() == [1.0] * 3
     assert np.asarray(params["a"]["w"]).std() > 0
+
+
+@pytest.mark.parametrize("cell", [
+    "lfm2-24b-a2b-ep8.train", "laguna-xs2-ep32.train",
+    "keye-vl2-30b-a3b-ep16.train"])
+def test_the_heads_per_position_terms_sum_to_its_sum(cell):
+    """The head's per-position form (a looped family's exits read it)
+    summed is the summed form the three other families' steps take, bit
+    for bit, eagerly and compiled, on a row of each cell's own traffic
+    at its rehearsal sizes; and a state normed before the head gives the
+    head's own norm's terms."""
+    import importlib
+    from types import SimpleNamespace
+
+    from benchmarks import run
+
+    _, _, _, spec = run.load_cell(cell, rehearse=True)
+    row = {k: jnp.asarray(v[0]) for k, v in importlib.import_module(
+        f"benchmarks.runners.{spec['kind']}").traffic(spec, 3).items()}
+    first, rows = spec["deployment"]["vocab_rows_held"]
+    d = spec["hidden_size"]
+    cfg = SimpleNamespace(norm_eps=spec.get("rms_norm_eps",
+                                            spec.get("norm_eps")))
+    rng = np.random.default_rng(5)
+    head = jnp.asarray(rng.normal(0, 0.02, (rows, d)), jnp.float32)
+    norm = jnp.asarray(1 + 0.1 * rng.standard_normal(d), jnp.float32)
+    x = jnp.asarray(rng.standard_normal((row["tokens"].shape[0], d)),
+                    jnp.bfloat16)
+    local, segments = row["tokens"] - first, row["segments"]
+
+    def summed(x):
+        return seq_layers.head_loss(head, norm, x, local, segments, cfg=cfg)
+
+    def per_position(x):
+        return seq_layers.head_loss(head, norm, x, local, segments, cfg=cfg,
+                                    per_position=True)
+
+    terms = per_position(x)
+    assert terms.shape == local.shape
+    assert (np.asarray(terms)[~np.asarray(
+        seq_layers.target_positions(segments))] == 0).all()
+    assert float(terms.sum()) == float(summed(x))
+    assert float(jax.jit(lambda x: per_position(x).sum())(x)) == float(
+        jax.jit(summed)(x))
+    normed = seq_layers.rms_norm(x, norm, cfg.norm_eps)
+    np.testing.assert_array_equal(np.asarray(seq_layers.head_loss(
+        head, None, normed, local, segments, cfg=cfg, per_position=True)),
+        np.asarray(terms))

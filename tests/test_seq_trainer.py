@@ -12,6 +12,7 @@ import pytest
 from dragonfly2_tpu.models.keye_vl2 import KeyeVL2Config
 from dragonfly2_tpu.models.laguna import LagunaConfig, Rope
 from dragonfly2_tpu.models.lfm2_moe import Lfm2MoeConfig
+from dragonfly2_tpu.models.ouro import OuroConfig
 from dragonfly2_tpu.parallel import data_parallel_mesh
 from dragonfly2_tpu.train import step_budget
 from dragonfly2_tpu.train.seq_trainer import (
@@ -54,6 +55,12 @@ KEYE = KeyeVL2Config(
     rope_theta=10000000, mrope_section=(1, 1, 2), indexer_num_heads=2,
     indexer_head_dim=8, sparse_topk=6, experts_held=(4, 4),
     vocab_held=(0, 64))
+# The fourth: two dense layers run three times with an exit after each
+# pass, no expert layer.
+OURO = OuroConfig(
+    hidden_size=32, intermediate_size=48, num_hidden_layers=2,
+    num_attention_heads=4, num_key_value_heads=4, head_dim=8, vocab_size=64,
+    rope_theta=1_000_000, total_ut_steps=3)
 FAMILIES = pytest.mark.parametrize(
     "model,window", [(MODEL, 0), (LAGUNA, 8), (KEYE, 0)],
     ids=["lfm2_moe", "laguna", "KeyeVL2"])
@@ -420,3 +427,93 @@ def test_token_segments_round_trip_and_a_seq_model_is_registered(
     # The trained segments are gone; nothing is left to train.
     assert storage.token_files("host-1") == []
     assert not storage.has_closed_segments("host-1")
+
+
+def test_a_looped_family_counts_its_passes_and_exits_and_no_experts():
+    """``seq_loop_steps`` (3 here, on every step's span too, 0 for a
+    family that runs its layers once) and ``seq_exit_mass_1`` ..: each
+    exit's share of the counted positions, summed on the device over
+    sequences and steps and added once at the drain, all of them
+    together every counted position of every step. No expert layer: the
+    routing counts are ``[0, 0]`` and no ``moe_*`` counter moves."""
+    corpus = pack_documents(documents(), SEQ)
+    corpus = SeqCorpus(*(a[:8] for a in (
+        corpus.tokens, corpus.segments, corpus.positions)))
+    counted = int(((corpus.segments[:, 1:] == corpus.segments[:, :-1])
+                   ).sum())
+    before = step_budget.TRAINING.snapshot()
+    result = train_seq(corpus, SeqTrainConfig(
+        model=OURO, batch_size=4, epochs=3, learning_rate=3e-3, seed=3),
+        one_device())
+    after = step_budget.TRAINING.snapshot()
+    assert after["seq_loop_steps"] == 3
+    assert result.history[-1] < result.history[0]
+    assert result.routing_counts.shape == (0, 0)
+    for key in ("moe_steps", "moe_assignments_held",
+                "moe_assignments_hottest"):
+        assert after[key] == before[key]
+    assert after["loop_compiles"] - before["loop_compiles"] == 1
+    mass = [after[f"seq_exit_mass_{t}"] - before[f"seq_exit_mass_{t}"]
+            for t in (1, 2, 3)]
+    assert all(m > 0 for m in mass)
+    assert after["seq_exit_mass_4"] == before["seq_exit_mass_4"]
+    # Three epochs over all eight rows; each position's p(t) rounded
+    # to 2^-16 at each of three exits.
+    assert abs(sum(mass) - 3 * counted) <= 3 * 3 * counted * 2.0 ** -17
+    train_seq(corpus, SeqTrainConfig(model=MODEL, batch_size=4, epochs=1),
+              one_device())
+    assert step_budget.TRAINING.snapshot()["seq_loop_steps"] == 0
+
+
+def test_a_looped_family_on_two_devices_is_one_device():
+    """Two devices, each on half of a step's sequences, against one
+    device on all of them: the same losses, the same exits' mass."""
+    corpus = pack_documents(documents(1), SEQ)
+    config = SeqTrainConfig(model=OURO, batch_size=4, epochs=1, seed=5)
+    before = step_budget.TRAINING.snapshot()["seq_exit_mass_1"]
+    one = train_seq(corpus, config, one_device())
+    middle = step_budget.TRAINING.snapshot()["seq_exit_mass_1"]
+    two = train_seq(corpus, config,
+                    data_parallel_mesh(devices=jax.devices()[:2]))
+    after = step_budget.TRAINING.snapshot()["seq_exit_mass_1"]
+    np.testing.assert_allclose(two.history, one.history, rtol=2e-3)
+    np.testing.assert_allclose(after - middle, middle - before, rtol=2e-3)
+
+
+def test_a_looped_family_is_registered_by_the_trainer_service(tmp_path):
+    """The trainer service's sequence job with a ``model_type`` ``ouro``
+    model: trained on the host's token segments and registered as a
+    ``seq`` model whose tree holds the exit gate and an empty routing
+    count."""
+    from dragonfly2_tpu.train.checkpoint import load_model, seq_from_tree
+    from dragonfly2_tpu.trainer import (
+        TrainerStorage,
+        Training,
+        TrainingConfig,
+    )
+    from dragonfly2_tpu.trainer.storage import TOKENS_PREFIX
+
+    storage = TrainerStorage(str(tmp_path / "data"))
+    for doc in documents(2, n=30):
+        storage.append(TOKENS_PREFIX, "host-1", doc.astype("<u2").tobytes(),
+                       True)
+    storage.close_host("host-1")
+    registry = _Registry()
+    saved = {}
+    plain = registry.create_model
+
+    def keep(**kwargs):
+        saved["tree"], saved["meta"] = load_model(kwargs["artifact_dir"])
+        plain(**kwargs)
+
+    registry.create_model = keep
+    outcome = Training(
+        storage, registry,
+        TrainingConfig(train_seq_model=True, seq=SeqTrainConfig(
+            model=OURO, batch_size=2, seq_len=SEQ, epochs=1)),
+        mesh=one_device()).train("10.0.0.1", "sched-1", "host-1")
+    assert outcome.errors == [] and outcome.seq_model_id
+    params, counts = seq_from_tree(saved["tree"])
+    assert saved["meta"].config["model_type"] == "ouro"
+    assert counts.shape == (0, 0)
+    assert set(params["exit_gate"]) == {"w", "b"} and "layer_1" in params
