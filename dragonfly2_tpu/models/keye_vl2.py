@@ -76,7 +76,7 @@ from dragonfly2_tpu.parallel.moe import expert_layer
 # backward pass.
 MOE_TOKENS = 8192
 # What a block keeps for the backward pass beside its input
-# (``seq_layers.sequence_loss``): the selection, one bit a pair, in
+# (``seq_layers.head_inputs``): the selection, one bit a pair, in
 # place of being scored and ranked again.
 SAVED = (SELECTION,)
 

@@ -15,7 +15,7 @@ packed sequence ``[S, hidden]``, block ``l`` is the pre-and-post
   over ``s <= t`` in the same document; ``out W_o``.
 - ``y = h + n(W_2 (silu(W_1 a) · W_3 a); w_ff_out)``, ``a = n(h; w_ff)``.
 
-The loop (``seq_layers.sequence_loss``): for ``t = 1 .. T`` the kept
+The loop (``seq_layers.head_inputs``): for ``t = 1 .. T`` the kept
 layers run in order, then ``h_t = n(x; w_final)``, which is the next
 pass's input; the exit gate ``λ_t = σ(h_t · w_g + b_g)``
 (:func:`exit_gate`) and the logits ``h_t W_head^T`` (untied, no second

@@ -5,9 +5,10 @@ embedding lookup with its one-hot backward, causal same-document
 attention with or without a window (plain, and through JAX's
 splash-attention kernel on a TPU), attention over a learned selection of
 keys (an indexer's scores, an exact top-k a query, the softmax over the
-kept keys alone), the gated FFN, the next-token loss summed or per
-position, a stack of layers run several times with an exit after each
-pass, and the per-sequence recomputed loss of a batch.
+kept keys alone), the gated FFN, the next-token loss weighted by
+position with its gradient formed in its forward pass, a stack of
+layers run several times with an exit after each pass, and a batch's
+loss: its layers recomputed a sequence at a time, its head in one call.
 
 A family is a module with ``param_shapes(cfg)`` and ``block(p, x,
 router_bias, segments, positions, *, cfg, layer)`` (a looped one also
@@ -325,7 +326,7 @@ SELECT_PANEL = 512
 # many keys (``models/selected_attention.py``: the TPU kernels' tile).
 SELECT_BLOCK = 1024
 # The name under which a block's selection is kept for the backward pass
-# (``sequence_loss``'s ``saved``).
+# (the ``saved`` of ``head_inputs``).
 SELECTION = "df2_selection"
 
 
@@ -509,45 +510,105 @@ def gated_ffn(p, a):
             ) @ p["w2"].astype(dt)
 
 
-# Positions whose logits are held at a time where a sequence is longer:
+# Positions whose logits are held at a time where a sequence has more:
 # at 32,768 positions against 18,992 rows one sequence's float32 logits
-# are 2.5 GB; a block's are kept for neither pass and made again in the
-# backward one. A sequence no longer than this is one block, as it was.
+# are 2.5 GB. A sequence no longer than this is one block.
 HEAD_BLOCK = 8192
 
 
-def head_loss(head, final_norm, x, local, segments, *, cfg,
-              per_position=False):
-    """The summed cross-entropy of one sequence's next tokens, over the
-    positions whose next token is in the same document. ``head``: the
-    output rows held ``[rows, hidden]``; ``local``: token ids as rows of
-    it; ``final_norm``: the norm's weight, or None where ``x`` is normed
-    already. With ``per_position`` each position's term ``[S]`` (0 where
-    it is not counted), whose sum is the sum. A sequence longer than
-    :data:`HEAD_BLOCK` positions is a whole number of blocks of that
-    many, one at a time."""
-    length, dt = x.shape[0], x.dtype
-    if length > HEAD_BLOCK and length % HEAD_BLOCK:
+def head_blocks(length: int) -> int:
+    """How many blocks of positions :func:`head_loss` takes ``length``
+    positions in: one up to :data:`HEAD_BLOCK`, else whole blocks of
+    that many (a length that is neither is refused)."""
+    if length <= HEAD_BLOCK:
+        return 1
+    if length % HEAD_BLOCK:
         raise ValueError(f"{length} positions are neither one block of the "
                          f"head's loss nor whole blocks of {HEAD_BLOCK}")
+    return length // HEAD_BLOCK
 
-    def one(args):
-        x, target, counted = args
-        if final_norm is not None:
-            x = rms_norm(x, final_norm, cfg.norm_eps)
-        logits = jnp.matmul(x, head.astype(dt).T,
-                            preferred_element_type=jnp.float32)
-        hit = jnp.arange(head.shape[0])[None, :] == target[:, None]
-        nll = jax.nn.logsumexp(logits, -1) - jnp.where(hit, logits, 0).sum(-1)
-        nll = jnp.where(counted, nll, 0)
-        return nll if per_position else nll.sum()
 
-    whole = (x, jnp.roll(local, -1), target_positions(segments))
-    if length <= HEAD_BLOCK:
-        return one(whole)
-    out = jax.lax.map(jax.checkpoint(one), tuple(
-        a.reshape(-1, HEAD_BLOCK, *a.shape[1:]) for a in whole))
-    return out.reshape(length) if per_position else out.sum()
+def _head_block(head, x, targets, weights, with_gradient: bool):
+    """One block's cross-entropies ``[P]`` (``x`` ``[P, hidden]`` against
+    every row of ``head``: products in ``x``'s dtype, accumulated and
+    normalised in float32) and, ``with_gradient``, the gradients of
+    ``Σ weights·nll`` with respect to ``x`` (in its dtype) and to the
+    head's rows in that dtype (as float32): the logits' gradient
+    ``weights ⊙ (softmax - onehot)`` is rounded to the products' dtype,
+    where the MXU would round it."""
+    dt = x.dtype
+    rows = head.astype(dt)
+    logits = jnp.matmul(x, rows.T, preferred_element_type=jnp.float32)
+    hit = jnp.arange(head.shape[0])[None, :] == targets[:, None]
+    lse = jax.nn.logsumexp(logits, -1)
+    nll = lse - jnp.where(hit, logits, 0).sum(-1)
+    if not with_gradient:
+        return nll
+    g = (weights[:, None] * (jnp.exp(logits - lse[:, None]) - hit)).astype(dt)
+    dx = jnp.matmul(g, rows, preferred_element_type=jnp.float32).astype(dt)
+    d_rows = jnp.matmul(g.T, x, preferred_element_type=jnp.float32)
+    return nll, dx, d_rows.astype(dt).astype(jnp.float32)
+
+
+def _head_pass(head, x, targets, weights, with_gradient: bool):
+    """:func:`_head_block` over the call's blocks, one at a time: each
+    sequence's positions (the last axis but one of ``x``) in
+    :func:`head_blocks` of them; the rows' gradient summed over all the
+    blocks in float32."""
+    shape = x.shape
+    size = shape[-2] // head_blocks(shape[-2])
+    blocks = (x.reshape(-1, size, shape[-1]), targets.reshape(-1, size),
+              weights.reshape(-1, size))
+    if len(blocks[0]) == 1:
+        out = _head_block(head, *(b[0] for b in blocks), with_gradient)
+    elif not with_gradient:
+        out = jax.lax.map(lambda b: _head_block(head, *b, False), blocks)
+    else:
+        def one(d_rows, block):
+            nll, dx, d = _head_block(head, *block, True)
+            return d_rows + d, (nll, dx)
+
+        d_rows, (nll, dx) = jax.lax.scan(
+            one, jnp.zeros(head.shape, jnp.float32), blocks)
+        out = nll, dx, d_rows
+    if not with_gradient:
+        return out.reshape(shape[:-1])
+    nll, dx, d_rows = out
+    return nll.reshape(shape[:-1]), dx.reshape(shape), d_rows
+
+
+@jax.custom_vjp
+def head_loss(head, x, targets, weights):
+    """``Σ weights·nll``: the cross-entropy of each position of ``x``
+    ``[..., P, hidden]`` (normed already; leading axes, if any, are
+    sequences) against the output rows ``head`` ``[rows, hidden]`` with
+    ``targets`` ``[..., P]`` (row ids), weighted by ``weights`` ``[...,
+    P]`` float32 (a mask of the counted positions, or a looped family's
+    exit distribution on them). A sequence's positions are one block,
+    or past :data:`HEAD_BLOCK` a whole number of blocks of that many;
+    the blocks are taken one at a time.
+
+    Differentiated, the pass that makes a block's logits also forms the
+    gradients (:func:`_head_block`): three products a block, and
+    nothing of the logits kept or made again; the backward pass scales
+    what it kept (the input's gradient, the rows' gradient summed over
+    the blocks, and each position's cross-entropy, which is the
+    weights' gradient) by the loss's cotangent: one rows' gradient for
+    the whole call. Undifferentiated, one product a block."""
+    return (weights * _head_pass(head, x, targets, weights, False)).sum()
+
+
+def _head_loss_fwd(head, x, targets, weights):
+    nll, dx, d_rows = _head_pass(head, x, targets, weights, True)
+    return (weights * nll).sum(), (nll, dx, d_rows)
+
+
+def _head_loss_bwd(saved, ct):
+    nll, dx, d_rows = saved
+    return d_rows * ct, (dx * ct).astype(dx.dtype), None, nll * ct
+
+
+head_loss.defvjp(_head_loss_fwd, _head_loss_bwd)
 
 
 def target_positions(segments):
@@ -559,27 +620,38 @@ def target_positions(segments):
 
 def sequence_loss(params, router_bias, tokens, segments, positions, *,
                   cfg, block, saved=None, exit_gate=None):
-    """One packed sequence ``[S]`` through a family's ``block``s: the
-    summed cross-entropy over :func:`target_positions` and what each
-    expert layer's block counted, stacked over the expert layers: the
-    assignment counts ``[expert layers, E]`` (with whatever else the
-    family's block counts beside them, as a tuple of such stacks).
-    ``router_bias``: ``[expert layers, E]``. The logits are against
-    ``lm_head`` where the family has one, else against the embedding
-    rows (tied). Each block, and the head with the loss, keeps its input
-    alone for the backward pass and is computed again there; ``saved``
-    (names of ``jax.ad_checkpoint.checkpoint_name``) is what a family's
-    blocks keep beside it.
+    """:func:`batch_loss` of one packed sequence ``[S]``."""
+    return batch_loss(params, router_bias, tokens[None], segments[None],
+                      positions[None], cfg=cfg, block=block, saved=saved,
+                      exit_gate=exit_gate)
 
-    A looped family gives ``exit_gate`` (``(params["exit_gate"], h) ->
-    z``, float32 ``[S]``): its kept layers run ``cfg.total_ut_steps``
-    times with the same weights, and after each pass ``t`` the final
-    norm gives ``h_t``, which feeds pass ``t + 1``, the gate ``λ_t =
-    σ(z_t)`` and that exit's cross-entropy against the head (no second
-    norm); the loss is :func:`exit_mixture`'s. The assignment counts are
-    then summed over the passes, and beside them stands the exit
-    distribution's mass ``[T, 3]`` (:func:`count_limbs` of ``p(t)`` in
-    units of ``2^-EXIT_MASS_BITS`` over the counted positions)."""
+
+def head_inputs(params, router_bias, tokens, segments, positions, *,
+                cfg, block, saved=None, exit_gate=None):
+    """One packed sequence ``[S]`` through a family's ``block``s up to
+    the head: what :func:`head_loss` takes of it (the states ``[P,
+    hidden]``, normed, their targets and weights ``[P]``), the part of
+    the loss outside the head (0, or a looped family's entropy bonus)
+    and what each expert layer's block counted, stacked over the expert
+    layers: the assignment counts ``[expert layers, E]`` (with whatever
+    else the family's block counts beside them, as a tuple of such
+    stacks). ``router_bias``: ``[expert layers, E]``. Each block, and the
+    final norm, keeps its input alone for the backward pass and is
+    computed again there; ``saved`` (names of
+    ``jax.ad_checkpoint.checkpoint_name``) is what a family's blocks
+    keep beside it.
+
+    A plain family's ``P`` positions are the sequence's, weighted by
+    :func:`target_positions`. A looped family gives ``exit_gate``
+    (``(params["exit_gate"], h) -> z``, float32 ``[S]``): its kept
+    layers run ``cfg.total_ut_steps`` times with the same weights, and
+    after each pass ``t`` the final norm gives ``h_t``, which feeds pass
+    ``t + 1``, and the gate ``λ_t = σ(z_t)``; the head then takes every
+    exit's positions, ``P = T·S`` (no second norm), each exit's weighted
+    by the exit distribution :func:`exit_mixture` gives. The assignment
+    counts are then summed over the passes, and beside them stands the
+    exit distribution's mass ``[T, 3]`` (:func:`count_limbs` of ``p(t)``
+    in units of ``2^-EXIT_MASS_BITS`` over the counted positions)."""
     keep = (None if saved is None else
             jax.checkpoint_policies.save_only_these_names(*saved))
     local = tokens - cfg.held_vocab[0]
@@ -603,37 +675,37 @@ def sequence_loss(params, router_bias, tokens, segments, positions, *,
         return (jax.tree.map(lambda *c: jnp.stack(c), *counts) if counts
                 else jnp.zeros((0, cfg.num_experts), jnp.int32))
 
-    head = params.get("lm_head", params["embed"])
+    targets, counted = jnp.roll(local, -1), target_positions(segments)
     if exit_gate is None:
         x, counts = layers(x)
         with jax.named_scope("df2.loss"):
-            loss = jax.checkpoint(partial(head_loss, cfg=cfg))(
-                head, params["final_norm"], x, local, segments)
-        return loss, stacked(counts)
+            h = jax.checkpoint(rms_norm, static_argnums=2)(
+                x, params["final_norm"], cfg.norm_eps)
+        return (h, targets, counted.astype(jnp.float32),
+                jnp.zeros((), jnp.float32), stacked(counts))
 
-    def exit_of(final_norm, head, gate, x):
+    def exit_of(final_norm, gate, x):
         h = rms_norm(x, final_norm, cfg.norm_eps)
-        return h, exit_gate(gate, h), head_loss(
-            head, None, h, local, segments, cfg=cfg, per_position=True)
+        return h, exit_gate(gate, h)
 
     def one_pass(x, _):
         x, counts = layers(x)
         with jax.named_scope("df2.seq.exit"):
-            # The exit's logits are made again in the backward pass.
-            h, z, nll = jax.checkpoint(exit_of)(
-                params["final_norm"], head, params["exit_gate"], x)
-        return h, (z, nll, stacked(counts))
+            h, z = jax.checkpoint(exit_of)(
+                params["final_norm"], params["exit_gate"], x)
+        return h, (h, z, stacked(counts))
 
     # One pass traced once, its weights the same at every step: their
     # gradients add into one a leaf.
-    _, (z, nll, counts) = jax.lax.scan(one_pass, x,
-                                       length=cfg.total_ut_steps)
+    _, (h, z, counts) = jax.lax.scan(one_pass, x,
+                                     length=cfg.total_ut_steps)
     with jax.named_scope("df2.seq.exit"):
-        counted = target_positions(segments)
-        loss, p = exit_mixture(z, nll, counted, cfg.exit_entropy)
-        mass = count_limbs(jnp.round(jnp.where(
-            counted, p, 0) * 2 ** EXIT_MASS_BITS).astype(jnp.int32)).sum(-2)
-    return loss, (jax.tree.map(lambda c: c.sum(0), counts), mass)
+        weights, bonus = exit_mixture(z, counted, cfg.exit_entropy)
+        mass = count_limbs(jnp.round(
+            weights * 2 ** EXIT_MASS_BITS).astype(jnp.int32)).sum(-2)
+    return (h.reshape(-1, h.shape[-1]), jnp.tile(targets, len(z)),
+            weights.reshape(-1), bonus,
+            (jax.tree.map(lambda c: c.sum(0), counts), mass))
 
 
 # A looped family's exit distribution is counted in units of 2^-16 of a
@@ -641,13 +713,14 @@ def sequence_loss(params, router_bias, tokens, segments, positions, *,
 EXIT_MASS_BITS = 16
 
 
-def exit_mixture(z, nll, counted, entropy_weight: float):
-    """The looped objective of one sequence: over the ``counted``
-    positions ``[S]``, ``Σ_t p(t)·CE_t - β·H(p)`` summed, with the exit
-    distribution ``p(t) = λ_t Π_{j<t} (1 - λ_j)`` for ``t < T`` and ``p(T)
-    = Π_{j<T} (1 - λ_j)``, ``λ_t = σ(z_t)``. ``z`` and ``nll`` (each
-    exit's cross-entropy) ``[T, S]`` float32; ``β`` ``entropy_weight``.
-    Returns the sum and ``p`` ``[T, S]``; the last step's gate is not
+def exit_mixture(z, counted, entropy_weight: float):
+    """The looped objective of one sequence, ``Σ_t p(t)·CE_t - β·H(p)``
+    summed over the ``counted`` positions ``[S]``, in two parts: the
+    weights ``[T, S]`` float32 of the exits' cross-entropies (the exit
+    distribution ``p(t) = λ_t Π_{j<t} (1 - λ_j)`` for ``t < T`` and
+    ``p(T) = Π_{j<T} (1 - λ_j)``, ``λ_t = σ(z_t)``, on the counted
+    positions, 0 elsewhere) and ``-β·H(p)`` summed. ``z`` ``[T, S]``
+    float32; ``β`` ``entropy_weight``; the last step's gate is not
     read. In logs, as ``log σ``, so that a gate near 0 or 1 stays
     finite."""
     leave = jax.nn.log_sigmoid(z[:-1])
@@ -655,18 +728,31 @@ def exit_mixture(z, nll, counted, entropy_weight: float):
     none = jnp.zeros_like(z[:1])
     log_p = (jnp.concatenate([none, stayed])
              + jnp.concatenate([leave, none]))
-    p = jnp.exp(log_p)
-    per_position = (p * (nll + entropy_weight * log_p)).sum(0)
-    return jnp.where(counted, per_position, 0).sum(), p
+    weights = jnp.where(counted, jnp.exp(log_p), 0)
+    return weights, entropy_weight * (weights * log_p).sum()
 
 
 def batch_loss(params, router_bias, tokens, segments, positions, *,
                cfg, block, saved=None, exit_gate=None):
-    """:func:`sequence_loss` over a batch ``[B, S]``, one sequence at a
-    time (a sequence is the unit of memory: the batch costs residuals of
-    ``B`` block inputs a layer and no more). Returns the two sums."""
+    """A batch ``[B, S]`` of packed sequences: the summed cross-entropy
+    over :func:`target_positions` (for a looped family, the summed
+    :func:`exit_mixture` objective) and what :func:`head_inputs` counted,
+    summed over the sequences. The layers take one sequence at a time (a
+    sequence is the unit of memory: the batch costs residuals of ``B``
+    block inputs a layer and no more); the head then takes every
+    sequence's positions in one call (:func:`head_loss`, under
+    ``df2.loss``, or ``df2.seq.exit`` for a looped family), which forms
+    its gradients where it makes its logits, under no recomputation, and
+    keeps one rows' gradient for the batch. The logits are against
+    ``lm_head`` where the family has one, else against the embedding
+    rows (tied)."""
     def one(args):
-        return sequence_loss(params, router_bias, *args, cfg=cfg, block=block,
-                             saved=saved, exit_gate=exit_gate)
-    loss, counts = jax.lax.map(one, (tokens, segments, positions))
-    return loss.sum(), jax.tree.map(lambda c: c.sum(0), counts)
+        return head_inputs(params, router_bias, *args, cfg=cfg, block=block,
+                           saved=saved, exit_gate=exit_gate)
+
+    h, targets, weights, outside, counts = jax.lax.map(
+        one, (tokens, segments, positions))
+    head = params.get("lm_head", params["embed"])
+    with jax.named_scope("df2.loss" if exit_gate is None else "df2.seq.exit"):
+        loss = outside.sum() + head_loss(head, h, targets, weights)
+    return loss, jax.tree.map(lambda c: c.sum(0), counts)

@@ -56,6 +56,14 @@ def family_of(cfg):
     return FAMILIES[cfg.model_type][0]
 
 
+def fused_head_blocks(cfg, seq_len: int) -> int:
+    """The blocks of positions in which the loss head forms its gradient
+    in its forward pass, a sequence (``seq_layers.head_loss``): one call
+    takes a sequence's positions, or a looped family's every exit's."""
+    return seq_layers.head_blocks(
+        seq_len * max(getattr(cfg, "total_ut_steps", 0), 1))
+
+
 @dataclass(frozen=True)
 class SeqCorpus:
     """Packed sequences, ``[R, S]`` each: token ids, a document id per
@@ -314,11 +322,12 @@ def train_seq(
 
     train_step = build_train_step(cfg, mesh)
     # Last values set: which attention the loop's sliding layers ran, how
-    # many keys a learned selection keeps and how many times a looped
-    # family runs its layers (all three on every step's span too), the
-    # tiles counted above, and the grid steps of one call of the
-    # selection's attention kernels at the step's shapes.
-    grid_steps = 0
+    # many keys a learned selection keeps, how many times a looped family
+    # runs its layers and in how many blocks a sequence's loss head forms
+    # its gradient in its forward pass (all four on every step's span
+    # too), the tiles counted above, and the grid steps of one call of
+    # the selection's attention kernels at the step's shapes.
+    grid_steps, head_blocks = 0, fused_head_blocks(cfg, seq_len)
     if sparse_topk:
         from dragonfly2_tpu.models import selected_attention
 
@@ -329,7 +338,8 @@ def train_seq(
                  seq_attn_tiles=tiles, seq_attn_tiles_kept=tiles_kept,
                  seq_sparse_topk=sparse_topk,
                  seq_sparse_grid_steps=grid_steps,
-                 seq_loop_steps=loop_steps)
+                 seq_loop_steps=loop_steps,
+                 seq_head_fused_blocks=head_blocks)
 
     budget = StepBudget(config.max_seconds, step_samples=batch * seq_len)
     rng = np.random.default_rng((config.seed, 11))
@@ -352,7 +362,8 @@ def train_seq(
         serialize_launches=mesh.serialize_launches,
         step_facts={"seq_attn_window": cfg.attention_window,
                     "seq_sparse_topk": sparse_topk,
-                    "seq_loop_steps": loop_steps})
+                    "seq_loop_steps": loop_steps,
+                    "seq_head_fused_blocks": head_blocks})
 
     # One read of the routing counts, after the drain.
     routing = np.asarray(jax.device_get(state.routing_counts), np.int64)

@@ -119,6 +119,13 @@ class TrainingStats:
       device in fixed point, ``seq_layers.EXIT_MASS_BITS``, and read
       once, at a loop's drain). Where the last one holds nearly all the
       positions, the gates leave nothing to the earlier exits.
+    - ``seq_head_fused_blocks``: the last value set where ``train_seq``
+      builds its step: the blocks of ``seq_layers.HEAD_BLOCK`` positions
+      in which a sequence's loss head forms its gradient in its forward
+      pass (three products a block, the logits made once), 1 for a
+      sequence no longer than a block; a looped family's one call takes
+      every exit's positions (2 for four exits of 4,096). 0 before any
+      sequence loop.
     - ``setup_data_seconds``, ``setup_state_seconds``,
       ``setup_tables_seconds``: wall seconds of the trainers' set-up
       phases (:func:`setup_phase`): host structures from the records;
@@ -143,7 +150,8 @@ class TrainingStats:
             "seq_sparse_candidates", "seq_sparse_selected",
             "seq_sparse_tiles_held", "seq_sparse_grid_steps",
             "seq_loop_steps", "seq_exit_mass_1", "seq_exit_mass_2",
-            "seq_exit_mass_3", "seq_exit_mass_4", "setup_data_seconds",
+            "seq_exit_mass_3", "seq_exit_mass_4", "seq_head_fused_blocks",
+            "setup_data_seconds",
             "setup_state_seconds", "setup_tables_seconds", "setup_compiles",
             "loop_compile_seconds")
     # What is counted in fractions: seconds, and positions' shares.

@@ -27,7 +27,10 @@ phases (docs/OBSERVABILITY.md "Training loops").
   a learned selection, 0 where it does not, whose scopes are
   ``df2.seq.index``, ``df2.seq.select`` and ``df2.seq.attn_sparse``;
   ``seq_loop_steps``: how many times a looped sequence model runs its
-  layers, 0 where it runs them once, whose exits are ``df2.seq.exit``).
+  layers, 0 where it runs them once, whose exits are ``df2.seq.exit``;
+  ``seq_head_fused_blocks``: the blocks of positions in which a
+  sequence's loss head forms its gradient in its forward pass, under
+  ``df2.loss`` or ``df2.seq.exit``).
 - The longest device idle gaps, each with the ``df2.train.*`` or
   ``df2.setup.*`` span the loop's thread was in.
 

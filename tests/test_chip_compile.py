@@ -379,6 +379,21 @@ def test_the_third_familys_step_fits_the_chip(topo, as_tpu_program):
     assert not any("rematted_computation" in ln and (
         "df2.seq.index" in ln or "df2.seq.select" in ln)
         for ln in compiled.as_text().splitlines())
+    _head_products_run_forward(compiled.as_text(), cfg.held_vocab[1])
+
+
+def _head_products_run_forward(text: str, rows: int) -> None:
+    """The loss head's products (those with the head's rows in their
+    result: the logits and the rows' gradient) all run in the forward
+    pass, where ``seq_layers.head_loss`` forms its gradients: none is
+    made again in a recomputation or in the backward pass."""
+    head = [ln for ln in text.splitlines()
+            if " convolution(" in ln and ("df2.loss" in ln
+                                         or "df2.seq.exit" in ln)
+            and str(rows) in re.match(r"\s*\S+ = \w+\[([\d,]*)\]",
+                                      ln).group(1).split(",")]
+    assert head and not any("rematted_computation" in ln or "transpose("
+                            in ln for ln in head), head
 
 
 def test_the_looped_familys_step_fits_the_chip(topo, as_tpu_program):
@@ -388,9 +403,10 @@ def test_the_looped_familys_step_fits_the_chip(topo, as_tpu_program):
     benchmark's runner builds it: four layers traced once as one pass
     of a loop that runs four times, so the kernels appear once a layer
     in the forward loop and twice in the backward one (the recomputed
-    forward and the fused backward); each pass's logits are made in the
-    pass and again in its backward, never held for all four exits; no
-    pass holds a copy of the weights of its own."""
+    forward and the fused backward); the exits' logits are made once,
+    after the loop, a block of positions at a time, never held for all
+    four exits, and the head's products run in the forward pass alone;
+    no pass holds a copy of the weights of its own."""
     import json
 
     import optax
@@ -443,7 +459,7 @@ def test_the_looped_familys_step_fits_the_chip(topo, as_tpu_program):
     # Parameters and Adam's two moments (12 bytes a parameter) and the
     # corpus's three arrays.
     assert 4.8e9 < memory.argument_size_in_bytes < 5.0e9
-    # 8.1 GB by the compiler's count (it counts high: PERF.md).
+    # 7.2 GB by the compiler's count (it counts high: PERF.md).
     assert memory.temp_size_in_bytes < 10e9
     text = compiled.as_text()
     calls = [ln for ln in text.splitlines()
@@ -455,6 +471,7 @@ def test_the_looped_familys_step_fits_the_chip(topo, as_tpu_program):
                   f"[{passes},{cfg.hidden_size},{cfg.intermediate_size}]",
                   f"[{passes},{cfg.hidden_size},{cfg.hidden_size}]"):
         assert shape not in text, shape
+    _head_products_run_forward(text, cfg.held_vocab[1])
 
 
 def _runs_on_its_own(text: str) -> str:

@@ -454,26 +454,81 @@ def test_config_refuses_what_the_family_does_not_have():
             layer_types=["conv", "conv", "sliding", "conv", "conv", "conv"]))
 
 
+def head_case(weighting, seed=3):
+    """A head, a state ``[S, 64]``, targets and weights over ``sequence(1)``'s
+    documents, in float32: ``weighting`` ``"mask"`` (the counted positions,
+    as the non-looped families weight them) or ``"mixed"`` (a fraction on
+    some counted positions, 0 on the rest of them and on the uncounted:
+    a looped family's exit distribution, and zeros)."""
+    rng = np.random.default_rng(seed)
+    head = jnp.asarray(rng.standard_normal((96, 64)) * 0.1, jnp.float32)
+    x = jnp.asarray(rng.standard_normal((S, 64)), jnp.float32)
+    targets = jnp.asarray(rng.integers(0, 96, S), jnp.int32)
+    counted = np.asarray(seq_layers.target_positions(sequence(1)[1]))
+    weights = (counted.astype(np.float32) if weighting == "mask" else
+               np.where(counted & (rng.random(S) > 0.3), rng.random(S), 0))
+    return head, x, targets, jnp.asarray(weights, jnp.float32)
+
+
+def plain_head(head, x, targets, weights):
+    """``Σ weights·nll`` written plainly, for autodiff: the whole call's
+    logits at once, the backward pass making them again."""
+    logits = jnp.matmul(x, head.astype(x.dtype).T,
+                        preferred_element_type=jnp.float32)
+    nll = jax.nn.logsumexp(logits, -1) - jnp.take_along_axis(
+        logits, targets[:, None], -1)[:, 0]
+    return (weights * nll).sum()
+
+
+@pytest.mark.parametrize("blocks", [1, 4])
+@pytest.mark.parametrize("weighting", ["mask", "mixed"])
+def test_the_heads_gradient_is_plain_autodiffs(monkeypatch, blocks,
+                                               weighting):
+    """The head forms its gradients in its forward pass: its value and
+    the gradients of its input, its rows and its weights are plain
+    autodiff's of the same sum, in one block and in several (float32:
+    the same products in another order, within 1e-6 of the loss and
+    1e-6 of a gradient's largest element); a position of weight 0 gets
+    a gradient of exactly 0."""
+    head, x, targets, weights = head_case(weighting)
+    if blocks > 1:
+        monkeypatch.setattr(seq_layers, "HEAD_BLOCK", S // blocks)
+    args = (head, x, targets, weights)
+    loss, grads = jax.value_and_grad(seq_layers.head_loss,
+                                     argnums=(0, 1, 3))(*args)
+    want, want_grads = jax.value_and_grad(plain_head, argnums=(0, 1, 3))(
+        *args)
+    np.testing.assert_allclose(float(loss), float(want), rtol=1e-6)
+    for got, expected, name in zip(grads, want_grads,
+                                   ("rows", "input", "weights")):
+        scale = float(jnp.abs(expected).max())
+        np.testing.assert_allclose(np.asarray(got), np.asarray(expected),
+                                   rtol=1e-5, atol=1e-6 * scale,
+                                   err_msg=name)
+    left_out = np.asarray(weights) == 0
+    assert left_out.any() and not left_out.all()
+    assert (np.asarray(grads[1])[left_out] == 0).all()
+    # Undifferentiated, the same sum.
+    np.testing.assert_allclose(float(seq_layers.head_loss(*args)),
+                               float(want), rtol=1e-6)
+
+
 @pytest.mark.parametrize("blocks", [2, 4])
 def test_the_head_in_position_blocks_is_the_head(monkeypatch, blocks):
-    """A sequence longer than ``HEAD_BLOCK`` takes its logits and loss a
-    block of positions at a time (the third family's 32k sequences): the
-    loss and every gradient are the whole head's."""
-    cfg = config()
-    rng = np.random.default_rng(3)
-    head = jnp.asarray(rng.standard_normal((96, 64)) * 0.1, jnp.float32)
-    norm = jnp.asarray(1 + 0.1 * rng.standard_normal(64), jnp.float32)
-    x = jnp.asarray(rng.standard_normal((S, 64)), jnp.float32)
-    local = jnp.asarray(rng.integers(0, 96, S), jnp.int32)
-    _, segments, _ = sequence(1)
+    """A call longer than ``HEAD_BLOCK`` takes its logits and loss a
+    block of positions at a time (the third family's 32k sequences, a
+    looped family's exits): the loss and every gradient are the whole
+    head's, and the blocks are a loop, differentiated or not."""
+    head, x, targets, weights = head_case("mixed")
 
-    def loss(head, norm, x):
-        return seq_layers.head_loss(head, norm, x, local, segments, cfg=cfg)
+    def loss(head, x, weights):
+        return seq_layers.head_loss(head, x, targets, weights)
 
-    whole = jax.value_and_grad(loss, argnums=(0, 1, 2))(head, norm, x)
+    whole = jax.value_and_grad(loss, argnums=(0, 1, 2))(head, x, weights)
     monkeypatch.setattr(seq_layers, "HEAD_BLOCK", S // blocks)
-    parts = jax.value_and_grad(loss, argnums=(0, 1, 2))(head, norm, x)
-    assert "scan" in str(jax.make_jaxpr(loss)(head, norm, x))
+    parts = jax.value_and_grad(loss, argnums=(0, 1, 2))(head, x, weights)
+    assert "scan" in str(jax.make_jaxpr(loss)(head, x, weights))
+    assert "scan" in str(jax.make_jaxpr(jax.grad(loss))(head, x, weights))
     np.testing.assert_allclose(float(parts[0]), float(whole[0]), rtol=1e-6)
     for got, want in zip(parts[1], whole[1]):
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
@@ -485,17 +540,17 @@ def test_a_length_that_is_no_whole_number_of_head_blocks_is_refused(
     """Past one block the head takes whole blocks only: it does not fall
     back to the whole sequence's logits, which is what the blocks are
     there to avoid. One block or less is taken as it is."""
-    cfg = config()
-    head, norm = jnp.ones((96, 64)), jnp.ones(64)
-    x, local = jnp.ones((S, 64)), jnp.zeros(S, jnp.int32)
-    _, segments, _ = sequence(1)
+    head, x = jnp.ones((96, 64)), jnp.ones((S, 64))
+    targets, weights = jnp.zeros(S, jnp.int32), jnp.ones(S)
     monkeypatch.setattr(seq_layers, "HEAD_BLOCK", S - 1)
     with pytest.raises(ValueError, match="whole blocks"):
-        seq_layers.head_loss(head, norm, x, local, segments, cfg=cfg)
+        seq_layers.head_loss(head, x, targets, weights)
+    with pytest.raises(ValueError, match="whole blocks"):
+        jax.grad(seq_layers.head_loss)(head, x, targets, weights)
     monkeypatch.setattr(seq_layers, "HEAD_BLOCK", S + 1)
-    assert "scan" not in str(jax.make_jaxpr(
-        lambda x: seq_layers.head_loss(head, norm, x, local, segments,
-                                       cfg=cfg))(x))
+    assert seq_layers.head_blocks(S) == 1
+    assert "scan" not in str(jax.make_jaxpr(jax.grad(
+        lambda x: seq_layers.head_loss(head, x, targets, weights)))(x))
 
 
 def test_parameters_are_drawn_set_to_one_or_set_to_zero():
@@ -513,11 +568,13 @@ def test_parameters_are_drawn_set_to_one_or_set_to_zero():
     "lfm2-24b-a2b-ep8.train", "laguna-xs2-ep32.train",
     "keye-vl2-30b-a3b-ep16.train"])
 def test_the_heads_per_position_terms_sum_to_its_sum(cell):
-    """The head's per-position form (a looped family's exits read it)
-    summed is the summed form the three other families' steps take, bit
-    for bit, eagerly and compiled, on a row of each cell's own traffic
-    at its rehearsal sizes; and a state normed before the head gives the
-    head's own norm's terms."""
+    """The head's per-position terms (the gradient of its weights: each
+    position's cross-entropy, which a looped family's exit distribution
+    weighs) summed over the counted positions are the sum the three
+    other families' steps take, with the counted positions as the
+    weights, bit for bit, eagerly and compiled, on a row of each cell's
+    own traffic at its rehearsal sizes; and a position's term is the
+    head's sum with a weight of 1 there alone."""
     import importlib
     from types import SimpleNamespace
 
@@ -533,25 +590,26 @@ def test_the_heads_per_position_terms_sum_to_its_sum(cell):
     rng = np.random.default_rng(5)
     head = jnp.asarray(rng.normal(0, 0.02, (rows, d)), jnp.float32)
     norm = jnp.asarray(1 + 0.1 * rng.standard_normal(d), jnp.float32)
-    x = jnp.asarray(rng.standard_normal((row["tokens"].shape[0], d)),
-                    jnp.bfloat16)
-    local, segments = row["tokens"] - first, row["segments"]
+    x = seq_layers.rms_norm(jnp.asarray(
+        rng.standard_normal((row["tokens"].shape[0], d)), jnp.bfloat16),
+        norm, cfg.norm_eps)
+    targets = jnp.roll(row["tokens"] - first, -1)
+    counted = seq_layers.target_positions(row["segments"])
+    mask = counted.astype(jnp.float32)
 
     def summed(x):
-        return seq_layers.head_loss(head, norm, x, local, segments, cfg=cfg)
+        return seq_layers.head_loss(head, x, targets, mask)
 
-    def per_position(x):
-        return seq_layers.head_loss(head, norm, x, local, segments, cfg=cfg,
-                                    per_position=True)
+    def terms(x):
+        return jax.grad(lambda w: seq_layers.head_loss(head, x, targets, w))(
+            mask)
 
-    terms = per_position(x)
-    assert terms.shape == local.shape
-    assert (np.asarray(terms)[~np.asarray(
-        seq_layers.target_positions(segments))] == 0).all()
-    assert float(terms.sum()) == float(summed(x))
-    assert float(jax.jit(lambda x: per_position(x).sum())(x)) == float(
+    each = terms(x)
+    assert each.shape == targets.shape
+    assert float((mask * each).sum()) == float(summed(x))
+    assert float(jax.jit(lambda x: (mask * terms(x)).sum())(x)) == float(
         jax.jit(summed)(x))
-    normed = seq_layers.rms_norm(x, norm, cfg.norm_eps)
-    np.testing.assert_array_equal(np.asarray(seq_layers.head_loss(
-        head, None, normed, local, segments, cfg=cfg, per_position=True)),
-        np.asarray(terms))
+    for at in (0, int(np.flatnonzero(~np.asarray(counted))[0])):
+        one = jnp.zeros_like(mask).at[at].set(1)
+        assert float(seq_layers.head_loss(head, x, targets, one)) == float(
+            each[at])

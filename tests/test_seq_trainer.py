@@ -12,6 +12,7 @@ import pytest
 from dragonfly2_tpu.models.keye_vl2 import KeyeVL2Config
 from dragonfly2_tpu.models.laguna import LagunaConfig, Rope
 from dragonfly2_tpu.models.lfm2_moe import Lfm2MoeConfig
+from dragonfly2_tpu.models import seq_layers
 from dragonfly2_tpu.models.ouro import OuroConfig
 from dragonfly2_tpu.parallel import data_parallel_mesh
 from dragonfly2_tpu.train import step_budget
@@ -19,6 +20,8 @@ from dragonfly2_tpu.train.seq_trainer import (
     SeqCorpus,
     SeqTrainConfig,
     config_from_dict,
+    family_of,
+    fused_head_blocks,
     pack_documents,
     train_seq,
 )
@@ -429,24 +432,31 @@ def test_token_segments_round_trip_and_a_seq_model_is_registered(
     assert not storage.has_closed_segments("host-1")
 
 
-def test_a_looped_family_counts_its_passes_and_exits_and_no_experts():
+def test_a_looped_family_counts_its_passes_and_exits_and_no_experts(
+        monkeypatch):
     """``seq_loop_steps`` (3 here, on every step's span too, 0 for a
     family that runs its layers once) and ``seq_exit_mass_1`` ..: each
     exit's share of the counted positions, summed on the device over
     sequences and steps and added once at the drain, all of them
     together every counted position of every step. No expert layer: the
-    routing counts are ``[0, 0]`` and no ``moe_*`` counter moves."""
+    routing counts are ``[0, 0]`` and no ``moe_*`` counter moves.
+    ``seq_head_fused_blocks``: the head's blocks a sequence, over every
+    exit's positions."""
     corpus = pack_documents(documents(), SEQ)
     corpus = SeqCorpus(*(a[:8] for a in (
         corpus.tokens, corpus.segments, corpus.positions)))
     counted = int(((corpus.segments[:, 1:] == corpus.segments[:, :-1])
                    ).sum())
     before = step_budget.TRAINING.snapshot()
+    # Blocks of half a sequence's three exits: the head's one call takes
+    # its 96 positions in two.
+    monkeypatch.setattr(seq_layers, "HEAD_BLOCK", 3 * SEQ // 2)
     result = train_seq(corpus, SeqTrainConfig(
         model=OURO, batch_size=4, epochs=3, learning_rate=3e-3, seed=3),
         one_device())
     after = step_budget.TRAINING.snapshot()
     assert after["seq_loop_steps"] == 3
+    assert after["seq_head_fused_blocks"] == 2
     assert result.history[-1] < result.history[0]
     assert result.routing_counts.shape == (0, 0)
     for key in ("moe_steps", "moe_assignments_held",
@@ -463,6 +473,7 @@ def test_a_looped_family_counts_its_passes_and_exits_and_no_experts():
     train_seq(corpus, SeqTrainConfig(model=MODEL, batch_size=4, epochs=1),
               one_device())
     assert step_budget.TRAINING.snapshot()["seq_loop_steps"] == 0
+    assert step_budget.TRAINING.snapshot()["seq_head_fused_blocks"] == 1
 
 
 def test_a_looped_family_on_two_devices_is_one_device():
@@ -517,3 +528,68 @@ def test_a_looped_family_is_registered_by_the_trainer_service(tmp_path):
     assert saved["meta"].config["model_type"] == "ouro"
     assert counts.shape == (0, 0)
     assert set(params["exit_gate"]) == {"w", "b"} and "layer_1" in params
+
+
+def _head_products(jaxpr, rows, scopes=("df2.loss", "df2.seq.exit")):
+    """The products of the loss head in a program (``jax.make_jaxpr``):
+    ``dot_general`` equations under one of ``scopes`` with ``rows`` (the
+    head's row count) among their operands' or result's dimensions,
+    each counted as often as the ``scan`` loops around it run (a
+    sequence, a block of positions); and how many of them lie inside a
+    recomputed (``checkpoint``) region."""
+    found = under_remat = 0
+
+    def walk(jaxpr, path, times, remat):
+        nonlocal found, under_remat
+        for eqn in jaxpr.eqns:
+            here = f"{path}/{eqn.source_info.name_stack}"
+            if eqn.primitive.name == "dot_general" and any(
+                    scope in here for scope in scopes) and any(
+                    rows in v.aval.shape
+                    for v in (*eqn.invars, *eqn.outvars)):
+                found += times
+                under_remat += times * remat
+            inner = times * eqn.params.get("length", 1)
+            for value in eqn.params.values():
+                for sub in value if isinstance(value, (tuple, list)) else (
+                        value,):
+                    sub = getattr(sub, "jaxpr", sub)
+                    if isinstance(sub, jax.extend.core.Jaxpr):
+                        walk(sub, here, inner, remat or eqn.primitive.name
+                             in ("checkpoint", "remat2"))
+
+    walk(jaxpr.jaxpr, "", 1, False)
+    return found, under_remat
+
+
+@pytest.mark.parametrize("model,head_block,blocks", [
+    (MODEL, SEQ, 1), (LAGUNA, SEQ, 1), (KEYE, SEQ // 4, 4),
+    (OURO, 3 * SEQ // 2, 2)], ids=["lfm2_moe", "laguna", "KeyeVL2", "ouro"])
+def test_the_head_forms_its_gradient_in_its_forward_pass(
+        monkeypatch, model, head_block, blocks):
+    """In the differentiated loss of a step's sequences (what the step's
+    ``value_and_grad`` takes), the head's products are three a block of
+    positions (logits, the input's gradient, the rows' gradient) and
+    none of them lies in a recomputed region: no logits are made again
+    in the backward pass. Blocks as the cells have them (1 in the two 8k
+    cells, 4 of a 32k sequence, 2 of a looped family's exits), and
+    ``fused_head_blocks``, which ``train_seq`` writes as
+    ``seq_head_fused_blocks``, says so."""
+    monkeypatch.setattr(seq_layers, "HEAD_BLOCK", head_block)
+    family, batch = family_of(model), 4
+    params = jax.eval_shape(lambda: seq_layers.init_params(
+        jax.random.key(0), family.param_shapes(model)))
+    bias = np.zeros((len(model.expert_layers), model.num_experts),
+                    np.float32)
+
+    def loss(p, *sequences):
+        return seq_layers.batch_loss(
+            p, bias, *sequences, cfg=model, block=family.block,
+            saved=getattr(family, "SAVED", None),
+            exit_gate=getattr(family, "exit_gate", None))[0]
+
+    jaxpr = jax.make_jaxpr(jax.value_and_grad(loss))(
+        params, *[jax.ShapeDtypeStruct((batch, SEQ), np.int32)] * 3)
+    assert fused_head_blocks(model, SEQ) == blocks
+    assert _head_products(jaxpr, model.held_vocab[1]) == (
+        3 * blocks * batch, 0)
