@@ -11,8 +11,6 @@ from __future__ import annotations
 
 import numpy as np
 
-from benchmarks.runners.lfm2_moe import document_lengths
-
 BF16 = 2
 
 
@@ -58,27 +56,19 @@ def expert_forward_flops_per_assignment(spec: dict) -> int:
     return 3 * 2 * spec["hidden_size"] * spec["moe_intermediate_size"]
 
 
-def attention_pairs_per_step(spec: dict, window: int | None = None) -> float:
+def attention_pairs_per_step(spec: dict, window: int | None = None) -> int:
     """Same-document causal (query, key) pairs a step, with a ``window``
-    those of them with ``t - s < window``, expected over the seed's
-    order. A document of L tokens has ``f(L) = Σ_p min(p + 1, window)``
-    (L(L+1)/2 without one); each of the corpus's R - 1 row ends falls at
-    one of a document's L places with probability 1 / tokens each and
-    cuts it there into a and L - a (a = 0: not at all), which takes
-    ``f(L) - f(a) - f(L - a)`` pairs away (L(L² - 1)/6 over a document's
-    places without a window)."""
-    corpus = spec["corpus"]
-    lengths = document_lengths(corpus)
-    upto = np.arange(int(lengths.max()) + 1, dtype=np.float64)
-    # f(n) for n = 0 .. the longest document, and its running sum.
-    f = np.concatenate([[0.0], np.cumsum(
-        upto[1:] if window is None else np.minimum(upto[1:], window))])
-    running = np.cumsum(f)
-    # Σ over a = 1 .. L - 1 of f(L) - f(a) - f(L - a).
-    lost = (lengths - 1) * f[lengths] - 2 * running[lengths - 1]
-    rows = corpus["tokens"] // spec["seq_len"]
-    pairs = f[lengths].sum() - (rows - 1) * lost.sum() / corpus["tokens"]
-    return pairs * shapes(spec)["tokens"] / corpus["tokens"]
+    those of them with ``t - s < window``: a document of L tokens has
+    ``Σ_p min(p + 1, window)`` (L(L+1)/2 without one), and a step takes
+    ``batch`` rows of the same documents."""
+    lengths = np.asarray(spec["corpus"]["document_lengths"], np.int64)
+    per_row = 0
+    for n in lengths.tolist():
+        if window is None or n <= window:
+            per_row += n * (n + 1) // 2
+        else:
+            per_row += window * (window + 1) // 2 + (n - window) * window
+    return per_row * spec["batch"]
 
 
 def attention_forward_flops_per_step(spec: dict) -> float:

@@ -1,5 +1,6 @@
-"""The readings a cell's limits are set from, several seeds in one
-process (set-up is long; the chip is one process's at a time):
+"""The readings a cell's limits are set from, one seed a process (the
+chip is one process's at a time, and a process's peak of host memory
+never falls again):
 
     python3 benchmarks/limits.py --workload <cell> --seeds 11,12,13 [--control-seeds 3]
 
@@ -9,10 +10,19 @@ for the first ``--control-seeds`` of them the reference put in the
 program's place: computed in the control's precision (fp8 for the
 bfloat16 the configurations state), with half of the batch left out and
 the mean taken over the rest, and with its state left unchanged (the
-*upper* readings). Each set of numbers also goes through
-``compare.verdict`` with the limits the cell's file holds, so a line
-says what ``correct`` the program, the control and each fault would get
-at the cell's own size: the program's has to be true and every other
+*upper* readings). Each stand-in's trees are made into the program's
+readings (:class:`InProgramPlace`), and then the sound reference is run
+again into ``compare.Fold``, as ``run.py`` does. While a stand-in is
+made the host holds its parameters before the steps and its first
+moments (up to 4P float32 at P parameters, the parameters after the
+steps coming once the moments' updates are done) and Adam's two moments
+(2P): 6P at the most, as much as a run's comparison (counted, not
+measured). A seed with the controls costs seven references (the sound
+one, then each stand-in and the sound one again) where one without
+costs one. Each set of numbers also goes
+through ``compare.verdict`` with the limits the cell's file holds, so a
+line says what ``correct`` the program, the control and each fault would
+get at the cell's own size: the program's has to be true and every other
 false. Prints one JSON line per seed and a summary.
 """
 
@@ -21,6 +31,7 @@ from __future__ import annotations
 import argparse
 import json
 import os
+import subprocess
 import sys
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -28,18 +39,40 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
 
-def in_program_place(readings: dict) -> dict:
-    """A reference's readings in the shape the program's come in."""
-    from benchmarks import compare
-    moments, before = [], None
-    for grads in readings["grads"]:
-        before = {k: (compare.ADAM_B1 * before[k] if before else 0.0)
-                  + (1.0 - compare.ADAM_B1) * g for k, g in grads.items()}
-        moments.append(before)
-    return {"params_before": readings["params_before"],
-            "moments": moments,
-            "params_after": readings["params_after"],
-            "losses": readings["losses"]}
+class InProgramPlace:
+    """A sink for ``references/common.py: follow`` that keeps what the
+    program's observer keeps, in its arithmetic: the parameters before
+    step 1 and after the last, Adam's first moment after each step in
+    float32 (m_t = β₁·m_{t−1} + (1 − β₁)·g_t) and each loss."""
+
+    def __init__(self):
+        self.before, self.after, self.moments, self.losses = None, None, [], []
+
+    def initial(self, params) -> None:
+        from benchmarks.compare import host
+        self.before = {k: host(v) for k, v in params.items()}
+
+    def logit(self, tree) -> None:
+        pass
+
+    def half(self, step: int, tree) -> None:
+        pass
+
+    def gradient(self, step: int, loss, tree) -> None:
+        from benchmarks.compare import ADAM_B1, host
+        last = self.moments[-1] if self.moments else None
+        self.moments.append({
+            k: (ADAM_B1 * last[k] if last else 0.0)
+            + (1.0 - ADAM_B1) * host(g) for k, g in tree.items()})
+        self.losses.append(float(loss))
+
+    def final(self, after, before) -> None:
+        from benchmarks.compare import host
+        self.after = {k: host(v) for k, v in after.items()}
+
+    def readings(self) -> dict:
+        return {"params_before": self.before, "moments": self.moments,
+                "params_after": self.after, "losses": self.losses}
 
 
 STAND_INS = (("fp8", {"precision": "fp8"}),
@@ -60,12 +93,33 @@ def program_only(ctx: dict) -> dict:
 def control_study(ctx: dict) -> dict:
     from benchmarks import compare
     out = program_only(ctx)
+    reference, args = ctx["reference"], (
+        ctx["spec"], ctx["arrays"], ctx["seed"], ctx["steps"])
     for name, kwargs in STAND_INS:
-        stand_in = ctx["reference"].readings(
-            ctx["spec"], ctx["arrays"], ctx["seed"], ctx["steps"], **kwargs)
-        out[name] = _judged(compare.numbers(
-            in_program_place(stand_in), ctx["followed"]), ctx["limits"])
+        placed = reference.readings(*args, InProgramPlace(), **kwargs)
+        found = reference.readings(
+            *args, compare.Fold(placed.readings())).numbers()
+        out[name] = _judged(found, ctx["limits"])
     return out
+
+
+def one_seed(args) -> int:
+    from benchmarks import run
+    import jax
+
+    if not args.rehearse and jax.devices()[0].platform != "tpu":
+        print("limits are read on the chip", file=sys.stderr)
+        return run.NO_CHIP
+    run.enable_compilation_cache()
+    seed = int(args.seeds)
+    result = run.run_cell(
+        args.workload, seed, args.seconds, False, rehearse=args.rehearse,
+        study=control_study if args.control_seeds else program_only)
+    line = {"seed": seed, **result["study"]}
+    line["train_samples_per_s"] = result["metrics"][
+        "train_samples_per_s"]["value"]
+    print(json.dumps(line), flush=True)
+    return 0
 
 
 def main(argv=None) -> int:
@@ -75,23 +129,28 @@ def main(argv=None) -> int:
     parser.add_argument("--control-seeds", type=int, default=3)
     parser.add_argument("--seconds", type=float, default=1.0)
     parser.add_argument("--rehearse", action="store_true")
+    parser.add_argument("--one", action="store_true",
+                        help="read the one seed in this process")
     args = parser.parse_args(argv)
+    if args.one:
+        return one_seed(args)
 
-    from benchmarks import run
-    import jax
-
-    if not args.rehearse and jax.devices()[0].platform != "tpu":
-        print("limits are read on the chip", file=sys.stderr)
-        return run.NO_CHIP
-    run.enable_compilation_cache()
-
+    # This process touches no JAX: each seed's process has the chip.
     lower, upper, verdicts = {}, {}, {}
     for i, seed in enumerate(int(s) for s in args.seeds.split(",")):
-        result = run.run_cell(
-            args.workload, seed, args.seconds, False, rehearse=args.rehearse,
-            study=control_study if i < args.control_seeds else program_only)
-        line = {"seed": seed, **result["study"]}
-        for who, found in result["study"].items():
+        done = subprocess.run(
+            [sys.executable, os.path.abspath(__file__), "--one",
+             "--workload", args.workload, "--seeds", str(seed),
+             "--control-seeds", str(int(i < args.control_seeds)),
+             "--seconds", str(args.seconds)]
+            + (["--rehearse"] if args.rehearse else []),
+            stdout=subprocess.PIPE, text=True)
+        if done.returncode:
+            return done.returncode
+        line = json.loads(done.stdout.strip().splitlines()[-1])
+        for who, found in line.items():
+            if not isinstance(found, dict):
+                continue
             verdicts.setdefault(who, []).append(found["correct"])
             for k, v in found.items():
                 if k == "correct":
@@ -101,8 +160,6 @@ def main(argv=None) -> int:
                 else:
                     upper[f"{who}.{k}"] = min(
                         upper.get(f"{who}.{k}", float("inf")), v)
-        line["train_samples_per_s"] = result["metrics"][
-            "train_samples_per_s"]["value"]
         print(json.dumps(line), flush=True)
     print(json.dumps({"largest_program_reading": lower,
                       "smallest_control_reading": upper,

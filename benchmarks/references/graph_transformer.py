@@ -118,11 +118,12 @@ def logits(params, model, feat, nbr, val, src, dst, rnd):
     return common.dense(z, lay("head_out"), rnd)[:, 0]
 
 
-def readings(config: dict, graph: dict, seed: int, steps: int,
+def readings(config: dict, graph: dict, seed: int, steps: int, sink,
              precision: str = "float32", keep_rows: float = 1.0,
-             frozen: bool = False) -> dict:
-    """Follow the trainer's first ``steps`` from the seed. For the
-    control's readings ``keep_rows`` < 1 plants the fault "part of the
+             frozen: bool = False):
+    """Follow the trainer's first ``steps`` from the seed, handing
+    ``sink`` what the comparison reads as it is made. For the control's
+    readings ``keep_rows`` < 1 plants the fault "part of the
     batch left out, the mean taken over the rest" and ``frozen`` the
     fault "a step that returns its state unchanged"."""
     model, opt = config["model"], config["optimizer"]
@@ -168,10 +169,10 @@ def readings(config: dict, graph: dict, seed: int, steps: int,
         return mean_logit_grad(params, (feat, nbr_d, val_d),
                                jnp.asarray(src[ids]), jnp.asarray(dst[ids]))
 
-    params = common.init_params(seed, param_spec(
-        model, graph["node_features"].shape[1]))
-    return common.follow(params, batches, step, {
-        "learning_rate": opt["learning_rate"],
-        "weight_decay": opt["weight_decay"],
-        "warmup": common.warmup_steps(total), "total_steps": total},
-        frozen=frozen, logit_grad=logit_grad)
+    spec = param_spec(model, graph["node_features"].shape[1])
+    return common.follow(
+        lambda: common.init_params(seed, spec), batches, common.whole(step), {
+            "learning_rate": opt["learning_rate"],
+            "weight_decay": opt["weight_decay"],
+            "warmup": common.warmup_steps(total), "total_steps": total},
+        sink, frozen=frozen, logit=logit_grad)

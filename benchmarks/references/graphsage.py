@@ -107,11 +107,12 @@ def block_logits(params, tables, feat, fanouts, src, dst, row0, salts, rnd):
     return common.dense(z, common.layer(params, "Dense_1"), rnd)[:, 0]
 
 
-def readings(config: dict, graph: dict, seed: int, steps: int,
+def readings(config: dict, graph: dict, seed: int, steps: int, sink,
              precision: str = "float32", keep_rows: float = 1.0,
-             frozen: bool = False) -> dict:
-    """Follow the trainer's first ``steps`` from the seed. For the
-    control's readings ``keep_rows`` < 1 plants the fault "part of the
+             frozen: bool = False):
+    """Follow the trainer's first ``steps`` from the seed, handing
+    ``sink`` what the comparison reads as it is made. For the control's
+    readings ``keep_rows`` < 1 plants the fault "part of the
     batch left out, the mean taken over the rest" and ``frozen`` the
     fault "a step that returns its state unchanged"."""
     model, opt = config["model"], config["optimizer"]
@@ -170,10 +171,11 @@ def readings(config: dict, graph: dict, seed: int, steps: int,
                 jnp.add, total_grad, grad)
         return total_loss / rows, jax.tree.map(lambda g: g / rows, total_grad)
 
-    params = common.init_params(seed, param_spec(model, feat.shape[1]))
-    return common.follow(params, batches, step, {
-        "learning_rate": opt["learning_rate"],
-        "weight_decay": opt["weight_decay"],
-        "warmup": common.warmup_steps(total), "total_steps": total},
-        frozen=frozen,
-        logit_grad=lambda params, ids: step(params, ids, 0, weight=0.0)[1])
+    spec = param_spec(model, feat.shape[1])
+    return common.follow(
+        lambda: common.init_params(seed, spec), batches, common.whole(step), {
+            "learning_rate": opt["learning_rate"],
+            "weight_decay": opt["weight_decay"],
+            "warmup": common.warmup_steps(total), "total_steps": total},
+        sink, frozen=frozen,
+        logit=lambda params, ids: step(params, ids, 0, weight=0.0)[1])

@@ -6,9 +6,9 @@ applied to every token and masked by the routing), no online softmax.
 The index scores of a block of queries are held dense against the keys
 they may see, ranked by a plain ``jax.lax.top_k``, and the attention is
 a dense softmax under the mask that selection gives. Its own weights
-from the seed, its own masks from the packed arrays, its own batch
-order, AdamW written out (:func:`adamw`, under ``references/common.py``'s
-schedule). Imports nothing of the program.
+from the seed, its own masks from the packed arrays; the batch order and
+AdamW (its one-program form, ``corrections="device"``) are
+``references/common.py``'s. Imports nothing of the program.
 
 With ``n(x; w) = x / sqrt(mean(x²) + rms_norm_eps) · w`` and ``x`` one
 packed sequence ``[S, hidden]``: block ``l`` is ``h = x + Attn(n(x;
@@ -51,6 +51,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.references import common
+# The compiler's effort on the programs below, least: they run a few
+# dozen times a run, and at its usual effort a run's first reference
+# compiled for two minutes (PERF.md section 6).
+from benchmarks.references.common import QUICKLY
 
 HIGHEST = "highest"
 # Every product but the index scores and the router's logits: float32
@@ -69,11 +73,6 @@ QUERY_BLOCK = 256
 # against all of it, then its first half the same way.
 GROUP_FLOOR = 1024
 HEAD_BLOCK = 8192
-# The compiler's effort on the programs below, least: they run a few
-# dozen times a run, and at its usual effort a run's first reference
-# compiled for two minutes (PERF.md, PR 33).
-QUICKLY = {"exec_time_optimization_effort": -1.0,
-           "memory_fitting_effort": -1.0}
 # The leaves of a layer that its attention reads; the rest are the
 # output projection's and the expert layer's.
 ATTENDING = ("in_norm", "attn/q", "attn/k", "attn/v", "attn/q_norm",
@@ -543,15 +542,17 @@ def _split(q: dict) -> tuple:
 
 
 def sequence_gradient(s: dict, rnd):
-    """``(params, tokens, segments, positions, weight) -> ((sums, count),
-    gradient)``: the value and gradient of :func:`forward_sums`, a layer
-    and in it a group of queries (:func:`groups`) at a time, each a
-    compiled program of its own (:func:`programs`); what the backward
-    pass needs of the forward one is kept (each layer's input, its
-    heads' outputs and its selections)."""
+    """``(params, tokens, segments, positions, weight, into=None) ->
+    ((sums, count), gradient)``: the value and gradient of
+    :func:`forward_sums`, a layer and in it a group of queries
+    (:func:`groups`) at a time, each a compiled program of its own
+    (:func:`programs`); what the backward pass needs of the forward one
+    is kept (each layer's input, its heads' outputs and its selections).
+    With ``into`` (a running sum over sequences) each layer's gradient is
+    added into it as it is made (``common.Sum``)."""
     run = programs(s, rnd)
 
-    def gradient(p, tokens, segments, positions, weight):
+    def gradient(p, tokens, segments, positions, weight, into=None):
         asked = groups(segments)
         local, segments, positions = (
             jnp.asarray(tokens) - s["vocab"][0], jnp.asarray(segments),
@@ -571,7 +572,8 @@ def sequence_gradient(s: dict, rnd):
             x = run.rest(others, x, rows)
         out, (d_norm, d_head, ct) = run.head(
             p["final_norm"], p["lm_head"], x, local, segments, weight)
-        grads = {"final_norm": d_norm, "lm_head": d_head}
+        grads = common.Sum(into)
+        grads.add({"final_norm": d_norm, "lm_head": d_head})
         for i in reversed(s["kept"]):
             mine, others = _split(layer_leaves(p, i))
             x, rows, selections = kept.pop()
@@ -581,139 +583,21 @@ def sequence_gradient(s: dict, rnd):
                 d_mine, d_x = run.attend_back(
                     length, first, mine, x, segments, positions, chosen,
                     d_mine, d_x, ct)
-            grads.update({f"layer_{i}/{k}": g
-                          for k, g in {**d_rest, **d_mine}.items()})
+            grads.add({f"layer_{i}/{k}": g
+                       for k, g in {**d_rest, **d_mine}.items()})
             ct = d_x
-        grads["embed"] = run.embed_back(p["embed"], local, ct)
-        return out, {k: grads[k] for k in p}
+        grads.add({"embed": run.embed_back(p["embed"], local, ct)})
+        return out, grads.tree(p)
     return gradient
 
 
-def readings(spec: dict, arrays: dict, seed: int, steps: int,
+def readings(spec: dict, arrays: dict, seed: int, steps: int, sink,
              precision: str = "float32", keep_rows: float = 1.0,
-             frozen: bool = False) -> dict:
-    s, opt = sizes(spec), spec["optimizer"]
-    rnd = common.rounder(precision)
-    rows, batch = arrays["tokens"].shape[0], spec["batch"]
-    per_epoch = max(rows // batch, 1)
-    total = max(spec["epochs"] * per_epoch, 2)
-    if steps > per_epoch:
-        raise ValueError("the reference follows steps of the first epoch only")
-    order = np.random.default_rng((seed, 11)).permutation(rows)
-    batches = [order[i * batch:(i + 1) * batch] for i in range(steps)]
-    kept = max(int(batch * keep_rows), 1)
-
-    one_sequence = sequence_gradient(s, rnd)
-    add = jax.jit(lambda a, b: jax.tree.map(jnp.add, a, b))
-    mean = jax.jit(lambda n, value, grad: (
-        value / n, jax.tree.map(lambda g: g / n, grad)))
-    norms = jax.jit(lambda grads: {
-        k: jnp.sqrt(jnp.sum(jnp.square(g))).reshape(1)
-        for k, g in grads.items()})
-    # The sums of the first of a batch's sequences at the parameters
-    # last asked about: the first half of a batch is asked for right
-    # after the whole of it, and served from here.
-    first_half = {}
-
-    def step(params, ids, count, rows=None, weight=1.0):
-        """The mean over the target positions of the first ``rows``
-        sequences of the batch (whole sequences), and its gradient."""
-        ids, sums = ids[:rows or kept], None
-        for n, i in enumerate(ids):
-            asked = (id(params), int(i), float(weight))
-            if first_half.get("asked") == asked:
-                mine = first_half.pop("sums")
-                first_half.clear()
-            else:
-                (value, targets), grad = one_sequence(
-                    params, *(arrays[k][i] for k in (
-                        "tokens", "segments", "positions")),
-                    jnp.float32(weight))
-                mine = (value, targets, grad)
-                if n == 0 and len(ids) > 1:
-                    # ``of``: the parameters live as long as their id is
-                    # held.
-                    first_half.update(asked=asked, sums=mine, of=params)
-            sums = mine if sums is None else add(sums, mine)
-        value, targets, grad = sums
-        return mean(jnp.maximum(targets, 1).astype(jnp.float32), value, grad)
-
-    def logit_scale(params, ids):
-        """The gradient of the batch's mean target logit, each leaf
-        handed over as its norm alone (one element): the comparison
-        reads this tree through its leaves' squared norms only (PERF.md
-        section 7, harness debt 3)."""
-        return norms(step(params, ids, 0, weight=0.0)[1])
-
-    return follow(
-        init_params(seed, s), batches, step, logit_scale, {
-            "learning_rate": opt["learning_rate"],
-            "weight_decay": opt["weight_decay"],
-            "warmup": common.warmup_steps(total), "total_steps": total},
-        frozen)
-
-
-@partial(jax.jit, donate_argnums=(1, 2), compiler_options=QUICKLY)
-def adamw(params, mu, nu, grads, lr, t, weight_decay, b1=0.9, b2=0.999,
-          eps=1e-8):
-    """One update of ``common.AdamW``, every leaf in one program (there
-    an operation and a leaf at a time: some 130 programs for a run's
-    first reference to compile). ``t`` counts from 1."""
-    mu = jax.tree.map(lambda m, g: b1 * m + (1 - b1) * g, mu, grads)
-    nu = jax.tree.map(lambda v, g: b2 * v + (1 - b2) * g * g, nu, grads)
-
-    def moved(p, m, v):
-        m_hat, v_hat = m / (1 - b1 ** t), v / (1 - b2 ** t)
-        return p - lr * (m_hat / (jnp.sqrt(v_hat) + eps) + weight_decay * p)
-
-    return jax.tree.map(moved, params, mu, nu), mu, nu
-
-
-def follow(params: dict, batches, loss_and_grad, logit_grad, optimizer: dict,
-           frozen: bool = False) -> dict:
-    """``common.follow`` (what the comparison reads of ``batches`` under
-    AdamW and the trainers' schedule, as host arrays; ``frozen`` plants
-    the fault "a step that returns its state unchanged"), with
-    :func:`adamw` for its update, and a tree's copy to the host started
-    when the tree is made and read once the next one's arithmetic has
-    been handed to the device."""
-    leaving = []
-
-    def get(tree):
-        for leaf in tree.values():
-            leaf.copy_to_host_async()
-        leaving.append(({}, tree))
-        return leaving[-1][0]
-
-    def landed():
-        while leaving:
-            host, tree = leaving.pop()
-            host.update({k: np.asarray(v) for k, v in tree.items()})
-
-    mu, nu = zeros_like(params), zeros_like(params)
-    start, scale = get(params), get(logit_grad(params, batches[0]))
-    losses, all_grads, halves = [], [], []
-    at_start = True
-    for step, batch in enumerate(batches):
-        loss, grads = loss_and_grad(params, batch, step)
-        landed()
-        all_grads.append(get(grads))
-        if at_start:
-            # While the parameters are the initial ones (the schedule's
-            # first learning rate is 0: two steps), the same gradient
-            # over the first half of the batch's rows alone.
-            halves.append(get(
-                loss_and_grad(params, batch, step, rows=len(batch) // 2)[1]))
-        losses.append(loss)
-        lr = common.learning_rate(step, optimizer["learning_rate"],
-                                  optimizer["warmup"],
-                                  optimizer["total_steps"])
-        at_start = at_start and lr == 0.0
-        if not frozen:
-            params, mu, nu = adamw(params, mu, nu, grads, lr, step + 1,
-                                   optimizer["weight_decay"])
-    after = get(params)
-    landed()
-    return {"params_before": start, "grads": all_grads, "params_after": after,
-            "losses": [float(x) for x in losses], "logit_grad": scale,
-            "grads_first_half": halves}
+             frozen: bool = False):
+    """What the comparison reads, handed to ``sink`` as it is made
+    (``common.sequence_readings``; AdamW in its one-program form)."""
+    s = sizes(spec)
+    return common.sequence_readings(
+        spec, arrays, seed, steps, lambda: init_params(seed, s),
+        sequence_gradient(s, common.rounder(precision)), sink,
+        keep_rows=keep_rows, frozen=frozen, corrections="device")

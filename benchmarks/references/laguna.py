@@ -10,8 +10,8 @@ chip, and the gradient taken layer by layer (plain autodiff of each
 layer from its kept input), so that no compiled program holds the whole
 model and the benchmark's host has room for it. Its own weights from the
 seed, its own masks from the packed
-arrays, its own batch order, AdamW written out
-(``references/common.py``). Imports nothing of the program.
+arrays; the batch order and AdamW are ``references/common.py``'s.
+Imports nothing of the program.
 
 With ``n(x; w) = x / sqrt(mean(x²) + rms_norm_eps) · w`` and ``x`` one
 packed sequence ``[S, hidden]``: block ``l`` is ``h = x + Attn_l(n(x;
@@ -279,12 +279,14 @@ def forward_sums(p, tokens, segments, positions, weight, s, rnd):
 
 
 def sequence_gradient(s: dict, rnd):
-    """``(params, tokens, segments, positions, weight) -> ((sums, count),
-    gradient)``: plain autodiff of :func:`forward_sums`, taken layer by
-    layer (each layer's input kept, its vector-Jacobian product a
-    compiled program of its own, one for the layers of a kind) so that
-    no one program holds the whole model: the numbers are those of
-    ``jax.value_and_grad(forward_sums)``, the host's memory is not."""
+    """``(params, tokens, segments, positions, weight, into=None) ->
+    ((sums, count), gradient)``: plain autodiff of :func:`forward_sums`,
+    taken layer by layer (each layer's input kept, its vector-Jacobian
+    product a compiled program of its own, one for the layers of a kind)
+    so that no one program holds the whole model: the numbers are those
+    of ``jax.value_and_grad(forward_sums)``, the host's memory is not.
+    With ``into`` (a running sum over sequences) each layer's gradient
+    is added into it as it is made (``common.Sum``)."""
     @jax.jit
     def embed(table, local):
         return table[local]
@@ -309,7 +311,7 @@ def sequence_gradient(s: dict, rnd):
             lambda *a: head_sums(*a, local, segments, weight, s, rnd),
             argnums=(0, 1, 2), has_aux=True)(final_norm, lm_head, x)
 
-    def gradient(p, tokens, segments, positions, weight):
+    def gradient(p, tokens, segments, positions, weight, into=None):
         local = tokens - s["vocab"][0]
         x, inputs = embed(p["embed"], local), []
         for i, kind, h, sparse in layers(s):
@@ -318,61 +320,24 @@ def sequence_gradient(s: dict, rnd):
                         sparse)
         out, (d_norm, d_head, ct) = head(
             p["final_norm"], p["lm_head"], x, local, segments, weight)
-        grads = {"final_norm": d_norm, "lm_head": d_head}
+        grads = common.Sum(into)
+        grads.add({"final_norm": d_norm, "lm_head": d_head})
         for (i, kind, h, sparse), x in reversed(list(zip(layers(s), inputs))):
             d_layer, ct = backward(layer_leaves(p, i), x, segments, positions,
                                    kind, h, sparse, ct)
-            grads.update({f"layer_{i}/{k}": g for k, g in d_layer.items()})
-        grads["embed"] = embed_back(p["embed"], local, ct)
-        return out, {k: grads[k] for k in p}
+            grads.add({f"layer_{i}/{k}": g for k, g in d_layer.items()})
+        grads.add({"embed": embed_back(p["embed"], local, ct)})
+        return out, grads.tree(p)
     return gradient
 
 
-def readings(spec: dict, arrays: dict, seed: int, steps: int,
+def readings(spec: dict, arrays: dict, seed: int, steps: int, sink,
              precision: str = "float32", keep_rows: float = 1.0,
-             frozen: bool = False) -> dict:
-    s, opt = sizes(spec), spec["optimizer"]
-    rnd = common.rounder(precision)
-    tokens, segments, positions = (
-        jnp.asarray(arrays[k]) for k in ("tokens", "segments", "positions"))
-    rows, batch = tokens.shape[0], spec["batch"]
-    per_epoch = max(rows // batch, 1)
-    total = max(spec["epochs"] * per_epoch, 2)
-    if steps > per_epoch:
-        raise ValueError("the reference follows steps of the first epoch only")
-    order = np.random.default_rng((seed, 11)).permutation(rows)
-    batches = [order[i * batch:(i + 1) * batch] for i in range(steps)]
-    kept = max(int(batch * keep_rows), 1)
-
-    one_sequence = sequence_gradient(s, rnd)
-    add = jax.jit(lambda a, b: jax.tree.map(jnp.add, a, b), donate_argnums=0)
-
-    def step(params, ids, count, rows=None, weight=1.0):
-        """The mean over the target positions of the first ``rows``
-        sequences of the batch (whole sequences), and its gradient."""
-        total_sum, total_n, total_grad = 0.0, 0, None
-        for i in ids[:rows or kept]:
-            (value, n), grad = one_sequence(
-                params, tokens[i], segments[i], positions[i],
-                jnp.float32(weight))
-            total_sum, total_n = total_sum + value, total_n + n
-            total_grad = grad if total_grad is None else add(total_grad, grad)
-        n = jnp.maximum(total_n, 1).astype(jnp.float32)
-        return total_sum / n, jax.tree.map(lambda g: g / n, total_grad)
-
-    def logit_scale(params, ids):
-        """The gradient of the batch's mean target logit, each leaf
-        handed over as its norm alone (one element): the comparison
-        reads this tree through its leaves' squared norms only, and at
-        this size a whole tree more is 1.9 GB of a host that has 3 GB
-        to spare (PERF.md section 7, harness debt 3)."""
-        grads = step(params, ids, 0, weight=0.0)[1]
-        return {k: jnp.sqrt(jnp.sum(jnp.square(g))).reshape(1)
-                for k, g in grads.items()}
-
-    return common.follow(
-        init_params(seed, s), batches, step, {
-            "learning_rate": opt["learning_rate"],
-            "weight_decay": opt["weight_decay"],
-            "warmup": common.warmup_steps(total), "total_steps": total},
-        frozen=frozen, logit_grad=logit_scale)
+             frozen: bool = False):
+    """What the comparison reads, handed to ``sink`` as it is made
+    (``common.sequence_readings``)."""
+    s = sizes(spec)
+    return common.sequence_readings(
+        spec, arrays, seed, steps, lambda: init_params(seed, s),
+        sequence_gradient(s, common.rounder(precision)), sink,
+        keep_rows=keep_rows, frozen=frozen)

@@ -6,8 +6,8 @@ at ``precision="highest"``; no kernel, no grouping of rows by expert
 selection), no online softmax (a head's ``[S, S]`` scores are held
 whole). One sequence at a time, so that the cell's own size fits the
 chip. Its own weights from the seed, its own masks from the packed
-arrays, its own batch order, AdamW written out
-(``references/common.py``). Imports nothing of the program.
+arrays; the batch order and AdamW are ``references/common.py``'s.
+Imports nothing of the program.
 
 With ``n(x; w) = x / sqrt(mean(x²) + norm_eps) · w`` and ``x`` one packed
 sequence ``[S, hidden]``: block ``l`` is ``h = x + Op_l(n(x; w_op))``,
@@ -232,59 +232,36 @@ def forward_sums(p, tokens, segments, positions, bias, weight, s, rnd):
     return jnp.where(valid, per_position, 0.0).sum(), valid.sum()
 
 
-def readings(spec: dict, arrays: dict, seed: int, steps: int,
-             precision: str = "float32", keep_rows: float = 1.0,
-             frozen: bool = False) -> dict:
-    s, opt = sizes(spec), spec["optimizer"]
-    rnd = common.rounder(precision)
-    tokens, segments, positions = (
-        jnp.asarray(arrays[k]) for k in ("tokens", "segments", "positions"))
-    rows, batch = tokens.shape[0], spec["batch"]
-    per_epoch = max(rows // batch, 1)
-    total = max(spec["epochs"] * per_epoch, 2)
-    if steps > per_epoch:
-        raise ValueError("the reference follows steps of the first epoch only")
-    order = np.random.default_rng((seed, 11)).permutation(rows)
-    batches = [order[i * batch:(i + 1) * batch] for i in range(steps)]
-    kept = max(int(batch * keep_rows), 1)
-    # A host array: a device array closed over by a jitted function
-    # lives as long as JAX's cache of that function.
-    bias = selection_bias(spec)
-
+def sequence_gradient(s: dict, rnd, bias):
+    """``(params, tokens, segments, positions, weight, into=None) ->
+    ((sums, count), gradient)``: ``jax.value_and_grad`` of
+    :func:`forward_sums`, one compiled program for a whole sequence;
+    with ``into`` (a running sum over sequences) the gradient is added
+    into it (``common.Sum``). ``bias``: the routers' selection bias, a
+    host array (a device array closed over by a jitted function lives as
+    long as JAX's cache of that function)."""
     @jax.jit
     def one_sequence(params, tok, seg, pos, weight):
         def summed(p):
             return forward_sums(p, tok, seg, pos, bias, weight, s, rnd)
         return jax.value_and_grad(summed, has_aux=True)(params)
 
-    add = jax.jit(lambda a, b: jax.tree.map(jnp.add, a, b), donate_argnums=0)
+    def gradient(p, tokens, segments, positions, weight, into=None):
+        out, grads = one_sequence(p, tokens, segments, positions, weight)
+        total = common.Sum(into)
+        total.add(grads)
+        return out, total.tree(p)
+    return gradient
 
-    def step(params, ids, count, rows=None, weight=1.0):
-        """The mean over the target positions of the first ``rows``
-        sequences of the batch (whole sequences), and its gradient."""
-        total_sum, total_n, total_grad = 0.0, 0, None
-        for i in ids[:rows or kept]:
-            (value, n), grad = one_sequence(
-                params, tokens[i], segments[i], positions[i],
-                jnp.float32(weight))
-            total_sum, total_n = total_sum + value, total_n + n
-            total_grad = grad if total_grad is None else add(total_grad, grad)
-        n = jnp.maximum(total_n, 1).astype(jnp.float32)
-        return total_sum / n, jax.tree.map(lambda g: g / n, total_grad)
 
-    def logit_scale(params, ids):
-        """The gradient of the batch's mean target logit, each leaf
-        handed over as its norm alone (one element): the comparison
-        reads this tree through its leaves' squared norms only, and at
-        this size a whole tree more is 1.9 GB of a host that has 3 GB
-        to spare (PERF.md section 6, PR 27)."""
-        grads = step(params, ids, 0, weight=0.0)[1]
-        return {k: jnp.sqrt(jnp.sum(jnp.square(g))).reshape(1)
-                for k, g in grads.items()}
-
-    return common.follow(
-        init_params(seed, s), batches, step, {
-            "learning_rate": opt["learning_rate"],
-            "weight_decay": opt["weight_decay"],
-            "warmup": common.warmup_steps(total), "total_steps": total},
-        frozen=frozen, logit_grad=logit_scale)
+def readings(spec: dict, arrays: dict, seed: int, steps: int, sink,
+             precision: str = "float32", keep_rows: float = 1.0,
+             frozen: bool = False):
+    """What the comparison reads, handed to ``sink`` as it is made
+    (``common.sequence_readings``)."""
+    s = sizes(spec)
+    return common.sequence_readings(
+        spec, arrays, seed, steps, lambda: init_params(seed, s),
+        sequence_gradient(s, common.rounder(precision),
+                          selection_bias(spec)), sink,
+        keep_rows=keep_rows, frozen=frozen)

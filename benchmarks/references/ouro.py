@@ -5,9 +5,9 @@ float32, every product at ``precision="highest"``; no kernel, no online
 softmax and no skipped tile (a head's ``[S, S]`` scores are held whole,
 the document mask written as comparisons), no loop construct (the
 passes are written out one after another). Its own weights from the
-seed, its own masks from the packed arrays, its own batch order, AdamW
-written out (``references/keye_vl2.py``'s one-program form under
-``references/common.py``'s schedule). Imports nothing of the program.
+seed, its own masks from the packed arrays; the batch order and AdamW
+(its one-program form, ``corrections="device"``) are
+``references/common.py``'s. Imports nothing of the program.
 
 With ``n(x; w) = x / sqrt(mean(x²) + rms_norm_eps) · w`` and ``x`` one
 packed sequence ``[S, hidden]``: block ``l`` is ``h = x + n(Attn(n(x;
@@ -42,7 +42,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.references import common
-from benchmarks.references.keye_vl2 import QUICKLY, follow, zeros_like
+from benchmarks.references.common import QUICKLY
 
 HIGHEST = "highest"
 INIT_STD = 0.02
@@ -302,13 +302,16 @@ def programs(s: dict, rnd) -> SimpleNamespace:
 
 
 def sequence_gradient(s: dict, rnd):
-    """``(params, tokens, segments, positions, weight) -> ((sums, count),
-    gradient)``: the value and gradient of :func:`forward_sums`, a piece
-    at a time (:func:`programs`); what the backward pass needs of the
-    forward one is kept (each layer application's input, each exit's)."""
+    """``(params, tokens, segments, positions, weight, into=None) ->
+    ((sums, count), gradient)``: the value and gradient of
+    :func:`forward_sums`, a piece at a time (:func:`programs`); what the
+    backward pass needs of the forward one is kept (each layer
+    application's input, each exit's). With ``into`` (a running sum over
+    sequences) the exits' leaves and each layer's are added into it once
+    the last pass's backward has made them (``common.Sum``)."""
     run = programs(s, rnd)
 
-    def gradient(p, tokens, segments, positions, weight):
+    def gradient(p, tokens, segments, positions, weight, into=None):
         local, segments, positions = (
             jnp.asarray(tokens) - s["vocab"][0], jnp.asarray(segments),
             jnp.asarray(positions))
@@ -326,87 +329,34 @@ def sequence_gradient(s: dict, rnd):
                                       segments, weight * s["beta"])
         # The last pass's state feeds nothing.
         ct, d_exit, d_layers = jnp.zeros_like(x), None, {}
+        grads = common.Sum(into)
         for t in reversed(range(s["loops"])):
             more, ct = run.exit_back(leaves, exits[t], local, segments,
                                      weight, ct, d_z[t], d_terms[t])
             d_exit = more if d_exit is None else run.add(d_exit, more)
+            if t == 0:
+                grads.add(d_exit)
             for i in reversed(s["kept"]):
                 more, ct = run.layer_back(layer_leaves(p, i), inputs.pop(),
                                           segments, positions, ct)
                 d_layers[i] = (more if i not in d_layers
                                else run.add(d_layers[i], more))
-        grads = dict(d_exit)
-        for i, d in d_layers.items():
-            grads.update({f"layer_{i}/{k}": g for k, g in d.items()})
-        grads["embed"] = run.embed_back(p["embed"], local, ct)
-        return out, {k: grads[k] for k in p}
+                if t == 0:
+                    grads.add({f"layer_{i}/{k}": g
+                               for k, g in d_layers.pop(i).items()})
+        grads.add({"embed": run.embed_back(p["embed"], local, ct)})
+        return out, grads.tree(p)
     return gradient
 
 
-def readings(spec: dict, arrays: dict, seed: int, steps: int,
+def readings(spec: dict, arrays: dict, seed: int, steps: int, sink,
              precision: str = "float32", keep_rows: float = 1.0,
-             frozen: bool = False) -> dict:
-    s, opt = sizes(spec), spec["optimizer"]
-    rnd = common.rounder(precision)
-    rows, batch = arrays["tokens"].shape[0], spec["batch"]
-    per_epoch = max(rows // batch, 1)
-    total = max(spec["epochs"] * per_epoch, 2)
-    if steps > per_epoch:
-        raise ValueError("the reference follows steps of the first epoch only")
-    order = np.random.default_rng((seed, 11)).permutation(rows)
-    batches = [order[i * batch:(i + 1) * batch] for i in range(steps)]
-    kept = max(int(batch * keep_rows), 1)
-
-    one_sequence = sequence_gradient(s, rnd)
-    jit = partial(jax.jit, compiler_options=QUICKLY)
-    add = jit(lambda a, b: jax.tree.map(jnp.add, a, b))
-    mean = jit(lambda n, value, grad: (
-        value / n, jax.tree.map(lambda g: g / n, grad)))
-    norms = jit(lambda grads: {
-        k: jnp.sqrt(jnp.sum(jnp.square(g))).reshape(1)
-        for k, g in grads.items()})
-    # The sums of the first half of a batch's sequences at the parameters
-    # last asked about: the first half of a batch is asked for right
-    # after the whole of it, and served from here.
-    first_half = {}
-
-    def step(params, ids, count, rows=None, weight=1.0):
-        """The mean over the target positions of the first ``rows``
-        sequences of the batch (whole sequences), and its gradient."""
-        ids, sums = ids[:rows or kept], None
-        asked = (id(params), tuple(int(i) for i in ids), float(weight))
-        if first_half.get("asked") == asked:
-            sums = first_half["sums"]
-            first_half.clear()
-            return mean(jnp.maximum(sums[1], 1).astype(jnp.float32),
-                        sums[0], sums[2])
-        first_half.clear()
-        half = len(ids) // 2
-        for n, i in enumerate(ids):
-            (value, targets), grad = one_sequence(
-                params, *(arrays[k][i] for k in (
-                    "tokens", "segments", "positions")), jnp.float32(weight))
-            mine = (value, targets, grad)
-            sums = mine if sums is None else add(sums, mine)
-            if n + 1 == half:
-                # ``of``: the parameters live as long as their id is held.
-                first_half.update(asked=(id(params), tuple(
-                    int(i) for i in ids[:half]), float(weight)),
-                    sums=sums, of=params)
-        value, targets, grad = sums
-        return mean(jnp.maximum(targets, 1).astype(jnp.float32), value, grad)
-
-    def logit_scale(params, ids):
-        """The gradient of the batch's mean target logit under the exit
-        distribution, each leaf handed over as its norm alone (one
-        element): the comparison reads this tree through its leaves'
-        squared norms only (PERF.md section 7, harness debt 3)."""
-        return norms(step(params, ids, 0, weight=0.0)[1])
-
+             frozen: bool = False):
+    """What the comparison reads, handed to ``sink`` as it is made
+    (``common.sequence_readings``)."""
+    s = sizes(spec)
     with jax.default_matmul_precision(HIGHEST):
-        return follow(
-            init_params(seed, s), batches, step, logit_scale, {
-                "learning_rate": opt["learning_rate"],
-                "weight_decay": opt["weight_decay"],
-                "warmup": common.warmup_steps(total), "total_steps": total},
-            frozen)
+        return common.sequence_readings(
+            spec, arrays, seed, steps, lambda: init_params(seed, s),
+            sequence_gradient(s, common.rounder(precision)), sink,
+            keep_rows=keep_rows, frozen=frozen, corrections="device")

@@ -172,12 +172,18 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool,
                window_seconds=plan.window_seconds,
                setup_seconds=plan.t_open - T0,
                compile_seconds=plan.compile_seconds)
-    program = observers.pop().readings()
+    observer = observers.pop()
+    program = observer.readings()
+    # The comparison drops the program's trees as it takes their numbers;
+    # nothing else may hold them.
+    observer.params_before = observer.params_after = None
+    observer.moments = []
+    del observer
 
     t_ref = time.perf_counter()
-    followed = reference.readings(
-        spec, arrays, program_seed, workload["compare_steps"])
-    found = compare.numbers(program, followed)
+    found = reference.readings(
+        spec, arrays, program_seed, workload["compare_steps"],
+        compare.Fold(program)).numbers()
     # Rounding and sampling noise depend on the sizes, so the tiny
     # rehearsal sizes have limits of their own (read on the CPU).
     limits = workload["rehearse_limits" if rehearse else "limits"]
@@ -209,8 +215,9 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool,
         device["busy_s"] = reduced.busy_s
         device["window_s"] = run["window_seconds"]
         result["breakdown"] = tracing.breakdown(reduced, selects)
-    # What the host held at the most (the observer's and the reference's
-    # trees are host memory): kilobytes on Linux.
+    # What the host held at the most (the observer's trees and those the
+    # comparison holds of the reference's are host memory): kilobytes on
+    # Linux.
     run["host_peak_rss_bytes"] = 1024 * resource.getrusage(
         resource.RUSAGE_SELF).ru_maxrss
     result["run"] = {k: run[k] for k in (
@@ -222,8 +229,7 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool,
         result["study"] = study({
             "reference": reference, "spec": spec, "arrays": arrays,
             "seed": program_seed, "steps": workload["compare_steps"],
-            "program": program, "followed": followed, "found": found,
-            "limits": limits})
+            "found": found, "limits": limits})
     result["compared"] = compared
     return result
 

@@ -100,7 +100,7 @@ def main(argv=None) -> int:
                 print(f"{label}: {taken[label]}", file=sys.stderr)
         return wrapper
 
-    compare.numbers = noted(compare.numbers, "comparison")
+    compare.Fold.numbers = noted(compare.Fold.numbers, "comparison")
     instrument._fetch = noted(instrument._fetch, "observer_fetch")
     with tempfile.TemporaryDirectory(dir=ROOT) as scratch, placed(scratch):
         reference = importlib.import_module(

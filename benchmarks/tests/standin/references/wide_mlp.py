@@ -1,11 +1,13 @@
 """Plain reference for the stand-in kind ``wide_mlp``: the same stack
 of dense layers in float32, its own weights from the seed, its own
-batch order, the loss and its gradient in row blocks, AdamW written out
-(``references/common.py``). Imports nothing of the program."""
+batch order, the loss and its gradient in row blocks added into one
+running sum, AdamW written out (``references/common.py: follow``).
+Imports nothing of the program."""
 
 from __future__ import annotations
 
 import math
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -14,6 +16,9 @@ import numpy as np
 from benchmarks.references import common
 
 ROW_BLOCK = 1024
+# A test's look at the device: called before and after every block's
+# program with no argument.
+PROBE = None
 
 
 def init_params(seed: int, width: int, layers: int) -> dict:
@@ -29,9 +34,9 @@ def init_params(seed: int, width: int, layers: int) -> dict:
     return out
 
 
-def readings(spec: dict, arrays: dict, seed: int, steps: int,
+def readings(spec: dict, arrays: dict, seed: int, steps: int, sink,
              precision: str = "float32", keep_rows: float = 1.0,
-             frozen: bool = False) -> dict:
+             frozen: bool = False):
     model, opt = spec["model"], spec["optimizer"]
     layers = model["layers"]
     x, y = jnp.asarray(arrays["features"]), jnp.asarray(arrays["labels"])
@@ -41,43 +46,57 @@ def readings(spec: dict, arrays: dict, seed: int, steps: int,
     if steps > per_epoch:
         raise ValueError("the reference follows steps of the first epoch only")
     order = np.random.default_rng((seed, 0)).permutation(len(x))
-    batches = [order[i * batch:(i + 1) * batch] for i in range(steps)]
     kept = max(int(batch * keep_rows), 1)
-    block = min(max(kept // 2, 1), ROW_BLOCK)
-    if (kept // 2) % block:
-        raise ValueError(f"{kept // 2} rows do not split into blocks of "
-                         f"{block}")
+    block = min(max(batch // 2, 1), ROW_BLOCK)
+    if batch % block or kept % block:
+        raise ValueError(f"{batch} and {kept} rows do not split into "
+                         f"blocks of {block}")
+    # A batch is its blocks of rows: the parts of ``common.summed``.
+    batches = [order[i * batch:(i + 1) * batch].reshape(-1, block)
+               for i in range(steps)]
     rnd = common.rounder(precision)
 
-    # ``weight`` 1: the block's summed loss; 0: its summed logits.
-    @jax.jit
-    def block_sum(params, x_, y_, ids, weight):
+    def block_sum(params, ids, weight):
+        """``weight`` 1: the block's summed loss; 0: its summed logits."""
         def loss(p):
-            h = x_[ids]
+            h = x[ids]
             for i in range(layers):
                 h = jax.nn.relu(common.dense(
                     h, common.layer(p, f"layer_{i}"), rnd))
             z = common.dense(h, common.layer(p, "head"), rnd)[:, 0]
-            return (weight * common.sigmoid_bce(z, y_[ids])
+            return (weight * common.sigmoid_bce(z, y[ids])
                     + (1.0 - weight) * z).sum()
         return jax.value_and_grad(loss)(params)
 
-    def step(params, ids, count, rows=None, weight=1.0):
-        rows = rows or kept
-        total_loss, total_grad = 0.0, None
-        for row0 in range(0, rows, block):
-            loss, grad = block_sum(
-                params, x, y, jnp.asarray(ids[row0:row0 + block], jnp.int32),
-                jnp.float32(weight))
-            total_loss = total_loss + loss
-            total_grad = grad if total_grad is None else jax.tree.map(
-                jnp.add, total_grad, grad)
-        return total_loss / rows, jax.tree.map(lambda g: g / rows, total_grad)
+    @jax.jit
+    def first(params, ids, weight):
+        return block_sum(params, ids, weight)
+
+    @partial(jax.jit, donate_argnums=1)
+    def more(params, into, ids, weight):
+        value, grad = block_sum(params, ids, weight)
+        return value, jax.tree.map(jnp.add, into, grad)
+
+    def part(params, ids, weight, into):
+        if PROBE is not None:
+            PROBE()
+        ids = jnp.asarray(ids, jnp.int32)
+        weight = jnp.float32(weight)
+        value, into = (first(params, ids, weight) if into is None
+                       else more(params, into, ids, weight))
+        if PROBE is not None:
+            PROBE()
+        return (value, len(ids)), into
+
+    mean_gradient = common.summed(part, kept // block)
+
+    def logit(params, ids):
+        return mean_gradient(params, ids, 0, weight=0.0)[1]
 
     return common.follow(
-        init_params(seed, model["width"], layers), batches, step, {
+        lambda: init_params(seed, model["width"], layers), batches,
+        mean_gradient, {
             "learning_rate": opt["learning_rate"],
             "weight_decay": opt["weight_decay"],
             "warmup": common.warmup_steps(total), "total_steps": total},
-        frozen=frozen,
-        logit_grad=lambda params, ids: step(params, ids, 0, weight=0.0)[1])
+        sink, frozen=frozen, logit=logit)

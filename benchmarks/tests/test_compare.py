@@ -1,8 +1,13 @@
-"""``compare.numbers`` walks the trees one leaf at a time; the numbers
-are the ones the all-at-once arithmetic gave (every tree in float64 at
-once, the leaves of a tree concatenated), which is kept here and
-nowhere in the harness. Held equal on both kinds' rehearsal readings:
-the sound program's and the fp8 control's."""
+"""``compare.Fold`` takes the reference's trees as they are made and
+drops each once its numbers are taken; the numbers are the ones the
+all-at-once arithmetic gives (every tree in float64 at once, the leaves
+of a tree concatenated), which is kept here and nowhere in the harness.
+Held equal on every kind's rehearsal readings: the sound program's and
+the fp8 control's, each against the sound reference."""
+
+import copy
+import json
+import os
 
 import numpy as np
 import pytest
@@ -101,27 +106,130 @@ def numbers_all_at_once(program: dict, reference: dict) -> dict:
             for name, (value, at) in out.items()}
 
 
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+BENCH = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
+KINDS = {}
+for _config in BENCH["configs"]:
+    KINDS.setdefault(json.load(open(os.path.join(ROOT, _config["file"])))[
+        "kind"], _config["name"])
+# One cell of each kind.
+CELLS = [next(w["name"] for w in BENCH["workloads"] if w["config"] == c)
+         for c in KINDS.values()]
+
+
+def _copied(tree) -> dict:
+    return {k: np.array(v) for k, v in tree.items()}
+
+
+def recording(fold):
+    """``fold`` with a whole host copy kept of every tree it is handed,
+    in the shapes ``numbers_all_at_once`` reads; each instance is listed
+    in ``made``."""
+    class Recording(fold):
+        made = []
+
+        def __init__(self, program):
+            self.program = copy.deepcopy(program)
+            self.followed = {"grads": [], "losses": [],
+                             "grads_first_half": []}
+            self.made.append(self)
+            super().__init__(program)
+
+        def initial(self, params):
+            self.followed["params_before"] = _copied(params)
+            super().initial(params)
+
+        def logit(self, tree):
+            self.followed["logit_grad"] = _copied(tree)
+            super().logit(tree)
+
+        def half(self, step, tree):
+            self.followed["grads_first_half"].append(_copied(tree))
+            super().half(step, tree)
+
+        def gradient(self, step, loss, tree):
+            self.followed["grads"].append(_copied(tree))
+            self.followed["losses"].append(float(loss))
+            super().gradient(step, loss, tree)
+
+        def final(self, after, before):
+            self.followed["params_after"] = _copied(after)
+            super().final(after, before)
+    return Recording
+
+
 @pytest.fixture(autouse=True)
 def _cache_outside_the_checkout(tmp_path, monkeypatch):
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cache"))
 
 
-@pytest.mark.parametrize("cell", ["gat-fleet50k.train",
-                                  "sage-fleet100k.train"])
-def test_the_numbers_did_not_move(cell):
-    from benchmarks import limits, run
+@pytest.mark.parametrize("cell", CELLS)
+def test_the_numbers_did_not_move(cell, monkeypatch):
+    from benchmarks import compare, limits, run
 
-    ctx = run.run_cell(cell, 2**31 + 11, 0.3, False, rehearse=True,
-                       study=lambda ctx: ctx)["study"]
-    control = limits.in_program_place(ctx["reference"].readings(
-        ctx["spec"], ctx["arrays"], ctx["seed"], ctx["steps"],
-        precision="fp8"))
-    for program in (ctx["program"], control):
-        new = compare.numbers(program, ctx["followed"])
-        old = numbers_all_at_once(program, ctx["followed"])
+    Recording = recording(compare.Fold)
+    monkeypatch.setattr(compare, "Fold", Recording)
+
+    def control(ctx):
+        args = (ctx["spec"], ctx["arrays"], ctx["seed"], ctx["steps"])
+        placed = ctx["reference"].readings(
+            *args, limits.InProgramPlace(), precision="fp8")
+        ctx["reference"].readings(*args, Recording(placed.readings()))
+        return {}
+
+    run.run_cell(cell, 2**31 + 11, 0.3, False, rehearse=True, study=control)
+    sound, fp8 = Recording.made
+    for fold in (sound, fp8):
+        new = fold.numbers()
+        old = numbers_all_at_once(fold.program, fold.followed)
         assert list(new) == list(old)
         for name, (value, at) in old.items():
             assert new[name][1] == at, name
             assert new[name][0] == pytest.approx(value, rel=1e-12, abs=0), name
-    assert new["grad_diff_scaled"][0] > 3 * ctx["found"][
+    assert fp8.numbers()["grad_diff_scaled"][0] > 3 * sound.numbers()[
         "grad_diff_scaled"][0]
+
+
+def folded(program: dict, reference: dict) -> dict:
+    """Whole trees handed to ``compare.Fold`` in the order the reference
+    makes them; ``program`` is not changed."""
+    fold = compare.Fold(dict(program))
+    fold.initial(reference["params_before"])
+    fold.logit(reference["logit_grad"])
+    halves = reference["grads_first_half"]
+    for step, (loss, grads) in enumerate(zip(reference["losses"],
+                                             reference["grads"])):
+        if step < len(halves):
+            fold.half(step, halves[step])
+        fold.gradient(step, loss, grads)
+    fold.final(reference["params_after"], reference["params_before"])
+    return fold.numbers()
+
+
+def test_the_whole_trees_fold_as_they_come():
+    """Trees made up here, handed to the fold whole: a leaf of several
+    slices and a leaf of one element give the numbers of
+    ``numbers_all_at_once``."""
+    rng = np.random.default_rng(7)
+    shapes = {"w": (3, compare.SLICE + 5), "b": (), "v": (17,)}
+    tree = lambda scale=1.0: {  # noqa: E731
+        k: (scale * rng.standard_normal(s)).astype(np.float32)
+        for k, s in shapes.items()}
+    before = tree()
+    program = {"params_before": before, "params_after": {
+        k: v + 1e-3 * rng.standard_normal(v.shape).astype(np.float32)
+        for k, v in before.items()},
+        "moments": [tree(0.1), tree(0.1), tree(0.1)],
+        "losses": [1.0, 0.9, 0.8]}
+    reference = {"params_before": before, "grads": [tree(), tree(), tree()],
+                 "params_after": {k: v + 1e-3 for k, v in before.items()},
+                 "losses": [1.01, 0.91, 0.81], "logit_grad": tree(),
+                 "grads_first_half": [tree(), tree()]}
+    new = folded(program, reference)
+    old = numbers_all_at_once(program, reference)
+    assert list(new) == list(old)
+    for name, (value, at) in old.items():
+        assert new[name][1] == at, name
+        assert new[name][0] == pytest.approx(value, rel=1e-12, abs=0), name
+    assert len(program["moments"]) == 3

@@ -50,29 +50,25 @@ def test_forward_flops_of_a_token_by_part(spec):
 
 def test_pairs_with_and_without_a_window_by_hand(spec):
     from benchmarks.counts import laguna as counts
-    from benchmarks.runners.lfm2_moe import document_lengths
 
     tiny = dict(spec, batch=2, seq_len=8, sliding_window=3,
-                corpus=dict(spec["corpus"], tokens=32, documents=4, median=8,
-                            sigma=0.5, min=2, max=16))
-    lengths = [int(n) for n in document_lengths(tiny["corpus"])]
+                corpus=dict(spec["corpus"], tokens=32,
+                            document_lengths=[1, 2, 5]))
 
     def f(n, window):
         return sum(min(p + 1, window) for p in range(n))
 
-    def lost(n, window):
-        """Over a document's n places (the first cuts nothing)."""
-        return sum(f(n, window) - f(a, window) - f(n - a, window)
-                   for a in range(n))
-
     for window in (3, 10**9):
-        whole = sum(f(n, window) for n in lengths)
-        cut = 3 * sum(lost(n, window) for n in lengths) / 32
         got = counts.attention_pairs_per_step(
             tiny, None if window > 100 else window)
-        assert got == pytest.approx((whole - cut) / 2)
-    # Without a window the rule is lfm2's: L(L+1)/2 less (L² - 1)/6.
-    assert f(9, 10**9) == 45 and lost(9, 10**9) == 9 * 80 / 6
+        assert got == 2 * sum(f(n, window) for n in (1, 2, 5))
+    # 1 + 3 + 15 pairs a row without the window; the five-token
+    # document keeps 1 + 2 + 3 + 3 + 3 of its 15 within it.
+    assert counts.attention_pairs_per_step(tiny) == 2 * 19
+    assert counts.attention_pairs_per_step(tiny, 3) == 2 * (1 + 3 + 12)
+    # The cell's row: six documents of 116 to 4,651 tokens.
+    assert counts.attention_pairs_per_step(spec) == 4 * 12_848_339
+    assert counts.attention_pairs_per_step(spec, 512) == 4 * 3_511_928
 
 
 def test_the_steps_totals(spec):
@@ -104,34 +100,53 @@ def test_the_steps_totals(spec):
 
 @pytest.mark.parametrize("seed", [1, 2**31 + 7, 3000000019])
 def test_the_pair_counts_are_what_the_traffic_holds(spec, seed):
-    """The expected pairs of the counts against the pairs counted in a
-    seed's arrays: within 3%, and never above them by more than that (a
-    share of a roofline over 105% is refused)."""
+    """The pairs of the counts against the pairs counted in a seed's
+    arrays: equal, every step alike (a share of a roofline over 105% is
+    refused)."""
     from benchmarks.counts import laguna as counts
     from benchmarks.runners.laguna import traffic
 
-    steps = spec["corpus"]["tokens"] / counts.shapes(spec)["tokens"]
+    batch = counts.shapes(spec)["tokens"] // spec["seq_len"]
     positions = traffic(spec, seed)["positions"].astype(np.int64)
     # A token at position p of its document attends p + 1 keys, and
     # min(p + 1, window) of them in a sliding layer.
-    for window, held in ((None, (positions + 1).sum()),
-                         (512, np.minimum(positions + 1, 512).sum())):
-        expected = counts.attention_pairs_per_step(spec, window) * steps
-        assert expected == pytest.approx(held, rel=0.03)
-        assert expected <= 1.03 * held
+    for window, held in ((None, positions + 1),
+                         (512, np.minimum(positions + 1, 512))):
+        by_step = held.reshape(-1, batch, spec["seq_len"]).sum((1, 2))
+        assert (by_step == counts.attention_pairs_per_step(
+            spec, window)).all()
 
 
 def test_the_traffic_is_the_other_sequence_cells_over_the_rows_held(spec):
+    """The same seed, the same arrays; every row the same six documents
+    at the same places, ids from the other sequence cells' table, and so
+    the same tiles of the full layers' attention kept in every row."""
     from benchmarks.runners.laguna import traffic
+    from dragonfly2_tpu.models.seq_layers import (
+        ATTENTION_BLOCK,
+        document_tiles,
+    )
 
     a, b = traffic(spec, 2**31 + 7), traffic(spec, 2**31 + 7)
     assert all(np.array_equal(a[k], b[k]) for k in a)
     for name in ("tokens", "segments", "positions"):
         assert a[name].shape == (512, 8192) and a[name].dtype == np.int32
     assert a["tokens"].min() >= 0 and 8192 < a["tokens"].max() < 12_544
+    assert not np.array_equal(a["tokens"], traffic(spec, 2)["tokens"])
+    assert (a["positions"] == a["positions"][0]).all()
+    starts = np.flatnonzero(a["positions"][0] == 0)
+    assert np.diff(np.append(starts, 8192)).tolist() == [
+        116, 291, 532, 920, 1682, 4651]
+    # Ids differ by row, the layout does not.
+    assert (np.diff(a["segments"], axis=1) == np.diff(
+        a["segments"][:1], axis=1)).all()
+    kept = document_tiles(a["segments"], ATTENTION_BLOCK).sum((-1, -2))
+    assert (kept == 22).all()
     with open(os.path.join(ROOT, "benchmarks", "configs",
                            "lfm2-24b-a2b-ep8.json")) as fh:
-        assert json.load(fh)["corpus"] == spec["corpus"]
+        theirs = json.load(fh)["corpus"]
+    assert {k: spec["corpus"][k] for k in ("tokens", "exponent", "offset")
+            } == {k: theirs[k] for k in ("tokens", "exponent", "offset")}
 
 
 def _ctx(spec, counts, under):
